@@ -3,7 +3,7 @@
 # command and fails if DOTS_PASSED drops below the seed baseline, so test
 # regressions are caught mechanically instead of by eyeballing pytest output.
 #
-# Usage: scripts/check_tier1.sh [BASELINE] [--chaos] [--load]  (default baseline: 137)
+# Usage: scripts/check_tier1.sh [BASELINE] [--chaos] [--load]  (default baseline: 600)
 #
 #   --chaos   also run the fast chaos smoke stage (3-failpoint subset of
 #             scripts/chaos_sweep.py) after the test gate (ISSUE 2 satellite)
@@ -21,15 +21,21 @@
 # trace smoke gate (scripts/trace_smoke.py): a traced spheroid job through
 # the real service must emit a schema-valid, Perfetto-loadable trace that
 # trace_report.py renders.  Then the perf-sentinel self-check
-# (scripts/perf_sentinel.py): the committed BENCH_r*.json history must pass
-# against itself and a synthetic regression must trip the gate.
+# (scripts/perf_sentinel.py): the synthetic fixture history under
+# tests/data/perf_history must pass against itself and a synthetic
+# regression must trip the gate.
+#
+# Everything here runs on the CPU platform (jax/jaxlib 0.9.0); the chip
+# check is chip_smoke.py, which only passes on a TPU — its legs at 16x16 px
+# and its must-fail-off-chip contract are tests/test_chip_smoke.py.
 #
 # Exit codes: 0 = all gates pass, 1 = regression / gate failure.
-# Note: pytest's own exit code is nonzero while the 32 pre-existing
-# failures/6 errors remain, so the GATE is the dots count, not pytest's rc.
+# The GATE is the dots count, not pytest's rc: the suite was all green at
+# PR 21 (BASELINE below) except timing-sensitive tests that can flake on
+# a loaded host (tests/test_load_sweep.py).
 set -u -o pipefail
 
-BASELINE="137"
+BASELINE="600"
 RUN_CHAOS=0
 RUN_LOAD=0
 for arg in "$@"; do
@@ -249,9 +255,10 @@ if ! env JAX_PLATFORMS=cpu python scripts/load_sweep.py --elastic; then
 fi
 
 # perf-sentinel self-check (ISSUE 6): the regression gate itself is gated —
-# the newest committed BENCH_r*.json must pass against its own history AND
-# a synthetically degraded copy must trip the sentinel
-if ! env JAX_PLATFORMS=cpu python scripts/perf_sentinel.py --self-check; then
+# the newest artifact of the synthetic fixture history must pass against it
+# AND a synthetically degraded copy must trip the sentinel
+if ! env JAX_PLATFORMS=cpu python scripts/perf_sentinel.py --self-check \
+        --history 'tests/data/perf_history/bench_*.json'; then
     echo "check_tier1: FAIL — perf sentinel self-check failed" >&2
     exit 1
 fi

@@ -51,12 +51,9 @@ os.environ["XLA_FLAGS"] = " ".join(_flags)
 REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 from scripts.load_sweep import Harness, _msg, build_fixtures  # noqa: E402
 from sm_distributed_tpu.analysis import retrace, surface  # noqa: E402
+from sm_distributed_tpu.parallel.distributed import clear_compile_cache  # noqa: E402
 
 N_DEVICES = 2
 
@@ -99,7 +96,9 @@ def run(work: Path) -> int:
     })
     retrace.enable()   # harness init already bound the service metrics
     try:
-        # ---- phase 1: first job = the cold surface
+        # ---- phase 1: first job = the cold surface (cleared cache, so
+        # every executable is a compile event, not a cache load)
+        clear_compile_cache(h.sm_config)
         retrace.reset()
         status, _hd, body = h.submit(_msg(fx, "fast", "census1"))
         if status != 202:

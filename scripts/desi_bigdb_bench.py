@@ -8,7 +8,7 @@ default bench's ``desi`` case runs the same pixel count at 500 formulas;
 the cold-path script runs the same DB at 100x100 px; this is the first
 measurement that combines both axes, which is where the HBM plan (pre-run
 estimate ~2.2 GB resident peaks + per-batch band scratch; the measured run
-came to 1.95 GB after window-union restriction — docs/PERF.md), the sticky
+came to 1.95 GB after window-union restriction — PERF.md), the sticky
 band-bucket ladder over ~6.5k batches, and sustained-stream throughput
 actually get stressed.
 
@@ -67,8 +67,7 @@ def run(*, n_formulas: int, nrows: int, ncols: int, decoy_sample_size: int,
                     "store_images": False},
         "work_dir": str(work_dir),
         "parallel": {"formula_batch": formula_batch,
-                     "checkpoint_every": checkpoint_every,
-                     "compile_cache_dir": str(cache_dir / "xla_cache")},
+                     "checkpoint_every": checkpoint_every},
     })
     ds_config = DSConfig.from_dict({
         "isotope_generation": {"adducts": ["+H"]},

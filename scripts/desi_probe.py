@@ -62,8 +62,7 @@ def build(formula_batch=256, nrows=512, ncols=512, n_formulas=500):
                for s in range(0, table.n_ions, b)]
     sm_config = SMConfig.from_dict(
         {"backend": "jax_tpu", "fdr": {"decoy_sample_size": 20},
-         "parallel": {"formula_batch": formula_batch,
-                      "compile_cache_dir": str(cache_dir / "xla_cache")}})
+         "parallel": {"formula_batch": formula_batch}})
     backend = make_backend("jax_tpu", ds, ds_config, sm_config, table=table)
     return ds, table, batches, backend
 

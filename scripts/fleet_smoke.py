@@ -22,8 +22,8 @@ Three phases, all through real service stacks:
    job's trace, and ``trace_report.py --by-replica`` must attribute
    that device time to the serving replica.
 3. **Measured-roofline pins.**  The newest committed ``PROFILE_r*.json``
-   artifact (the CPU-recorded profiled-roofline history — BENCH_r*.json
-   stays TPU/driver-recorded) must carry non-null
+   artifact (the CPU-recorded profiled-roofline history: a CPU smoke
+   artifact, not a device measurement) must carry non-null
    ``measured_roofline_frac`` / ``kernel_time_frac``, and a degraded
    replay must trip the perf-sentinel band on BOTH fields (regress-down
    direction).
@@ -382,8 +382,7 @@ def phase_profile(work: Path) -> int:
         # force the fused Pallas scoring kernel (interpret mode off-TPU):
         # the capture must attribute device time to it BY NAME
         "parallel": {"formula_batch": 4, "checkpoint_every": 1,
-                     "fused_metrics": "on",
-                     "compile_cache_dir": str(base / "xla_cache")},
+                     "fused_metrics": "on"},
     })
     try:
         def submit(i: int) -> str:
@@ -486,11 +485,8 @@ def phase_roofline_pins() -> int:
     from scripts import perf_sentinel as ps
 
     # PROFILE_r*.json is the CPU-recorded profiled-roofline history (its
-    # own namespace, like ANALYSIS_r*/NUMERICS_r*): the BENCH_r*.json
-    # entries are driver-recorded on TPU, and a CPU smoke artifact mixed
-    # into that history would wreck the throughput medians the perf
-    # sentinel self-check replays.  TPU-recorded BENCH entries gain the
-    # same keys from bench.py and band through the normal --fresh path.
+    # own namespace, like ANALYSIS_r*/NUMERICS_r*): a CPU smoke artifact,
+    # never a device measurement.
     hist = sorted(REPO_ROOT.glob("PROFILE_r*.json"))
     if not hist:
         return fail("no committed PROFILE_r*.json history")

@@ -52,9 +52,6 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import pandas as pd  # noqa: E402
 
 from scripts.chaos_sweep import _debris  # noqa: E402

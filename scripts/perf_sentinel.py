@@ -1,15 +1,16 @@
 #!/usr/bin/env python
-"""Perf-regression sentinel over the committed bench history (ISSUE 6).
+"""Perf-regression sentinel over a history of artifacts (ISSUE 6).
 
-The BENCH_r01→r05 trajectory (2,040 → 44,184 ions/s) is guarded by nothing:
-a PR that halves throughput or triples compile time ships unless a human
+A PR that halves throughput or triples compile time ships unless a human
 happens to eyeball the JSON.  This tool makes the measurement discipline
 mechanical:
 
-- **history** = the committed ``BENCH_r*.json`` artifacts (the driver
-  wrapper ``{"parsed": {...}}`` or a bare ``bench.py`` JSON line both
-  load); a ``trace_report.py --json`` summary is also understood, so a
-  service-level trace artifact can be sentineled against prior traces;
+- **history** = a glob of earlier artifacts of one kind (``--history``):
+  ``bench.py`` JSON lines (bare, or in a ``{"parsed": {...}}`` wrapper), a
+  ``trace_report.py --json`` summary, or the ``ANALYSIS_r*`` /
+  ``NUMERICS_r*`` / ``PROFILE_r*`` series this repo commits.  Device
+  numbers have no committed history here: the driver's
+  ``PERF_LEDGER.jsonl`` is their record;
 - **fresh** = one new artifact of either kind;
 - each comparable metric (headline/scale/desi ions/s, ``compile_s``,
   ``isocalc_s``, the pinned per-phase splits, trace phase/accounting
@@ -26,14 +27,15 @@ mechanical:
 ``--self-check`` proves the sentinel fires: the newest history artifact is
 replayed as an honest fresh run (must pass), then synthetically degraded by
 ``2 x tolerance`` in the bad direction (must flag regressions).  Wired into
-``scripts/check_tier1.sh``.
+``scripts/check_tier1.sh`` over the synthetic fixture history under
+``tests/data/perf_history/``.
 
 Usage::
 
-    python scripts/perf_sentinel.py --fresh out.json            # vs BENCH_r*.json
     python scripts/perf_sentinel.py --history 'runs/*.json' --fresh out.json
-    python scripts/perf_sentinel.py --fresh trace_summary.json --tolerance 0.4
-    python scripts/perf_sentinel.py --self-check
+    python scripts/perf_sentinel.py --history 'traces/*.json' \
+        --fresh trace_summary.json --tolerance 0.4
+    python scripts/perf_sentinel.py --history 'runs/*.json' --self-check
 """
 
 from __future__ import annotations
@@ -45,7 +47,6 @@ import sys
 from pathlib import Path
 from statistics import median
 
-REPO_ROOT = Path(__file__).resolve().parent.parent
 
 # bench-case keys, direction: "up" = higher is better (regression when the
 # fresh value drops), "down" = lower is better (regression when it rises)
@@ -87,7 +88,7 @@ def load_artifact(path: str | Path) -> dict:
     """A bench JSON (bare or driver-wrapped) or trace_report summary."""
     data = json.loads(Path(path).read_text())
     if isinstance(data, dict) and isinstance(data.get("parsed"), dict):
-        data = data["parsed"]           # BENCH_r*.json driver wrapper
+        data = data["parsed"]           # a driver's wrapper
     if not isinstance(data, dict):
         raise ValueError(f"{path}: artifact is not a JSON object")
     return data
@@ -277,9 +278,8 @@ def self_check(history_paths: list[str], tolerance: float, min_history: int,
 
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--history", default=None,
-                    help="glob of history artifacts (default: the repo's "
-                         "committed BENCH_r*.json)")
+    ap.add_argument("--history", required=True,
+                    help="glob of history artifacts")
     ap.add_argument("--fresh", default=None,
                     help="the fresh bench.py / trace_report.py --json "
                          "artifact to judge")
@@ -300,8 +300,7 @@ def main(argv: list[str] | None = None) -> int:
                          "the CI gate's gate")
     args = ap.parse_args(argv)
 
-    pattern = args.history or str(REPO_ROOT / "BENCH_r*.json")
-    history_paths = sorted(glob.glob(pattern))
+    history_paths = sorted(glob.glob(args.history))
     if args.self_check:
         if args.fresh:
             ap.error("--self-check takes no --fresh artifact")

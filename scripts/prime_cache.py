@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Offline AOT cache priming (ISSUE 13, docs/PERF.md "Cold start").
+"""Offline AOT cache priming (ISSUE 13).
 
 Reads the shape-bucket lattice manifest (``bucket_manifest.json`` next to
 the persistent XLA cache — written by the jax backends as traffic records
@@ -14,7 +14,7 @@ entries are environment-keyed).
 Usage::
 
     python scripts/prime_cache.py --sm-config conf/config.json
-    python scripts/prime_cache.py --work-dir /srv/sm --force
+    JAX_COMPILATION_CACHE_DIR=/srv/sm/xla python scripts/prime_cache.py --force
     python scripts/prime_cache.py --spec '{"kind":"flat", ...}'  # ad hoc
 
 Prints ONE JSON summary line on stdout ({known, compiled, skipped,
@@ -38,9 +38,6 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(prog="prime_cache")
     ap.add_argument("--sm-config", default=None,
                     help="SMConfig json (default: env/default resolution)")
-    ap.add_argument("--work-dir", default=None,
-                    help="override work_dir (the default cache lives at "
-                         "<work_dir>/xla_cache)")
     ap.add_argument("--force", action="store_true",
                     help="re-prime specs the prime manifest already marks "
                          "primed for this environment")
@@ -55,10 +52,6 @@ def main(argv: list[str] | None = None) -> int:
     init_logger()
     sm = (SMConfig.set_path(args.sm_config) if args.sm_config
           else SMConfig.get_conf())
-    if args.work_dir:
-        import dataclasses
-
-        sm = dataclasses.replace(sm, work_dir=args.work_dir)
 
     from sm_distributed_tpu.ops import buckets
     from sm_distributed_tpu.parallel.distributed import compile_cache_path

@@ -1,6 +1,6 @@
 """Roofline probe for the fused score graph (ISSUE 3 satellite).
 
-Replaces the per-phase-probe basis of docs/PERF.md's "no headroom left"
+Replaces the per-phase-probe basis of PERF.md's "no headroom left"
 claim with a measured ROOFLINE statement: the fused extract+score stream is
 timed against this device's own measured peaks (reduction/copy bandwidth,
 f32 matmul throughput) and the engine's minimum-work cost model
@@ -131,8 +131,7 @@ def main() -> None:
          "fdr": {"decoy_sample_size": args.decoy_sample_size},
          "parallel": {"formula_batch": args.formula_batch,
                       "fused_metrics": args.fused,
-                      "cube_dtype": args.cube_dtype,
-                      "compile_cache_dir": str(cache_dir / "xla_cache")}})
+                      "cube_dtype": args.cube_dtype}})
     backend = make_backend("jax_tpu", ds, prep["ds_config"], sm_config,
                            table=table)
     batches = prep["batches"]

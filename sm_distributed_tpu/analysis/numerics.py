@@ -197,3 +197,31 @@ def component_drift(got: np.ndarray, want: np.ndarray) -> dict[str, int]:
             f"vs {want.shape}")
     return {comp: max_ulp(got[:, i], want[:, i])
             for i, comp in enumerate(COMPONENTS)}
+
+
+def component_report(got: np.ndarray, want: np.ndarray) -> dict[str, dict]:
+    """Per-MSM-component comparison of two (N, 4) metric blocks against
+    :data:`COMPONENT_CONTRACTS`, at sizes where relative ulps alone mislead:
+    ``{comp: {max_ulp, max_abs, outside}}``.
+
+    A component is a quantity in [0, 1].  Near 1 its ulp(N) ceiling is an
+    absolute ``N * 2**-24`` (the "1e-6-grade" bound); near 0 a correlation
+    is a cancelling sum over every pixel, so its *relative* error grows
+    without bound while its absolute error — what the MSM product and an
+    FDR rank see — stays at the f32 floor (on the v5e at 65,536 px:
+    hundreds of ulps on values ~1e-4, absolute error ~1e-9).  An ion is
+    ``outside`` when it exceeds the ceiling in ulps AND in absolute terms;
+    a ``bit_exact`` (0) component has no absolute allowance at all."""
+    got = np.asarray(got)
+    want = np.asarray(want)
+    out = {}
+    for i, comp in enumerate(COMPONENTS):
+        ulps = ulp_distance(got[:, i], want[:, i])
+        err = np.abs(got[:, i].astype(np.float32).astype(np.float64)
+                     - want[:, i].astype(np.float32).astype(np.float64))
+        ceiling = COMPONENT_CONTRACTS[comp]
+        outside = (ulps > ceiling) & (err > ceiling * 2.0 ** -24)
+        out[comp] = {"max_ulp": int(ulps.max()) if ulps.size else 0,
+                     "max_abs": float(err.max()) if err.size else 0.0,
+                     "outside": int(outside.sum())}
+    return out

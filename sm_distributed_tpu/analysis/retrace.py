@@ -19,9 +19,9 @@ compile-cache miss — and, per compile:
   ``compile`` trace event onto the ambient job trace (so a cold-start
   compile shows up INSIDE the job that paid for it).
 
-The listener cannot be unregistered in this jax version, so ``enable()``
-registers exactly once per process and ``disable()`` just de-activates;
-both are idempotent.  A listener fault must never fail a compile: the
+``enable()`` registers the listeners exactly once per process and
+``disable()`` just de-activates them (cheaper than an unregister/register
+cycle per test); both are idempotent.  A listener fault must never fail a compile: the
 handler catches everything and logs once per process.
 
 ``scripts/compile_census.py`` drives a real service with this tracer on
@@ -41,7 +41,7 @@ from ..utils.logger import logger
 
 # the jax monitoring event fired once per backend-compile REQUEST.  It
 # wraps ``compile_or_get_cached``, so it fires on persistent-cache HITS
-# too (jax 0.4.x) — the hit is announced by a separate cache-hits event
+# too (checked on jax 0.9) — the hit is announced by a separate cache-hits event
 # just before the duration event lands on the same thread, which is how
 # the listener below tells a real compile from a cache load (ISSUE 13:
 # a primed cache must show up as loads, not compiles).
@@ -272,9 +272,9 @@ def _on_event_duration(name: str, duration: float, **_kw) -> None:
 
 
 def enable(metrics=None) -> None:
-    """Start attributing compiles.  Idempotent; the jax listener is
-    registered once per process (this jax version has no unregister), so
-    repeated enable/disable cycles only flip the active flag.  ``metrics``
+    """Start attributing compiles.  Idempotent; the jax listeners are
+    registered once per process, so repeated enable/disable cycles only
+    flip the active flag.  ``metrics``
     (a service MetricsRegistry) rebinds the ``sm_compile_*`` export —
     the latest caller wins, matching the oom/breaker attach pattern."""
     global _active, _registered, _metrics

@@ -834,9 +834,6 @@ def _jit_sites(mod):
         if callee in _JIT_CALLEES:
             kind = "jit"
         elif callee == "shard_map":
-            fn = mod.enclosing_function(node)
-            if fn is not None and fn.name == "shard_map":
-                continue              # the version-compat shim itself
             kind = "shard_map"
         elif callee == "partial" and node.args and \
                 _attr_chain(node.args[0]).split(".")[-1] in _JIT_CALLEES:

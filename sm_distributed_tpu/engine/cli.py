@@ -185,7 +185,8 @@ def cmd_serve(args) -> int:
         child_conf = write_child_config(sm_config, sm_config.work_dir)
         controller = FleetController(
             args.queue_dir, sm_config.service.fleet, sm_config.service,
-            spawn=serve_spawn(args.queue_dir, child_conf),
+            spawn=serve_spawn(args.queue_dir, child_conf,
+                              backend=sm_config.backend),
             signals=service_signals(service), metrics=service.metrics,
             self_replica_id=sm_config.service.replica_id)
         controller.start()

@@ -1,7 +1,7 @@
 """Device-backend circuit breaker (ISSUE 4 degradation layer; per-chip
 labels since ISSUE 14).
 
-A flaky device backend — preempted TPU, dying tunnel, XLA launch failures —
+A flaky device backend — preempted TPU, XLA launch failures —
 used to be retried forever by the scheduler's failure policy, burning every
 attempt of every job on the same broken path.  The breaker wraps the device
 scoring seam in ``MSMBasicSearch._score_and_rank``:

@@ -107,7 +107,7 @@ def maybe_order_table(table: IsotopePatternTable, order_ions: str,
     locality won +20% at 6 batches (65k px) and 8.3x at 41 batches
     (262k px), but lost 17% at 3 batches where there is no locality to win
     and ordering spreads the blob-heavy target images' chaos cost across
-    every batch (docs/PERF.md ledger)."""
+    every batch (PERF.md ledger)."""
     if order_ions == "mz":
         return order_table_by_mz(table)
     if order_ions == "table":
@@ -629,11 +629,14 @@ class MSMBasicSearch:
         invisible in the results.
 
         Every non-cancel exception routes through the ONE fault taxonomy
-        (models/faults.py, ISSUE 14).  HBM ``RESOURCE_EXHAUSTED`` is a
+        (models/faults.py, ISSUE 14).  A *program* fault (raised while
+        tracing, lowering or compiling — a Mosaic refusal, a kernel past
+        its scoped VMEM, a removed API) fails the job untouched by any of
+        the machinery below.  HBM ``RESOURCE_EXHAUSTED`` is a
         *sizing* signal: the batch halves and the group rescores in place,
         the breaker never counts it, and the converged size is remembered
         so the next job on this shape starts there.  A *transient* fault
-        (collective timeout, dying tunnel) fails the attempt into the
+        (collective timeout, connection reset) fails the attempt into the
         retry policy — same chip, backoff, no breaker count, no
         quarantine.  A *sticky* fault reports the lease chips to the
         health tracker (quarantine / probe attribution) AND counts on the
@@ -669,6 +672,21 @@ class MSMBasicSearch:
                 if not (on_device or injected):
                     raise             # a host-backend bug is not a device fault
                 kind = faults.classify(exc)
+                if kind == faults.FAULT_PROGRAM:
+                    # raised while tracing, lowering or compiling: the
+                    # program is wrong for this installation or shape, the
+                    # chip is fine.  Fail the job with the compiler's
+                    # words — counting it on the breaker would end in a
+                    # numpy degrade that reports `done` with the device
+                    # unused, and batch backoff would hunt for a size at
+                    # which a broken kernel happens to compile
+                    logger.error(
+                        "program fault while scoring (not a device fault: "
+                        "no breaker count, no degrade, no backoff): "
+                        "%s: %s", type(exc).__name__, exc)
+                    tracing.event("program_fault",
+                                  error=f"{type(exc).__name__}: {exc}"[:300])
+                    raise
                 if kind == faults.FAULT_OOM:
                     new_cap = self._oom_backoff(backend, slices, oom_cap, exc)
                     if not new_cap:

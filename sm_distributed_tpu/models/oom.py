@@ -9,9 +9,10 @@ scoring seam (``models/msm_basic.py::MSMBasicSearch._score_group``) the
 vocabulary to treat OOM as what it is:
 
 - :func:`is_oom_error` — recognizes the allocator's failure shapes
-  (``XlaRuntimeError: RESOURCE_EXHAUSTED``, "out of memory" texts, and
+  (``JaxRuntimeError: RESOURCE_EXHAUSTED``, "out of memory" texts, and
   plain ``MemoryError`` — which the ``backend.device_error`` failpoint can
-  inject deterministically);
+  inject deterministically) and tells them from a Pallas kernel that
+  overflows its scoped VMEM, which comes back under the same status;
 - the **safe-batch registry** — after a backoff converges, the proven-safe
   batch size is recorded per :func:`shape_key` (dataset shape × backend ×
   device lease), so the NEXT job on the same shape starts at the size that
@@ -32,22 +33,39 @@ import threading
 from ..utils import tracing
 from ..utils.logger import logger
 
-# substrings that mark an exception as accelerator memory exhaustion; the
-# XLA client raises XlaRuntimeError("RESOURCE_EXHAUSTED: Out of memory
-# while trying to allocate ..."), older jaxlibs RuntimeError with the same
-# text.  MemoryError is the host-side (and failpoint-injectable) shape.
+# substrings that mark an exception as accelerator memory exhaustion: the
+# XLA client raises JaxRuntimeError("RESOURCE_EXHAUSTED: Out of memory
+# while trying to allocate ...") at run time, and the TPU compiler's static
+# HBM plan refuses an oversized program under the same status.  MemoryError
+# is the host-side (and failpoint-injectable) shape.
 _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory",
                 "Resource exhausted")
+# ...unless the exhausted space is a kernel's ON-CHIP scratch.  Mosaic
+# reports a Pallas kernel that overflows scoped VMEM/SMEM as
+# "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... exceeded
+# scoped vmem limit" (libtpu 0.0.34, verbatim in tests/test_oom.py).  That
+# is a kernel that does not fit, not a batch that does not fit HBM:
+# halving the batch until the kernel happens to compile would hide it.
+_ON_CHIP_MARKERS = ("memory space vmem", "memory space smem", "scoped vmem",
+                    "vmem limit", "smem limit")
+
+
+def is_kernel_scratch_error(exc: BaseException) -> bool:
+    """A Pallas kernel that does not fit its scoped VMEM/SMEM — a program
+    fault (``models/faults.py``), whatever status it comes back under."""
+    text = str(exc).lower()
+    return any(m in text for m in _ON_CHIP_MARKERS)
 
 
 def is_oom_error(exc: BaseException) -> bool:
-    """Is this exception a memory-exhaustion signal (device or host)?
-    Deliberately string-based for the XLA shapes: the concrete exception
-    class moved across jaxlib versions, but the status text has not."""
+    """Is this exception an HBM/host memory-exhaustion signal?  String-
+    based for the XLA shapes: the status text is the stable part of a
+    JaxRuntimeError.  On-chip kernel-scratch exhaustion is NOT one."""
     if isinstance(exc, MemoryError):
         return True
-    text = str(exc)
-    return any(m in text for m in _OOM_MARKERS)
+    if is_kernel_scratch_error(exc):
+        return False
+    return any(m in str(exc) for m in _OOM_MARKERS)
 
 
 def shape_key(n_pixels: int, backend: str, device_indices=None) -> str:

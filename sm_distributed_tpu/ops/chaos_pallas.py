@@ -76,6 +76,17 @@ COMPILE_SURFACE = compile_surface(__name__, {
 _BIG = np.int32(2**30)
 
 
+def _level_fracs(nlevels: int) -> np.ndarray:
+    """The oracle's threshold fractions ``f32(i) / f32(nlevels)``, divided
+    on the HOST.  The kernels look them up instead of dividing a loop index
+    at run time: a run-time ``x / 30.0`` is a reciprocal multiply on the TPU
+    (and after XLA's strength reduction on CPU), one ulp off numpy's true
+    division for many of the levels — enough to flip a pixel that sits
+    on a threshold, which integer-grid images do all the time (seen on the
+    v5e: 3,091,698 vs scipy's 3,091,699 components at 1024x1024)."""
+    return np.arange(nlevels, dtype=np.float32) / np.float32(nlevels)
+
+
 def _shift(x: jnp.ndarray, d: int, axis: int, reverse: bool, fill) -> jnp.ndarray:
     """Non-circular shift by static d (fill at the exposed edge)."""
     n = x.shape[axis]
@@ -105,8 +116,8 @@ def _seg_min_scan(v: jnp.ndarray, o: jnp.ndarray, axis: int, reverse: bool,
     return v
 
 
-def _chaos_kernel(img_ref, vmax_ref, out_ref, *, ncols: int, nlevels: int,
-                  lean: bool = False, work_span: int = 0):
+def _chaos_kernel(frac_ref, img_ref, vmax_ref, out_ref, *, ncols: int,
+                  nlevels: int, lean: bool = False, work_span: int = 0):
     """One program: IB images of shape (R, ncols) packed as (R, IB*ncols).
 
     ``lean``: rematerialize the mask/open-flag arrays inside every sweep
@@ -135,9 +146,9 @@ def _chaos_kernel(img_ref, vmax_ref, out_ref, *, ncols: int, nlevels: int,
         # the structure, cutting sweeps-to-fixpoint on the dense low levels.
         acc, prev_lab = carry
         li = nlevels - 1 - li_rev
-        # threshold grid identical to the oracle: vmax * li/nlevels,
-        # f32 arithmetic (li/nlevels rounds exactly as arange/nlevels)
-        thr = vmax * (li.astype(jnp.float32) / np.float32(nlevels))
+        # threshold grid identical to the oracle: vmax * (li/nlevels), the
+        # fraction from the host-divided table, ONE f32 multiply here
+        thr = vmax * frac_ref[li]
         mask = img > thr
 
         def flags():
@@ -287,12 +298,13 @@ def chaos_count_sums(
         out_shape=jax.ShapeDtypeStruct((1, n_pad * cp), jnp.int32),
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((rp, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
         interpret=interpret,
-    )(img_l, vmax_l)
+    )(_level_fracs(nlevels), img_l, vmax_l)
     # per-image count sum: reduce each image's cp lanes
     return counts.reshape(n_pad, cp).sum(axis=1)[:n].astype(jnp.float32)
 
@@ -325,9 +337,10 @@ def chaos_count_sums(
 # ---------------------------------------------------------------------------
 
 
-def _chaos_strip_kernel(smax_ref, img_ref, out_ref, lab_hbm, img_vmem,
-                        lab_vmem, sems, *, ncols: int, nrows_pad: int,
-                        strip_rows: int, nlevels: int, work_span: int):
+def _chaos_strip_kernel(smax_ref, thr_ref, img_ref, out_ref, lab_hbm,
+                        img_vmem, lab_vmem, sems, *, ncols: int,
+                        nrows_pad: int, strip_rows: int, nlevels: int,
+                        work_span: int):
     """One program: one image, (nrows_pad + 2*_HALO, ncols) in HBM."""
     pid = pl.program_id(0)
     n_strips = nrows_pad // strip_rows
@@ -336,7 +349,6 @@ def _chaos_strip_kernel(smax_ref, img_ref, out_ref, lab_hbm, img_vmem,
     lrow = lax.broadcasted_iota(jnp.int32, shape, 0)
     col = lax.broadcasted_iota(jnp.int32, shape, 1)
     core = (lrow >= _HALO) & (lrow < _HALO + strip_rows)
-    vmax = smax_ref[pid, n_strips]
 
     def load_strip(s, *, want_img: bool):
         r0 = pl.multiple_of(s * strip_rows, 8)
@@ -383,7 +395,7 @@ def _chaos_strip_kernel(smax_ref, img_ref, out_ref, lab_hbm, img_vmem,
 
     def level_body(li_rev, acc):
         li = nlevels - 1 - li_rev                     # descending thresholds
-        thr = vmax * (li.astype(jnp.float32) / np.float32(nlevels))
+        thr = thr_ref[pid, li]                        # vmax * (li/nlevels)
 
         def visit(s):
             """Returns True when the strip's core labels changed (written)."""
@@ -510,8 +522,9 @@ def chaos_count_sums_strips(
         jnp.maximum(principal.reshape(n, nrows, ncols), 0.0))
     body = img[:, _HALO:_HALO + rp, :]
     smax = body.reshape(n, n_strips, strip * cp).max(axis=2)   # (N, S)
-    vmax = smax.max(axis=1, keepdims=True)                     # (N, 1)
-    smax_v = jnp.concatenate([smax, vmax], axis=1)             # (N, S+1)
+    # the oracle's threshold grid, one f32 multiply per (image, level) by
+    # the host-divided fractions; the kernel only looks thresholds up
+    thr = smax.max(axis=1, keepdims=True) * _level_fracs(nlevels)[None, :]
 
     counts, _labels = pl.pallas_call(
         functools.partial(_chaos_strip_kernel, ncols=cp, nrows_pad=rp,
@@ -527,16 +540,18 @@ def chaos_count_sums_strips(
         in_specs=[
             # whole-array SMEM block (scalars): TPU lowering forbids partial
             # blocks that aren't 8x128-aligned, so index by program id
-            pl.BlockSpec((n, n_strips + 1), lambda i: (0, 0),
+            pl.BlockSpec((n, n_strips), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((n, nlevels), lambda i: (0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
             # whole-array SMEM out block (scalar per program) for the same
             # TPU alignment reason; each program writes its own row
             pl.BlockSpec((n, 1), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ),
         scratch_shapes=[
             pltpu.VMEM((strip + 2 * _HALO, cp), jnp.float32),
@@ -544,5 +559,5 @@ def chaos_count_sums_strips(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(smax_v, img)
+    )(smax, thr, img)
     return counts.reshape(n).astype(jnp.float32)

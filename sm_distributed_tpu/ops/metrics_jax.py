@@ -107,6 +107,27 @@ def _cc_count(mask_flat: jnp.ndarray, nrows: int, ncols: int) -> jnp.ndarray:
     return jnp.sum((labels == iota) & m)
 
 
+def refine_quotient(q: jnp.ndarray, a: jnp.ndarray,
+                    b: jnp.ndarray) -> jnp.ndarray:
+    """One correction step that turns an APPROXIMATE f32 quotient ``q`` of
+    ``a / b`` (a device divide: the TPU's is reciprocal-based and can land
+    a unit in the last place off numpy's) into the correctly rounded one.  The residual ``a - q*b`` is formed exactly
+    with Dekker's split product — only f32 add/sub/mul, which the VPU
+    rounds like IEEE — so ``q + r/b`` needs ``r/b`` to a few bits only.
+    A no-op where the divide is already correctly rounded (XLA-CPU)."""
+    split = np.float32(4097.0)                   # 2**12 + 1
+
+    def halves(x):
+        t = split * x
+        hi = t - (t - x)
+        return hi, x - hi
+
+    qh, ql = halves(q)
+    bh, bl = halves(b)
+    r = (((a - qh * bh) - qh * bl) - ql * bh) - ql * bl
+    return q + r / b
+
+
 def measure_of_chaos_batch(
     principal: jnp.ndarray,   # (N, n_pix) f32, n_pix == nrows*ncols
     nrows: int,
@@ -177,13 +198,12 @@ def measure_of_chaos_batch(
     # ONE division by a runtime denominator: "count_sums / nlevels" would let
     # XLA strength-reduce the constant divisor into a reciprocal multiply
     # (different rounding than numpy's true division — observed 1-ulp chaos
-    # drift); nlevels * n_notnull is exact in f32 (< 2**24).  On CPU this
-    # makes chaos bit-identical to the oracle; the TPU VPU's division is
-    # itself reciprocal-based (not correctly rounded), so on TPU chaos can
-    # still sit 1 ulp off — FDR ranks/levels remain exactly identical (the
-    # north-star criterion; verified on-chip in round 2)
+    # drift); nlevels * n_notnull is exact in f32 (< 2**24).  The TPU's
+    # divide is itself reciprocal-based (the v5e put chaos 1 ulp off the
+    # oracle, PERF.md PR 21), hence the refinement: chaos is bit-identical
+    # to the oracle on every platform
     denom = (nlevels * jnp.maximum(n_notnull, 1)).astype(jnp.float32)
-    chaos = 1.0 - count_sums / denom
+    chaos = 1.0 - refine_quotient(count_sums / denom, count_sums, denom)
     chaos = jnp.clip(chaos, 0.0, 1.0)
     return jnp.where((vmax > 0) & (n_notnull > 0), chaos, 0.0)
 

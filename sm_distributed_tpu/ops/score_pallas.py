@@ -6,7 +6,7 @@ band slice -> membership matmul -> materialized (B*K, P) image block ->
 moments kernel -> metric epilogues.  The image block round-trips HBM
 between the matmul and the moments pass — at DESI shapes that is ~1 GB
 written and ~1 GB re-read per 256-ion batch that the roofline ledger
-(docs/PERF.md) charges to pure memory traffic.
+(``fused_score_cost_model``) charges to pure memory traffic.
 
 This kernel fuses the band matmul WITH the moment reductions so each image
 tile lives only in VMEM: grid ``(C, 2, nt)`` — C m/z-sorted window chunks
@@ -71,12 +71,19 @@ COMPILE_SURFACE = compile_surface(__name__, {
 # are fetched as nsb super-rows of SC grid rows and the <SC-row residual
 # start offset becomes an in-kernel rank shift.
 SC = 8
-# VMEM budget in f32 cells for one grid step's resident set (band tile +
-# membership + image tile + partials) — same scoped-VMEM envelope as
-# ops/moments_pallas._MAX_CELLS.
-_MAX_CELLS = 2 * 1024 * 1024
+# VMEM accounting for one grid step, in bytes.  Pallas double-buffers every
+# pipelined operand, and Mosaic keeps the kernel body's big values in VMEM
+# too: the membership matrix costs ~4 (Wc, rows) arrays (rank iota, the f32
+# matrix and its bf16 splits for the HIGHEST-precision dot) and each pass
+# ~3 (Wc, pt) image-sized values.  `step_vmem_bytes` prices all of them; it
+# sat 10-20% above what Mosaic reported for every shape compiled during
+# bring-up (PERF.md, PR 21 findings).  v5e's default scoped-VMEM limit is 16 MiB
+# of the core's 128 MiB, so the call raises it explicitly.
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+_VMEM_BUDGET_BYTES = 40 * 1024 * 1024
 # pixel-tile ladder (lanes): largest dividing tile wins
 _PT_LADDER = (4096, 2048, 1024, 512, 256, 128)
+_LANES = 128
 
 
 def n_super_blocks(gc_width: int) -> int:
@@ -95,22 +102,28 @@ def cols_padded(g: int, gc_width: int) -> int:
     return -(-base // SC) * SC + (n_super_blocks(gc_width) - 1) * SC
 
 
+def step_vmem_bytes(pt: int, wc: int, ipc: int, gc_width: int) -> int:
+    """VMEM one (chunk, pass, tile) step holds at pixel tile ``pt``."""
+    rows = n_super_blocks(gc_width) * SC
+    rows_l = -(-rows // _LANES) * _LANES
+    cells = (2 * rows * pt          # staged band tile, double-buffered
+             + 2 * ipc * pt         # principal output block
+             + 6 * wc * _LANES      # lo/hi columns + partials, lane-padded
+             + 4 * wc * rows_l      # membership matrix and its temporaries
+             + 3 * wc * pt)         # image tile, centered tile, a product
+    return 4 * cells
+
+
 def pick_tile(n_pix: int, wc: int, ipc: int, gc_width: int):
     """Largest pixel tile (multiple of 128 dividing n_pix) whose resident
-    set fits the VMEM budget, or None when none fits / n_pix is off the
-    128-lane lattice (the caller then keeps the unfused path)."""
-    if n_pix <= 0 or n_pix % 128 != 0:
+    set fits the VMEM budget, or None when none fits, n_pix is off the
+    128-lane lattice or the per-peak row groups are not whole sublane
+    tiles (the caller then keeps the unfused path)."""
+    if n_pix <= 0 or n_pix % _LANES != 0 or ipc % SC != 0:
         return None
-    rows = n_super_blocks(gc_width) * SC
     for pt in _PT_LADDER:
-        if n_pix % pt != 0:
-            continue
-        cells = (rows * pt          # staged band tile
-                 + wc * rows        # membership matrix
-                 + wc * pt          # image tile
-                 + ipc * pt         # principal output block
-                 + wc * 5)          # partials block
-        if cells <= _MAX_CELLS:
+        if n_pix % pt == 0 and step_vmem_bytes(
+                pt, wc, ipc, gc_width) <= _VMEM_BUDGET_BYTES:
             return pt
     return None
 
@@ -132,6 +145,14 @@ def _fused_kernel(starts_ref, s3_ref, nr_ref, wh_ref, rlo_ref, rhi_ref,
     Pallas accumulation pattern.  Principal rows are written on BOTH
     passes (bit-identical values) so every visited output block is fully
     defined.
+
+    Window rows arrive PEAK-MAJOR (row ``j * ipc + i`` = peak j of the
+    chunk's ion i; the wrapper permutes the tiny rank-bound arrays), so
+    the principal rows and every per-peak group are contiguous
+    sublane-aligned row slices — Mosaic has no cheap sublane-strided
+    ``reshape(ipc, k, pt)[:, 0]``.  Every value stays 2-D, bounds arrive as
+    (Wc, 1) columns, and the five moment columns are assembled with lane
+    selects: a 5-lane ``stack`` or a lane->sublane relayout does not lower.
     """
     ps = pl.program_id(1)
     t = pl.program_id(2)
@@ -143,14 +164,15 @@ def _fused_kernel(starts_ref, s3_ref, nr_ref, wh_ref, rlo_ref, rhi_ref,
     shift = starts_ref[c] - s3_ref[c] * SC
 
     band = wh_ref[...]                                    # (nsb*SC, pt)
-    lo = rlo_ref[0, :] + shift                            # (Wc,)
-    hi = rhi_ref[0, :] + shift
+    lo = rlo_ref[0] + shift                               # (Wc, 1)
+    hi = rhi_ref[0] + shift
     gg = jax.lax.broadcasted_iota(jnp.int32, (wc, rows), 1)
-    d = ((gg > lo[:, None]) & (gg <= hi[:, None])).astype(jnp.float32)
+    d = ((gg > lo) & (gg <= hi)).astype(jnp.float32)
     # integer-grid sums < 2**24: exact in f32 at HIGHEST in any order
     imgs = jnp.dot(d, band, precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)    # (Wc, pt)
-    prin_ref[0] = imgs.reshape(ipc, k, pt)[:, 0, :]
+    prin_ref[0] = imgs[:ipc]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (wc, 5), 1)
 
     @pl.when((ps == 0) & (t == 0))
     def _init():
@@ -162,11 +184,14 @@ def _fused_kernel(starts_ref, s3_ref, nr_ref, wh_ref, rlo_ref, rhi_ref,
         # pad pixel columns are exact zeros (pad peaks scatter 0.0), so
         # sums/vmax/nn need no n_real mask — same argument as the masked
         # jnp moments (images >= 0: window sums of nonnegative intensity)
-        sums = acc[:, 0] + jnp.sum(imgs, axis=1)
-        vmax = jnp.maximum(acc[:, 3], jnp.max(imgs, axis=1))
-        nn = acc[:, 4] + jnp.sum((imgs > 0.0).astype(jnp.float32), axis=1)
-        out_ref[0] = jnp.stack([sums, acc[:, 1], acc[:, 2], vmax, nn],
-                               axis=1)
+        sums = jnp.sum(imgs, axis=1, keepdims=True)       # (Wc, 1)
+        vmax = jnp.max(imgs, axis=1, keepdims=True)
+        nn = jnp.sum((imgs > 0.0).astype(jnp.float32), axis=1,
+                     keepdims=True)
+        out_ref[0] = jnp.where(
+            lane == 0, acc + sums,
+            jnp.where(lane == 3, jnp.maximum(acc, vmax),
+                      jnp.where(lane == 4, acc + nn, acc)))
 
     @pl.when(ps == 1)
     def _pass1():
@@ -175,12 +200,14 @@ def _fused_kernel(starts_ref, s3_ref, nr_ref, wh_ref, rlo_ref, rhi_ref,
         mean = acc[:, 0:1] / nre.astype(jnp.float32)      # (Wc, 1)
         col = jax.lax.broadcasted_iota(jnp.int32, (wc, pt), 1) + t * pt
         cent = jnp.where(col < nre, imgs - mean, 0.0)
-        c3 = cent.reshape(ipc, k, pt)
-        dots = jnp.sum(c3 * c3[:, 0:1, :], axis=2).reshape(wc)
-        normsq = jnp.sum(cent * cent, axis=1)
-        out_ref[0] = jnp.stack(
-            [acc[:, 0], acc[:, 1] + normsq, acc[:, 2] + dots,
-             acc[:, 3], acc[:, 4]], axis=1)
+        c0 = cent[:ipc]                                   # principal rows
+        dots = jnp.concatenate(
+            [jnp.sum(cent[j * ipc:(j + 1) * ipc] * c0, axis=1,
+                     keepdims=True) for j in range(k)], axis=0)
+        normsq = jnp.sum(cent * cent, axis=1, keepdims=True)
+        out_ref[0] = jnp.where(
+            lane == 1, acc + normsq,
+            jnp.where(lane == 2, acc + dots, acc))
 
 
 @partial(jax.jit, static_argnames=("gc_width", "k", "interpret"))
@@ -198,7 +225,7 @@ def fused_window_moments(whp, starts, r_lo_loc, r_hi_loc, n_real, *,
         the lattice-padded grid; pads past it are masked out of the
         centered reductions exactly like the masked moments kernel.
       gc_width / k: static band width and isotope-peak count.
-      interpret: run the Pallas interpreter (CPU fallback / tests).
+      interpret: run the Pallas interpreter (CPU tests only).
 
     Returns:
       partials: (C, Wc, 5) f32 — columns (sums, normsq, dots, vmax, nn)
@@ -228,19 +255,22 @@ def fused_window_moments(whp, starts, r_lo_loc, r_hi_loc, n_real, *,
     s3 = jnp.minimum(starts // SC, np.int32(cols_p // SC - nsb))
     nr = jnp.reshape(jnp.asarray(n_real, jnp.int32), (1,))
 
+    def peak_major(r):
+        # (C, ipc*k) ion-major -> (C, k*ipc, 1) peak-major columns
+        return r.reshape(C, ipc, k).transpose(0, 2, 1).reshape(C, wc, 1)
+
     # the band start is data-dependent (scalar-prefetched), so the
-    # histogram operand uses ELEMENT-offset (Unblocked) indexing: row
-    # offset s3*SC is sublane-aligned, column offset t*pt lane-aligned
+    # histogram operand uses ELEMENT-offset indexing (all dims or none):
+    # row offset s3*SC is sublane-aligned, column offset t*pt lane-aligned
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # starts, s3, n_real
         grid=(C, 2, nt),
         in_specs=[
-            pl.BlockSpec((nsb * SC, pt),
+            pl.BlockSpec((pl.Element(nsb * SC), pl.Element(pt)),
                          lambda c, ps, t, starts, s3, nr:
-                         (s3[c] * SC, t * pt),
-                         indexing_mode=pl.unblocked),
-            pl.BlockSpec((1, wc), lambda c, ps, t, *_: (c, 0)),
-            pl.BlockSpec((1, wc), lambda c, ps, t, *_: (c, 0)),
+                         (s3[c] * SC, t * pt)),
+            pl.BlockSpec((1, wc, 1), lambda c, ps, t, *_: (c, 0, 0)),
+            pl.BlockSpec((1, wc, 1), lambda c, ps, t, *_: (c, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, wc, 5), lambda c, ps, t, *_: (c, 0, 0)),
@@ -254,6 +284,14 @@ def fused_window_moments(whp, starts, r_lo_loc, r_hi_loc, n_real, *,
             jax.ShapeDtypeStruct((C, wc, 5), jnp.float32),
             jax.ShapeDtypeStruct((C, ipc, n_pix), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            # the partials block accumulates across (pass, tile) and
+            # chunks share nothing, but one TensorCore runs them in order
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(starts, s3, nr, whp, r_lo_loc, r_hi_loc)
+    )(starts, s3, nr, whp, peak_major(r_lo_loc), peak_major(r_hi_loc))
+    # back to the plan's ion-major window order
+    partials = partials.reshape(C, k, ipc, 5).transpose(0, 2, 1, 3).reshape(
+        C, wc, 5)
     return partials, principal

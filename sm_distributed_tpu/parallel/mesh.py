@@ -28,31 +28,6 @@ PIXELS_AXIS = "pixels"
 FORMULAS_AXIS = "formulas"
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compatible ``shard_map`` (ISSUE 7 satellite).
-
-    jax >= 0.6 exposes ``jax.shard_map`` with the VMA type-system knob
-    ``check_vma``; the 0.4.x line only ships
-    ``jax.experimental.shard_map.shard_map`` whose equivalent knob is
-    ``check_rep``.  Every mesh-sharded program in this repo goes through
-    this one seam so the rest of parallel/ never has to care which jax is
-    installed.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # transitional releases: jax.shard_map exists but still takes
-            # the old replication-check keyword
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def resolve_axis_sizes(n_devices: int, cfg: ParallelConfig) -> tuple[int, int]:
     """(pixels, formulas) axis sizes using exactly their product <= n_devices.
 

@@ -70,7 +70,7 @@ from ..utils import tracing
 from ..ops.quantize import expand_cube_jnp, quantize_window
 from ..utils.config import DSConfig, SMConfig
 from ..utils.logger import logger
-from .mesh import FORMULAS_AXIS, PIXELS_AXIS, make_mesh, shard_map
+from .mesh import FORMULAS_AXIS, PIXELS_AXIS, make_mesh
 
 # Declared compile surface (ISSUE 12, analysis/surface.py): the sharded
 # step's statics ride in through make()'s partial closure, so the whole
@@ -187,7 +187,7 @@ def build_sharded_score_factory(
     def make(gc_width, n_keep=0, w_cap=0):
         from functools import partial
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             partial(step, gc_width=gc_width, n_keep=n_keep, w_cap=w_cap),
             mesh=mesh,
             in_specs=(
@@ -563,6 +563,7 @@ class ShardedJaxBackend:
             rd = np.zeros((n_px, f), np.int32)
             nb = np.zeros((n_px, f), np.int32)
         key = (gc, n_keep, w_cap)
+        tracing.event("batch_variant", variant=variant, b=int(self.batch))
         if key not in self._fns:
             self._fns[key] = self._make_fn(gc, n_keep, w_cap)
         pos_d = jax.device_put(pos, self._pos_sharding)
@@ -664,7 +665,7 @@ class ShardedJaxBackend:
                 n_pixels=p_loc)
 
         if not hasattr(self, "_extract_fn"):
-            self._extract_fn = jax.jit(shard_map(
+            self._extract_fn = jax.jit(jax.shard_map(
                 step,
                 mesh=self.mesh,
                 in_specs=(

@@ -48,7 +48,9 @@ RabbitMQ's management UI):
 - ``GET /debug/devices``  the chip-level device-pool view (ISSUE 14):
   per-chip health state + fault strikes + quarantine evidence
   (``service/health.py``), lease holders, probe/quarantine/readmit/
-  host-eviction totals, and per-chip breaker states;
+  host-eviction totals, per-chip breaker states, and the ``runtime``
+  identity (platform, device kind/count, jax/jaxlib/libtpu versions) of
+  the process that holds the chips;
 - ``GET /fleet/metrics`` / ``GET /fleet/slo`` / ``GET /fleet/status``
   the fleet observability plane (ISSUE 20, ``service/fleetview.py``):
   every live replica's exposition merged into one pane (counters summed,
@@ -594,15 +596,19 @@ class AdminAPI:
         view: per-chip health (``ok``/``suspect``/``quarantined`` with
         fault strikes, quarantine reason and timestamp), current lease
         holders, per-host occupancy, probe/quarantine/readmit/eviction
-        totals (``service/health.py``), and every per-chip circuit
-        breaker's state (``models/breaker.py``)."""
+        totals (``service/health.py``), every per-chip circuit
+        breaker's state (``models/breaker.py``), and under ``runtime`` the
+        platform, device kind/count and jax/jaxlib/libtpu versions of the
+        process that holds the chips (``utils/devicemem.py``)."""
         pool = getattr(self.service, "device_pool", None)
         if pool is None:
             return 404, {"error": "device pool not configured",
                          "reason": "not_found"}
         from ..models.breaker import breakers_snapshot
+        from ..utils.devicemem import runtime_identity
 
-        return 200, {**pool.snapshot(), "breakers": breakers_snapshot()}
+        return 200, {**pool.snapshot(), "breakers": breakers_snapshot(),
+                     "runtime": runtime_identity()}
 
     def _resources(self) -> tuple[int, dict]:
         """``GET /debug/resources`` — the resource governor's snapshot

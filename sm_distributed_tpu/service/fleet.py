@@ -610,16 +610,46 @@ class FleetController:
 
 
 # --------------------------------------------------------------- spawn glue
+class ChipsBusyError(OSError):
+    """A replica was not spawned because it could not have had a chip."""
+
+
+def child_chip_conflict(backend: str) -> str | None:
+    """Why a spawned ``serve`` replica cannot get a chip on this host, or
+    ``None`` when it can.  A TPU chip belongs to one process at a time and
+    a JAX process opens EVERY local chip, so once this process runs the
+    ``jax_tpu`` backend on the TPU platform there are no free chips for a
+    child: it would hang or die opening them.  CPU platforms (CI fleets)
+    share freely."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if backend != "jax_tpu" or jax is None or jax.default_backend() != "tpu":
+        return None
+    n = jax.local_device_count()
+    return (f"this process holds all {n} local TPU chip(s) and a chip "
+            "belongs to one process at a time: 0 free chips for a spawned "
+            "replica, which would hang or die opening them — run one "
+            "`serve` per host and let its device pool pack the chips")
+
+
 def serve_spawn(queue_dir: str | Path, sm_config_path: str | Path,
-                extra_args: tuple = (), env: dict | None = None):
+                extra_args: tuple = (), env: dict | None = None,
+                backend: str = ""):
     """Production spawn factory: each replica is a full ``serve`` process
     over the shared spool under its own identity, with an ephemeral admin
     port (the parent already owns the configured one) and its own fleet
-    controller DISABLED (exactly one controller per fleet)."""
+    controller DISABLED (exactly one controller per fleet).  ``backend``
+    is the replicas' scoring backend: a spawn that could not get a chip
+    (``child_chip_conflict``) is refused with ``ChipsBusyError``, which the
+    controller logs and counts as a failed spawn."""
     import os
     import sys
 
     def _spawn(rid: str, host: str = "") -> subprocess.Popen:
+        why = child_chip_conflict(backend)
+        if why is not None:
+            raise ChipsBusyError(why)
         cmd = [sys.executable, "-m", "sm_distributed_tpu.engine.cli",
                "serve", str(queue_dir), "--sm-config", str(sm_config_path),
                "--replica-id", rid, "--port", "0", *extra_args]

@@ -22,11 +22,8 @@ device health as pool state; this module is that state:
   tiny device round-trip — ``jax.device_put`` onto the chip + host
   readback — following the ``utils/devicemem`` import-light convention
   (no-op when jax was never imported, or for simulated chips beyond the
-  visible device count).  Deliberately COMPILE-FREE: jax initializes its
-  persistent compilation cache at most once per process, so a jitted
-  probe running before the first backend's ``enable_compile_cache`` would
-  latch the cache off service-wide (the compile-census gate catches
-  exactly this).  A probe failure at grant time quarantines the chip
+  visible device count).  Compile-free, so a lease never waits on XLA.
+  A probe failure at grant time quarantines the chip
   before the job ever touches it and the pool re-grants from the
   survivors;
 - **quarantined chips are excluded from grants** (``DevicePool`` treats
@@ -84,9 +81,7 @@ def _device_probe(chip: int) -> tuple[bool, str]:
     The probe is a DMA round-trip, not a kernel launch: ``device_put``
     onto the chip, sync, read the bytes back on host.  A wedged/fenced
     chip fails its transfers just like its launches, and a compile-free
-    probe can never initialize XLA's once-per-process persistent
-    compilation cache before the backends configure it (see module
-    docstring)."""
+    probe never makes a lease wait on XLA."""
     failpoint(FP_DEVICE_PROBE)
     jax = sys.modules.get("jax")
     if jax is None:

@@ -19,7 +19,7 @@ import threading
 from bisect import bisect_left
 
 # Default buckets span the service's realities: sub-ms fake jobs in tests up
-# through multi-hour whole-slide searches (docs/PERF.md: 32 min DESI jobs).
+# through multi-hour whole-slide searches (PERF.md: 32 min DESI jobs).
 DEFAULT_BUCKETS = (
     0.001, 0.005, 0.025, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0, 600.0, 3600.0,
 )

@@ -5,7 +5,7 @@ lattice (``ops/buckets.py``) makes it CLOSED — every dataset size maps
 into a finite set of executables identified by recorded ``BucketSpec``s.
 This module walks that set and compiles it into the persistent XLA cache
 **before traffic arrives**, so a cold submit loads executables from disk
-instead of paying 40–120 s of XLA compile (BENCH_r05 cold numbers):
+instead of paying the cold XLA compile:
 
 - :func:`prime_spec` AOT-compiles ONE spec: it rebuilds the exact jitted
   program a real backend would construct (``models/msm_jax.make_flat_jits``

@@ -129,6 +129,14 @@ class DeviceMonitor:
             "Backend warmups by persistent-cache outcome (hit = manifest "
             "proved warm, executions skipped)", ("result",))
 
+        self.c_interpret = m.counter(
+            "sm_pallas_interpret_total",
+            "Scoring programs traced with a Pallas kernel in interpret "
+            "mode (CPU test vehicle; must stay 0 on an accelerator)")
+        # pulled at SCRAPE time: a scrape right after a job must already
+        # count what that job did
+        m.add_collector(self._collect_backend_events)
+
     # ------------------------------------------------------------- sampling
     def _cache_stats(self) -> tuple[int | None, int | None]:
         """(entry count, total bytes) of the persistent XLA cache, or
@@ -209,7 +217,6 @@ class DeviceMonitor:
                     entries > self._prev_cache_entries:
                 self.c_cache_miss.inc(entries - self._prev_cache_entries)
             self._prev_cache_entries = entries
-        self._collect_warmup_events()
 
         # pod identity (ISSUE 17): samples from different host processes
         # interleave in shared dashboards — stamp which process took each
@@ -289,16 +296,19 @@ class DeviceMonitor:
             self._ring.append(snap)
         return snap
 
-    def _collect_warmup_events(self) -> None:
-        """Pull warmup cache hit/miss counts from the jax backend module —
-        lazily, ONLY if it was ever imported (a CPU-only service never pays
-        for it).  Counters move by delta, same as the residency collector."""
+    def _collect_backend_events(self, _registry=None) -> None:
+        """Pull warmup cache hit/miss and interpret-mode trace counts from
+        the jax backend module — lazily, ONLY if it was ever imported (a
+        CPU-only service never pays for it).  Counters move by delta, same
+        as the residency collector."""
+        interp = self.c_interpret.labels()       # exposed from the start
         mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
-        if mod is None or not hasattr(mod, "warmup_cache_events"):
+        if mod is None:
             return
         for result, count in mod.warmup_cache_events().items():
             child = self.c_warmup_cache.labels(result=result)
             child.inc(max(0.0, count - child.value))
+        interp.inc(max(0.0, mod.pallas_interpret_events() - interp.value))
 
     def timeseries(self, n: int | None = None) -> list[dict]:
         with self._lock:

@@ -115,7 +115,7 @@ class ParallelConfig:
     formulas_axis: int = 1               # mesh axis sharding the formula dimension
     # ions scored per fused-graph invocation: 2048 balances histogram-
     # scatter amortization against padding waste (measured sweep on v5e,
-    # docs/PERF.md); batches pad to this so small jobs may prefer less
+    # PERF.md); batches pad to this so small jobs may prefer less
     formula_batch: int = 2048
     mz_chunk: int = 0                    # 0 = no m/z chunking inside the kernel
     # per-batch peak compaction on the flat path: histogram only the peaks
@@ -155,9 +155,9 @@ class ParallelConfig:
     # formula batches; 0 disables.  A killed multi-hour search (BASELINE
     # configs #3/#5) resumes from the last complete group.
     checkpoint_every: int = 0
-    # persistent XLA compilation cache: "" = <work_dir>/xla_cache (repeat
-    # datasets with the same shapes skip the ~15-20s TPU compile entirely),
-    # "off" = disabled, anything else = explicit directory
+    # persistent XLA compilation cache: "" = on, "off" = disabled.  WHERE it
+    # lives is not a config value: $JAX_COMPILATION_CACHE_DIR when set,
+    # else <checkout>/.cache/xla_cache (parallel/distributed.py)
     compile_cache_dir: str = ""
     # --- isotope-pattern cold path (ops/isocalc.py, docs/ISOCALC.md) ---
     # process-pool size for cold pattern generation: 0 = all cores
@@ -294,7 +294,7 @@ class FleetConfig:
 @dataclass(frozen=True)
 class PrimeConfig:
     """Ahead-of-time XLA cache priming (ISSUE 13, service/primer.py,
-    docs/PERF.md "Cold start"): a scheduler-idle background thread AOT-
+    PERF.md "Cold start"): a scheduler-idle background thread AOT-
     compiles the recorded (config, bucket, lease-shape) lattice into the
     persistent compilation cache, so a cold submit loads executables from
     disk instead of paying the compile.  ``GET /debug/compile`` reports
@@ -754,6 +754,7 @@ class SMConfig:
                             ("peak_compaction", ("auto", "on", "off")),
                             ("isocalc_device", ("on", "off")),
                             ("overlap_isocalc", ("auto", "on", "off")),
+                            ("compile_cache_dir", ("", "off")),
                             ("cube_dtype", ("f32", "bf16", "int8")),
                             ("fused_metrics", ("auto", "on", "off"))):
             v = getattr(self.parallel, knob)

@@ -112,3 +112,28 @@ def hbm_peak_bytes() -> int | None:
     peaks = [d["peak_bytes"] for d in device_stats()
              if d["peak_bytes"] is not None]
     return max(peaks) if peaks else None
+
+
+def runtime_identity() -> dict:
+    """What JAX runs on in THIS process, as JAX reports it: ``platform`` /
+    ``device_kind`` of the first device, ``device_count``, and the
+    installed jax / jaxlib / libtpu versions.  ``platform`` is
+    ``None`` when jax was never imported here — same import-light rule as
+    ``device_stats``.  ``GET /debug/devices`` serves it, so a driver that
+    must stay off the chip (``chip_smoke.py``) can ask the process that
+    holds it."""
+    from importlib import metadata
+
+    versions = {}
+    for dist in ("jax", "jaxlib", "libtpu"):
+        try:
+            versions[dist] = metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            versions[dist] = None
+    per = device_stats()
+    return {
+        "platform": per[0]["platform"] if per else None,
+        "device_kind": per[0]["kind"] if per else None,
+        "device_count": len(per),
+        "versions": versions,
+    }

@@ -183,12 +183,19 @@ def test_oom_shrunk_batch_lands_on_lattice(offgrid_ds):
 
 def test_masked_moments_match_unpadded():
     """batch_moments with trailing zero padding + traced n_real returns
-    the unpadded moments bit-for-bit (jnp fallback AND the masked Pallas
-    kernel in interpret mode)."""
+    the unpadded moments: bit-for-bit on the jnp fallback, and inside the
+    declared ulp contract on the masked Pallas kernel (interpret mode)."""
     import jax.numpy as jnp
 
+    from sm_distributed_tpu.analysis.numerics import (
+        contract_ulps,
+        max_ulp,
+        parse_policy,
+    )
     from sm_distributed_tpu.ops.moments_pallas import (
+        NUMERICS,
         batch_moments_jnp,
+        batch_moments_pallas,
         batch_moments_pallas_masked,
     )
 
@@ -201,16 +208,20 @@ def test_masked_moments_match_unpadded():
     got = batch_moments_jnp(jnp.asarray(padded), n_real=jnp.int32(128))
     for a, b in zip(want, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the masked kernel against ITS unpadded program (the unmasked kernel
+    # on the unpadded block), inside the contract the kernel declares
+    ceiling = contract_ulps(
+        parse_policy(NUMERICS["batch_moments_pallas_masked"])["contract"])
+    want_pl = batch_moments_pallas(jnp.asarray(imgs), interpret=True)
     got_pl = batch_moments_pallas_masked(
         jnp.asarray(padded), jnp.int32(128), interpret=True)
-    for a, b in zip(want, got_pl):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
+    for a, b in zip(want_pl, got_pl):
+        assert max_ulp(np.asarray(a), np.asarray(b)) <= ceiling
 
 
 # ------------------------------------------------------------------- primer
 @pytest.fixture()
-def recorded_backend(offgrid_ds, tmp_path):
+def recorded_backend(offgrid_ds, tmp_path, isolated_compile_cache):
     """A scored backend with an isolated cache dir, so the bucket
     manifest + prime manifest live under tmp_path."""
     from sm_distributed_tpu.models.msm_jax import JaxBackend
@@ -221,8 +232,7 @@ def recorded_backend(offgrid_ds, tmp_path):
     dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]}})
     sm = SMConfig.from_dict(
         {"backend": "jax_tpu", "work_dir": str(tmp_path / "work"),
-         "parallel": {"formula_batch": 8,
-                      "compile_cache_dir": str(tmp_path / "xla")}})
+         "parallel": {"formula_batch": 8}})
     b = JaxBackend(ds, dc, sm)
     _score_all(b, table, 8)
     yield sm, tmp_path
@@ -271,7 +281,8 @@ def test_primer_yields_to_real_work(recorded_backend):
     assert res["compiled"] == 0
 
 
-def test_warmup_manifest_rekeyed_on_buckets(offgrid_ds, tmp_path):
+def test_warmup_manifest_rekeyed_on_buckets(offgrid_ds, tmp_path,
+                                            isolated_compile_cache):
     """ISSUE 13 satellite: the warmup manifest keys on BUCKET ids, so a
     cache warmed by one dataset size is recognized as warm for another
     size in the same bucket — no redundant representative executions."""
@@ -292,8 +303,7 @@ def test_warmup_manifest_rekeyed_on_buckets(offgrid_ds, tmp_path):
     dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]}})
     sm = SMConfig.from_dict(
         {"backend": "jax_tpu", "work_dir": str(tmp_path / "work"),
-         "parallel": {"formula_batch": 8,
-                      "compile_cache_dir": str(tmp_path / "xla")}})
+         "parallel": {"formula_batch": 8}})
     b1 = JaxBackend(ds, dc, sm)
     b1.warmup(batches)
     assert not b1.last_warmup_skipped

@@ -182,7 +182,8 @@ def test_scheduler_retry_releases_excluding_quarantined(tmp_path):
 
 
 # ------------------------------------------------- primer sharded support
-def test_primer_compiles_recorded_sharded_spec(tmp_path):
+def test_primer_compiles_recorded_sharded_spec(tmp_path,
+                                               isolated_compile_cache):
     """ISSUE 14 satellite (the PR 13 follow-up): a recorded mesh-shaped
     spec AOT-compiles through prime_spec — including a shrunken-mesh
     topology — and hosts without enough devices skip gracefully."""
@@ -204,8 +205,7 @@ def test_primer_compiles_recorded_sharded_spec(tmp_path):
     dsc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]}})
     sm = SMConfig.from_dict(
         {"backend": "jax_tpu", "fdr": {"decoy_sample_size": 2, "seed": 1},
-         "parallel": {"formula_batch": 8, "overlap_isocalc": "off",
-                      "compile_cache_dir": str(tmp_path / "cache")},
+         "parallel": {"formula_batch": 8, "overlap_isocalc": "off"},
          "work_dir": str(tmp_path / "work")})
     iso = IsocalcWrapper(dsc.isotope_generation, cache_dir=None)
     pairs = [(f, "+H") for f in truth.formulas[:4]]

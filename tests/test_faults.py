@@ -34,14 +34,110 @@ def test_classification_matrix():
     assert faults.classify(RuntimeError(
         "UNAVAILABLE: socket closed")) == faults.FAULT_TRANSIENT
     assert faults.classify(OSError(
-        "device tunnel died: connection reset")) == faults.FAULT_TRANSIENT
+        "device link died: connection reset")) == faults.FAULT_TRANSIENT
     # everything else at the device seam is sticky
     assert faults.classify(RuntimeError(
         "INTERNAL: failed to enqueue program")) == faults.FAULT_STICKY
     assert faults.classify(RuntimeError(
         "injected failpoint backend.chip_fault (hit 1)")) == \
         faults.FAULT_STICKY
-    assert faults.classify(ValueError("bad shape")) == faults.FAULT_STICKY
+
+
+# the compiler's own words for a Pallas kernel past its scoped VMEM
+# (libtpu 0.0.34, AOT-compiled for a v5e during bring-up)
+VMEM_COMPILE_ERROR = (
+    "RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem while "
+    "allocating on stack for %_lambda_.1 = f32[4096,4096]{1,0:T(8,128)} "
+    "custom-call(%a_0_.1), custom_call_target=\"tpu_custom_call\". Scoped "
+    "allocation with size 128.00M and limit 16.00M exceeded scoped vmem "
+    "limit by 112.00M. It should not be possible to run out of scoped vmem")
+
+
+def test_program_faults_are_not_device_faults():
+    """Raised while tracing, lowering or compiling: the program is wrong,
+    the chip is fine — never oom, transient or sticky."""
+
+    class LoweringException(Exception):     # Pallas' class, matched by name
+        pass
+
+    for exc in (
+            AttributeError("module 'jax.experimental.pallas' has no "
+                           "attribute 'unblocked'"),
+            TypeError("BlockSpec.__init__() got an unexpected keyword "
+                      "argument"),
+            ValueError("bad shape"),
+            NotImplementedError("All block dimensions must be Elements or "
+                                "none of them can be Elements."),
+            LoweringException("Block shape (1, 512) must be divisible by "
+                              "(8, 128)"),
+            RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                         "unsupported operand layout"),
+            RuntimeError(VMEM_COMPILE_ERROR),
+            # a compiler refusal may quote any status text
+            RuntimeError("Mosaic failed to compile: all-reduce ABORTED")):
+        assert faults.classify(exc) == faults.FAULT_PROGRAM, exc
+
+
+def test_program_fault_fails_job_without_breaker_or_degrade(
+        tmp_path, monkeypatch):
+    """A lowering/compile exception on the scoring path fails the job: no
+    breaker count (threshold 1 would have opened), no numpy degrade, no
+    health report, no batch backoff."""
+    from sm_distributed_tpu.io.dataset import SpectralDataset
+    from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset
+    from sm_distributed_tpu.models import msm_jax, oom
+    from sm_distributed_tpu.models.msm_basic import MSMBasicSearch
+    from sm_distributed_tpu.service.metrics import MetricsRegistry
+    from sm_distributed_tpu.utils.config import DSConfig, SMConfig
+
+    path, truth = generate_synthetic_dataset(
+        tmp_path / "ds", nrows=8, ncols=8, formulas=None,
+        present_fraction=0.5, noise_peaks=30, seed=11)
+    ds = SpectralDataset.from_imzml(path)
+    ds_config = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]}})
+    sm = SMConfig.from_dict(
+        {"backend": "jax_tpu", "fdr": {"decoy_sample_size": 2, "seed": 1},
+         "parallel": {"formula_batch": 8, "overlap_isocalc": "off",
+                      "pixels_axis": 1, "formulas_axis": 1},
+         "service": {"breaker_threshold": 1},
+         "work_dir": str(tmp_path / "work")})
+    metrics = MetricsRegistry()
+    breaker_mod.attach_metrics(metrics)
+    oom.attach_metrics(metrics)
+
+    class Sink:
+        faults = []
+
+        def report_fault(self, devices, kind, error):
+            self.faults.append(kind)
+
+        def report_ok(self, devices):
+            pass
+
+    faults.set_fault_listener(Sink())
+
+    def score(self, tables, cancel=None):
+        raise AttributeError("module 'jax.experimental.pallas' has no "
+                             "attribute 'unblocked'")
+
+    for exc_text, raiser in (("unblocked", score),
+                             ("scoped vmem", None)):
+        if raiser is None:
+            def raiser(self, tables, cancel=None):
+                raise RuntimeError(VMEM_COMPILE_ERROR)
+        monkeypatch.setattr(msm_jax.JaxBackend, "score_batches", raiser)
+        search = MSMBasicSearch(ds, truth.formulas[:4], ds_config, sm,
+                                device_indices=(0,))
+        with pytest.raises((AttributeError, RuntimeError), match=exc_text):
+            search.search()
+        assert search.last_backend.name == "jax_tpu"
+        assert breaker_mod.get_device_breaker(devices=(0,)).state == "closed"
+    text = metrics.expose()
+    assert "sm_breaker_degraded_total 1" not in text
+    assert metrics.value("sm_breaker_degraded_total") in (None, 0.0)
+    assert metrics.value("sm_oom_events_total") == 0.0
+    assert oom.snapshot()["events"] == 0 and not oom.snapshot()["safe_batches"]
+    assert Sink.faults == []
 
 
 def test_transient_xla_error_does_not_feed_breaker(tmp_path):

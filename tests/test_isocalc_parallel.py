@@ -270,7 +270,8 @@ def test_rate_collector_derives_scrape_rate():
     assert float(line.split()[-1]) > 0
 
 
-def test_warmup_manifest_skips_second_process(small_search_setup, tmp_path):
+def test_warmup_manifest_skips_second_process(small_search_setup,
+                                              isolated_compile_cache):
     """Warm-start trim: a second backend over the same stream + persistent
     cache skips the representative-batch executions, and still scores
     identically."""
@@ -286,8 +287,7 @@ def test_warmup_manifest_skips_second_process(small_search_setup, tmp_path):
          # 1x1 mesh: the test targets JaxBackend.warmup; conftest forces 8
          # virtual host devices, which would route to the sharded backend
          "parallel": {"formula_batch": 16, "pixels_axis": 1,
-                      "formulas_axis": 1,
-                      "compile_cache_dir": str(tmp_path / "xla")}})
+                      "formulas_axis": 1}})
     table = IsocalcWrapper(ds_cfg.isotope_generation).pattern_table(
         [(sf, "+H") for sf in truth.formulas])
     batches = [_slice_table(table, s, min(s + 16, table.n_ions))

@@ -30,8 +30,23 @@ def test_is_oom_classification():
         "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
         "2147483648 bytes"))
     assert oom.is_oom_error(Exception("XlaRuntimeError: Resource exhausted"))
-    assert not oom.is_oom_error(RuntimeError("device tunnel died"))
+    assert not oom.is_oom_error(RuntimeError("device link died"))
     assert not oom.is_oom_error(ValueError("bad shape"))
+    # the TPU compiler's static HBM plan refusing a program is still OOM...
+    assert oom.is_oom_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 20.10G of 15.75G hbm."))
+
+
+def test_vmem_exhaustion_is_not_an_oom_backoff():
+    """...but a Pallas kernel past its scoped VMEM comes back under the
+    same status and is NOT a sizing signal: halving the batch until the
+    kernel happens to compile would hide a kernel that does not fit."""
+    from test_faults import VMEM_COMPILE_ERROR
+
+    assert not oom.is_oom_error(RuntimeError(VMEM_COMPILE_ERROR))
+    assert not oom.is_oom_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Ran out of memory in memory space smem"))
 
 
 def test_safe_batch_registry_roundtrip():

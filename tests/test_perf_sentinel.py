@@ -3,7 +3,7 @@
 Covers artifact loading (driver wrapper vs bare bench line vs trace_report
 summary), the median-band comparison in both directions, the noise floors
 (min-seconds, min-history), the nothing-comparable guard, and the
-self-check mode against the repo's committed BENCH history.
+self-check mode against the synthetic fixture history under tests/data.
 """
 
 from __future__ import annotations
@@ -145,11 +145,17 @@ def test_degrade_flips_both_directions():
 
 
 # ---------------------------------------------------------------- self-check
-def test_self_check_against_committed_history():
-    """The real CI gate: the repo's own BENCH_r*.json must self-check."""
-    assert perf_sentinel.main(["--self-check"]) == 0
-    assert sorted(REPO_ROOT.glob("BENCH_r*.json")), \
-        "committed history disappeared"
+FIXTURE_HISTORY = REPO_ROOT / "tests" / "data" / "perf_history"
+
+
+def test_self_check_against_fixture_history():
+    """The real CI gate (check_tier1.sh runs the same command): the
+    synthetic fixture history must self-check."""
+    assert sorted(FIXTURE_HISTORY.glob("bench_*.json")), \
+        "fixture history disappeared"
+    assert perf_sentinel.main(
+        ["--self-check", "--history",
+         str(FIXTURE_HISTORY / "bench_*.json")]) == 0
 
 
 def test_self_check_fails_without_history(tmp_path):

@@ -150,9 +150,8 @@ def test_jit_compile_surface_statics_drift_and_dead_entry():
     assert "dead entry" in msgs
 
 
-def test_jit_compile_surface_policy_grammar_and_shard_map_shim():
-    # missing buckets= clause fires; the mesh shim's internal jax.shard_map
-    # forwarding calls are exempt (enclosing function named shard_map)
+def test_jit_compile_surface_policy_grammar():
+    # missing buckets= clause fires
     src = (
         "import jax\n"
         "COMPILE_SURFACE = compile_surface(__name__, {\n"
@@ -166,14 +165,6 @@ def test_jit_compile_surface_policy_grammar_and_shard_map_shim():
         f.message for f in RULES["jit-compile-surface"].run_fixture(
             {"sm_distributed_tpu/ops/x_jax.py": src}))
     assert "buckets=" in msgs
-    shim = (
-        "import jax\n"
-        "def shard_map(f, *, mesh, in_specs, out_specs):\n"
-        "    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,\n"
-        "                         out_specs=out_specs)\n"
-    )
-    assert not RULES["jit-compile-surface"].run_fixture(
-        {"sm_distributed_tpu/parallel/mesh.py": shim})
 
 
 def test_retrace_hazard_taints_through_locals_and_dict_sinks():
