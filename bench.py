@@ -426,18 +426,18 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
 def measure_profiled(cfg: BenchConfig, prep: dict, backend,
                      floor_s: float, cache_dir: Path) -> dict:
     """Profiled stream (ISSUE 20): one extra full stream captured under
-    ``jax.profiler``, device time attributed by kernel class
-    (analysis/profiling.py — fused Pallas scoring kernel vs the
-    gather/segment-sum chain vs transfers).  Pins
+    ``jax.profiler``, device time attributed by ``jax.named_scope``
+    (analysis/profiling.py; a TPU capture only — on XLA-CPU the capture
+    holds no device plane and the pins stay null).  Pins
 
     - ``measured_roofline_frac``: the cost-model floor over the MEASURED
       per-rep device seconds the scoring kernels took.  The modeled
       ``roofline_frac`` above divides by end-to-end wall time, so it mixes
       in host dispatch slack; this one is the device-only answer, and a
       drop means the kernels themselves slowed down.
-    - ``kernel_time_frac``: scoring kernels' share of ALL device time in
-      the capture — falls when transfers/layout ops start eating the
-      device.
+    - ``kernel_time_frac``: the ``sm_`` scopes' share of ALL device time in
+      the capture — falls when unscoped transfers/layout ops start eating
+      the device.
 
     None-safe: a failed or empty capture (profiler unavailable on this
     runtime) pins nulls and never fails the bench."""
@@ -453,16 +453,15 @@ def measure_profiled(cfg: BenchConfig, prep: dict, backend,
             backend.score_batches(prep["batches"] * cfg.reps)
         finally:
             cap = sess.stop()
+        red = profiling.reduce_capture(cap)
     except Exception:
         logger.warning("[%s] profiled stream failed; pinning nulls",
                        cfg.name, exc_info=True)
         return out
-    attr = cap.get("attribution") or {}
-    total = float(attr.get("total_device_s") or 0.0)
-    by = attr.get("by_class_s") or {}
-    kernel_s = float(by.get("fused_kernel", 0.0)) + \
-        float(by.get("score_chain", 0.0))
-    out["profile_n_events"] = int(attr.get("n_events", 0))
+    total = sum(c["busy_s"] for c in red["chips"])
+    kernel_s = sum(v for scope, v in red["by_scope_s"].items()
+                   if scope != profiling.UNSCOPED)
+    out["profile_n_events"] = sum(c["n_ops"] for c in red["chips"])
     if total > 0 and kernel_s > 0:
         out["measured_roofline_frac"] = round(
             profiling.measured_roofline(floor_s, kernel_s / cfg.reps), 4)

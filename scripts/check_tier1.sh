@@ -234,9 +234,10 @@ fi
 # partial view with per-replica scrape-error evidence (never a 500), and
 # once the victim goes stale the merged SLO must be BIT-EQUAL to a
 # recomputation from the union of the survivors' raw histogram buckets.
-# Then an on-demand /debug/profile capture during a running sharded job
-# must attribute device time to the fused Pallas scoring kernel BY NAME
-# and inject correlated device_kernel spans into the job trace; finally
+# Then an on-demand /debug/profile capture during a running job must list
+# that job's lease hold and map the sm: annotations of its spans to within
+# 1 ms of the job-trace records through the sm_clock events (a CPU capture
+# holds no device plane: device time is the chip's to show); finally
 # the committed PROFILE_r*.json must carry the measured-roofline pins and a
 # degraded replay must trip both perf_sentinel bands.
 if ! env JAX_PLATFORMS=cpu python scripts/fleet_smoke.py; then

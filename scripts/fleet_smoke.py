@@ -17,10 +17,10 @@ Three phases, all through real service stacks:
 2. **On-demand device profiling.**  An in-process service on the
    ``jax_tpu`` backend with the fused Pallas scoring kernel forced on
    (interpret mode off-TPU) runs real jobs; ``GET /debug/profile``
-   during one must attribute device time to a *named* fused scoring
-   kernel, inject correlated ``device_kernel`` spans into the running
-   job's trace, and ``trace_report.py --by-replica`` must attribute
-   that device time to the serving replica.
+   during one must list that job among the lease holds it overlaps and
+   map the ``sm:`` annotations of the job's spans, through the ``sm_clock``
+   events, to within 1 ms of the job-trace records (device time itself
+   needs a TPU: ``chip_smoke.py`` and ``benchmarks/run.py --trace 1``).
 3. **Measured-roofline pins.**  The newest committed ``PROFILE_r*.json``
    artifact (the CPU-recorded profiled-roofline history: a CPU smoke
    artifact, not a device measurement) must carry non-null
@@ -416,65 +416,33 @@ def phase_profile(work: Path) -> int:
                                         timeout=60.0)
                 if code != 200:
                     return fail(f"/debug/profile returned {code}: {body}")
-                kernels = (body.get("attribution") or {}).get("kernels", [])
-                fused = [k for k in kernels if "fused" in k["module"]]
-                if fused and body.get("injected_spans", 0) > 0 \
-                        and mid in body.get("jobs_running", []):
-                    capture = (mid, body, fused)
+                # on XLA-CPU a capture holds no /device:TPU plane (device
+                # time is the chip smoke's to show): what it must prove here
+                # is the shared clock and the job's own spans inside it
+                ann = (body.get("clock") or {}).get("annotations") or {}
+                if mid in [j["job"] for j in body.get("jobs", [])] \
+                        and ann.get("matched", 0) > 0:
+                    capture = (mid, body, ann)
                     break
             if capture:
                 break
             h.wait_terminal([mid], timeout_s=300.0)
         if not capture:
-            return fail("no profile capture attributed a named fused "
-                        "scoring kernel during a running job (4 attempts)")
-        mid, body, fused = capture
-        by_class = body["attribution"]["by_class_frac"]
-        print(f"fleet_smoke: profile capture OK — {fused[0]['module']} "
-              f"({fused[0]['device_s']:.4f}s device), classes={by_class}, "
-              f"{body['injected_spans']} spans injected into {mid}")
-
-        h.wait_terminal([mid], timeout_s=300.0)
-        _s, _hd2, tr = None, None, None
-        with urllib.request.urlopen(
-                f"{h.base}/jobs/{mid}/trace?raw=1", timeout=30.0) as r:
-            tr = json.loads(r.read())
-        records = tr["records"]
-        dev = [rec for rec in records if rec.get("kind") == "span"
-               and rec.get("name") == "device_kernel"]
-        if not dev:
-            return fail(f"job {mid} trace gained no device_kernel spans")
-        fused_spans = [rec for rec in dev
-                       if "fused" in (rec.get("attrs") or {}).get("module",
-                                                                  "")]
-        if not fused_spans:
-            return fail("device_kernel spans carry no fused kernel")
-        rid = fused_spans[0].get("replica")
-        if not rid:
-            return fail("injected device_kernel spans carry no replica "
-                        "stamp — --by-replica attribution impossible")
-
-        # the --by-replica satellite, end to end over the same trace
-        tf = base / "trace.jsonl"
-        with open(tf, "w") as f:
-            for rec in records:
-                f.write(json.dumps(rec) + "\n")
-        out = subprocess.run(
-            [sys.executable, str(REPO_ROOT / "scripts" / "trace_report.py"),
-             str(tf), "--by-replica", "--json"],
-            capture_output=True, text=True, cwd=str(REPO_ROOT))
-        if out.returncode != 0:
-            return fail(f"trace_report --by-replica failed: {out.stderr}")
-        br = json.loads(out.stdout)["by_replica"]
-        if br.get(rid, {}).get("device_kernel_s", 0.0) <= 0.0:
-            return fail(f"--by-replica attributes no device time to {rid}: "
-                        f"{br}")
+            return fail("no profile capture overlapped a running job's "
+                        "lease hold with annotated spans (4 attempts)")
+        mid, body, ann = capture
+        if ann["max_err_us"] > 1000.0:
+            return fail(f"sm: annotations are {ann['max_err_us']:.0f} us "
+                        "off their job-trace spans (limit 1 ms)")
+        if body["chips"] or body["injected_spans"]:
+            return fail(f"a CPU capture reports chips {body['chips']}")
         if "sm_profile_captures_total" not in h.metrics_text():
             return fail("sm_profile_captures_total missing from /metrics")
-        print(f"fleet_smoke: trace attribution OK — "
-              f"{len(dev)} device_kernel spans on {mid}, "
-              f"{br[rid]['device_kernel_s']:.4f}s device attributed to "
-              f"{rid}")
+        print(f"fleet_smoke: profile capture OK — job {mid} under the "
+              f"capture, {ann['matched']} sm: annotations within "
+              f"{ann['max_err_us']:.0f} us of their spans, clock drift "
+              f"{body['clock']['drift_us']:.1f} us")
+        h.wait_terminal([mid], timeout_s=300.0)
         return 0
     finally:
         h.service.shutdown()

@@ -12,6 +12,13 @@ running service) into the standard perf artifact for this repo:
   time moved (scheduler? token contention? device?);
 - the **slowest batches** — the top score_batch spans with backend/ion
   counts, the needle for per-batch regressions;
+- the **build and store split** — ``backend_build`` with its four
+  ``build_*`` children under ``score``, the four ``store_*`` children under
+  ``store_results``;
+- the **device split**, when a ``/debug/profile`` capture overlapped the
+  job's lease hold: device seconds per ``jax.named_scope``, busy share of
+  the hold per chip, and the longest idle gaps with the program span that
+  covers each (``device_scope`` / ``device_busy`` / ``device_idle`` spans);
 - attempts (with timeout/abandon flags) and event counts (retries,
   cancels, failpoints, breaker flips).
 
@@ -41,6 +48,14 @@ from sm_distributed_tpu.utils import tracing  # noqa: E402
 _PHASE_ORDER = ("stage_input", "read_dataset", "decoy_selection",
                 "isotope_patterns", "score", "fdr", "store_results")
 _TOP_BATCHES = 10
+# the spans that split the two phases a job spends most of its lease in
+# (models/msm_basic.py + models/msm_jax.py, engine/search_job.py)
+_CHILDREN = {
+    "score": ("backend_build", "build_sort", "build_restrict",
+              "build_pad_compact", "build_device_put"),
+    "store_results": ("store_select", "store_extract_images",
+                      "store_write_images", "store_tables"),
+}
 
 
 def load_records(args) -> list[dict]:
@@ -101,6 +116,25 @@ def summarize(records: list[dict]) -> dict:
     for r in _events(records):
         events[r["name"]] = events.get(r["name"], 0) + 1
     worker_spans = list(_spans(records, "isocalc_chunk"))
+    children = {}
+    for name in (n for names in _CHILDREN.values() for n in names):
+        found = [float(r["dur"]) for r in _spans(records, name)]
+        if found:
+            children[name] = {"count": len(found),
+                              "seconds": round(sum(found), 6)}
+    device = {"scopes": {}, "busy": [], "idle": []}
+    for r in _spans(records, "device_scope"):
+        a = r["attrs"]
+        device["scopes"][a["scope"]] = round(
+            device["scopes"].get(a["scope"], 0.0) + a["device_s"], 6)
+    for r in _spans(records, "device_busy"):
+        device["busy"].append({k: r["attrs"][k] for k in
+                               ("chip", "busy_s", "hold_s", "whole")})
+    for r in sorted(_spans(records, "device_idle"),
+                    key=lambda r: -float(r["dur"])):
+        device["idle"].append({"chip": r["attrs"]["chip"],
+                               "host": r["attrs"]["host"],
+                               "seconds": round(float(r["dur"]), 6)})
     return {
         "trace_id": records[0].get("trace_id", "") if records else "",
         "job_id": next((r["job_id"] for r in records if r.get("job_id")), ""),
@@ -159,6 +193,8 @@ def summarize(records: list[dict]) -> dict:
             "ions": (r.get("attrs") or {}).get("ions"),
             "pid": r.get("pid"), "tid": r.get("tid"),
         } for r in batches[:_TOP_BATCHES]],
+        "children": children,
+        "device": device if device["busy"] else None,
         "n_batches": len(batches),
         "n_isocalc_worker_spans": len(worker_spans),
         "events": events,
@@ -169,7 +205,7 @@ def summarize(records: list[dict]) -> dict:
 def by_replica(records: list[dict]) -> dict:
     """Per-replica attribution (ISSUE 20) from the ISSUE-8 replica stamps.
 
-    A trace that survived a takeover (or had device_kernel spans injected
+    A trace that survived a takeover (or had device_scope spans appended
     by a profiling replica) holds records from several processes; this
     groups the work by WHO ran it.  Records emitted before replica
     identity existed (or by non-service tooling) land under "-".
@@ -179,7 +215,7 @@ def by_replica(records: list[dict]) -> dict:
         rid = str(r.get("replica") or "-")
         b = out.setdefault(rid, {
             "spans": 0, "events": 0, "seconds": 0.0, "attempts": 0,
-            "device_kernel_s": 0.0, "phases": {}, "pids": set(),
+            "device_s": 0.0, "phases": {}, "pids": set(),
         })
         if r.get("pid") is not None:
             b["pids"].add(r["pid"])
@@ -189,8 +225,8 @@ def by_replica(records: list[dict]) -> dict:
             b["seconds"] += dur
             if r.get("name") == "attempt":
                 b["attempts"] += 1
-            elif r.get("name") == "device_kernel":
-                b["device_kernel_s"] += dur
+            elif r.get("name") == "device_scope":
+                b["device_s"] += float(r["attrs"]["device_s"])
             if (r.get("attrs") or {}).get("phase"):
                 ph = b["phases"]
                 ph[r["name"]] = ph.get(r["name"], 0.0) + dur
@@ -199,7 +235,7 @@ def by_replica(records: list[dict]) -> dict:
     for b in out.values():
         b["pids"] = sorted(b["pids"])
         b["seconds"] = round(b["seconds"], 6)
-        b["device_kernel_s"] = round(b["device_kernel_s"], 6)
+        b["device_s"] = round(b["device_s"], 6)
         b["phases"] = {k: round(v, 6) for k, v in sorted(b["phases"].items())}
     return out
 
@@ -213,7 +249,7 @@ def render_by_replica(br: dict) -> str:
         phases = ", ".join(f"{k}={v:.3f}s" for k, v in b["phases"].items())
         lines.append(f"  {rid:<14} {b['spans']:>6} {b['events']:>7} "
                      f"{b['seconds']:>10.3f} {b['attempts']:>8} "
-                     f"{b['device_kernel_s']:>10.3f}  {phases or '-'}")
+                     f"{b['device_s']:>10.3f}  {phases or '-'}")
     return "\n".join(lines)
 
 
@@ -240,9 +276,30 @@ def render(s: dict) -> str:
         v = s["phases"][p]
         lines.append(f"  {p:<22} {v['seconds']:9.3f}s "
                      f"{_pct(v['seconds'], total)}  x{v['count']}")
+        for c in _CHILDREN.get(p, ()):
+            if c in s.get("children", {}):
+                v = s["children"][c]
+                pad = "      " if c.startswith("build_") else "    "
+                lines.append(f"{pad}{c:<{26 - len(pad)}}{v['seconds']:8.3f}s "
+                             f"{_pct(v['seconds'], total)}  x{v['count']}")
     if not ordered:
         lines.append("  (no phase spans)")
     lines.append("")
+    if s.get("device"):
+        d = s["device"]
+        lines.append("device (from a /debug/profile capture):")
+        for b in d["busy"]:
+            lines.append(
+                f"  chip {b['chip']}: busy {b['busy_s']:.4f}s of a "
+                f"{b['hold_s']:.3f}s lease hold "
+                f"({_pct(b['busy_s'], b['hold_s']).strip()})"
+                + ("" if b["whole"] else "  [hold cut by the capture]"))
+        for scope, sec in sorted(d["scopes"].items(), key=lambda kv: -kv[1]):
+            lines.append(f"  {scope:<22} {sec:9.4f}s")
+        for g in d["idle"][:8]:
+            lines.append(f"  idle {g['seconds']:8.3f}s on chip {g['chip']} "
+                         f"during {g['host']}")
+        lines.append("")
     a = s["accounting"]
     lines.append("accounting (where the wall went):")
     if a["queue_wait_s"] is not None:
@@ -304,7 +361,7 @@ def main(argv: list[str] | None = None) -> int:
                          "problem) — the trace smoke gate's mode")
     ap.add_argument("--by-replica", action="store_true",
                     help="append the per-replica attribution table (who ran "
-                         "each span, incl. injected device_kernel time)")
+                         "each span, incl. appended device_scope time)")
     args = ap.parse_args(argv)
     if bool(args.url) == bool(args.trace):
         ap.error("give exactly one of TRACE or --url/--job")

@@ -32,12 +32,32 @@ adds zero new signatures).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import sys
 import threading
 from pathlib import Path
 
 from ..utils import tracing
 from ..utils.logger import logger
+
+# chips of the device lease this thread's job holds: a ``compile`` event
+# names them, so a job on chip 1..3 that pays a compile the chip-0 primer
+# did not cover says so
+_LEASE_DEVICES: contextvars.ContextVar[tuple[int, ...] | None] = \
+    contextvars.ContextVar("sm_lease_devices", default=None)
+
+
+@contextlib.contextmanager
+def lease(token):
+    """Name the chips of a GRANTED device lease (``token.devices``; a plain
+    lock has none) on the compile events of this thread for the block."""
+    devs = getattr(token, "devices", None)
+    reset = _LEASE_DEVICES.set(tuple(int(i) for i in devs) if devs else None)
+    try:
+        yield
+    finally:
+        _LEASE_DEVICES.reset(reset)
 
 # the jax monitoring event fired once per backend-compile REQUEST.  It
 # wraps ``compile_or_get_cached``, so it fires on persistent-cache HITS
@@ -234,6 +254,8 @@ def _on_event_duration(name: str, duration: float, **_kw) -> None:
             _census.record_duration("backend_compile_s", float(duration))
         site, fn_name, sig = _attribute()
         signature = f"{fn_name}{sig}" if fn_name else sig
+        held = _LEASE_DEVICES.get()
+        devices = {"devices": list(held)} if held else {}
         m = _metrics
         if cached:
             # the executable came off the persistent cache — the primed
@@ -248,7 +270,8 @@ def _on_event_duration(name: str, duration: float, **_kw) -> None:
                     ("site",)).labels(site=site).inc()
             tracing.event("compile", site=site, fn=fn_name,
                           signature=sig[:500],
-                          dur_s=round(float(duration), 4), cached=True)
+                          dur_s=round(float(duration), 4), cached=True,
+                          **devices)
             return
         new, distinct = _census.record(site, signature)
         if m is not None:
@@ -262,7 +285,7 @@ def _on_event_duration(name: str, duration: float, **_kw) -> None:
                 "call site", ("site",)).labels(site=site).set(distinct)
         tracing.event("compile", site=site, fn=fn_name,
                       signature=sig[:500], dur_s=round(float(duration), 4),
-                      new_signature=bool(new), cached=False)
+                      new_signature=bool(new), cached=False, **devices)
     except Exception:
         # a tracer fault must never fail the compile it observes
         if not _warned:

@@ -18,6 +18,7 @@ from __future__ import annotations
 import traceback
 from pathlib import Path
 
+from ..analysis import retrace
 from ..io.dataset import SpectralDataset
 from ..models.msm_basic import IsotopePrefetch, MSMBasicSearch, SearchResultsBundle
 from ..utils import devicemem, tracing
@@ -137,10 +138,10 @@ class SearchJob:
                 self.ds_id, ds.nrows, ds.ncols, ds.n_spectra, ds.n_peaks,
             )
             if self.profile_dir:
-                import jax
+                from ..analysis.profiling import ProfileSession
 
-                prof = self.profile_dir
-                jax.profiler.start_trace(prof)
+                prof = ProfileSession(self.profile_dir)
+                prof.start()
                 # correlate the jax.profiler trace dir into the job trace:
                 # /jobs/<id>/trace surfaces it in otherData.jax_profile_dir
                 tracing.event("jax_profile", dir=str(self.profile_dir))
@@ -160,7 +161,8 @@ class SearchJob:
             # trace accounting: the device_hold span covers token WAIT +
             # HOLD; the acquired event inside marks the boundary, so
             # trace_report can split queue-wait vs token-wait vs compute
-            with tracing.span("device_hold"), token:
+            with tracing.span("device_hold"), token, \
+                    retrace.lease(self.device_token):
                 # a DeviceLease exposes the granted chip indices; a plain
                 # Lock (legacy callers) has none — the event then matches
                 # the pre-pool shape and the search meshes over all devices
@@ -184,9 +186,7 @@ class SearchJob:
                 if search.isocalc is not None:
                     self.last_isocalc_stats = dict(search.isocalc.last_stats)
                 if prof:
-                    import jax
-
-                    jax.profiler.stop_trace()
+                    prof.stop()
                     prof = None
                     logger.info("profile trace written to %s", self.profile_dir)
                 bundle.timings.update(timings)
@@ -213,7 +213,8 @@ class SearchJob:
                     # fully queryable (ADVICE r1)
                     if self.sm_config.storage.store_images:
                         self._store_annotation_images(ds, search, bundle)
-                    self.store.store(self.ds_id, job_id, bundle, ion_mzs)
+                    with tracing.span("store_tables"):
+                        self.store.store(self.ds_id, job_id, bundle, ion_mzs)
                 # pin the device high-water mark while this job's arrays
                 # are still resident; the trace gets it as an event so
                 # every per-phase hbm sample has a job-level roll-up
@@ -248,9 +249,7 @@ class SearchJob:
                     logger.warning("isotope prefetch cancel failed",
                                    exc_info=True)
             if prof:
-                import jax
-
-                jax.profiler.stop_trace()
+                prof.stop()
             self.ledger.fail_job(job_id, f"{exc}\n{traceback.format_exc()}")
             # remove THIS job's partial index entries (the reference's ES
             # cleanup [U]); earlier successful jobs' rows stay queryable
@@ -330,33 +329,44 @@ class SearchJob:
         table = search.last_table
         if table is None or bundle.annotations.empty:
             return
-        keep = bundle.annotations[bundle.annotations.fdr_level <= 0.5]
-        want = set(zip(keep.sf, keep.adduct))
-        idx = [
-            i for i, (sf, ad) in enumerate(zip(table.sfs, table.adducts))
-            if (sf, ad) in want
-        ]
-        if not idx:
-            return
-        sub = table.__class__(
-            sfs=[table.sfs[i] for i in idx],
-            adducts=[table.adducts[i] for i in idx],
-            mzs=table.mzs[idx],
-            ints=table.ints[idx],
-            n_valid=table.n_valid[idx],
-            targets=table.targets[idx],
-        )
-        backend = search.last_backend
-        if backend is not None and hasattr(backend, "extract_ion_images"):
-            images = backend.extract_ion_images(sub)
-        else:
-            from ..ops.imager_np import SortedPeakView, extract_ion_images
+        # store_select / store_extract_images / store_write_images (and
+        # store_tables in run) split the store_results phase: PERF.md
+        # section 3, store_images_s
+        with tracing.span("store_select"):
+            keep = bundle.annotations[bundle.annotations.fdr_level <= 0.5]
+            want = set(zip(keep.sf, keep.adduct))
+            idx = [
+                i for i, (sf, ad) in enumerate(zip(table.sfs, table.adducts))
+                if (sf, ad) in want
+            ]
+            if not idx:
+                return
+            sub = table.__class__(
+                sfs=[table.sfs[i] for i in idx],
+                adducts=[table.adducts[i] for i in idx],
+                mzs=table.mzs[idx],
+                ints=table.ints[idx],
+                n_valid=table.n_valid[idx],
+                targets=table.targets[idx],
+            )
+        # ends when the images are on the host (both extractors return numpy)
+        with tracing.span("store_extract_images", ions=len(idx)):
+            backend = search.last_backend
+            if backend is not None and hasattr(backend, "extract_ion_images"):
+                images = backend.extract_ion_images(sub)
+            else:
+                from ..ops.imager_np import SortedPeakView, extract_ion_images
 
-            view = SortedPeakView.prepare(ds, self.ds_config.image_generation.ppm)
-            images = extract_ion_images(view, sub, self.ds_config.image_generation.ppm)
-        path = self.store.store_ion_images(
-            self.ds_id, np.asarray(images),
-            list(zip(sub.sfs, sub.adducts)), ds.nrows, ds.ncols,
-            mask=ds.get_sample_area_mask(),
-        )
+                view = SortedPeakView.prepare(ds, self.ds_config.image_generation.ppm)
+                images = extract_ion_images(view, sub, self.ds_config.image_generation.ppm)
+            images = np.asarray(images)
+            tracing.annotate(bytes=int(images.nbytes))
+        with tracing.span("store_write_images",
+                          format=self.sm_config.storage.image_format,
+                          bytes=int(images.nbytes)):
+            path = self.store.store_ion_images(
+                self.ds_id, images,
+                list(zip(sub.sfs, sub.adducts)), ds.nrows, ds.ncols,
+                mask=ds.get_sample_area_mask(),
+            )
         logger.info("stored %d ion image sets -> %s", len(idx), path)

@@ -870,6 +870,7 @@ class MSMBasicSearch:
                        else self._fingerprint(table))
 
         def build():
+            tracing.annotate(cache_hit=False)    # onto the backend_build span
             return make_backend(
                 self.sm_config.backend, self.ds, self.ds_config,
                 self.sm_config, table=table,
@@ -895,17 +896,28 @@ class MSMBasicSearch:
             record_degraded()
             backend = NumpyBackend(self.ds, self.ds_config)
             degraded = True
-        elif self.backend_cache is not None:
-            par = self.sm_config.parallel
-            key = (self.sm_config.backend, fingerprint,
-                   par.mz_chunk, par.pixels_axis, par.formulas_axis,
-                   par.peak_compaction, par.band_slice, par.order_ions,
-                   # a backend is pinned to its lease's chips — a cached one
-                   # must never be reused by a job holding DIFFERENT chips
-                   self.device_indices)
-            backend = self.backend_cache.backend(key, build)
         else:
-            backend = build()
+            # the span behind backend_build_s (PERF.md section 3); the jax
+            # backend splits it into build_sort / build_restrict /
+            # build_pad_compact / build_device_put
+            with tracing.span("backend_build", cache_hit=True):
+                if self.backend_cache is not None:
+                    par = self.sm_config.parallel
+                    key = (self.sm_config.backend, fingerprint,
+                           par.mz_chunk, par.pixels_axis, par.formulas_axis,
+                           par.peak_compaction, par.band_slice,
+                           par.order_ions,
+                           # a backend is pinned to its lease's chips — a
+                           # cached one must never be reused by a job
+                           # holding DIFFERENT chips
+                           self.device_indices)
+                    backend = self.backend_cache.backend(key, build)
+                else:
+                    backend = build()
+                tracing.annotate(
+                    peaks_in=int(self.ds.n_peaks),
+                    peaks_resident=getattr(backend, "resident_peaks", None),
+                    resident_bytes=getattr(backend, "resident_bytes", None))
         self.last_backend = backend
         batch = self._batch_eff
         if batch < max(1, self.sm_config.parallel.formula_batch) and \

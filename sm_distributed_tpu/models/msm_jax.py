@@ -221,17 +221,19 @@ def fused_score_fn_flat_banded(
     f32 TRANSIENT in-graph (XLA fuses the cast into the scatter's operand
     read) — with cube_dtype="f32" (legacy default) the expansion is a
     python-level no-op and the traced program is byte-identical."""
-    int_sorted = expand_cube_jnp(int_sorted, scales)
-    imgs = extract_images_flat_banded(
-        pixel_sorted, int_sorted, pos, starts, r_lo_loc, r_hi_loc, None,
-        gc_width=gc_width, n_pixels=nrows * ncols)
-    imgs = _maybe_barrier(imgs, k, nrows * ncols)
-    imgs = imgs.reshape(b, k, -1)
+    with jax.named_scope("sm_extract"):
+        int_sorted = expand_cube_jnp(int_sorted, scales)
+        imgs = extract_images_flat_banded(
+            pixel_sorted, int_sorted, pos, starts, r_lo_loc, r_hi_loc, None,
+            gc_width=gc_width, n_pixels=nrows * ncols)
+        imgs = _maybe_barrier(imgs, k, nrows * ncols)
+        imgs = imgs.reshape(b, k, -1)
     out = batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    return jnp.take(out, inv, axis=0)
+    with jax.named_scope("sm_epilogue"):
+        return jnp.take(out, inv, axis=0)
 
 
 def fused_score_fn_flat_fused(
@@ -277,7 +279,6 @@ def fused_score_fn_flat_fused(
         raise ValueError(
             "the fused scoring kernel cannot apply hotspot preprocessing "
             "(no materialized image block); route via the plain variant")
-    int_sorted = expand_cube_jnp(int_sorted, scales)
     n_pix = nrows * ncols
     n = pixel_sorted.shape[0]
     g = pos.shape[0]
@@ -285,12 +286,14 @@ def fused_score_fn_flat_fused(
     # the scratch rows padded to whole super-rows (score_pallas.SC) plus
     # the spare band the unclamped super-row fetch may touch — spare rows
     # are zero-initialized and outside every window's rank range
-    delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
-    bins = jnp.cumsum(delta[:-1])
-    cols_p = cols_padded(g, gc_width)
-    wh = jnp.zeros((cols_p, n_pix + 1), jnp.float32).at[
-        bins, pixel_sorted].add(int_sorted)
-    whp = wh[:, :n_pix]
+    with jax.named_scope("sm_extract"):
+        int_sorted = expand_cube_jnp(int_sorted, scales)
+        delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
+        bins = jnp.cumsum(delta[:-1])
+        cols_p = cols_padded(g, gc_width)
+        wh = jnp.zeros((cols_p, n_pix + 1), jnp.float32).at[
+            bins, pixel_sorted].add(int_sorted)
+        whp = wh[:, :n_pix]
     nr = n_real if n_real is not None else np.int32(n_pix)
     # the Pallas interpreter is a CPU test vehicle (fused_metrics="on" in
     # tests and the ulp sentinel): same kernel schedule, no Mosaic.  Every
@@ -299,13 +302,15 @@ def fused_score_fn_flat_fused(
     interpret = jax.default_backend() == "cpu"
     if interpret:
         _PALLAS_INTERPRET_EVENTS["traced"] += 1
-    partials, principal = fused_window_moments(
-        whp, starts, r_lo_loc, r_hi_loc, nr,
-        gc_width=gc_width, k=k, interpret=interpret)
+    with jax.named_scope("sm_fused"):
+        partials, principal = fused_window_moments(
+            whp, starts, r_lo_loc, r_hi_loc, nr,
+            gc_width=gc_width, k=k, interpret=interpret)
     out = batch_metrics_from_partials(
         partials.reshape(b, k, 5), principal.reshape(b, n_pix),
         theor_ints, n_valid, nrows, ncols, nlevels)
-    return jnp.take(out, inv, axis=0)
+    with jax.named_scope("sm_epilogue"):
+        return jnp.take(out, inv, axis=0)
 
 
 def _extract_sliced(
@@ -360,19 +365,21 @@ def fused_score_fn_flat_banded_sliced(
     metrics) are bit-identical to the uncompacted path.  Ion-major chunk
     plan: see fused_score_fn_flat_banded (``inv`` un-permutes metric
     rows)."""
-    int_sorted = expand_cube_jnp(int_sorted, scales)
-    px_b = jax.lax.dynamic_slice(pixel_sorted, (w_start,), (w_cap,))
-    in_b = jax.lax.dynamic_slice(int_sorted, (w_start,), (w_cap,))
-    imgs = extract_images_flat_banded(
-        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
-        gc_width=gc_width, n_pixels=nrows * ncols)
-    imgs = _maybe_barrier(imgs, k, nrows * ncols)
-    imgs = imgs.reshape(b, k, -1)
+    with jax.named_scope("sm_extract"):
+        int_sorted = expand_cube_jnp(int_sorted, scales)
+        px_b = jax.lax.dynamic_slice(pixel_sorted, (w_start,), (w_cap,))
+        in_b = jax.lax.dynamic_slice(int_sorted, (w_start,), (w_cap,))
+        imgs = extract_images_flat_banded(
+            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+            gc_width=gc_width, n_pixels=nrows * ncols)
+        imgs = _maybe_barrier(imgs, k, nrows * ncols)
+        imgs = imgs.reshape(b, k, -1)
     out = batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    return jnp.take(out, inv, axis=0)
+    with jax.named_scope("sm_epilogue"):
+        return jnp.take(out, inv, axis=0)
 
 
 def _extract_compact(
@@ -423,20 +430,22 @@ def fused_score_fn_flat_banded_compact(
     Images, and hence metrics, are bit-identical to the uncompacted path.
     Ion-major chunk plan: see fused_score_fn_flat_banded (``inv``
     un-permutes metric rows)."""
-    int_sorted = expand_cube_jnp(int_sorted, scales)
-    px_b, in_b = compact_peaks(
-        pixel_sorted, int_sorted, run_pos, run_delta, n_b,
-        n_keep=n_keep, n_pixels=nrows * ncols)
-    imgs = extract_images_flat_banded(
-        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
-        gc_width=gc_width, n_pixels=nrows * ncols)
-    imgs = _maybe_barrier(imgs, k, nrows * ncols)
-    imgs = imgs.reshape(b, k, -1)
+    with jax.named_scope("sm_extract"):
+        int_sorted = expand_cube_jnp(int_sorted, scales)
+        px_b, in_b = compact_peaks(
+            pixel_sorted, int_sorted, run_pos, run_delta, n_b,
+            n_keep=n_keep, n_pixels=nrows * ncols)
+        imgs = extract_images_flat_banded(
+            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+            gc_width=gc_width, n_pixels=nrows * ncols)
+        imgs = _maybe_barrier(imgs, k, nrows * ncols)
+        imgs = imgs.reshape(b, k, -1)
     out = batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    return jnp.take(out, inv, axis=0)
+    with jax.named_scope("sm_epilogue"):
+        return jnp.take(out, inv, axis=0)
 
 
 def fused_score_fn_chunked(
@@ -465,10 +474,11 @@ def fused_score_fn_chunked(
     bit-identical to the unchunked path; spatial/spectral can differ by ulps
     because XLA picks different reduction fusions for the two program
     variants (observed at 128x128 px on TPU)."""
-    imgs = extract_images_mz_chunked(
-        mz_q_cube, int_cube, grid, starts, r_lo_loc, r_hi_loc, inv,
-        gc_width=gc_width)
-    imgs = imgs.reshape(b, k, -1)[:, :, : nrows * ncols]
+    with jax.named_scope("sm_extract"):
+        imgs = extract_images_mz_chunked(
+            mz_q_cube, int_cube, grid, starts, r_lo_loc, r_hi_loc, inv,
+            gc_width=gc_width)
+        imgs = imgs.reshape(b, k, -1)[:, :, : nrows * ncols]
     return batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q,
@@ -721,7 +731,10 @@ class JaxBackend:
                     f" x {k_est} peaks); reduce parallel.formula_batch, shard"
                     " pixels over a mesh (parallel.pixels_axis), or set"
                     " parallel.mz_chunk to use the bounded-scratch cube path")
-            mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, self.ppm)
+            # the four build_* spans split the backend_build span of
+            # models/msm_basic.py (PERF.md section 3, backend_build_s)
+            with tracing.span("build_sort"):
+                mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, self.ppm)
             if restrict_table is not None:
                 # drop peaks outside EVERY window of the search up front —
                 # the reference's "only hits shuffle" property [U]: on noisy
@@ -729,54 +742,63 @@ class JaxBackend:
                 # the dominant extraction cost
                 from ..ops.imager_jax import restrict_flat_to_windows
 
-                lo_q, hi_q = quantize_window(restrict_table.mzs, self.ppm)
-                mzk, pxk, ink, n_eff = restrict_flat_to_windows(
-                    mz_s[None], px_s[None], in_s[None],
-                    lo_q, hi_q, overflow_row=ds.n_pixels)
+                with tracing.span("build_restrict"):
+                    lo_q, hi_q = quantize_window(restrict_table.mzs, self.ppm)
+                    mzk, pxk, ink, n_eff = restrict_flat_to_windows(
+                        mz_s[None], px_s[None], in_s[None],
+                        lo_q, hi_q, overflow_row=ds.n_pixels)
                 logger.info(
                     "window-union restriction: %d -> %d peaks (%.0f%% dropped)",
                     mz_s.size, n_eff,
                     100.0 * (1 - n_eff / max(mz_s.size, 1)))
                 mz_s, px_s, in_s = mzk[0], pxk[0], ink[0]
-            if self._buckets:
-                # lattice-pad the resident arrays (ops/buckets.peak_bucket)
-                # with the SAME slot shape the 1024-multiple rounding
-                # already uses: m/z saturates to the MZ_PAD_Q sentinel
-                # (outside every window), pixel points at the overflow row,
-                # intensity 0 — bit-exact, and every dataset whose peak
-                # count shares the bucket shares the executable
-                n_pad = shape_buckets.peak_bucket(mz_s.size)
-                if n_pad > mz_s.size:
-                    from ..ops.quantize import MZ_PAD_Q
+            with tracing.span("build_pad_compact"):
+                if self._buckets:
+                    # lattice-pad the resident arrays (ops/buckets.peak_bucket)
+                    # with the SAME slot shape the 1024-multiple rounding
+                    # already uses: m/z saturates to the MZ_PAD_Q sentinel
+                    # (outside every window), pixel points at the overflow row,
+                    # intensity 0 — bit-exact, and every dataset whose peak
+                    # count shares the bucket shares the executable
+                    n_pad = shape_buckets.peak_bucket(mz_s.size)
+                    if n_pad > mz_s.size:
+                        from ..ops.quantize import MZ_PAD_Q
 
-                    tail = n_pad - mz_s.size
+                        tail = n_pad - mz_s.size
+                        mz_s = np.concatenate(
+                            [mz_s, np.full(tail, MZ_PAD_Q, mz_s.dtype)])
+                        px_s = np.concatenate(
+                            [px_s, np.full(tail, ds.n_pixels, px_s.dtype)])
+                        in_s = np.concatenate(
+                            [in_s, np.zeros(tail, in_s.dtype)])
+                # resident-cube intensity compaction (ISSUE 18): bf16 halves /
+                # int8 quarters the HBM-resident cube; the f32 view is a
+                # per-batch transient inside the scoring jits.  int8 needs
+                # QTILE-aligned peaks — lattice points are 1024-multiples, so
+                # only the lattice-off int8 combination pads here (same
+                # zero-intensity overflow-row slots as the lattice pad).
+                self._cube_dtype = sm_config.parallel.cube_dtype
+                from ..ops.quantize import MZ_PAD_Q, QTILE
+                if self._cube_dtype == "int8" and in_s.size % QTILE != 0:
+                    tail = -in_s.size % QTILE
                     mz_s = np.concatenate(
                         [mz_s, np.full(tail, MZ_PAD_Q, mz_s.dtype)])
                     px_s = np.concatenate(
                         [px_s, np.full(tail, ds.n_pixels, px_s.dtype)])
-                    in_s = np.concatenate(
-                        [in_s, np.zeros(tail, in_s.dtype)])
-            # resident-cube intensity compaction (ISSUE 18): bf16 halves /
-            # int8 quarters the HBM-resident cube; the f32 view is a
-            # per-batch transient inside the scoring jits.  int8 needs
-            # QTILE-aligned peaks — lattice points are 1024-multiples, so
-            # only the lattice-off int8 combination pads here (same
-            # zero-intensity overflow-row slots as the lattice pad).
-            self._cube_dtype = sm_config.parallel.cube_dtype
-            from ..ops.quantize import MZ_PAD_Q, QTILE
-            if self._cube_dtype == "int8" and in_s.size % QTILE != 0:
-                tail = -in_s.size % QTILE
-                mz_s = np.concatenate(
-                    [mz_s, np.full(tail, MZ_PAD_Q, mz_s.dtype)])
-                px_s = np.concatenate(
-                    [px_s, np.full(tail, ds.n_pixels, px_s.dtype)])
-                in_s = np.concatenate([in_s, np.zeros(tail, in_s.dtype)])
-            codes, scales = compact_cube(in_s, self._cube_dtype)
+                    in_s = np.concatenate([in_s, np.zeros(tail, in_s.dtype)])
+                codes, scales = compact_cube(in_s, self._cube_dtype)
             self._mz_host = mz_s
-            self._px_s = jax.device_put(px_s, self.device)
-            self._in_s = jax.device_put(codes, self.device)
-            self._scales = (jax.device_put(scales, self.device)
-                            if scales is not None else None)
+            with tracing.span("build_device_put"):
+                self._px_s = jax.device_put(px_s, self.device)
+                self._in_s = jax.device_put(codes, self.device)
+                self._scales = (jax.device_put(scales, self.device)
+                                if scales is not None else None)
+                # smlint: host-sync-ok[backend build, once per resident dataset: the span must be the transfer, not its enqueue]
+                jax.block_until_ready(
+                    (self._px_s, self._in_s, self._scales))
+            self.resident_peaks = int(mz_s.size)
+            self.resident_bytes = int(px_s.nbytes + codes.nbytes) + (
+                int(scales.nbytes) if scales is not None else 0)
             logger.info(
                 "jax_tpu flat peaks resident: %d sorted peaks (%.1f MB, "
                 "cube_dtype=%s) on %s",

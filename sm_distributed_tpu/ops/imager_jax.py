@@ -184,14 +184,18 @@ def extract_images_flat(
     +1 at every pos, one inclusive cumsum."""
     n = pixel_sorted.shape[0]
     g = pos.shape[0]
-    delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
-    bins = jnp.cumsum(delta[:-1])
-    wh = jnp.zeros((n_pixels + 1, g + 1), jnp.float32).at[
-        pixel_sorted, bins].add(int_sorted)
-    gg = jnp.arange(g + 1, dtype=jnp.int32)[:, None]
-    d = ((gg > r_lo[None, :]) & (gg <= r_hi[None, :])).astype(jnp.float32)
-    img_pw = jnp.dot(wh[:n_pixels], d, precision=jax.lax.Precision.HIGHEST)
-    return img_pw.T
+    # the store's re-extraction (JaxBackend.extract_ion_images) is this
+    # function's one program: the scope /debug/profile attributes it by
+    with jax.named_scope("sm_store_extract"):
+        delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
+        bins = jnp.cumsum(delta[:-1])
+        wh = jnp.zeros((n_pixels + 1, g + 1), jnp.float32).at[
+            pixel_sorted, bins].add(int_sorted)
+        gg = jnp.arange(g + 1, dtype=jnp.int32)[:, None]
+        d = ((gg > r_lo[None, :]) & (gg <= r_hi[None, :])).astype(jnp.float32)
+        img_pw = jnp.dot(wh[:n_pixels], d,
+                         precision=jax.lax.Precision.HIGHEST)
+        return img_pw.T
 
 
 def extract_images_flat_banded(

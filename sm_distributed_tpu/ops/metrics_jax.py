@@ -325,32 +325,37 @@ def batch_metrics(
     threshold, so component counts, ``vmax`` and ``n_notnull`` are exact
     integers either way.  Result: metrics are bit-identical to unpadded
     scoring while every dataset size in a bucket shares ONE executable."""
+    # the named scopes are what /debug/profile attributes device time by
+    # (analysis/profiling.py); they are HLO metadata only
     k = images.shape[1]
-    valid = jnp.arange(k, dtype=jnp.int32)[None, :] < n_valid[:, None]
-    images = jnp.where(valid[:, :, None], images, 0.0)
-    if do_preprocessing:
-        images = hotspot_clip_batch(images, q)
+    with jax.named_scope("sm_moments"):
+        valid = jnp.arange(k, dtype=jnp.int32)[None, :] < n_valid[:, None]
+        images = jnp.where(valid[:, :, None], images, 0.0)
+        if do_preprocessing:
+            images = hotspot_clip_batch(images, q)
 
-    # every per-pixel reduction the metrics need, in ONE streaming pass
-    # over the image block (ops/moments_pallas.py; XLA fallback identical
-    # semantics) — separate XLA reductions measured ~25-30 ms per 1 GB
-    # DESI batch against ~3 ms fused
-    from .moments_pallas import batch_moments
+        # every per-pixel reduction the metrics need, in ONE streaming pass
+        # over the image block (ops/moments_pallas.py; XLA fallback
+        # identical semantics) — separate XLA reductions measured ~25-30 ms
+        # per 1 GB DESI batch against ~3 ms fused
+        from .moments_pallas import batch_moments
 
-    sums, normsq, dots, vmax, n_notnull = batch_moments(images,
-                                                        n_real=n_real)
-    chaos = measure_of_chaos_batch(
-        images[:, 0, :], nrows, ncols, nlevels,
-        vmax=vmax, n_notnull=n_notnull)
-    spatial = correlation_from_moments(normsq, dots, theor_ints, valid)
-    spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
+        sums, normsq, dots, vmax, n_notnull = batch_moments(images,
+                                                            n_real=n_real)
+    with jax.named_scope("sm_chaos"):
+        chaos = measure_of_chaos_batch(
+            images[:, 0, :], nrows, ncols, nlevels,
+            vmax=vmax, n_notnull=n_notnull)
+    with jax.named_scope("sm_epilogue"):
+        spatial = correlation_from_moments(normsq, dots, theor_ints, valid)
+        spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
 
-    alive = (n_valid > 0) & (vmax > 0)
-    chaos = jnp.where(alive, chaos, 0.0)
-    spatial = jnp.where(alive, spatial, 0.0)
-    spectral = jnp.where(alive, spectral, 0.0)
-    msm = chaos * spatial * spectral
-    return jnp.stack([chaos, spatial, spectral, msm], axis=1)
+        alive = (n_valid > 0) & (vmax > 0)
+        chaos = jnp.where(alive, chaos, 0.0)
+        spatial = jnp.where(alive, spatial, 0.0)
+        spectral = jnp.where(alive, spectral, 0.0)
+        msm = chaos * spatial * spectral
+        return jnp.stack([chaos, spatial, spectral, msm], axis=1)
 
 
 def batch_metrics_from_partials(
@@ -392,14 +397,16 @@ def batch_metrics_from_partials(
     n_notnull = jnp.where(alive0, partials[:, 0, 4], 0.0)
     principal = jnp.where(alive0[:, None], principal, 0.0)
 
-    chaos = measure_of_chaos_batch(
-        principal, nrows, ncols, nlevels, vmax=vmax, n_notnull=n_notnull)
-    spatial = correlation_from_moments(normsq, dots, theor_ints, valid)
-    spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
+    with jax.named_scope("sm_chaos"):
+        chaos = measure_of_chaos_batch(
+            principal, nrows, ncols, nlevels, vmax=vmax, n_notnull=n_notnull)
+    with jax.named_scope("sm_epilogue"):
+        spatial = correlation_from_moments(normsq, dots, theor_ints, valid)
+        spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
 
-    alive = alive0 & (vmax > 0)
-    chaos = jnp.where(alive, chaos, 0.0)
-    spatial = jnp.where(alive, spatial, 0.0)
-    spectral = jnp.where(alive, spectral, 0.0)
-    msm = chaos * spatial * spectral
-    return jnp.stack([chaos, spatial, spectral, msm], axis=1)
+        alive = alive0 & (vmax > 0)
+        chaos = jnp.where(alive, chaos, 0.0)
+        spatial = jnp.where(alive, spatial, 0.0)
+        spectral = jnp.where(alive, spectral, 0.0)
+        msm = chaos * spatial * spectral
+        return jnp.stack([chaos, spatial, spectral, msm], axis=1)

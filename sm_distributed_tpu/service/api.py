@@ -60,10 +60,10 @@ RabbitMQ's management UI):
   partial view with ``sm_fleetview_scrape_errors_total{replica=}``
   evidence, never a 500;
 - ``GET /debug/profile?seconds=``  single-flight on-demand
-  ``jax.profiler`` capture around in-flight work: per-kernel device-time
-  attribution (fused scoring kernel vs gather/segment-sum chain vs
-  transfers) + ``device_kernel`` spans injected into running jobs'
-  traces (409 while another capture runs);
+  ``jax.profiler`` capture around in-flight work: device time per chip and
+  ``jax.named_scope``, idle gaps by program span, all on the job traces'
+  clock + ``device_scope`` / ``device_busy`` / ``device_idle`` spans
+  appended to the overlapped jobs' traces (409 while another capture runs);
 - ``GET /datasets`` / ``GET /datasets/<id>/annotations`` /
   ``GET /annotations`` / ``GET /datasets/<id>/images/<sf_adduct>``  the
   result read path (ISSUE 16, ``service/readpath.py``): dataset listing,
@@ -667,9 +667,9 @@ class AdminAPI:
 
     def _profile(self, seconds: float | None) -> tuple[int, dict]:
         """``GET /debug/profile?seconds=`` (ISSUE 20) — single-flight
-        ``jax.profiler`` capture around in-flight work: per-kernel device
-        time attribution + ``device_kernel`` span injection into running
-        jobs' traces.  409 while another capture runs."""
+        ``jax.profiler`` capture around in-flight work, reduced by
+        ``analysis/profiling.py``; device spans are appended to the traces
+        of the jobs it overlapped.  409 while another capture runs."""
         prof = getattr(self.service, "profiler", None)
         if prof is None:
             return 404, {"error": "device profiler not configured",

@@ -34,11 +34,13 @@ single pane on the serving replica:
 
 - **DeviceProfiler** — ``GET /debug/profile?seconds=`` runs a
   ``jax.profiler`` capture around whatever the scheduler has in flight
-  (single-flight: concurrent requests get 409), attributes per-kernel
-  device time through ``analysis/profiling.py`` (fused Pallas scoring
-  kernel vs gather/segment-sum chain vs transfers), and injects
-  ``device_kernel`` spans into every RUNNING job's trace so Perfetto shows
-  host spans and device kernels on one timeline.
+  (single-flight: concurrent requests get 409), reduces the ``.xplane.pb``
+  through ``analysis/profiling.py`` (busy union per chip, self time per
+  ``jax.named_scope``, idle gaps by the program span that covers them, all
+  on the wall clock of the job traces) and appends ``device_scope`` /
+  ``device_busy`` / ``device_idle`` spans to the trace of EVERY job whose
+  lease hold overlaps the capture, so ``GET /jobs/<id>/trace`` shows host
+  spans and device time on one timeline.
 
 Config: ``service.fleetview`` + ``telemetry.profile``.  Docs:
 docs/OBSERVABILITY.md ("Fleet plane", "Device profiles").
@@ -46,6 +48,7 @@ docs/OBSERVABILITY.md ("Fleet plane", "Device profiles").
 
 from __future__ import annotations
 
+import subprocess
 import threading
 import time
 import urllib.request
@@ -458,60 +461,60 @@ class DeviceProfiler:
             return 409, {"error": "a profile capture is already running",
                          "reason": "busy"}
         try:
-            from ..analysis.profiling import ProfileSession
+            from ..analysis.profiling import ProfileSession, reduce_capture
 
             session = ProfileSession(self.dir)
-            running = [j for j in self.service.scheduler.jobs()
-                       if j["state"] == "running"]
             try:
                 session.start()
             except RuntimeError as exc:
                 return 503, {"error": str(exc),
                              "reason": "profiler_unavailable"}
             time.sleep(secs)
-            result = session.stop()
-            injected = self._inject_device_spans(result["events"], running)
+            capture = session.stop()
+            try:
+                reduced = reduce_capture(capture, self._trace_files(capture))
+            except (RuntimeError, subprocess.TimeoutExpired) as exc:
+                # the capture itself is on disk for xprof / a later reduction
+                return 500, {"error": str(exc), "reason": "reduction_failed",
+                             "trace_file": capture["xplane"]}
+            injected = self._inject_device_spans(reduced.pop("inject"))
             self.c_captures.inc()
             return 200, {
                 "seconds": secs,
-                "duration_s": result["duration_s"],
-                "trace_file": result["trace_file"],
-                "attribution": result["attribution"],
-                "jobs_running": [j["msg_id"] for j in running],
+                "duration_s": capture["duration_s"],
+                "trace_file": capture["xplane"],
+                **reduced,
                 "injected_spans": injected,
             }
         finally:
             self._busy.release()
 
-    # a capture window can cover thousands of kernel launches; the job
-    # trace gets the longest ones (the attribution table carries the rest)
-    _MAX_INJECTED = 64
-
-    def _inject_device_spans(self, events: list[dict],
-                             running: list[dict]) -> int:
-        """Inject ``device_kernel`` spans (wall-clock mapped) into every
-        running job's trace file, so the Perfetto view of ``GET
-        /jobs/<id>/trace`` shows host spans and device kernels on one
-        timeline.  Returns the number of spans written (0 with no running
-        traced jobs — the capture result still carries the attribution)."""
+    def _trace_files(self, capture: dict) -> list[Path]:
+        """Trace files of the jobs that can overlap the capture: written to
+        since it began, or of a job still running (a job that holds its
+        lease through a long store may have written nothing since)."""
         trace_dir = getattr(self.service, "trace_dir", None)
-        if not events or not running or not trace_dir:
-            return 0
-        top = sorted(events, key=lambda e: e["dur_s"],
-                     reverse=True)[:self._MAX_INJECTED]
-        injected = 0
-        for job in running:
-            tid = job.get("trace_id")
-            if not tid:
-                continue
+        if not trace_dir:
+            return []
+        running = {j.get("trace_id") for j in self.service.scheduler.jobs()
+                   if j["state"] == "running"}
+        return [p for p in Path(trace_dir).glob("*.jsonl")
+                if p.stem in running
+                or p.stat().st_mtime >= capture["t0_wall"]]
+
+    def _inject_device_spans(self, inject: list[dict]) -> int:
+        """Append the reduction's per-hold spans (``device_scope`` per chip
+        and scope, ``device_busy`` per chip, ``device_idle`` for the longest
+        gaps) to each job's trace file, parented under the job's
+        ``device_hold`` span.  Returns the number of spans written."""
+        n = 0
+        for hold in inject:
             ctx = tracing.TraceContext(
-                trace_id=tid, span_id=tracing.new_id(),
-                job_id=job["msg_id"],
-                file=str(tracing.trace_path(trace_dir, tid)))
-            for e in top:
-                tracing.emit_span(
-                    ctx, "device_kernel", ts=e["ts_wall"], dur=e["dur_s"],
-                    module=e["module"], op=e["op"],
-                    kernel_class=e["class"])
-                injected += 1
-        return injected
+                trace_id=hold["trace_id"], span_id=hold["parent_id"],
+                job_id=hold["job"], file=hold["file"])
+            for rec in hold["records"]:
+                tracing.emit_span(ctx, rec["name"], ts=rec["ts"],
+                                  dur=rec["dur"],
+                                  parent_id=hold["parent_id"], **rec["attrs"])
+                n += 1
+        return n

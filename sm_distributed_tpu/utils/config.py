@@ -571,8 +571,9 @@ class ProfileConfig:
     """On-demand device profiling (ISSUE 20, service/fleetview.py,
     docs/OBSERVABILITY.md "Device profiles"): ``GET /debug/profile?seconds=``
     runs a ``jax.profiler`` capture around in-flight work, attributes device
-    time per kernel, and injects ``device_kernel`` spans into live job
-    traces."""
+    time per ``jax.named_scope`` and idle gaps per program span, and appends
+    ``device_scope`` / ``device_busy`` / ``device_idle`` spans to the traces
+    of the jobs it overlapped."""
     enabled: bool = True                 # serve /debug/profile
     default_seconds: float = 2.0         # capture window when ?seconds= is
                                          # omitted

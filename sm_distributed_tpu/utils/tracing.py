@@ -58,6 +58,16 @@ Overhead
 no-op immediately — untraced hot paths (bench floors, raw backend calls)
 pay one ContextVar read.  File emission caches one append handle per path
 and writes a single flushed line per record.
+
+Device captures
+---------------
+While a ``jax.profiler`` capture runs (``analysis/profiling.py::
+ProfileSession``), every span opened also enters a
+``jax.profiler.TraceAnnotation("sm:<name>", trace_id=, span_id=, job_id=)``
+so the raw ``.xplane.pb`` carries the program's spans on the profiler's own
+timeline.  The session installs the hook with ``set_capture`` and clears it
+when the capture stops; with no capture a span pays one ``is not None`` test
+and this module never imports jax.
 """
 
 from __future__ import annotations
@@ -96,6 +106,17 @@ _CAPTURE: contextvars.ContextVar["list | None"] = contextvars.ContextVar(
 
 _enabled = True
 
+# set by analysis/profiling.py::ProfileSession for the length of a capture:
+# called with a span's record as it opens, returns an object whose close()
+# runs as the span ends.  The hook must not raise.
+_capture = None
+
+
+def set_capture(hook) -> None:
+    """Install (or clear, with ``None``) the device-capture span hook."""
+    global _capture
+    _capture = hook
+
 
 def new_id() -> str:
     return uuid.uuid4().hex[:16]
@@ -109,11 +130,14 @@ class TraceContext:
     span_id: str
     job_id: str = ""
     file: str = ""                # per-job JSONL sink ("" = ring only)
+    # the open span's attrs, for ``annotate`` (None outside ``span``)
+    attrs: dict | None = field(default=None, compare=False, repr=False)
 
-    def child(self, span_id: str | None = None) -> "TraceContext":
+    def child(self, span_id: str | None = None,
+              attrs: dict | None = None) -> "TraceContext":
         return TraceContext(trace_id=self.trace_id,
                             span_id=span_id or new_id(),
-                            job_id=self.job_id, file=self.file)
+                            job_id=self.job_id, file=self.file, attrs=attrs)
 
     def to_wire(self) -> dict:
         """Minimal dict for a process hop (no file — workers have no sinks)."""
@@ -354,22 +378,35 @@ def span(name: str, /, ctx: TraceContext | None = None, **attrs):
     if parent is None or not _enabled:
         yield None
         return
-    child = parent.child()
+    child = parent.child(attrs=attrs)
     rec = _base(child, name, "span")
     rec["parent_id"] = parent.span_id
-    if attrs:
-        rec["attrs"] = attrs
+    rec["attrs"] = attrs
+    mark = _capture(rec) if _capture is not None else None
     token = _CTX.set(child)
     t0 = time.perf_counter()
     try:
         yield child
     except BaseException as exc:
-        rec.setdefault("attrs", {})["error"] = f"{type(exc).__name__}: {exc}"
+        attrs["error"] = f"{type(exc).__name__}: {exc}"
         raise
     finally:
         _CTX.reset(token)
         rec["dur"] = time.perf_counter() - t0
+        if mark is not None:
+            mark.close()
+        if not attrs:
+            del rec["attrs"]
         _emit(rec, parent.file)
+
+
+def annotate(**attrs) -> None:
+    """Add attrs to the innermost span open on this thread — for counts
+    known only once the work is done (a cache hit, bytes moved).  No-op on
+    an untraced path."""
+    ctx = _CTX.get()
+    if ctx is not None and ctx.attrs is not None:
+        ctx.attrs.update(attrs)
 
 
 def emit_span(ctx: TraceContext, name: str, /, ts: float = 0.0,

@@ -278,11 +278,111 @@ def test_profiler_validation_paths(tmp_path):
 
 @pytest.mark.slow
 def test_profiler_capture_smoke(tmp_path):
-    """A real (idle) capture returns 200 with a trace file or an empty
-    attribution — never an exception."""
+    """A real (idle) capture returns 200 with the file the profiler wrote
+    and its reduction — never an exception."""
     svc = _fake_service(tmp_path)
     prof = DeviceProfiler(svc, ProfileConfig(default_seconds=0.2))
     code, body = prof.run(0.2)
     assert code == 200
     assert body["seconds"] == 0.2
-    assert "attribution" in body and "injected_spans" in body
+    assert body["trace_file"].endswith(".xplane.pb")
+    assert body["clock"]["pairs"] == 2
+    for key in ("chips", "by_scope_s", "programs", "idle_gaps", "jobs",
+                "injected_spans"):
+        assert key in body
+
+
+def test_profiler_injects_device_spans_under_device_hold(tmp_path):
+    """ISSUE 24: every job whose lease hold overlaps the capture gets
+    ``device_scope`` / ``device_busy`` / ``device_idle`` spans parented
+    under its ``device_hold`` — ``whole`` for a hold that began after the
+    capture did and ended inside it, not for one the capture's edge cut."""
+    from sm_distributed_tpu.analysis import profiling
+    from sm_distributed_tpu.utils import tracing
+
+    svc = _fake_service(tmp_path)
+    svc.trace_dir = str(tmp_path / "traces")
+    t0 = 1_790_000_000.0                       # wall time of profiler t=0
+    ns = 1e9
+
+    def clock(at_s):
+        return ("sm_clock", at_s * ns, at_s * ns + 2000.0,
+                {"wall_ns": int((t0 + at_s) * ns)})
+
+    def op(a, b, name, scope):
+        return (a * ns, b * ns, name, scope)
+
+    # one chip, a 10 s capture; job "in" holds [2, 5], job "cut" from 8 on
+    chips = {0: {"modules": [(2.5 * ns, 2.9 * ns, "jit_score(1)")], "ops": [
+        op(2.5, 2.7, "%fusion.1 = f32[8] fusion()", "sm_extract"),
+        op(2.7, 2.9, "%while.2 = f32[8] while()", "sm_chaos"),
+        op(2.75, 2.85, "%custom-call.3 = f32[8] custom-call()", "sm_chaos"),
+        op(4.0, 4.1, "%fusion.4 = f32[8] fusion()", "sm_store_extract"),
+        op(8.5, 8.6, "%copy.5 = f32[8] copy()", "unscoped")]}}
+    annotations = [clock(0.0), clock(10.0)]
+
+    files = {}
+    for job, lease_at, hold_end in (("in", 2.0, 5.0), ("cut", 8.0, None)):
+        ctx = tracing.new_trace(job_id=job, trace_dir=svc.trace_dir)
+        hold = ctx.child()
+        rec = {"kind": "event", "trace_id": ctx.trace_id,
+               "span_id": hold.span_id, "name": "device_token_acquired",
+               "ts": t0 + lease_at, "pid": 1, "tid": 1, "job_id": job,
+               "attrs": {"devices": [0]}}
+        tracing.emit_records([rec], ctx)
+        tracing.emit_span(hold, "store_results", ts=t0 + lease_at + 1.0,
+                          dur=1.5, span_id="store" + job,
+                          parent_id=hold.span_id)
+        if hold_end is not None:
+            tracing.emit_span(hold, "device_hold", ts=t0 + lease_at - 0.5,
+                              dur=hold_end - lease_at + 0.5,
+                              span_id=hold.span_id, parent_id=ctx.span_id)
+        files[job] = (tracing.trace_path(svc.trace_dir, ctx.trace_id),
+                      hold.span_id)
+    traces = [(str(f), tracing.read_trace(f)) for f, _ in files.values()]
+    reduced = profiling.reduce_planes(chips, annotations, 10.0 * ns,
+                                      traces, [])
+    assert [(j["job"], j["whole"]) for j in reduced["jobs"]] == \
+        [("in", True), ("cut", False)]
+    # store_results of "in" is [3, 4.5] less the 0.1 s op, of "cut" [9, 10.5]
+    # cut at the capture's end; no job holds the chip in [0, 2] and [5, 8]
+    assert reduced["idle_by_host_s"]["store_results"] == \
+        pytest.approx(1.4 + 1.0, abs=1e-4)
+    assert reduced["idle_by_host_s"]["between_jobs"] == \
+        pytest.approx(5.0, abs=1e-4)
+
+    prof = DeviceProfiler(svc, ProfileConfig())
+    n = prof._inject_device_spans(reduced["inject"])
+    tracing.close_files()
+    got = {job: tracing.read_trace(f) for job, (f, _) in files.items()}
+    assert not tracing.validate_records(got["in"] + got["cut"])
+    assert n == sum(r["name"] in profiling.INJECTED
+                    for recs in got.values() for r in recs)
+    for job, whole in (("in", True), ("cut", False)):
+        dev = [r for r in got[job] if r["name"] in profiling.INJECTED]
+        assert dev and all(r["kind"] == "span" and r["job_id"] == job
+                           and r["parent_id"] == files[job][1] for r in dev)
+        assert all(r["attrs"]["whole"] is whole for r in dev
+                   if r["name"] != "device_idle")
+    scopes = {r["attrs"]["scope"]: r["attrs"] for r in got["in"]
+              if r["name"] == "device_scope"}
+    # self time: the custom call's 0.1 s comes out of the while loop's 0.2
+    assert scopes["sm_chaos"]["device_s"] == pytest.approx(0.2)
+    assert scopes["sm_chaos"]["n_ops"] == 2
+    assert scopes["sm_extract"]["device_s"] == pytest.approx(0.2)
+    assert scopes["sm_store_extract"]["device_s"] == pytest.approx(0.1)
+    (busy,) = [r for r in got["in"] if r["name"] == "device_busy"]
+    assert busy["attrs"]["busy_s"] == pytest.approx(0.5)
+    assert busy["attrs"]["hold_s"] == pytest.approx(3.0)
+    assert busy["ts"] == pytest.approx(t0 + 2.0)
+    idle = [r for r in got["in"] if r["name"] == "device_idle"]
+    assert max(idle, key=lambda r: r["dur"])["attrs"] == {
+        "chip": 0, "host": "store_results", "host_span_id": "storein"}
+    (cut_busy,) = [r for r in got["cut"] if r["name"] == "device_busy"]
+    assert cut_busy["attrs"]["hold_s"] == pytest.approx(2.0, abs=1e-4)
+    # a second capture over the same job does not read the first one's
+    # device spans as program spans
+    again = profiling.reduce_planes(
+        chips, annotations, 10.0 * ns,
+        [(str(f), tracing.read_trace(f)) for f, _ in files.values()], [])
+    assert again["idle_by_host_s"] == reduced["idle_by_host_s"]

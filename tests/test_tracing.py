@@ -456,3 +456,158 @@ def test_trace_report_renders_service_trace(tmp_path, capsys):
         assert summary["accounting"]["queue_wait_s"] is not None
     finally:
         h.shutdown()
+
+
+# ------------------------------------------ device captures (ISSUE 24)
+def test_span_without_capture_enters_no_annotation_and_imports_no_jax():
+    """The path with no capture: one ``is not None`` test, no jax."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from sm_distributed_tpu.utils import tracing\n"
+        "assert tracing._capture is None\n"
+        "ctx = tracing.new_trace(job_id='j')\n"
+        "with tracing.span('a', ctx=ctx):\n"
+        "    with tracing.span('b'):\n"
+        "        tracing.annotate(n=1)\n"
+        "assert 'jax' not in sys.modules, 'tracing imported jax'\n"
+        "assert [r['name'] for r in tracing.flight_recorder.recent()] "
+        "== ['b', 'a']\n")
+    repo = Path(__file__).resolve().parent.parent
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_span_under_capture_enters_sm_annotation(tmp_path):
+    """With the session's hook set, a span enters ``sm:<name>`` carrying
+    its ids, is listed as open while it runs, and exits as it closes —
+    also on a thread that attached the context."""
+    from sm_distributed_tpu.analysis import profiling
+
+    log = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            log.append(("enter", self.name, self.kw))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    session = profiling.ProfileSession(tmp_path)
+    session.annotation = FakeAnnotation
+    ctx = tracing.new_trace(job_id="job-9", trace_dir=tmp_path)
+    tracing.set_capture(lambda rec: profiling._OpenSpan(session, rec))
+    try:
+        with tracing.span("store_results", ctx=ctx) as outer:
+            assert [r["name"] for r in session.open.values()] == \
+                ["store_results"]
+
+            def pool_thread():
+                with tracing.attach(outer), tracing.span("store_tables"):
+                    assert len(session.open) == 2
+
+            t = threading.Thread(target=pool_thread)
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        tracing.set_capture(None)
+    assert session.open == {}
+    assert [e[:2] for e in log] == [
+        ("enter", "sm:store_results"), ("enter", "sm:store_tables"),
+        ("exit", "sm:store_tables"), ("exit", "sm:store_results")]
+    records = {r["name"]: r for r in tracing.read_trace(
+        tracing.trace_path(tmp_path, ctx.trace_id))}
+    for _what, name, kw in (e for e in log if e[0] == "enter"):
+        rec = records[name.removeprefix("sm:")]
+        assert kw == {"trace_id": ctx.trace_id, "span_id": rec["span_id"],
+                      "job_id": "job-9"}
+    # the hook is gone: the next span enters nothing
+    with tracing.span("after", ctx=ctx):
+        pass
+    assert len(log) == 4
+
+
+def test_annotate_adds_attrs_to_the_open_span(tmp_path):
+    ctx = tracing.new_trace(trace_dir=tmp_path)
+    tracing.annotate(ignored=True)               # untraced: a no-op
+    with tracing.span("backend_build", ctx=ctx):
+        with tracing.span("build_sort"):
+            pass
+        tracing.annotate(cache_hit=False, peaks_in=7)
+    recs = {r["name"]: r for r in tracing.read_trace(
+        tracing.trace_path(tmp_path, ctx.trace_id))}
+    assert recs["backend_build"]["attrs"] == {"cache_hit": False,
+                                              "peaks_in": 7}
+    assert "attrs" not in recs["build_sort"]
+
+
+def test_build_and_store_spans_of_a_real_job(tmp_path):
+    """A real 8x8-px job on the jax backend (XLA-CPU): ``backend_build``
+    with its four children and the four ``store_*`` spans appear once
+    each, correctly parented; a resubmit hits the backend cache and builds
+    nothing; a compile the job pays names its lease's chips."""
+    import sys
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from scripts.load_sweep import Harness, _msg, build_fixtures
+
+    fx = build_fixtures(tmp_path)
+    h = Harness(tmp_path, "svc", {
+        "backend": "jax_tpu", "storage": {"store_images": True},
+        "service": {"job_timeout_s": 300.0}})
+    try:
+        traces = []
+        for msg_id in ("first", "again"):
+            status, _hd, body = h.submit(_msg(fx, "fast", "ds", msg_id=msg_id))
+            assert status == 202
+            rows = h.wait_terminal([msg_id], timeout_s=300.0)
+            assert rows[msg_id]["state"] == "done", rows[msg_id]
+            traces.append(tracing.read_trace(tracing.trace_path(
+                h.service.trace_dir, body["trace_id"])))
+    finally:
+        h.shutdown()
+    first, again = traces
+    assert not tracing.validate_records(first + again)
+
+    def one(records, name):
+        (span,) = [r for r in records if r["kind"] == "span"
+                   and r["name"] == name]
+        return span
+
+    build = one(first, "backend_build")
+    assert build["parent_id"] == one(first, "device_hold")["span_id"]
+    assert build["attrs"]["cache_hit"] is False
+    assert build["attrs"]["peaks_resident"] <= build["attrs"]["peaks_in"] \
+        or build["attrs"]["peaks_resident"] % 1024 == 0
+    assert build["attrs"]["resident_bytes"] > 0
+    children = [one(first, n) for n in (
+        "build_sort", "build_restrict", "build_pad_compact",
+        "build_device_put")]
+    assert all(c["parent_id"] == build["span_id"] for c in children)
+    assert sum(c["dur"] for c in children) <= build["dur"]
+    assert [c["ts"] for c in children] == sorted(c["ts"] for c in children)
+    assert build["ts"] + build["dur"] <= one(first, "score")["ts"] + 1e-3
+    for records in (first, again):
+        store = one(records, "store_results")
+        parts = [one(records, n) for n in (
+            "store_select", "store_extract_images", "store_write_images",
+            "store_tables")]
+        assert all(p["parent_id"] == store["span_id"] for p in parts)
+        assert sum(p["dur"] for p in parts) <= store["dur"]
+        extract, write = parts[1], parts[2]
+        assert extract["attrs"]["ions"] > 0
+        assert extract["attrs"]["bytes"] == write["attrs"]["bytes"] > 0
+        assert write["attrs"]["format"] == "npz"
+    hit = one(again, "backend_build")
+    assert hit["attrs"]["cache_hit"] is True
+    assert not [r for r in again if r["name"].startswith("build_")]
+    compiles = [r for r in first if r["kind"] == "event"
+                and r["name"] == "compile"]
+    assert compiles and all(c["attrs"]["devices"] == [0] for c in compiles)
