@@ -23,11 +23,13 @@ from __future__ import annotations
 import json
 import sqlite3
 import time
+import zipfile
 from pathlib import Path
 
 import numpy as np
 import pandas as pd
 
+from ..utils import tracing
 from ..utils.failpoints import failpoint, record_recovery, register_failpoint
 from ..utils.logger import logger
 
@@ -39,6 +41,10 @@ FP_INDEX_COMMIT = register_failpoint(
     "inside the annotation index delete+insert, before the commit")
 FP_LEDGER_FINISH = register_failpoint(
     "ledger.finish_job", "before the job row flips STARTED -> FINISHED")
+
+# layout marker of ion_images.npz (store_ion_images); files without one are
+# the CSR triple written before PR 25
+IMAGE_LAYOUT = "bitmask_v1"
 
 JOB_STARTED = "STARTED"
 JOB_FINISHED = "FINISHED"
@@ -361,7 +367,24 @@ class SearchResultsStore:
         ``iso_image`` table [U]; dense tiles live on TPU, sparsity only at
         host egress — SURVEY.md §2c).  PNG mode writes ALL isotope-peak
         images (suffix _0.._K-1, like the reference's per-isotope PNGs [U])
-        with the sample-area mask rendered transparent."""
+        with the sample-area mask rendered transparent.
+
+        npz layout ``IMAGE_LAYOUT`` (PR 25), with ``flat`` the images as
+        ``(n_ions * K, n_pix)`` in C order:
+
+        - ``mask``: ``np.packbits(flat != 0)`` over the whole of ``flat``
+          (uint8, ``bitorder="big"``: pixel ``8 * i`` is the top bit of byte
+          ``i``; the last byte is zero-padded) — one bit a pixel where a CSR
+          column index spent 32.  Deflated: it is small, and at densities
+          under 1/32 that is what keeps it below an index list.
+        - ``data``: ``flat[flat != 0]`` as f32, i.e. the non-zero values in
+          row-major order.  STORED, never deflated: deflate took 10-27% off
+          them for seconds of one core under the device lease (11 s of a
+          13 s job at 128x128, PERF.md PR 25).
+        - ``shape`` ``[n_ions, K, nrows, ncols]``, ``ions`` ``"sf|adduct"``,
+          ``layout`` the marker ``load_ion_images`` branches on.
+
+        ``-0.0`` is a zero and reads back ``+0.0``; ``NaN`` is a value."""
         d = self.ds_dir(ds_id)
         if self.image_format == "png":
             from .png import PngGenerator
@@ -377,36 +400,50 @@ class SearchResultsStore:
             return img_dir
         flat = images.reshape(images.shape[0] * images.shape[1], -1)
         nz = flat != 0
-        counts = nz.sum(axis=1)
-        indptr = np.zeros(flat.shape[0] + 1, dtype=np.int64)
-        np.cumsum(counts, out=indptr[1:])
-        cols = np.nonzero(nz)[1].astype(np.int32)
-        vals = flat[nz].astype(np.float32)
+        members = {
+            "data": flat[nz].astype(np.float32, copy=False),
+            "mask": np.packbits(nz),
+            "shape": np.array(
+                [images.shape[0], images.shape[1], nrows, ncols]),
+            "ions": np.array([f"{sf}|{adduct}" for sf, adduct in ions]),
+            "layout": np.array(IMAGE_LAYOUT),
+        }
         # tmp + atomic rename: the tile service (ISSUE 16) reads this file
         # under concurrent re-annotation — readers must see the previous
         # complete npz or the new one, never a partial write
         tmp = d / "ion_images.npz.tmp"
-        with open(tmp, "wb") as f:
-            np.savez_compressed(
-                f,
-                data=vals, indices=cols, indptr=indptr,
-                shape=np.array(
-                    [images.shape[0], images.shape[1], nrows, ncols]),
-                ions=np.array([f"{sf}|{adduct}" for sf, adduct in ions]),
-            )
+        with zipfile.ZipFile(tmp, "w", zipfile.ZIP_STORED) as zf:
+            for name, arr in members.items():
+                info = zipfile.ZipInfo(name + ".npy")
+                if name == "mask":
+                    info.compress_type = zipfile.ZIP_DEFLATED
+                with zf.open(info, "w", force_zip64=True) as fid:
+                    np.lib.format.write_array(fid, arr, allow_pickle=False)
+        # onto the caller's store_write_images span: which writer ran and
+        # what it put on disk
+        tracing.annotate(layout=IMAGE_LAYOUT, nnz=int(members["data"].size),
+                         file_bytes=tmp.stat().st_size)
         tmp.replace(d / "ion_images.npz")
         return d / "ion_images.npz"
 
     @staticmethod
     def load_ion_images(path: str | Path) -> tuple[np.ndarray, list[tuple[str, str]]]:
         """Inverse of ``store_ion_images`` (npz format): dense (n_ions, K,
-        nrows, ncols) + ion list."""
-        z = np.load(path, allow_pickle=False)
-        n_ions, k, nrows, ncols = (int(x) for x in z["shape"])
-        flat = np.zeros((n_ions * k, nrows * ncols), dtype=np.float32)
-        indptr = z["indptr"]
-        for r in range(flat.shape[0]):
-            s, e = indptr[r], indptr[r + 1]
-            flat[r, z["indices"][s:e]] = z["data"][s:e]
-        ions = [tuple(s.split("|", 1)) for s in z["ions"].tolist()]
+        nrows, ncols) + ion list.  Reads by the file's own content: the
+        mask + values layout, or the CSR triple (``data`` / ``indices`` /
+        ``indptr``, deflated) that every store before PR 25 wrote."""
+        with np.load(path, allow_pickle=False) as z:
+            n_ions, k, nrows, ncols = (int(x) for x in z["shape"])
+            flat = np.zeros((n_ions * k, nrows * ncols), dtype=np.float32)
+            if "indptr" in z.files:
+                rows = np.repeat(np.arange(flat.shape[0]),
+                                 np.diff(z["indptr"]))
+                flat[rows, z["indices"]] = z["data"]
+            elif str(z["layout"]) == IMAGE_LAYOUT:
+                nz = np.unpackbits(z["mask"], count=flat.size)
+                flat[nz.view(bool).reshape(flat.shape)] = z["data"]
+            else:
+                raise ValueError(
+                    f"{path}: unknown ion image layout {z['layout']!r}")
+            ions = [tuple(s.split("|", 1)) for s in z["ions"].tolist()]
         return flat.reshape(n_ions, k, nrows, ncols), ions

@@ -4,6 +4,7 @@ DB-integration + end-to-end test tier (SURVEY.md §4) against the local
 sqlite/parquet/file-queue stand-ins."""
 
 import json
+import zipfile
 from pathlib import Path
 
 import numpy as np
@@ -106,6 +107,86 @@ def test_results_store_parquet_and_images(tmp_path):
     dense, ions = SearchResultsStore.load_ion_images(path)
     assert ions == [("A", "+H"), ("B", "+Na")]
     np.testing.assert_allclose(dense.reshape(2, 4, 12), imgs)
+
+
+def _image_store(tmp_path) -> SearchResultsStore:
+    return SearchResultsStore(JobLedger(tmp_path / "res"))
+
+
+def _image_case(name: str) -> tuple[np.ndarray, int, int]:
+    """(images (n_ions, K, n_pix), nrows, ncols) of one round-trip case."""
+    rng = np.random.default_rng(25)
+    n_ions, k, nrows, ncols = {
+        "npix_not_multiple_of_8": (3, 4, 7, 9),      # 63 px, 756 bits
+        "section_128x128": (5, 4, 128, 128),
+    }.get(name, (6, 4, 16, 20))
+    imgs = rng.random((n_ions, k, nrows * ncols), dtype=np.float32) + 0.5
+    density = {"density_0": 0.0, "density_3pct": 0.03, "density_100": 1.0,
+               }.get(name, 0.7)
+    imgs[rng.random(imgs.shape) >= density] = 0.0
+    if name == "negative_zero_and_nan":
+        imgs[0, 0, :5] = [-0.0, np.nan, 0.0, -np.nan, -1.5]
+        imgs[-1, -1, -1] = -0.0
+    return imgs, nrows, ncols
+
+
+@pytest.mark.parametrize("case", [
+    "density_0", "density_3pct", "density_70pct", "density_100",
+    "negative_zero_and_nan", "npix_not_multiple_of_8", "section_128x128"])
+def test_ion_images_npz_round_trip_bit_identical(tmp_path, case):
+    imgs, nrows, ncols = _image_case(case)
+    ions = [(f"C{i}H{2 * i}", "+H") for i in range(imgs.shape[0])]
+    store = _image_store(tmp_path)
+    path = store.store_ion_images("ds1", imgs, ions, nrows, ncols)
+    dense, got_ions = SearchResultsStore.load_ion_images(path)
+    assert got_ions == ions
+    assert dense.shape == (*imgs.shape[:2], nrows, ncols)
+    # `flat != 0` decides what is a value: -0.0 is a zero (reads back +0.0),
+    # NaN is a value and keeps its sign and payload bits
+    want = imgs.copy()
+    want[want == 0] = 0.0
+    assert np.array_equal(dense.reshape(imgs.shape).view(np.uint32),
+                          want.view(np.uint32))
+    if case != "negative_zero_and_nan":
+        assert np.array_equal(dense.reshape(imgs.shape), imgs)
+    assert path.name == "ion_images.npz"
+    assert not list(path.parent.glob("*.tmp"))
+    with np.load(path, allow_pickle=False) as z:
+        assert sorted(z.files) == ["data", "ions", "layout", "mask", "shape"]
+        assert z["data"].dtype == np.float32
+        assert z["data"].size == np.count_nonzero(imgs != 0)
+        assert z["mask"].size == -(-imgs.size // 8)
+
+
+def test_ion_images_npz_size_bound_and_values_not_deflated(tmp_path):
+    """The benchmark's 128x128 store: 302 ions x 4 peaks x 16,384 px at its
+    `_spatial_patterns`-like 72% density.  The file is the values, one bit a
+    pixel and headers - and `data` is never deflated (11 s of one core
+    under the device lease, PERF.md PR 25)."""
+    rng = np.random.default_rng(128)
+    rows, n_pix = 302 * 4, 128 * 128
+    imgs = rng.random((rows, n_pix), dtype=np.float32)
+    imgs[imgs < 0.28] = 0.0
+    imgs = imgs.reshape(302, 4, n_pix)
+    ions = [(f"C{i}", "+H") for i in range(302)]
+    path = _image_store(tmp_path).store_ion_images("ds1", imgs, ions, 128, 128)
+    nnz = int(np.count_nonzero(imgs))
+    assert 0.70 < nnz / imgs.size < 0.74
+    assert path.stat().st_size <= 4 * nnz + rows * n_pix // 8 + 65536
+    with zipfile.ZipFile(path) as zf:
+        assert zf.getinfo("data.npy").compress_type == zipfile.ZIP_STORED
+        assert zf.getinfo("data.npy").file_size >= 4 * nnz
+    dense, _ions = SearchResultsStore.load_ion_images(path)
+    assert np.array_equal(dense.reshape(imgs.shape), imgs)
+
+
+def test_ion_images_npz_unknown_layout_is_refused(tmp_path):
+    path = tmp_path / "ion_images.npz"
+    np.savez(path, shape=np.array([1, 1, 2, 2]), ions=np.array(["A|+H"]),
+             layout=np.array("bitmask_v9"), mask=np.zeros(1, np.uint8),
+             data=np.zeros(0, np.float32))
+    with pytest.raises(ValueError, match="bitmask_v9"):
+        SearchResultsStore.load_ion_images(path)
 
 
 def test_work_dir_staging_resume_and_subdirs(tmp_path):

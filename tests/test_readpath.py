@@ -214,7 +214,22 @@ def test_crashed_publish_leaves_previous_segment_served(tmp_path):
 
 
 # ----------------------------------------------------------------- tiles
-def _store_images(tmp_path, ds_id="ds1", n_ions=3, k=2, nrows=6, ncols=5):
+def _write_csr_v1(path, images, ions, nrows, ncols):
+    """`ion_images.npz` as every store before PR 25 wrote it: the CSR triple,
+    every member deflated, no `layout` member."""
+    flat = images.reshape(images.shape[0] * images.shape[1], -1)
+    nz = flat != 0
+    indptr = np.zeros(flat.shape[0] + 1, dtype=np.int64)
+    np.cumsum(nz.sum(axis=1), out=indptr[1:])
+    np.savez_compressed(
+        path, data=flat[nz].astype(np.float32),
+        indices=np.nonzero(nz)[1].astype(np.int32), indptr=indptr,
+        shape=np.array([images.shape[0], images.shape[1], nrows, ncols]),
+        ions=np.array([f"{sf}|{adduct}" for sf, adduct in ions]))
+
+
+def _store_images(tmp_path, ds_id="ds1", n_ions=3, k=2, nrows=6, ncols=5,
+                  layout="bitmask_v1"):
     rng = np.random.default_rng(7)
     images = rng.uniform(0, 1, (n_ions, k, nrows * ncols)).astype(np.float32)
     images[images < 0.3] = 0.0                  # sparsity, like real tiles
@@ -225,12 +240,22 @@ def _store_images(tmp_path, ds_id="ds1", n_ions=3, k=2, nrows=6, ncols=5):
     d = tmp_path / ds_id
     d.mkdir(parents=True, exist_ok=True)
     store.ds_dir = lambda _ds: d
-    store.store_ion_images(ds_id, images, ions, nrows, ncols)
+    if layout == "csr_v1":
+        _write_csr_v1(d / "ion_images.npz", images, ions, nrows, ncols)
+    else:
+        store.store_ion_images(ds_id, images, ions, nrows, ncols)
+    with np.load(d / "ion_images.npz") as z:
+        assert ("indptr" in z.files) == (layout == "csr_v1")
+        assert ("layout" in z.files) == (layout != "csr_v1")
     return images.reshape(n_ions, k, nrows, ncols), ions
 
 
-def test_tile_bytes_bit_identical_to_direct_render(tmp_path):
-    images, ions = _store_images(tmp_path)
+@pytest.mark.parametrize("layout", ["bitmask_v1", "csr_v1"])
+def test_tile_bytes_bit_identical_to_direct_render(tmp_path, layout):
+    images, ions = _store_images(tmp_path, layout=layout)
+    dense, got_ions = SearchResultsStore.load_ion_images(
+        tmp_path / "ds1" / "ion_images.npz")
+    assert got_ions == ions and np.array_equal(dense, images)
     rp = ReadPath(tmp_path, ReadPathConfig())
     for i, (sf, adduct) in enumerate(ions):
         for k in range(images.shape[1]):
@@ -245,6 +270,21 @@ def test_tile_bytes_bit_identical_to_direct_render(tmp_path):
     assert status == 404
     status, _body, _hd = rp.handle_tile("ds1", "no-pipe-here", {})
     assert status == 400
+
+
+def test_both_npz_layouts_render_the_same_tile_bytes(tmp_path):
+    """A deployment's old CSR file and the same images re-stored by today's
+    writer are one answer to a reader: same dense array, same PNG bytes."""
+    new, ions = _store_images(tmp_path / "new")
+    old, _ions = _store_images(tmp_path / "old", layout="csr_v1")
+    assert np.array_equal(new, old)
+    tiles = []
+    for root in (tmp_path / "new", tmp_path / "old"):
+        rp = ReadPath(root, ReadPathConfig())
+        tiles.append([rp.handle_tile("ds1", f"{sf}|{ad}", {"k": [str(k)]})[:2]
+                      for sf, ad in ions for k in range(new.shape[1])])
+    assert tiles[0] == tiles[1]
+    assert all(status == 200 for status, _body in tiles[0])
 
 
 def test_tile_disk_tier_round_trip(tmp_path):

@@ -605,6 +605,11 @@ def test_build_and_store_spans_of_a_real_job(tmp_path):
         assert extract["attrs"]["ions"] > 0
         assert extract["attrs"]["bytes"] == write["attrs"]["bytes"] > 0
         assert write["attrs"]["format"] == "npz"
+        assert write["attrs"]["layout"] == "bitmask_v1"
+        # the writer's own counts: 4 B a value + the bit mask, nothing more
+        assert 0 < 4 * write["attrs"]["nnz"] <= write["attrs"]["bytes"]
+        assert 4 * write["attrs"]["nnz"] < write["attrs"]["file_bytes"] \
+            <= 4 * write["attrs"]["nnz"] + write["attrs"]["bytes"] // 32 + 65536
     hit = one(again, "backend_build")
     assert hit["attrs"]["cache_hit"] is True
     assert not [r for r in again if r["name"].startswith("build_")]
