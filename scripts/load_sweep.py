@@ -191,9 +191,12 @@ class Harness:
 
     # ---------------------------------------------------------- invariants
     def sample_depth(self) -> int:
-        """Admitted-but-not-terminal occupancy as seen on disk."""
-        return (len(list(self.root.glob("pending/*.json")))
-                + len(list(self.root.glob("running/*.json"))))
+        """Admitted-but-not-terminal occupancy as seen on disk.  running/
+        is listed first: a message claimed between the two listings is then
+        missed, not counted in both (an upper-bound check must not see a
+        depth that never was)."""
+        running = len(list(self.root.glob("running/*.json")))
+        return running + len(list(self.root.glob("pending/*.json")))
 
     def assert_clean(self, label: str) -> None:
         zombies = [t.name for t in threading.enumerate()

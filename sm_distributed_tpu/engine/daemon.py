@@ -356,6 +356,7 @@ def annotate_callback(sm_config: SMConfig, residency=None):
             # from the first scored group surface on the job record's
             # ``partial`` field while later batches still run
             on_partial=getattr(ctx, "set_partial", None),
+            workers_busy=getattr(ctx, "workers_busy", None),
         )
         # the scheduler's attempt-span context (already ambient when the
         # scheduler ran this in an _Attempt thread; attached here too so the

@@ -47,6 +47,7 @@ class SearchJob:
         cancel=None,
         fence=None,
         on_partial=None,
+        workers_busy: int | None = None,
     ):
         self.ds_id = ds_id
         self.ds_name = ds_name
@@ -85,6 +86,9 @@ class SearchJob:
         # passes ``ctx.set_partial`` so GET /jobs shows the preview)
         self.on_partial = on_partial
         self.last_partial: dict = {}
+        # service mode: attempts in flight in this process when this one
+        # started (``JobContext.workers_busy``), the ``pre_lease`` span's attr
+        self.workers_busy = workers_busy
         self.ledger = JobLedger(self.sm_config.storage.results_dir)
         # generation stats of the last completed run (workers, patterns/s,
         # device flag) — read by probes/benches (scripts/cold_path_bench.py)
@@ -121,30 +125,36 @@ class SearchJob:
         prefetch = None
         try:
             timings: dict[str, float] = {}
-            # ISSUE 3 layer 3: isotope-pattern generation needs only the
-            # formula list + configs, and it dominates the cold path (94.5%
-            # of the BASELINE #3 wall) — start it FIRST, so staging + parse
-            # below overlap it instead of queueing behind it
-            formulas = self._load_formulas()
-            if self.cancel is not None:
-                self.cancel.check("load_formulas")
-            if self.sm_config.parallel.overlap_isocalc != "off":
-                prefetch = IsotopePrefetch(
-                    formulas, self.ds_config, self.sm_config,
-                    str(Path(self.sm_config.work_dir) / "isocalc_cache"))
-            ds = self._prepare_dataset(timings)
-            logger.info(
-                "dataset %s: %dx%d px, %d spectra, %d peaks",
-                self.ds_id, ds.nrows, ds.ncols, ds.n_spectra, ds.n_peaks,
-            )
-            if self.profile_dir:
-                from ..analysis.profiling import ProfileSession
+            # pre_lease: the attempt's host-only work, from here to the
+            # moment the job asks the pool for a chip (device_hold opens
+            # next); the phase spans below are its children
+            busy = {} if self.workers_busy is None else \
+                {"workers_busy": int(self.workers_busy)}
+            with tracing.span("pre_lease", **busy):
+                # ISSUE 3 layer 3: isotope-pattern generation needs only the
+                # formula list + configs, and it dominates the cold path (94.5%
+                # of the BASELINE #3 wall) — start it FIRST, so staging + parse
+                # below overlap it instead of queueing behind it
+                formulas = self._load_formulas()
+                if self.cancel is not None:
+                    self.cancel.check("load_formulas")
+                if self.sm_config.parallel.overlap_isocalc != "off":
+                    prefetch = IsotopePrefetch(
+                        formulas, self.ds_config, self.sm_config,
+                        str(Path(self.sm_config.work_dir) / "isocalc_cache"))
+                ds = self._prepare_dataset(timings)
+                logger.info(
+                    "dataset %s: %dx%d px, %d spectra, %d peaks",
+                    self.ds_id, ds.nrows, ds.ncols, ds.n_spectra, ds.n_peaks,
+                )
+                if self.profile_dir:
+                    from ..analysis.profiling import ProfileSession
 
-                prof = ProfileSession(self.profile_dir)
-                prof.start()
-                # correlate the jax.profiler trace dir into the job trace:
-                # /jobs/<id>/trace surfaces it in otherData.jax_profile_dir
-                tracing.event("jax_profile", dir=str(self.profile_dir))
+                    prof = ProfileSession(self.profile_dir)
+                    prof.start()
+                    # correlate the jax.profiler trace dir into the job trace:
+                    # /jobs/<id>/trace surfaces it in otherData.jax_profile_dir
+                    tracing.event("jax_profile", dir=str(self.profile_dir))
             import contextlib
 
             # everything up to here is CPU-bound (staging, parse, formula

@@ -33,7 +33,11 @@ old ``scheduler.device_token`` lock still behaves.
 
 Metrics (``attach_metrics``): ``sm_device_pool_in_use{device=}``,
 ``sm_device_pool_devices``, ``sm_device_pool_waiters``,
-``sm_device_pool_grants_total``, ``sm_device_pool_wait_seconds``.
+``sm_device_pool_grants_total``, ``sm_device_pool_wait_seconds``,
+``sm_device_pool_held_seconds_total{device=}`` (chip-seconds under a lease:
+what two scrapes of the ``in_use`` gauge cannot integrate) beside
+``sm_device_pool_clock_seconds_total`` (the same clock at the same instant:
+the two deltas of a window divide to the mean number of chips held).
 """
 
 from __future__ import annotations
@@ -64,6 +68,7 @@ class DeviceLease:
         self._bypassed = 0                   # grants that jumped this waiter
         self._queued = False
         self._waiting_since = 0.0
+        self._granted_at = 0.0               # monotonic, while devices held
 
     @property
     def hosts(self) -> tuple[int, ...]:
@@ -104,7 +109,9 @@ class DevicePool:
     # are the documented caller-holds-lock exception)
     _GUARDED_BY = {"_owner": "_cond", "_waiters": "_cond",
                    "_compat": "_cond", "grants_total": "_cond",
-                   "releases_total": "_cond", "leases_reaped_total": "_cond"}
+                   "releases_total": "_cond", "leases_reaped_total": "_cond",
+                   "_held_s": "_cond", "_held_exported": "_cond",
+                   "_clock_exported": "_cond"}
 
     def __init__(self, size: int, max_bypass: int = 64, hosts: int = 1,
                  health: HealthTracker | None = None):
@@ -136,6 +143,11 @@ class DevicePool:
         self._owner: list[DeviceLease | None] = [None] * self.size
         self._waiters: list[DeviceLease] = []
         self._compat: list[DeviceLease] = []   # legacy single-token grants
+        # chip-seconds under a lease, per chip: accrued when a lease gives
+        # its chips back; the scrape adds what open leases have held so far
+        self._held_s = [0.0] * self.size
+        self._held_exported = [0.0] * self.size
+        self._clock_exported = time.monotonic()
         self.grants_total = 0
         self.releases_total = 0
         self.leases_reaped_total = 0
@@ -144,6 +156,8 @@ class DevicePool:
         self._m_in_use = None
         self._m_waiters = None
         self._m_reaped = None
+        self._m_held = None
+        self._m_clock = None
 
     # ------------------------------------------------------------ metrics
     def attach_metrics(self, registry) -> None:
@@ -173,9 +187,29 @@ class DevicePool:
             "sm_device_pool_leases_reaped_total",
             "Abandoned-attempt leases reclaimed by the zombie reaper",
             ("reason",))
+        self._m_held = registry.counter(
+            "sm_device_pool_held_seconds_total",
+            "Seconds the chip has spent under a job lease, open leases "
+            "counted up to the scrape", ("device",))
+        self._m_clock = registry.counter(
+            "sm_device_pool_clock_seconds_total",
+            "Seconds on the clock held_seconds is read from, at the scrape")
+        registry.add_collector(self._collect_held)
         # per-chip health family (ISSUE 14): sm_device_health{device=},
         # quarantines/probes/readmits/host-evictions counters
         self.health.attach_metrics(registry)
+
+    def _collect_held(self, _registry) -> None:
+        with self._cond:
+            now = time.monotonic()
+            held = [s + (now - o._granted_at if o is not None else 0.0)
+                    for s, o in zip(self._held_s, self._owner)]
+            steps = [h - e for h, e in zip(held, self._held_exported)]
+            tick = now - self._clock_exported
+            self._held_exported, self._clock_exported = held, now
+        self._m_clock.inc(tick)
+        for i, step in enumerate(steps):
+            self._m_held.labels(device=str(i)).inc(step)
 
     # ---------------------------------------------------------- inspection
     def lease(self, n: int, msg_id: str = "") -> DeviceLease:
@@ -307,7 +341,8 @@ class DevicePool:
         for i in lease.devices:
             self._owner[i] = lease
         self.grants_total += 1
-        lease.last_wait_s = time.monotonic() - lease._waiting_since
+        lease._granted_at = time.monotonic()
+        lease.last_wait_s = lease._granted_at - lease._waiting_since
         if self._m_grants is not None:
             self._m_grants.inc()
             self._m_wait.observe(lease.last_wait_s)
@@ -367,18 +402,24 @@ class DevicePool:
                 lease.msg_id or "anonymous")
             self._regrant(lease)
 
+    def _free_locked(self, lease: DeviceLease) -> None:
+        # caller holds self._cond
+        held = time.monotonic() - lease._granted_at
+        for i in lease.devices:
+            if self._owner[i] is lease:
+                self._owner[i] = None
+                self._held_s[i] += held
+        if self._m_in_use is not None:
+            for i in lease.devices:
+                self._m_in_use.labels(device=str(i)).set(0)
+        lease.devices = ()
+
     def _regrant(self, lease: DeviceLease) -> None:
         """Return a probe-rejected grant's chips and requeue the lease at
         the FRONT (it had already won the FIFO race; the probe verdict
         must not cost it its place in line)."""
         with self._cond:
-            for i in lease.devices:
-                if self._owner[i] is lease:
-                    self._owner[i] = None
-            if self._m_in_use is not None:
-                for i in lease.devices:
-                    self._m_in_use.labels(device=str(i)).set(0)
-            lease.devices = ()
+            self._free_locked(lease)
             lease._queued = True
             self._waiters.insert(0, lease)
             if self._m_waiters is not None:
@@ -398,13 +439,7 @@ class DevicePool:
                 if self._m_waiters is not None:
                     self._m_waiters.set(len(self._waiters))
             if lease.devices:
-                for i in lease.devices:
-                    if self._owner[i] is lease:
-                        self._owner[i] = None
-                if self._m_in_use is not None:
-                    for i in lease.devices:
-                        self._m_in_use.labels(device=str(i)).set(0)
-                lease.devices = ()
+                self._free_locked(lease)
                 self.releases_total += 1
             self._cond.notify_all()
 

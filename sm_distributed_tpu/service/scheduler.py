@@ -226,6 +226,10 @@ class JobContext:
     # lands — it updates the job record's ``partial`` field served by
     # GET /jobs.  None for legacy callers.
     set_partial: object = field(repr=False, default=None)
+    # attempts in flight in this process when this one started, itself
+    # included: how many workers share the interpreter with the job's
+    # host-only work (the ``pre_lease`` span's attr)
+    workers_busy: int = 0
 
 
 def _callback_takes_ctx(fn) -> bool:
@@ -943,6 +947,7 @@ class JobScheduler:
             attempt = _Attempt(self.callback, msg, ctx, self._cb_takes_ctx)
             with self._records_lock:
                 self._live[msg_id] = (token, attempt)
+                ctx.workers_busy = len(self._live)
             timeout_s = self._job_timeout_s(msg)
             if deadline_at:
                 timeout_s = min(timeout_s, max(0.0, deadline_at - time.time()))

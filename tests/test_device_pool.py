@@ -359,6 +359,88 @@ def test_pool_metrics_exposition(tmp_path):
     assert "sm_device_pool_wait_seconds_count 1" in text
 
 
+# --------------------------- occupancy counters (ISSUE 26): what two scrapes
+# of the in_use gauge cannot integrate
+def _sample(text, name, **labels):
+    inner = ",".join(f'{k}="{v}"' for k, v in sorted(labels.items()))
+    line = name + ("{" + inner + "}" if inner else "") + " "
+    (hit,) = [row for row in text.splitlines() if row.startswith(line)]
+    return float(hit[len(line):])
+
+
+# each step: (seconds on the clock, what happens then); a scrape names what
+# held_seconds per chip, the pool's clock and the wait histogram must read
+_OCCUPANCY = {
+    # held seconds are the sum of the hold durations, chip by chip
+    "released": [
+        (0.0, ("acquire", "a", 1)), (0.5, ("acquire", "b", 1)),
+        (2.0, ("release", "a")), (4.0, ("release", "b")),
+        (5.0, ("acquire", "a", 1)), (6.0, ("release", "a")),
+        (9.0, ("scrape", [3.0, 3.5], 9.0, (0.0, 3)))],
+    # a lease still open counts up to the scrape, and only once
+    "open_at_scrape": [
+        (1.0, ("acquire", "a", 1)),
+        (5.0, ("scrape", [4.0, 0.0], 5.0, (0.0, 1))),
+        (7.0, ("scrape", [6.0, 0.0], 7.0, (0.0, 1))),
+        (8.0, ("release", "a")),
+        (8.5, ("scrape", [7.0, 0.0], 8.5, (0.0, 1)))],
+    # a two-chip lease holds both of its chips
+    "two_chip_lease": [
+        (0.0, ("acquire", "a", 2)), (1.5, ("release", "a")),
+        (2.0, ("scrape", [1.5, 1.5], 2.0, (0.0, 1)))],
+    # the wait histogram's _sum / _count grow by one observation per grant
+    "wait_histogram": [
+        (0.0, ("acquire", "a", 2)), (1.0, ("queue", "b", 1)),
+        (1.5, ("queue", "c", 2)),
+        (2.0, ("scrape", [2.0, 2.0], 2.0, (0.0, 1))),
+        (3.0, ("release", "a")), (3.0, ("acquire", "b", 1)),
+        (3.5, ("scrape", [3.5, 3.0], 3.5, (2.0, 2))),
+        (4.0, ("release", "b")), (4.0, ("acquire", "c", 2)),
+        (6.0, ("scrape", [6.0, 5.0], 6.0, (4.5, 3)))],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OCCUPANCY))
+def test_pool_occupancy_counters(case, monkeypatch):
+    from types import SimpleNamespace
+
+    from sm_distributed_tpu.service import device_pool
+    from sm_distributed_tpu.service.metrics import MetricsRegistry
+
+    now = [100.0]
+    monkeypatch.setattr(device_pool, "time",
+                        SimpleNamespace(monotonic=lambda: now[0]))
+    m = MetricsRegistry()
+    pool = DevicePool(2)
+    pool.attach_metrics(m)
+    leases = {}
+    for at, (op, *args) in _OCCUPANCY[case]:
+        now[0] = 100.0 + at
+        if op == "acquire":
+            name, n = args
+            lease = leases.setdefault(name, pool.lease(n, name))
+            assert lease.acquire(timeout=0)
+        elif op == "queue":                  # asks, and has to wait
+            name, n = args
+            leases[name] = pool.lease(n, name)
+            assert not leases[name].acquire(blocking=False)
+        elif op == "release":
+            leases.pop(args[0]).release()
+        else:
+            held, clock, (wait_sum, wait_count) = args
+            text = m.expose()
+            for i, want in enumerate(held):
+                assert _sample(text, "sm_device_pool_held_seconds_total",
+                               device=i) == pytest.approx(want)
+            assert _sample(text, "sm_device_pool_clock_seconds_total") == \
+                pytest.approx(clock)
+            assert _sample(text, "sm_device_pool_wait_seconds_sum") == \
+                pytest.approx(wait_sum)
+            assert _sample(text, "sm_device_pool_wait_seconds_count") == \
+                wait_count
+            assert _sample(text, "sm_device_pool_grants_total") == wait_count
+
+
 # ------------------------------------------ quarantine fragmentation (ISSUE 14)
 def _quarantine(pool, *chips):
     for c in chips:

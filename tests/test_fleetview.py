@@ -386,3 +386,55 @@ def test_profiler_injects_device_spans_under_device_hold(tmp_path):
         chips, annotations, 10.0 * ns,
         [(str(f), tracing.read_trace(f)) for f, _ in files.values()], [])
     assert again["idle_by_host_s"] == reduced["idle_by_host_s"]
+
+
+def test_each_chip_of_a_pool_gets_its_own_jobs_device_spans():
+    """ISSUE 26: four one-chip jobs held on chips 0-3 at once (the pool of a
+    2x2 host).  Each job's ``device_busy`` / ``device_scope`` spans carry its
+    own chip and that chip's device time - chips 1-3 as chip 0."""
+    from sm_distributed_tpu.analysis import profiling
+
+    t0, ns = 1_790_000_000.0, 1e9
+    chips, traces = {}, []
+    for chip in range(4):
+        # chip k is busy 0.1 x (k + 1) s inside its job's hold [2, 5]
+        dur = 0.1 * (chip + 1)
+        chips[chip] = {
+            "modules": [(3.0 * ns, (3.0 + 2 * dur) * ns, "jit_score(1)")],
+            "ops": [(3.0 * ns, (3.0 + dur) * ns, "%fusion.1 = f32[8] fusion()",
+                     "sm_extract"),
+                    ((3.0 + dur) * ns, (3.0 + 2 * dur) * ns,
+                     "%while.2 = f32[8] while()", "sm_chaos")]}
+        base = {"trace_id": f"t{chip}", "job_id": f"job{chip}", "pid": 1,
+                "tid": chip}
+        traces.append((f"job{chip}.jsonl", [
+            {**base, "kind": "event", "name": "device_token_acquired",
+             "span_id": f"hold{chip}", "ts": t0 + 2.0,
+             "attrs": {"devices": [chip]}},
+            {**base, "kind": "span", "name": "device_hold",
+             "span_id": f"hold{chip}", "parent_id": "attempt",
+             "ts": t0 + 1.0, "dur": 4.0}]))
+    annotations = [("sm_clock", at * ns, at * ns + 2000.0,
+                    {"wall_ns": int((t0 + at) * ns)}) for at in (0.0, 10.0)]
+    red = profiling.reduce_planes(chips, annotations, 10.0 * ns, traces, [])
+    assert sorted((j["job"], j["chips"], j["whole"]) for j in red["jobs"]) \
+        == [(f"job{c}", [c], True) for c in range(4)]
+    assert [(c["chip"], c["n_ops"]) for c in red["chips"]] == \
+        [(c, 2) for c in range(4)]
+    by_job = {h["job"]: h for h in red["inject"]}
+    assert set(by_job) == {f"job{c}" for c in range(4)}   # one hold, one chip
+    for chip in range(4):
+        inj = by_job[f"job{chip}"]
+        assert inj["parent_id"] == f"hold{chip}"
+        assert inj["file"] == f"job{chip}.jsonl"
+        (busy,) = [r for r in inj["records"] if r["name"] == "device_busy"]
+        assert busy["attrs"]["chip"] == chip and busy["attrs"]["whole"]
+        assert busy["attrs"]["busy_s"] == pytest.approx(0.2 * (chip + 1),
+                                                        abs=1e-5)
+        assert busy["attrs"]["hold_s"] == pytest.approx(3.0)
+        scopes = {r["attrs"]["scope"]: r["attrs"] for r in inj["records"]
+                  if r["name"] == "device_scope"}
+        assert set(scopes) == {"sm_extract", "sm_chaos"}
+        assert all(a["chip"] == chip and a["device_s"] ==
+                   pytest.approx(0.1 * (chip + 1), abs=1e-5)
+                   for a in scopes.values())
