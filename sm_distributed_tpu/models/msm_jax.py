@@ -89,12 +89,15 @@ COMPILE_SURFACE = compile_surface(__name__, {
         "compact resident cube per probed backend (production expands "
         "inside the scoring jits)",
     "extract_images":
-        "statics=none; buckets=one executable per backend — cube-path image "
-        "export at the padded (b, k) batch shape",
+        "statics=none; buckets=one executable per bucket of the KEPT ion "
+        "count — cube-path image export at (b_x, k), b_x = "
+        "ops/buckets.export_bucket(n_ions, batch): at most ~4 per octave up "
+        "to formula_batch",
     "extract_images_flat":
-        "statics=closure(n_pixels); buckets=one executable per backend — "
-        "flat-path image export at the padded (b, k) batch shape on the "
-        "row-bucketed pixel lattice",
+        "statics=closure(n_pixels); buckets=one executable per bucket of "
+        "the KEPT ion count — flat-path image export at (b_x, k), b_x = "
+        "ops/buckets.export_bucket(n_ions, batch), on the row-bucketed pixel "
+        "lattice",
     "ext_base":
         "statics=closure(n_pixels,gc_width,n_keep,w_cap); buckets=probe-only "
         "re-jit of the production extraction variant (probe_phases inherits "
@@ -1198,20 +1201,30 @@ class JaxBackend:
         """(n_ions, K, n_pix) de-quantized ion images from the DEVICE cube —
         the annotated-subset image export no longer re-extracts on CPU
         (VERDICT r1 item 9).  Bit-identical to the numpy path (shared
-        integer grids).  Compiles one extraction-only executable per
-        backend, padded to the scoring batch shape."""
-        n = table.n_ions
-        b = self.batch
-        if n > b:
-            # batch internally: annotated subsets can exceed formula_batch
-            from .msm_basic import _slice_table
+        integer grids: every sum is an exact f32 integer, so the padded
+        shape cannot move a bit).  The program's static shape follows the
+        number of ions KEPT, not the scoring batch: rows pad to the
+        lattice bucket of ``n_ions`` (``ops/buckets.export_bucket``), never
+        above ``self.batch`` — one extraction-only executable per bucket seen.
+        Annotates the open span (``store_extract_images``) with the padded
+        ``rows``, the ``fetched_bytes`` and the number of device ``calls``."""
+        from .msm_basic import _slice_table
 
-            return np.concatenate([
-                self.extract_ion_images(_slice_table(table, s, min(s + b, n)))
-                for s in range(0, n, b)
-            ])
-        k = table.max_peaks
-        grid, r_lo, r_hi, _ints, _nv = self._padded_windows(table)
+        b, n = self.batch, table.n_ions
+        # batch internally: annotated subsets can exceed formula_batch
+        tables = [table] if n <= b else [
+            _slice_table(table, s, min(s + b, n)) for s in range(0, n, b)]
+        images, rows, fetched = zip(*(self._export_images(t) for t in tables))
+        tracing.annotate(rows=sum(rows), fetched_bytes=sum(fetched),
+                         calls=len(tables))
+        return images[0] if len(images) == 1 else np.concatenate(images)
+
+    def _export_images(self, table: IsotopePatternTable):
+        """One device call of ``extract_ion_images`` (``n_ions <= batch``):
+        (images, padded rows, bytes fetched)."""
+        n, k = table.n_ions, table.max_peaks
+        b_x = shape_buckets.export_bucket(n, self.batch)
+        grid, r_lo, r_hi, _ints, _nv = self._padded_windows(table, b_x)
         if self.mz_chunk:
             if not hasattr(self, "_extract_fn"):
                 self._extract_fn = jax.jit(extract_images)
@@ -1230,14 +1243,16 @@ class JaxBackend:
             imgs = self._extract_fn(
                 self._px_s, self._in_f32(), jax.device_put(pos),
                 jax.device_put(r_lo), jax.device_put(r_hi))
+        # the fetch is the bucket's rows, read in place (no second host
+        # copy); the divide writes the kept rows only
         # smlint: host-sync-ok[image EXPORT; the annotated-subset fetch to host is the product of this method]
-        imgs = np.array(imgs).reshape(b, k, -1)[:n, :, : self.ds.n_pixels]
-        imgs /= np.float32(self.int_scale)  # exact power-of-two division
+        host = np.asarray(imgs).reshape(b_x, k, -1)
+        out = host[:n, :, : self.ds.n_pixels] / np.float32(self.int_scale)  # exact power-of-two division
         # zero out padded isotope peaks (window [0,0) is empty anyway, but
         # keep the contract explicit)
         valid = np.arange(k)[None, :] < table.n_valid[:, None]
-        imgs[~valid] = 0.0
-        return imgs
+        out[~valid] = 0.0
+        return out, b_x, int(host.nbytes)
 
     def presize(self, tables) -> None:
         """Grow the sticky static shapes to cover ``tables`` WITHOUT scoring.

@@ -55,6 +55,7 @@ from pathlib import Path
 PEAK_FLOOR = 4096       # resident-peak arrays (slots are 8 bytes)
 ROW_FLOOR = 8           # image rows
 PIXEL_FLOOR = 64        # flat pixel counts (oom shape keys)
+EXPORT_FLOOR = 64       # rows of the store's image export (kept ions)
 
 
 def pow2ish(n: int, floor: int = 1) -> int:
@@ -125,6 +126,14 @@ def batch_bucket_down(batch: int) -> int:
     OOM-shrunk caps snap DOWN so padding never grows a proven-fitting
     HBM footprint."""
     return pow2ish_down(batch, 1)
+
+
+def export_bucket(n_ions: int, batch: int) -> int:
+    """Padded row count of the store's image export: the lattice point of
+    the KEPT ion count, never above the scoring ``batch`` (a proven-fitting
+    footprint, OOM-shrunk or not) — the program's shape follows what is
+    fetched, not ``formula_batch``."""
+    return min(int(batch), pow2ish(n_ions, EXPORT_FLOOR))
 
 
 def buckets_enabled(parallel_cfg) -> bool:

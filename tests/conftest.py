@@ -46,6 +46,24 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(scope="module")
+def offgrid_ds(tmp_path_factory):
+    """A fixture whose geometry is deliberately OFF the lattice: 9 rows
+    bucket to 10 (real zero-row padding is exercised), 11 columns stay
+    exact, and the peak count sits under the 4096-slot floor (real
+    resident padding is exercised too).  Shared by test_buckets.py and
+    the export cases of test_jax_backend.py."""
+    from sm_distributed_tpu.io.dataset import SpectralDataset
+    from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset
+
+    out = tmp_path_factory.mktemp("dsb")
+    path, truth = generate_synthetic_dataset(
+        out, nrows=9, ncols=11, formulas=None, present_fraction=0.5,
+        noise_peaks=12, seed=41,
+    )
+    return SpectralDataset.from_imzml(path), truth
+
+
 @pytest.fixture
 def isolated_compile_cache(tmp_path, monkeypatch):
     """A private persistent-cache directory for ONE test (empty cache, no

@@ -18,20 +18,6 @@ from sm_distributed_tpu.ops import buckets
 from sm_distributed_tpu.utils.config import DSConfig, SMConfig
 
 
-@pytest.fixture(scope="module")
-def offgrid_ds(tmp_path_factory):
-    """A fixture whose geometry is deliberately OFF the lattice: 9 rows
-    bucket to 10 (real zero-row padding is exercised), 11 columns stay
-    exact, and the peak count sits under the 4096-slot floor (real
-    resident padding is exercised too)."""
-    out = tmp_path_factory.mktemp("dsb")
-    path, truth = generate_synthetic_dataset(
-        out, nrows=9, ncols=11, formulas=None, present_fraction=0.5,
-        noise_peaks=12, seed=41,
-    )
-    return SpectralDataset.from_imzml(path), truth
-
-
 def _table(truth, n=14):
     from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
     from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
@@ -53,6 +39,16 @@ def test_lattice_points_round_trip():
     # bounded waste: a quarter ladder never pads more than 25%
     for n in range(8, 4096):
         assert buckets.pow2ish(n) < 1.25 * n + 1
+
+
+@pytest.mark.parametrize("n, batch, rows", [
+    (1, 2048, 64), (64, 2048, 64), (65, 2048, 80), (301, 2048, 320),
+    (2047, 2048, 2048), (2048, 2048, 2048), (4, 48, 48), (301, 256, 256)])
+def test_export_bucket_follows_the_kept_count(n, batch, rows):
+    """The store's image export pads to the lattice point of the kept ion
+    count (floor 64) and never above the scoring batch."""
+    assert buckets.export_bucket(n, batch) == rows
+    assert n <= rows <= batch or batch < n
 
 
 def test_lattice_floors_and_batch_snap():

@@ -615,3 +615,169 @@ def test_tail_batch_executable_matches(fixture_ds):
     b_small = JaxBackend(ds, ds_config, sm_small)
     np.testing.assert_array_equal(
         b_small.score_batch(tail)[:, 0], outs[1][:, 0])
+
+
+# ------------------------------------------------- export row bucket (PR 27)
+# The store's re-extraction pads to the lattice bucket of the KEPT count
+# (floor 64, never above the batch), not to the scoring batch.
+_EXPORT_BATCH = 128
+
+
+@pytest.fixture(scope="module", params=["flat", "mz_chunk"])
+def export_backend(request, offgrid_ds):
+    """One backend per extraction branch on the off-lattice 9x11 fixture
+    (99 px in a 110-px bucket), with a 150-ion table whose every third
+    ion has ``n_valid`` < k (the mask must zero images the windows fill)."""
+    import dataclasses
+
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
+    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
+
+    ds, truth = offgrid_ds
+    adducts = ("+H", "+Na", "+K")
+    calc = IsocalcWrapper(IsotopeGenerationConfig(adducts=adducts))
+    table = calc.pattern_table(
+        [(sf, ad) for sf in truth.formulas for ad in adducts])
+    n_valid = table.n_valid.copy()
+    n_valid[::3] = 2
+    table = dataclasses.replace(table, n_valid=n_valid)
+    assert table.n_ions > _EXPORT_BATCH
+    sm = SMConfig.from_dict({"backend": "jax_tpu", "parallel": {
+        "formula_batch": _EXPORT_BATCH,
+        "mz_chunk": 32 if request.param == "mz_chunk" else 0}})
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
+                             "image_generation": {"ppm": 3.0}})
+    backend = JaxBackend(ds, dc, sm)
+    assert bool(backend.mz_chunk) == (request.param == "mz_chunk")
+    if not backend.mz_chunk:                            # off the lattice
+        assert backend._n_pix_b == 110 > ds.n_pixels == 99
+    return backend, table
+
+
+def _full_batch_export(backend, table):
+    """The export as it was before PR 27: every call padded to the scoring
+    batch, the whole padded block copied to the host, divided in place."""
+    import jax
+
+    from sm_distributed_tpu.models.msm_basic import _slice_table
+    from sm_distributed_tpu.ops.imager_jax import (
+        extract_images, extract_images_flat, flat_bound_ranks,
+    )
+
+    b, k = backend.batch, table.max_peaks
+    out = []
+    for s in range(0, table.n_ions, b):
+        t = _slice_table(table, s, min(s + b, table.n_ions))
+        grid, r_lo, r_hi, _ints, _nv = backend._padded_windows(t, b)
+        if backend.mz_chunk:
+            imgs = extract_images(backend._mz_q, backend._ints,
+                                  jax.device_put(grid), r_lo, r_hi)
+        else:
+            imgs = extract_images_flat(
+                backend._px_s, backend._in_f32(),
+                flat_bound_ranks(backend._mz_host, grid), r_lo, r_hi,
+                n_pixels=backend._n_pix_b)
+        imgs = np.array(imgs).reshape(b, k, -1)[
+            : t.n_ions, :, : backend.ds.n_pixels]
+        imgs /= np.float32(backend.int_scale)
+        imgs[~(np.arange(k)[None, :] < t.n_valid[:, None])] = 0.0
+        out.append(imgs)
+    return np.concatenate(out)
+
+
+def _traced_export(backend, table, tmp_path):
+    """(images, attrs the export left on the span open around it)."""
+    from sm_distributed_tpu.utils import tracing
+
+    ctx = tracing.new_trace("export", trace_dir=tmp_path)
+    with tracing.span("store_extract_images", ctx=ctx):
+        images = backend.extract_ion_images(table)
+    (rec,) = [r for r in tracing.read_trace(
+        tracing.trace_path(tmp_path, ctx.trace_id))
+        if r["name"] == "store_extract_images"]
+    return images, rec["attrs"]
+
+
+# n: one ion, the floor, floor + 1, a non-lattice count (301 of 2048 scaled
+# to 128), the batch, batch + 1 (the loop: one full call and a tail of one)
+@pytest.mark.parametrize("n, rows, calls", [
+    (1, 64, 1), (64, 64, 1), (65, 80, 1), (100, 112, 1),
+    (_EXPORT_BATCH, 128, 1), (_EXPORT_BATCH + 1, 128 + 64, 2)])
+def test_export_row_bucket_bit_identical(export_backend, tmp_path, n, rows,
+                                         calls):
+    from sm_distributed_tpu.models.msm_basic import _slice_table
+    from sm_distributed_tpu.ops.imager_np import extract_ion_images
+
+    backend, table = export_backend
+    sub = _slice_table(table, 0, n)
+    got, attrs = _traced_export(backend, sub, tmp_path)
+    assert got.dtype == np.float32
+    assert got.shape == (n, table.max_peaks, backend.ds.n_pixels)
+    for want in (extract_ion_images(backend.ds, sub, ppm=3.0),
+                 _full_batch_export(backend, sub)):
+        np.testing.assert_array_equal(
+            got.view(np.uint32), want.view(np.uint32))
+    assert got[0, 2:].max() == 0.0                      # n_valid = 2 of k
+    # pixels fetched per window: the row-bucketed grid, or the cube's rows
+    per_px = (backend._ints.shape[0] if backend.mz_chunk
+              else backend._n_pix_b)
+    assert attrs == {"rows": rows, "calls": calls,
+                     "fetched_bytes": rows * table.max_peaks * per_px * 4}
+
+
+def _export_traces():
+    """Executables the export site has traced so far: real compiles plus
+    loads from the session's persistent cache (which of the two a trace is
+    depends on what earlier tests compiled)."""
+    from sm_distributed_tpu.analysis import retrace
+
+    return sum(e["events"] + e["cache_hits"]
+               for s, e in retrace.snapshot()["sites"].items()
+               if s.endswith("models/msm_jax.py:_export_images"))
+
+
+def test_export_traces_once_per_bucket(offgrid_ds, tmp_path):
+    """Two subsets in one bucket share the executable; a subset in another
+    bucket traces one more; after an OOM shrink the bucket never exceeds
+    the batch."""
+    from sm_distributed_tpu.analysis import retrace
+    from sm_distributed_tpu.models.msm_basic import _slice_table
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
+    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
+
+    ds, truth = offgrid_ds
+    adducts = ("+H", "+Na")
+    table = IsocalcWrapper(
+        IsotopeGenerationConfig(adducts=adducts)).pattern_table(
+        [(sf, ad) for sf in truth.formulas for ad in adducts])
+    sm = SMConfig.from_dict({"backend": "jax_tpu",
+                             "parallel": {"formula_batch": 96}})
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
+                             "image_generation": {"ppm": 3.0}})
+    backend = JaxBackend(ds, dc, sm)
+    assert backend.batch == 96
+    retrace.enable()
+    retrace.reset()
+    try:
+        backend.extract_ion_images(_slice_table(table, 0, 3))     # 64
+        assert _export_traces() == 1
+        backend.extract_ion_images(_slice_table(table, 5, 64))    # 64 again
+        assert _export_traces() == 1
+        backend.extract_ion_images(_slice_table(table, 0, 70))    # 80
+        assert _export_traces() == 2
+        backend.extract_ion_images(_slice_table(table, 0, 90))    # 96 = batch
+        assert _export_traces() == 3
+    finally:
+        retrace.disable()
+        retrace.reset()
+    want = backend.extract_ion_images(table)
+    backend.shrink_batch(50)                  # snaps down to 48
+    assert backend.batch == 48
+    got, attrs = _traced_export(backend, table, tmp_path)
+    # 100 ions at batch 48: two full calls and a tail of 4, which pads to
+    # the batch and not to the floor of 64 above it
+    assert table.n_ions == 100
+    assert attrs["calls"] == 3 and attrs["rows"] == 3 * 48
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))

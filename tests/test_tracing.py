@@ -557,6 +557,8 @@ def test_build_and_store_spans_of_a_real_job(tmp_path):
 
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     from scripts.load_sweep import Harness, _msg, build_fixtures
+    from sm_distributed_tpu.ops import buckets
+    from sm_distributed_tpu.utils.config import SMConfig
 
     fx = build_fixtures(tmp_path)
     h = Harness(tmp_path, "svc", {
@@ -604,6 +606,16 @@ def test_build_and_store_spans_of_a_real_job(tmp_path):
         extract, write = parts[1], parts[2]
         assert extract["attrs"]["ions"] > 0
         assert extract["attrs"]["bytes"] == write["attrs"]["bytes"] > 0
+        # the export's own counts (PR 27): one device call whose rows are
+        # the lattice bucket of the kept ions, never above formula_batch;
+        # what is fetched is rows x k windows of the 8x8 pixel bucket
+        rows = extract["attrs"]["rows"]
+        assert extract["attrs"]["calls"] == 1
+        assert extract["attrs"]["ions"] <= rows == buckets.pow2ish(rows) \
+            <= SMConfig.from_dict({}).parallel.formula_batch
+        assert extract["attrs"]["fetched_bytes"] == rows * 4 * 64 * 4
+        assert extract["attrs"]["bytes"] \
+            == extract["attrs"]["ions"] * 4 * 64 * 4
         assert write["attrs"]["format"] == "npz"
         assert write["attrs"]["layout"] == "bitmask_v1"
         # the writer's own counts: 4 B a value + the bit mask, nothing more
