@@ -389,7 +389,7 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
     resident_peaks = int(resident.size) if resident is not None else int(
         prep["ds"].n_peaks)
     cube_dtype = getattr(backend, "_cube_dtype", "f32")
-    int_bytes = {"f32": 4, "bf16": 2, "int8": 1}[cube_dtype]
+    int_bytes = {"f32": 4, "bf16": 2}[cube_dtype]
     # price the variant that actually dispatched: parallel.fused_metrics
     # defaults to "auto", which engages the fused kernel on a real TPU
     fused_active = (getattr(backend, "_fused_mode", "off") != "off"
@@ -768,7 +768,7 @@ def main() -> None:
     ap.add_argument("--skip-cold", action="store_true",
                     help="skip the cleared-cache cold-start measurement "
                          "(cold_compile_s / first_annotation_cold_s)")
-    ap.add_argument("--cube-dtype", choices=("f32", "bf16", "int8"),
+    ap.add_argument("--cube-dtype", choices=("f32", "bf16"),
                     default="bf16",
                     help="parallel.cube_dtype for the benched backend "
                          "(ISSUE 18; default bf16 — the shipped perf "

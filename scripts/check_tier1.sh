@@ -88,8 +88,7 @@ fi
 # oracle — FDR-rank identity is a HARD gate, per-MSM-component max-ULP
 # drift must stay inside the declared COMPONENT_CONTRACTS ceilings, and
 # the drift is band-checked against the committed NUMERICS_r*.json
-# history (rising drift regresses).  This is the correctness backstop
-# for ROADMAP item 3's bf16/int8 compaction work.
+# history (rising drift regresses).
 if ! env JAX_PLATFORMS=cpu python scripts/ulp_sentinel.py; then
     echo "check_tier1: FAIL — ULP-contract numerics sentinel tripped" >&2
     exit 1

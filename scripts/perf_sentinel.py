@@ -151,8 +151,8 @@ def normalize(data: dict) -> dict[str, tuple[float, str]]:
                 out[f"analysis.{key[len('sm_'):]}"] = (v, "down")
     elif "sm_numerics_max_ulp" in data:               # ulp_sentinel (ISSUE 15)
         # per-MSM-component max-ULP drift vs the numpy oracle: RISING
-        # drift regresses (the ulp-contract gate for ROADMAP item 3's
-        # bf16/int8 compaction); rank mismatches are a hard 0
+        # drift regresses (the ulp-contract gate for bf16
+        # compaction); rank mismatches are a hard 0
         for comp, v in (data.get("sm_numerics_max_ulp") or {}).items():
             if (v := _num(v)) is not None:
                 out[f"numerics.max_ulp.{comp}"] = (v, "down")

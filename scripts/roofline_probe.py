@@ -96,7 +96,7 @@ def main() -> None:
                     help="parallel.fused_metrics for the probed backend "
                          "(ISSUE 18; 'on' forces the Pallas kernel, "
                          "interpret-mode off-TPU)")
-    ap.add_argument("--cube-dtype", choices=("f32", "bf16", "int8"),
+    ap.add_argument("--cube-dtype", choices=("f32", "bf16"),
                     default="f32",
                     help="parallel.cube_dtype for the probed backend")
     ap.add_argument("--min-frac", type=float, default=0.0,
@@ -175,7 +175,7 @@ def main() -> None:
     t_fl = model["matmul_flops"] / (peaks["peak_matmul_gflops"] * 1e9)
     floor_s = max(t_bw, t_fl)
     frac = floor_s / measured_s if measured_s > 0 else 0.0
-    int_bytes = {"f32": 4, "bf16": 2, "int8": 1}[args.cube_dtype]
+    int_bytes = {"f32": 4, "bf16": 2}[args.cube_dtype]
     out = {
         "metric": "fused_score_roofline",
         "measured_s_per_rep": round(measured_s, 4),

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """ULP-contract numerics sentinel (ISSUE 15 — the runtime half of numlint).
 
-ROADMAP item 3 (fused Pallas scoring + bf16/int8 intensity compaction) is
-gated on FDR ranks staying bit-identical — or within a *declared*
-tolerance — to the fp32/numpy oracle.  The static half of that gate is
+Fused Pallas scoring and bf16 intensity compaction are gated on FDR ranks
+staying bit-identical — or within a *declared* tolerance — to the
+fp32/numpy oracle.  The static half of that gate is
 the ``NUMERICS`` contract registries + the three numlint rules; this
 script is the measurement:
 

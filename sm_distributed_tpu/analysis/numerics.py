@@ -1,7 +1,7 @@
 """Numerics contracts + ULP instrumentation (ISSUE 15 "numlint").
 
-ROADMAP item 3 (fused Pallas scoring + bf16/int8 intensity compaction) is
-gated on one invariant: FDR ranks stay bit-identical — or within a
+Fused Pallas scoring and bf16 intensity compaction are gated on one
+invariant: FDR ranks stay bit-identical — or within a
 *declared* tolerance — to the fp32/numpy oracle.  This module is the
 declarative half of that gate, mirroring ``analysis/surface.py``:
 

@@ -6,10 +6,9 @@ scan coordinates to a dense row-major pixel index (``_define_pixels_order``),
 and exposes the sample-area mask.  Here the same responsibilities are
 TPU-first: spectra land in a flat CSR layout over the *dense* pixel grid
 (empty pixels = empty rows), sorted by m/z within each pixel, plus a
-prefix-sum array — so ion-image extraction becomes two vmapped
-``searchsorted`` calls and a cumulative-sum difference per (pixel, window)
-with fully static shapes (see ops/imager_jax.py).  The pixel axis is the
-sharding axis: ``NamedSharding(mesh, P("pixels"))`` over the padded cube.
+prefix-sum array.  The device layout is built from it in
+ops/imager_jax.py (``prepare_flat_sharded_arrays``): per pixel shard, one
+flat m/z-sorted peak list; the pixel axis is the sharding axis.
 """
 
 from __future__ import annotations
@@ -204,33 +203,6 @@ class SpectralDataset:
 
     def row_lengths(self) -> np.ndarray:
         return np.diff(self.row_ptr)
-
-    def padded_cube(
-        self, pad_to_multiple: int = 128, pixels_multiple: int = 1
-    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Dense (n_pixels_padded, L) m/z + intensity cube for the TPU path.
-
-        m/z rows are padded with +inf (so searchsorted puts windows before the
-        padding), intensities with 0.  L is the max spectrum length rounded up
-        to ``pad_to_multiple`` (lane-friendly).  ``pixels_multiple`` pads the
-        pixel axis so it divides the mesh's pixel-shard count.  Returns
-        (mz_cube f64, int_cube f32, lens i32); padded pixels have length 0.
-        """
-        lens = self.row_lengths()
-        L = int(max(1, lens.max())) if lens.size else 1
-        L = -(-L // pad_to_multiple) * pad_to_multiple
-        npix = self.n_pixels
-        npix_pad = -(-npix // pixels_multiple) * pixels_multiple
-        mz_cube = np.full((npix_pad, L), np.inf, dtype=np.float64)
-        int_cube = np.zeros((npix_pad, L), dtype=np.float32)
-        # vectorized scatter (no per-pixel Python loop; VERDICT r1 weak #5)
-        pixel_of_peak = np.repeat(np.arange(npix), lens)
-        col_of_peak = np.arange(self.n_peaks) - np.repeat(self.row_ptr[:-1], lens)
-        mz_cube[pixel_of_peak, col_of_peak] = self.mzs_flat
-        int_cube[pixel_of_peak, col_of_peak] = self.ints_flat
-        out_lens = np.zeros(npix_pad, dtype=np.int32)
-        out_lens[:npix] = lens
-        return mz_cube, int_cube, out_lens
 
     def norm_img_pixel_inds(self) -> np.ndarray:
         """Dense pixel index per spectrum (reference:

@@ -904,7 +904,7 @@ class MSMBasicSearch:
                 if self.backend_cache is not None:
                     par = self.sm_config.parallel
                     key = (self.sm_config.backend, fingerprint,
-                           par.mz_chunk, par.pixels_axis, par.formulas_axis,
+                           par.pixels_axis, par.formulas_axis,
                            par.peak_compaction, par.band_slice,
                            par.order_ions,
                            # a backend is pinned to its lease's chips — a

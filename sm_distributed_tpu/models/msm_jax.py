@@ -30,16 +30,12 @@ from ..ops.imager_jax import (
     batch_peak_band,
     batch_peak_runs,
     compact_peaks,
-    extract_images,
     extract_images_flat,
     extract_images_flat_banded,
-    extract_images_mz_chunked,
     flat_bound_ranks,
     ion_window_chunks,
     ions_per_chunk_for,
-    prepare_cube_arrays,
     prepare_flat_sorted_arrays,
-    window_chunks,
     window_rank_grid,
 )
 from ..ops.isocalc import IsotopePatternTable
@@ -61,10 +57,6 @@ from ..utils.logger import logger
 # cross-checks these entries against the AST, and scripts/compile_census.py
 # proves the observed runtime surface matches and stays closed.
 COMPILE_SURFACE = compile_surface(__name__, {
-    "fused_score_fn_chunked":
-        "statics=gc_width,b,k; buckets=one executable per dataset config — "
-        "b=formula_batch (batches padded), k=stream max_peaks, "
-        "gc_width=mz_chunk knob",
     "fused_score_fn_flat_banded":
         "statics=gc_width,b,k; buckets=b in {lattice formula_batch, 256 "
         "tail}, sticky stream-max gc_width (_grow_for_stream fixpoint), "
@@ -88,11 +80,6 @@ COMPILE_SURFACE = compile_surface(__name__, {
         "statics=none; buckets=probe-only — one f32 expansion of the "
         "compact resident cube per probed backend (production expands "
         "inside the scoring jits)",
-    "extract_images":
-        "statics=none; buckets=one executable per bucket of the KEPT ion "
-        "count — cube-path image export at (b_x, k), b_x = "
-        "ops/buckets.export_bucket(n_ions, batch): at most ~4 per octave up "
-        "to formula_batch",
     "extract_images_flat":
         "statics=closure(n_pixels); buckets=one executable per bucket of "
         "the KEPT ion count — flat-path image export at (b_x, k), b_x = "
@@ -117,14 +104,9 @@ COMPILE_SURFACE = compile_surface(__name__, {
 # Declared numerics contracts (ISSUE 15, analysis/numerics.py): one per
 # COMPILE_SURFACE site — the drift bound vs the site's reference (numpy
 # oracle or sibling variant), the committed test that proves it, and the
-# lattice-padded operands the masked-reduction rule tracks.  These are
-# the gate for ROADMAP item 3: bf16/int8 compaction may not land unless
-# every contract still holds (scripts/ulp_sentinel.py is the runtime
-# check on the spheroid fixture).
+# lattice-padded operands the masked-reduction rule tracks
+# (scripts/ulp_sentinel.py is the runtime check on the spheroid fixture).
 NUMERICS = numerics_surface(__name__, {
-    "fused_score_fn_chunked":
-        "contract=ulp(8); test=tests/test_mz_chunking.py::"
-        "test_chunked_scores_match",
     "fused_score_fn_flat_banded":
         "contract=ulp(16); test=tests/test_buckets.py::"
         "test_bucketed_scoring_bit_identical_fdr; "
@@ -141,15 +123,12 @@ NUMERICS = numerics_surface(__name__, {
     "expand_cube_jnp":
         "contract=bit_exact; test=tests/test_score_pallas.py::"
         "test_compact_expand_roundtrip",
-    "extract_images":
-        "contract=bit_exact; test=tests/test_jax_backend.py::"
-        "test_extraction_parity",
     "extract_images_flat":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
-        "test_extraction_flat_bit_identical_to_cube",
+        "test_extraction_parity",
     "ext_base":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
-        "test_extraction_flat_bit_identical_to_cube",
+        "test_extraction_parity",
     "batch_moments":
         "contract=ulp(16); test=tests/test_moments.py::"
         "test_moments_jnp_fallback_matches_f64",
@@ -192,7 +171,6 @@ def fused_score_fn_flat_banded(
     theor_ints: jnp.ndarray,
     n_valid: jnp.ndarray,
     n_real=None,               # () i32 traced: REAL pixel count (lattice)
-    scales=None,               # (N/QTILE,) f32 int8-cube dequant factors
     *,
     gc_width: int,
     b: int,
@@ -219,13 +197,12 @@ def fused_score_fn_flat_banded(
     scalar for the masked metric centering (bit-identical to unpadded —
     see batch_metrics).
 
-    ``scales`` + a compact ``int_sorted`` dtype (parallel.cube_dtype,
-    ISSUE 18): the resident cube arrives bf16/int8 and is expanded to an
-    f32 TRANSIENT in-graph (XLA fuses the cast into the scatter's operand
-    read) — with cube_dtype="f32" (legacy default) the expansion is a
-    python-level no-op and the traced program is byte-identical."""
+    A bf16 ``int_sorted`` (parallel.cube_dtype) is expanded to an f32
+    TRANSIENT in-graph (XLA fuses the cast into the scatter's operand
+    read); under the default f32 the expansion is a python-level no-op
+    and leaves no trace in the program."""
     with jax.named_scope("sm_extract"):
-        int_sorted = expand_cube_jnp(int_sorted, scales)
+        int_sorted = expand_cube_jnp(int_sorted)
         imgs = extract_images_flat_banded(
             pixel_sorted, int_sorted, pos, starts, r_lo_loc, r_hi_loc, None,
             gc_width=gc_width, n_pixels=nrows * ncols)
@@ -241,7 +218,7 @@ def fused_score_fn_flat_banded(
 
 def fused_score_fn_flat_fused(
     pixel_sorted: jnp.ndarray,  # (N,) int32
-    int_sorted: jnp.ndarray,   # (N,) f32/bf16/int8 resident intensities
+    int_sorted: jnp.ndarray,   # (N,) f32/bf16 resident intensities
     pos: jnp.ndarray,          # (G,) int32 host-computed bound ranks
     starts: jnp.ndarray,       # (C,) chunk grid offsets
     r_lo_loc: jnp.ndarray,     # (C, Wc)
@@ -250,7 +227,6 @@ def fused_score_fn_flat_fused(
     theor_ints: jnp.ndarray,
     n_valid: jnp.ndarray,
     n_real=None,               # () i32 traced: REAL pixel count (lattice)
-    scales=None,               # (N/QTILE,) f32 int8-cube dequant factors
     *,
     gc_width: int,
     b: int,
@@ -290,7 +266,7 @@ def fused_score_fn_flat_fused(
     # the spare band the unclamped super-row fetch may touch — spare rows
     # are zero-initialized and outside every window's rank range
     with jax.named_scope("sm_extract"):
-        int_sorted = expand_cube_jnp(int_sorted, scales)
+        int_sorted = expand_cube_jnp(int_sorted)
         delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
         bins = jnp.cumsum(delta[:-1])
         cols_p = cols_padded(g, gc_width)
@@ -343,7 +319,6 @@ def fused_score_fn_flat_banded_sliced(
     theor_ints: jnp.ndarray,
     n_valid: jnp.ndarray,
     n_real=None,               # () i32 traced: REAL pixel count (lattice)
-    scales=None,               # (N/QTILE,) f32 int8-cube dequant factors
     *,
     w_cap: int,
     gc_width: int,
@@ -369,7 +344,7 @@ def fused_score_fn_flat_banded_sliced(
     plan: see fused_score_fn_flat_banded (``inv`` un-permutes metric
     rows)."""
     with jax.named_scope("sm_extract"):
-        int_sorted = expand_cube_jnp(int_sorted, scales)
+        int_sorted = expand_cube_jnp(int_sorted)
         px_b = jax.lax.dynamic_slice(pixel_sorted, (w_start,), (w_cap,))
         in_b = jax.lax.dynamic_slice(int_sorted, (w_start,), (w_cap,))
         imgs = extract_images_flat_banded(
@@ -414,7 +389,6 @@ def fused_score_fn_flat_banded_compact(
     theor_ints: jnp.ndarray,
     n_valid: jnp.ndarray,
     n_real=None,               # () i32 traced: REAL pixel count (lattice)
-    scales=None,               # (N/QTILE,) f32 int8-cube dequant factors
     *,
     n_keep: int,
     gc_width: int,
@@ -434,7 +408,7 @@ def fused_score_fn_flat_banded_compact(
     Ion-major chunk plan: see fused_score_fn_flat_banded (``inv``
     un-permutes metric rows)."""
     with jax.named_scope("sm_extract"):
-        int_sorted = expand_cube_jnp(int_sorted, scales)
+        int_sorted = expand_cube_jnp(int_sorted)
         px_b, in_b = compact_peaks(
             pixel_sorted, int_sorted, run_pos, run_delta, n_b,
             n_keep=n_keep, n_pixels=nrows * ncols)
@@ -449,43 +423,6 @@ def fused_score_fn_flat_banded_compact(
     )
     with jax.named_scope("sm_epilogue"):
         return jnp.take(out, inv, axis=0)
-
-
-def fused_score_fn_chunked(
-    mz_q_cube: jnp.ndarray,
-    int_cube: jnp.ndarray,
-    grid: jnp.ndarray,
-    starts: jnp.ndarray,       # (C,) chunk grid offsets
-    r_lo_loc: jnp.ndarray,     # (C, Wc)
-    r_hi_loc: jnp.ndarray,     # (C, Wc)
-    inv: jnp.ndarray,          # (B*K,)
-    theor_ints: jnp.ndarray,
-    n_valid: jnp.ndarray,
-    *,
-    gc_width: int,
-    b: int,
-    k: int,
-    nrows: int,
-    ncols: int,
-    nlevels: int,
-    do_preprocessing: bool,
-    q: float,
-) -> jnp.ndarray:
-    """Fused cube-path scoring: extraction loops over m/z chunks so the
-    histogram scratch is bounded at (P, gc_width+2) — SURVEY §5.7 m/z-segment
-    axis.  Ion images (and hence chaos, which is integer-count based) are
-    bit-identical to the unchunked path; spatial/spectral can differ by ulps
-    because XLA picks different reduction fusions for the two program
-    variants (observed at 128x128 px on TPU)."""
-    with jax.named_scope("sm_extract"):
-        imgs = extract_images_mz_chunked(
-            mz_q_cube, int_cube, grid, starts, r_lo_loc, r_hi_loc, inv,
-            gc_width=gc_width)
-        imgs = imgs.reshape(b, k, -1)[:, :, : nrows * ncols]
-    return batch_metrics(
-        imgs, theor_ints, n_valid, nrows, ncols, nlevels,
-        do_preprocessing=do_preprocessing, q=q,
-    )
 
 
 # One row per extraction variant so the dispatch/probe sites cannot drift:
@@ -680,7 +617,6 @@ class JaxBackend:
         self._n_real = np.int32(ds.n_pixels) if self._buckets else None
 
         self.int_scale = ds.intensity_quantization(self.ppm)[1]
-        self.mz_chunk = max(0, sm_config.parallel.mz_chunk)
         common = dict(
             nrows=self._nrows_b,
             ncols=ds.ncols,
@@ -689,145 +625,100 @@ class JaxBackend:
             q=img_cfg.q,
         )
         self._common = dict(common)
-        if self.mz_chunk:
-            # chunked path stays on the padded cube: its scratch bound
-            # (gc_width) is the point, and the cube shards cleanly.  It
-            # also stays OFF the pixel lattice — the cube's row layout is
-            # per-dataset anyway, so bucketing rows would not close its
-            # signature family (COMPILE_SURFACE declares it per-dataset)
-            if restrict_table is not None:
-                logger.info(
-                    "window-union restriction not applicable on the "
-                    "mz_chunk cube path (dense per-pixel rows); scoring "
-                    "the full cube")
-            mz_q, int_cube = prepare_cube_arrays(ds, ppm=self.ppm)
-            self._mz_q = jax.device_put(mz_q, self.device)
-            self._ints = jax.device_put(int_cube, self.device)
+        # flat globally-sorted layout: no padding slots; per-batch bound
+        # ranks computed ON HOST against the host copy of the sorted m/z
+        # array and shipped as (G,) int32 (see ops/imager_jax.py)
+        # guard: the histogram scratch is (P+1, 2BK+gc) f32 — beyond a
+        # few GB the device OOM is opaque, so fail early with guidance
+        k_est = ds_config.isotope_generation.n_peaks
+        # scratch cols = max(G+1, gc+2): bins live in [0, G=2BK]; chunk
+        # slices clamp+shift instead of spilling past G (imager_jax);
+        # rows are the BUCKETED pixel count — that is what allocates
+        scratch = 4 * (self._n_pix_b + 1) * max(
+            2 * self.batch * k_est + 1, 4098)
+        if scratch > (8 << 30):
+            raise ValueError(
+                f"flat-path histogram scratch would be ~{scratch / 2**30:.0f}"
+                f" GiB ({ds.n_pixels} pixels x formula_batch={self.batch}"
+                f" x {k_est} peaks); reduce parallel.formula_batch, or shard"
+                " pixels over a mesh (parallel.pixels_axis)")
+        # the four build_* spans split the backend_build span of
+        # models/msm_basic.py (PERF.md section 3, backend_build_s)
+        with tracing.span("build_sort"):
+            mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, self.ppm)
+        if restrict_table is not None:
+            # drop peaks outside EVERY window of the search up front —
+            # the reference's "only hits shuffle" property [U]: on noisy
+            # data most peaks match nothing, and the per-peak scatter is
+            # the dominant extraction cost
+            from ..ops.imager_jax import restrict_flat_to_windows
+
+            with tracing.span("build_restrict"):
+                lo_q, hi_q = quantize_window(restrict_table.mzs, self.ppm)
+                mzk, pxk, ink, n_eff = restrict_flat_to_windows(
+                    mz_s[None], px_s[None], in_s[None],
+                    lo_q, hi_q, overflow_row=ds.n_pixels)
             logger.info(
-                "jax_tpu cube resident: %s int32 + %s f32 on %s",
-                mz_q.shape, int_cube.shape, self._mz_q.devices(),
-            )
-            self._nrows_b = ds.nrows
-            self._n_pix_b = ds.n_pixels
-            self._n_real = None
-            self._fn = jax.jit(
-                named_partial(fused_score_fn_chunked, **{**common,
-                                                         "nrows": ds.nrows}),
-                static_argnames=("gc_width", "b", "k"),
-            )
-        else:
-            # flat globally-sorted layout: no padding slots; per-batch bound
-            # ranks computed ON HOST against the host copy of the sorted m/z
-            # array and shipped as (G,) int32 (see ops/imager_jax.py)
-            # guard: the histogram scratch is (P+1, 2BK+gc) f32 — beyond a
-            # few GB the device OOM is opaque, so fail early with guidance
-            k_est = ds_config.isotope_generation.n_peaks
-            # scratch cols = max(G+1, gc+2): bins live in [0, G=2BK]; chunk
-            # slices clamp+shift instead of spilling past G (imager_jax);
-            # rows are the BUCKETED pixel count — that is what allocates
-            scratch = 4 * (self._n_pix_b + 1) * max(
-                2 * self.batch * k_est + 1, 4098)
-            if scratch > (8 << 30):
-                raise ValueError(
-                    f"flat-path histogram scratch would be ~{scratch / 2**30:.0f}"
-                    f" GiB ({ds.n_pixels} pixels x formula_batch={self.batch}"
-                    f" x {k_est} peaks); reduce parallel.formula_batch, shard"
-                    " pixels over a mesh (parallel.pixels_axis), or set"
-                    " parallel.mz_chunk to use the bounded-scratch cube path")
-            # the four build_* spans split the backend_build span of
-            # models/msm_basic.py (PERF.md section 3, backend_build_s)
-            with tracing.span("build_sort"):
-                mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, self.ppm)
-            if restrict_table is not None:
-                # drop peaks outside EVERY window of the search up front —
-                # the reference's "only hits shuffle" property [U]: on noisy
-                # data most peaks match nothing, and the per-peak scatter is
-                # the dominant extraction cost
-                from ..ops.imager_jax import restrict_flat_to_windows
+                "window-union restriction: %d -> %d peaks (%.0f%% dropped)",
+                mz_s.size, n_eff,
+                100.0 * (1 - n_eff / max(mz_s.size, 1)))
+            mz_s, px_s, in_s = mzk[0], pxk[0], ink[0]
+        with tracing.span("build_pad_compact"):
+            if self._buckets:
+                # lattice-pad the resident arrays (ops/buckets.peak_bucket)
+                # with the SAME slot shape the 1024-multiple rounding
+                # already uses: m/z saturates to the MZ_PAD_Q sentinel
+                # (outside every window), pixel points at the overflow row,
+                # intensity 0 — bit-exact, and every dataset whose peak
+                # count shares the bucket shares the executable
+                n_pad = shape_buckets.peak_bucket(mz_s.size)
+                if n_pad > mz_s.size:
+                    from ..ops.quantize import MZ_PAD_Q
 
-                with tracing.span("build_restrict"):
-                    lo_q, hi_q = quantize_window(restrict_table.mzs, self.ppm)
-                    mzk, pxk, ink, n_eff = restrict_flat_to_windows(
-                        mz_s[None], px_s[None], in_s[None],
-                        lo_q, hi_q, overflow_row=ds.n_pixels)
-                logger.info(
-                    "window-union restriction: %d -> %d peaks (%.0f%% dropped)",
-                    mz_s.size, n_eff,
-                    100.0 * (1 - n_eff / max(mz_s.size, 1)))
-                mz_s, px_s, in_s = mzk[0], pxk[0], ink[0]
-            with tracing.span("build_pad_compact"):
-                if self._buckets:
-                    # lattice-pad the resident arrays (ops/buckets.peak_bucket)
-                    # with the SAME slot shape the 1024-multiple rounding
-                    # already uses: m/z saturates to the MZ_PAD_Q sentinel
-                    # (outside every window), pixel points at the overflow row,
-                    # intensity 0 — bit-exact, and every dataset whose peak
-                    # count shares the bucket shares the executable
-                    n_pad = shape_buckets.peak_bucket(mz_s.size)
-                    if n_pad > mz_s.size:
-                        from ..ops.quantize import MZ_PAD_Q
-
-                        tail = n_pad - mz_s.size
-                        mz_s = np.concatenate(
-                            [mz_s, np.full(tail, MZ_PAD_Q, mz_s.dtype)])
-                        px_s = np.concatenate(
-                            [px_s, np.full(tail, ds.n_pixels, px_s.dtype)])
-                        in_s = np.concatenate(
-                            [in_s, np.zeros(tail, in_s.dtype)])
-                # resident-cube intensity compaction (ISSUE 18): bf16 halves /
-                # int8 quarters the HBM-resident cube; the f32 view is a
-                # per-batch transient inside the scoring jits.  int8 needs
-                # QTILE-aligned peaks — lattice points are 1024-multiples, so
-                # only the lattice-off int8 combination pads here (same
-                # zero-intensity overflow-row slots as the lattice pad).
-                self._cube_dtype = sm_config.parallel.cube_dtype
-                from ..ops.quantize import MZ_PAD_Q, QTILE
-                if self._cube_dtype == "int8" and in_s.size % QTILE != 0:
-                    tail = -in_s.size % QTILE
+                    tail = n_pad - mz_s.size
                     mz_s = np.concatenate(
                         [mz_s, np.full(tail, MZ_PAD_Q, mz_s.dtype)])
                     px_s = np.concatenate(
                         [px_s, np.full(tail, ds.n_pixels, px_s.dtype)])
-                    in_s = np.concatenate([in_s, np.zeros(tail, in_s.dtype)])
-                codes, scales = compact_cube(in_s, self._cube_dtype)
-            self._mz_host = mz_s
-            with tracing.span("build_device_put"):
-                self._px_s = jax.device_put(px_s, self.device)
-                self._in_s = jax.device_put(codes, self.device)
-                self._scales = (jax.device_put(scales, self.device)
-                                if scales is not None else None)
-                # smlint: host-sync-ok[backend build, once per resident dataset: the span must be the transfer, not its enqueue]
-                jax.block_until_ready(
-                    (self._px_s, self._in_s, self._scales))
-            self.resident_peaks = int(mz_s.size)
-            self.resident_bytes = int(px_s.nbytes + codes.nbytes) + (
-                int(scales.nbytes) if scales is not None else 0)
-            logger.info(
-                "jax_tpu flat peaks resident: %d sorted peaks (%.1f MB, "
-                "cube_dtype=%s) on %s",
-                mz_s.size,
-                (px_s.nbytes + codes.nbytes) / 1e6,
-                self._cube_dtype, self._px_s.devices(),
-            )
-            fns = make_flat_jits(common)
-            self._fn = fns["plain"]
-            self._fn_c = fns["compact"]
-            self._fn_bs = fns["band"]
-            self._fn_f = fns["fused"]
-            # fused-kernel routing (ISSUE 18): "auto" fuses on TPU when
-            # the plan shape fits the kernel's VMEM budget; "on" forces
-            # the fused variant everywhere (interpret mode on CPU — the
-            # tests/sentinel path); hotspot preprocessing excludes fusion
-            self._fused_mode = sm_config.parallel.fused_metrics
-            self._interpret_warned = False
-            # sticky static shapes: grow to the max seen so one executable
-            # serves (almost) all batches instead of recompiling per batch
-            self._gc_width = 0
-            self._gc_tail = 0         # band width of the small-batch variant
-            self._n_keep = 0          # compacted peak capacity
-            self._r_pad = 0           # compaction run-list capacity
-            self._compaction = sm_config.parallel.peak_compaction
-            self._band_mode = sm_config.parallel.band_slice
+                    in_s = np.concatenate(
+                        [in_s, np.zeros(tail, in_s.dtype)])
+            # resident intensities at parallel.cube_dtype; the f32 view is
+            # a per-batch transient inside the scoring jits
+            self._cube_dtype = sm_config.parallel.cube_dtype
+            codes = compact_cube(in_s, self._cube_dtype)
+        self._mz_host = mz_s
+        with tracing.span("build_device_put"):
+            self._px_s = jax.device_put(px_s, self.device)
+            self._in_s = jax.device_put(codes, self.device)
+            # smlint: host-sync-ok[backend build, once per resident dataset: the span must be the transfer, not its enqueue]
+            jax.block_until_ready((self._px_s, self._in_s))
+        self.resident_peaks = int(mz_s.size)
+        self.resident_bytes = int(px_s.nbytes + codes.nbytes)
+        logger.info(
+            "jax_tpu flat peaks resident: %d sorted peaks (%.1f MB, "
+            "cube_dtype=%s) on %s",
+            mz_s.size, self.resident_bytes / 1e6,
+            self._cube_dtype, self._px_s.devices(),
+        )
+        fns = make_flat_jits(common)
+        self._fn = fns["plain"]
+        self._fn_c = fns["compact"]
+        self._fn_bs = fns["band"]
+        self._fn_f = fns["fused"]
+        # fused-kernel routing (ISSUE 18): "auto" fuses on TPU when
+        # the plan shape fits the kernel's VMEM budget; "on" forces
+        # the fused variant everywhere (interpret mode on CPU — the
+        # tests/sentinel path); hotspot preprocessing excludes fusion
+        self._fused_mode = sm_config.parallel.fused_metrics
+        self._interpret_warned = False
+        # sticky static shapes: grow to the max seen so one executable
+        # serves (almost) all batches instead of recompiling per batch
+        self._gc_width = 0
+        self._gc_tail = 0         # band width of the small-batch variant
+        self._n_keep = 0          # compacted peak capacity
+        self._r_pad = 0           # compaction run-list capacity
+        self._compaction = sm_config.parallel.peak_compaction
+        self._band_mode = sm_config.parallel.band_slice
 
     # static batch size for SMALL tables (the stream's tail): a 212-ion
     # final slice padded to formula_batch=2048 pays the full batch's
@@ -836,8 +727,8 @@ class JaxBackend:
     _TAIL_BATCH = 256
 
     def _batch_for(self, n: int) -> int:
-        # cube path and small formula_batch configs keep one executable
-        if self.mz_chunk or self.batch <= self._TAIL_BATCH:
+        # small formula_batch configs keep one executable
+        if self.batch <= self._TAIL_BATCH:
             return self.batch
         return self._TAIL_BATCH if n <= self._TAIL_BATCH else self.batch
 
@@ -981,8 +872,7 @@ class JaxBackend:
         if self._cube_dtype == "f32":
             return self._in_s
         if not hasattr(self, "_in_f32_cache"):
-            self._in_f32_cache = jax.jit(expand_cube_jnp)(
-                self._in_s, self._scales)
+            self._in_f32_cache = jax.jit(expand_cube_jnp)(self._in_s)
         return self._in_f32_cache
 
     def _grow_compact_capacity(self, runs) -> None:
@@ -1055,13 +945,6 @@ class JaxBackend:
         if self._n_real is not None:
             # the lattice's traced real-pixel scalar rides after n_valid
             args.append(jax.device_put(self._n_real))
-        if self._scales is not None:
-            # int8 cube: the per-tile dequant scales ride last; off-lattice
-            # they still need the n_real slot filled (None traces as an
-            # empty pytree) so positions match the fn signatures
-            if self._n_real is None:
-                args.append(None)
-            args.append(self._scales)
         if self._n_real is not None:
             shape_buckets.record_spec(
                 self._bucket_spec(variant, args, statics))
@@ -1100,24 +983,14 @@ class JaxBackend:
 
     def _dispatch(self, table: IsotopePatternTable, flat_plan=None):
         """Async: enqueue one padded batch on device, return (device_out, n)."""
-        n, b, k = table.n_ions, self.batch, table.max_peaks
-        if self.mz_chunk:
-            grid, r_lo, r_hi, ints_p, nv_p = self._padded_windows(table)
-            starts, r_lo_loc, r_hi_loc, inv, gc_width = window_chunks(
-                r_lo, r_hi, self.mz_chunk)
-            args = [jax.device_put(a) for a in (
-                grid, starts, r_lo_loc, r_hi_loc, inv, ints_p, nv_p)]
-            out = self._fn(self._mz_q, self._ints, *args,
-                           gc_width=gc_width, b=b, k=k)
-        else:
-            variant, args, statics = self._flat_call(table, flat_plan)
-            # lands on the ambient score_batch span: which extraction
-            # variant THIS batch ran (chip_smoke.py prints it per batch)
-            tracing.event("batch_variant", variant=variant,
-                          b=int(statics["b"]))
-            fn = getattr(self, _VARIANTS[variant][0])
-            out = fn(self._px_s, self._in_s, *args, **statics)
-        return out, n
+        variant, args, statics = self._flat_call(table, flat_plan)
+        # lands on the ambient score_batch span: which extraction
+        # variant THIS batch ran (chip_smoke.py prints it per batch)
+        tracing.event("batch_variant", variant=variant,
+                      b=int(statics["b"]))
+        fn = getattr(self, _VARIANTS[variant][0])
+        out = fn(self._px_s, self._in_s, *args, **statics)
+        return out, table.n_ions
 
     def probe_phases(self, table: IsotopePatternTable):
         """Per-phase dispatch hooks for profiling (VERDICT r3 item 5):
@@ -1127,22 +1000,13 @@ class JaxBackend:
         would use — and returning the device output.  ``info`` carries the
         plan shape for logging.  Callers time the callables (forcing a
         readback); nothing here reaches into plan-tuple internals."""
-        if self.mz_chunk:
-            return {"fused_full": lambda: self._dispatch(table)[0]}, {
-                "path": "mz_chunk"}
         plan = self._flat_plan(table)
-        variant, fargs, statics = self._flat_call(table, plan)
+        variant, args, statics = self._flat_call(table, plan)
         fn_attr, ext_base, n_ext, pos_ix = _VARIANTS[variant]
         fn = getattr(self, fn_attr)
         phases = {"fused_full": lambda: fn(
-            self._px_s, self._in_s, *fargs, **statics)}
-        # the sub-phase probes index the tail below (n_valid / theor_ints /
-        # n_real) — strip the int8 scales (and their off-lattice n_real
-        # placeholder) first, and give them the expanded f32 cube the
-        # unfused probe fns expect
-        args = list(fargs)
-        if self._scales is not None:
-            args = args[:-1] if self._n_real is not None else args[:-2]
+            self._px_s, self._in_s, *args, **statics)}
+        # the unfused sub-phase probes expect f32 intensities
         in_probe = self._in_f32()
         img_cfg = self.ds_config.image_generation
         ext_statics = {kk: v for kk, v in statics.items()
@@ -1187,7 +1051,7 @@ class JaxBackend:
             _normsq, _dots, ints_p, valid_d)
         pat_fn = jax.jit(isotope_pattern_match_batch)
         phases["pattern"] = lambda: pat_fn(_sums, ints_p, valid_d)
-        info = dict(path="flat", variant=variant, **statics,
+        info = dict(variant=variant, **statics,
                     resident_peaks=int(self._px_s.shape[0]),
                     grid_bins=int(args[pos_ix].shape[0]))
         return phases, info
@@ -1225,24 +1089,17 @@ class JaxBackend:
         n, k = table.n_ions, table.max_peaks
         b_x = shape_buckets.export_bucket(n, self.batch)
         grid, r_lo, r_hi, _ints, _nv = self._padded_windows(table, b_x)
-        if self.mz_chunk:
-            if not hasattr(self, "_extract_fn"):
-                self._extract_fn = jax.jit(extract_images)
-            imgs = self._extract_fn(
-                self._mz_q, self._ints, jax.device_put(grid),
-                jax.device_put(r_lo), jax.device_put(r_hi))
-        else:
-            if not hasattr(self, "_extract_fn"):
-                # bucketed extraction grid (lattice): the host-side slice
-                # below takes the exact-pixel prefix, so the export is
-                # bit-identical while the executable is shared per bucket
-                self._extract_fn = jax.jit(
-                    named_partial(extract_images_flat,
-                                  n_pixels=self._n_pix_b))
-            pos = flat_bound_ranks(self._mz_host, grid)
-            imgs = self._extract_fn(
-                self._px_s, self._in_f32(), jax.device_put(pos),
-                jax.device_put(r_lo), jax.device_put(r_hi))
+        if not hasattr(self, "_extract_fn"):
+            # bucketed extraction grid (lattice): the host-side slice
+            # below takes the exact-pixel prefix, so the export is
+            # bit-identical while the executable is shared per bucket
+            self._extract_fn = jax.jit(
+                named_partial(extract_images_flat,
+                              n_pixels=self._n_pix_b))
+        pos = flat_bound_ranks(self._mz_host, grid)
+        imgs = self._extract_fn(
+            self._px_s, self._in_f32(), jax.device_put(pos),
+            jax.device_put(r_lo), jax.device_put(r_hi))
         # the fetch is the bucket's rows, read in place (no second host
         # copy); the divide writes the kept rows only
         # smlint: host-sync-ok[image EXPORT; the annotated-subset fetch to host is the product of this method]
@@ -1262,8 +1119,6 @@ class JaxBackend:
         wider window-chunk span would otherwise grow gc_width mid-search
         and recompile.  The orchestrator calls
         this once with every slice before the group loop."""
-        if self.mz_chunk:
-            return
         self._grow_for_stream([self._flat_plan(t) for t in tables])
 
     def _grow_for_stream(self, plans) -> None:
@@ -1309,10 +1164,6 @@ class JaxBackend:
         first real batch loads each executable from the cache instead."""
         tables = list(tables)
         self.last_warmup_skipped = False
-        if self.mz_chunk:
-            if tables:
-                self.score_batch(tables[0])
-            return
         plans = [self._flat_plan(t) for t in tables]
         self._grow_for_stream(plans)
         reps, seen = [], set()
@@ -1445,10 +1296,6 @@ class JaxBackend:
         tables = list(tables)
         if cancel is not None:
             cancel.check("score_batches")
-        if self.mz_chunk:
-            pending = [self._enqueue_traced(t) for t in tables]
-            with tracing.span("device_sync", batches=len(pending)):
-                return fetch_scored_batches(pending)
         # plan every batch up front: pre-sizes the static shapes (band width,
         # compaction capacities) to the stream's max so ONE executable serves
         # every batch (a mid-stream growth would recompile), and each plan
@@ -1460,7 +1307,7 @@ class JaxBackend:
         with tracing.span("device_sync", batches=len(pending)):
             return fetch_scored_batches(pending)
 
-    def _enqueue_traced(self, table, plan=None):
+    def _enqueue_traced(self, table, plan):
         """One async device dispatch, wrapped in a per-batch scoring span.
         The span measures ENQUEUE time (dispatch is async; device compute
         overlaps the stream and is settled by the device_sync span)."""
@@ -1468,5 +1315,4 @@ class JaxBackend:
                     if self.device is not None else {})
         with tracing.span("score_batch", backend="jax_tpu",
                           ions=int(table.n_ions), enqueue=True, **dev_attr):
-            return self._dispatch(table, plan) if plan is not None \
-                else self._dispatch(table)
+            return self._dispatch(table, plan)

@@ -171,9 +171,9 @@ _SPEC_KEYS = (
     "mesh_pix", "mesh_form",  # mesh axis sizes (pixels x formulas)
     "p_loc",              # per-shard pixel capacity (whole bucketed rows)
     "w",                  # total window count (the inv permutation length)
-    # compacted-cube executables only (ISSUE 18) — recorded only when
-    # parallel.cube_dtype != "f32", so legacy spec keys stay byte-stable:
-    "cube_dtype",         # "bf16" | "int8" resident intensity dtype
+    # recorded only when parallel.cube_dtype != "f32", so f32 spec keys
+    # stay byte-stable:
+    "cube_dtype",         # "bf16" resident intensity dtype
 )
 
 

@@ -2,17 +2,19 @@
 
 TPU-first reformulation of the reference hot loop (SURVEY.md §3.3,
 ``formula_imager_segm.compute_sf_images`` [U]).  Instead of a cluster-wide
-shuffle of (ion, pixel, intensity) hits, the spectral cube lives on device as
-a padded (pixels x peaks) matrix and an ion image is computed with *static
-shapes* through a per-batch WINDOW-BOUND HISTOGRAM:
+shuffle of (ion, pixel, intensity) hits, the dataset's peaks live on device
+as ONE flat, globally m/z-sorted list (pixel, intensity per peak) and an ion
+image is computed with *static shapes* through a per-batch WINDOW-BOUND
+HISTOGRAM:
 
 1. Host: sort the 2·W quantized window bounds of the batch into one grid;
    record each window's (lo, hi) leftmost rank in the grid (exact, integer).
-2. Device: bucket every cube peak into the grid — ONE shared-table
-   ``searchsorted`` over the whole cube (sort-method: a per-row merge sort,
-   no serialized binary-search gathers).
+2. Host: rank each grid bound among the sorted peaks (``flat_bound_ranks``:
+   G binary searches into the host copy of the m/z array).  On device every
+   peak's grid bin then falls out of ONE cumsum: bins[n] = #{g: grid[g] <=
+   mz[n]} = inclusive cumsum of a delta array with +1 at each bound's rank.
 3. Device: weighted scatter-add histogram (pixels x grid-bins) of peak
-   intensities.
+   intensities; it touches real peaks only (no per-pixel padding slots).
 4. ``img = wh @ D`` where ``D[g, w] = rank_lo(w) < g <= rank_hi(w)`` — ONE
    f32 matmul on the MXU sums each window's bins; no per-(pixel, window)
    gather at all.  Crucially this is exact-zero-preserving: an empty window
@@ -21,13 +23,11 @@ shapes* through a per-batch WINDOW-BOUND HISTOGRAM:
    uses different summation trees per position, leaving ~1e-4 residues that
    fabricate hit pixels).
 
-Design note (measured on TPU v5e, 4096 px x 384 peaks x 2048 windows): the
-naive two-vmapped-binary-searches + prefix-gather design costs ~1.8 s/batch —
-XLA lowers per-lane binary-search gathers to near-scalar code.  This
-histogram path runs the same batch in ~0.1-0.2 s and produces bit-identical
-hit sets (the grid is exact integer quantized bounds).  The pixel axis stays
-the sharding axis; each shard histograms its pixel slice independently
-(collectives only in metrics).
+Exactness: the grid is exact integer quantized bounds and the histogram sums
+exact integers (ops/quantize.py), so images equal the numpy oracle's bit for
+bit in any summation order (tests/test_jax_backend.py::
+test_extraction_parity).  The pixel axis is the sharding axis; each shard
+histograms its pixel slice independently (collectives only in metrics).
 """
 
 from __future__ import annotations
@@ -42,30 +42,6 @@ from .quantize import MZ_PAD_Q, quantize_mz
 # windows per band chunk in the flat-banded extraction (each chunk's
 # membership matmul covers ~2*BAND_WINDOWS grid columns)
 BAND_WINDOWS = 512
-
-
-def prepare_cube_arrays(
-    ds: SpectralDataset,
-    pad_to_multiple: int = 128,
-    pixels_multiple: int = 1,
-    ppm: float | None = None,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Host-side: (mz_q_cube int32 (P, L), int_cube float32 (P, L)).
-
-    m/z rows are quantized (padding saturates to the MZ_PAD_Q sentinel, above
-    every real window bound, so padded peaks land past every rank).  With
-    ``ppm`` given, intensities come from the shared integer grid
-    (ds.intensity_quantization): every per-(pixel, window) sum stays below
-    2**24, so scatter-add and matmul accumulation are EXACT in f32 in any
-    order — image bits equal the numpy oracle's."""
-    mz_cube, int_cube, _lens = ds.padded_cube(pad_to_multiple, pixels_multiple)
-    if ppm is not None:
-        ints_q, _scale = ds.intensity_quantization(ppm)
-        lens = ds.row_lengths()
-        pixel_of_peak = np.repeat(np.arange(ds.n_pixels), lens)
-        col_of_peak = np.arange(ints_q.size) - np.repeat(ds.row_ptr[:-1], lens)
-        int_cube[pixel_of_peak, col_of_peak] = ints_q
-    return quantize_mz(mz_cube), int_cube
 
 
 def window_rank_grid(
@@ -92,54 +68,7 @@ def window_rank_grid(
     return grid, r_lo, r_hi
 
 
-def extract_images(
-    mz_q_cube: jnp.ndarray,   # (P, L) int32, MZ_PAD_Q padding
-    int_cube: jnp.ndarray,    # (P, L) f32, 0 at padding
-    grid: jnp.ndarray,        # (G,) int32 sorted window bounds
-    r_lo: jnp.ndarray,        # (W,) int32 leftmost rank of each lo bound
-    r_hi: jnp.ndarray,        # (W,) int32 leftmost rank of each hi bound
-) -> jnp.ndarray:
-    """(W, P) f32 ion-window images on the current device/shard."""
-    p, _l = mz_q_cube.shape
-    g = grid.shape[0]
-    # bin[p,j] = #{grid bounds <= mz[p,j]} — shared small table, merge-sort path
-    bins = jnp.searchsorted(
-        grid, mz_q_cube.ravel(), side="right", method="sort"
-    ).reshape(p, -1)
-    rows = jnp.arange(p, dtype=jnp.int32)[:, None]
-    wh = jnp.zeros((p, g + 1), jnp.float32).at[rows, bins].add(int_cube)
-    # window-membership matrix: bin gg contributes to window w iff
-    # r_lo[w] < gg <= r_hi[w]  (== "mz < hi" minus "mz < lo" counting)
-    gg = jnp.arange(g + 1, dtype=jnp.int32)[:, None]          # (G+1, 1)
-    d = ((gg > r_lo[None, :]) & (gg <= r_hi[None, :])).astype(jnp.float32)
-    img_pw = jnp.dot(wh, d, precision=jax.lax.Precision.HIGHEST)  # (P, W)
-    return img_pw.T
-
-
-# -- flat globally-sorted layout (single-device fast path) --------------------
-#
-# The padded cube pays for its padding: on the 64x64 bench workload the cube
-# is (4096, 896) = 3.7M slots for 1.17M real peaks, and the per-batch
-# ``searchsorted(..., method="sort")`` sorts ALL slots (47.8 ms measured on
-# v5e) while the scatter-add histograms them (38.6 ms) — together ~80% of the
-# fused graph.  Both shrink dramatically with a dataset-static GLOBALLY
-# m/z-sorted flat peak list:
-#
-# 1. Host, once per dataset: sort all peaks by quantized m/z ->
-#    (mz_sorted, pixel_sorted, int_sorted).
-# 2. Device, per batch: ``pos = searchsorted(mz_sorted, grid)`` — G=8K binary
-#    searches instead of a 3.7M-element sort — then every peak's grid bin
-#    falls out of ONE cumsum: bins[n] = #{g: grid[g] <= mz[n]} = inclusive
-#    cumsum of a delta array with +1 at each pos[g].  (Each bound's rank
-#    among the sorted peaks IS the count of peaks below it.)
-# 3. The histogram scatter-add touches only real peaks (1.17M, not 3.7M).
-# 4. The membership matmul is unchanged.
-#
-# Exactness: bins equal the cube path's ``searchsorted(grid, mz, 'right')``
-# by construction, the histogram sums the same (pixel, bin, intensity)
-# multiset of exact integers, and the matmul is identical — images are
-# bit-identical to the cube path (asserted in tests).  Measured: extraction
-# 94 ms -> ~20 ms per 1024-ion batch.
+# -- flat globally-sorted layout ----------------------------------------------
 
 
 def prepare_flat_sorted_arrays(
@@ -286,9 +215,9 @@ def prepare_flat_sharded_arrays(
     Each shard owns a contiguous slice of ``p_loc = ceil(P/S)`` pixels and
     its peaks sorted by quantized m/z; rows pad to the max shard peak count
     (m/z -> MZ_PAD_Q sentinel, pixel -> the shard-local overflow row
-    ``p_loc``, intensity 0).  Unlike the padded cube — whose row length is
-    the MAX spectrum length, catastrophic for ragged DESI data — per-shard
-    bytes track the actual peak count.  The m/z rows stay host-side (bound
+    ``p_loc``, intensity 0).  Per-shard bytes track the actual peak count
+    (no per-pixel padding to the longest spectrum, which ragged DESI data
+    would make catastrophic).  The m/z rows stay host-side (bound
     ranks are host-computed); only pixel + intensity rows go to HBM.
 
     ``p_loc`` (ISSUE 13 lattice): an explicit per-shard pixel capacity
@@ -565,34 +494,31 @@ def compact_peaks(
     return px_b, in_b
 
 
-# -- m/z-chunked extraction ---------------------------------------------------
+# -- window-chunk plans --------------------------------------------------------
 #
-# The reference segments the m/z range so each task's working set stays
-# bounded (``formula_imager_segm`` m/z segmentation [U], SURVEY.md §2d/§5.7).
-# The TPU analog: the histogram scratch above is (P, 2*B*K+1) f32 — ~3.3 GB
-# for a >200k-pixel slide at formula_batch=512 (ADVICE r1) — so with
-# ``ParallelConfig.mz_chunk`` set, windows are sorted by m/z and processed in
-# chunks whose LOCAL bound-grid slice bounds the scratch at (P, gc_width+2).
-# The global cube searchsorted happens ONCE (local bins are global bins minus
-# the chunk's grid offset); only the scatter-add repeats per chunk, trading
-# compute for an HBM ceiling.  Extracted images are bit-identical to the
-# unchunked path: hit sets are exact integer-grid matches and sums are exact
-# integers (ops/quantize.py) in any grouping.
+# The histogram scratch is (P, 2*B*K+1) f32, and a membership matmul over all
+# of it does work quadratic in the batch.  Windows are therefore sorted by m/z
+# and cut into chunks whose LOCAL slice of the bound grid is gc_width wide:
+# each chunk's matmul reads only its band.  ``window_chunks`` is the plan of
+# the sharded backend, ``ion_window_chunks`` the single-device one.  Images
+# are bit-identical to an unchunked extraction: hit sets are exact
+# integer-grid matches and sums are exact integers (ops/quantize.py) in any
+# grouping.
 
 
 def window_chunks(
-    r_lo: np.ndarray, r_hi: np.ndarray, mz_chunk: int
+    r_lo: np.ndarray, r_hi: np.ndarray, windows_per_chunk: int
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
     """Host-side chunk plan: (starts (C,), r_lo_loc (C, Wc), r_hi_loc (C, Wc),
     inv (W,), gc_width).
 
-    Windows are ordered by lo rank and cut every ``mz_chunk`` windows; a
+    Windows are ordered by lo rank and cut every ``windows_per_chunk`` windows; a
     chunk's grid offset is its first window's lo rank; ``gc_width`` (the
     max local rank span, rounded up to a power of two so recompiles are
     rare) sizes the scratch.  ``inv`` maps sorted rows back to input order.
     """
     w = int(r_lo.size)
-    wc = max(1, int(mz_chunk))
+    wc = max(1, int(windows_per_chunk))
     c = max(1, -(-w // wc))
     # EMPTY windows (lo == hi: batch padding quantized to (0,0), or windows
     # collapsed by quantization) sort LAST, not by their rank-0 bounds —
@@ -676,39 +602,6 @@ def ion_window_chunks(
             order.astype(np.int32))
 
 
-def extract_images_mz_chunked(
-    mz_q_cube: jnp.ndarray,   # (P, L) int32
-    int_cube: jnp.ndarray,    # (P, L) f32
-    grid: jnp.ndarray,        # (G,) int32 sorted window bounds (all chunks)
-    starts: jnp.ndarray,      # (C,) int32 grid offset per chunk
-    r_lo_loc: jnp.ndarray,    # (C, Wc) int32 local lo ranks
-    r_hi_loc: jnp.ndarray,    # (C, Wc) int32 local hi ranks
-    inv: jnp.ndarray,         # (W,) int32 sorted-row -> input-order map
-    *,
-    gc_width: int,
-) -> jnp.ndarray:
-    """(W, P) f32 ion-window images, scratch bounded at (P, gc_width+2)."""
-    p, _l = mz_q_cube.shape
-    bins_g = jnp.searchsorted(
-        grid, mz_q_cube.ravel(), side="right", method="sort"
-    ).reshape(p, -1)                                   # global bins, ONCE
-    rows = jnp.arange(p, dtype=jnp.int32)[:, None]
-    gg = jnp.arange(gc_width + 2, dtype=jnp.int32)[:, None]
-
-    def chunk(_, data):
-        start, rlo, rhi = data
-        # out-of-chunk peaks clip to bins 0 / gc_width+1, excluded from every
-        # window (local interiors are (rlo, rhi] with rlo >= 0, rhi <= gc_width)
-        lb = jnp.clip(bins_g - start, 0, gc_width + 1)
-        wh = jnp.zeros((p, gc_width + 2), jnp.float32).at[rows, lb].add(int_cube)
-        d = ((gg > rlo[None, :]) & (gg <= rhi[None, :])).astype(jnp.float32)
-        return None, jnp.dot(wh, d, precision=jax.lax.Precision.HIGHEST).T
-
-    _, imgs = jax.lax.scan(chunk, None, (starts, r_lo_loc, r_hi_loc))
-    imgs = imgs.reshape(-1, p)                         # (C*Wc, P) sorted order
-    return jnp.take(imgs, inv, axis=0)                 # (W, P) input order
-
-
 # -- roofline cost model ------------------------------------------------------
 
 def fused_score_cost_model(
@@ -754,14 +647,14 @@ def fused_score_cost_model(
     principal images the chaos sweep needs, and the epilogue reads
     principal rather than the full K-peak block.  ``cube_dtype`` prices
     the resident intensity read of the histogram scatter at the compacted
-    width (ops/quantize.py: bf16 2 B, int8 1 B per peak).
+    width (ops/quantize.py: bf16 2 B per peak).
     """
     n_batches = max(1, -(-n_ions // formula_batch))
     g = 2 * formula_batch * max_peaks
     scratch_cols = max(g + 1, 4098)
     scatter_slots = (resident_peaks if ordered
                      else resident_peaks * n_batches)
-    int_bytes = {"f32": 4, "bf16": 2, "int8": 1}[cube_dtype]
+    int_bytes = {"f32": 4, "bf16": 2}[cube_dtype]
     # per slot: intensity read + index read + f32 scratch read-modify-write
     scatter_bytes = (int_bytes + 8) * scatter_slots
     init_bytes = 4 * n_batches * (n_pixels + 1) * scratch_cols

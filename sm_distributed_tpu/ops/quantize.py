@@ -36,16 +36,13 @@ NUMERICS = numerics_surface(__name__, {
     "intensity_scale":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
         "test_backend_parity_metrics_and_ranks",
-    # resident-cube compaction (ISSUE 18): bf16 rounds the quantized
-    # integer grid to 8 significant bits — still integers, still summed
-    # exactly in any order, so the drift vs the f32 cube is DATA-level
-    # (a coarser grid, ~2**-9 relative), not reduction-order: orders of
-    # magnitude above the same-data ulp ceilings, which is why this
-    # contract is wide.  What compaction must preserve is the RANKING —
-    # FDR ranks bit-identical on the sentinel fixture (the test's hard
-    # assertion) — with the measured component drift recorded in
-    # NUMERICS_r02.json.  int8 uses per-tile power-of-two scales, so
-    # dequantization itself is exact in f32.
+    # resident-intensity compaction: bf16 rounds the quantized integer
+    # grid to 8 significant bits — still integers, still summed exactly in
+    # any order, so the drift vs the f32 residents is DATA-level (a coarser
+    # grid, ~2**-9 relative), not reduction-order: orders of magnitude
+    # above the same-data ulp ceilings, which is why this contract is wide.
+    # What the test asserts hard is the RANKING: FDR ranks bit-identical on
+    # the sentinel fixture.
     "compact_cube":
         "contract=ulp(4096); test=tests/test_score_pallas.py::"
         "test_quantized_cube_rank_identity",
@@ -126,80 +123,44 @@ def quantize_intensities(ints_flat: np.ndarray, scale: float) -> np.ndarray:
     return np.rint(np.asarray(ints_flat, np.float64) * scale).astype(np.float32)
 
 
-# -- resident-cube compaction (ISSUE 18) --------------------------------------
+# -- resident-intensity compaction ---------------------------------------------
 #
-# The flat sorted-peaks cube is HBM-resident for the whole run (1.85 GB f32
-# intensities at DESI scale).  Halving (bf16) or quartering (int8) it buys
-# both capacity and scatter read bandwidth; the expanded f32 view exists
-# only as a per-batch transient inside the scoring jit (XLA fuses the cast
-# into the histogram scatter's operand read).
+# The flat sorted-peaks intensities are HBM-resident for the whole run; bf16
+# halves them, and the expanded f32 view exists only as a per-batch transient
+# inside the scoring jit (XLA fuses the cast into the histogram scatter's
+# operand read).
 #
-# bf16: a straight cast.  The intensities are already integer-valued f32
+# bf16 is a straight cast.  The intensities are already integer-valued f32
 # (quantize_intensities); bf16 keeps 8 significant bits and rounds to
 # NEAREST-EVEN, so every stored value is STILL an integer (e.g. 300 ->
 # 75 * 2**2) and every per-(pixel, window) sum stays below 2**24 — the
 # order-free exact-accumulation property survives, cross-backend identity
-# survives, and the drift vs the f32 cube is a data-level regrid bounded
-# by hmax * max_int * 2**-9 per pixel sum.
-#
-# int8: per-tile symmetric quantization with POWER-OF-TWO scales, tile =
-# QTILE consecutive peaks of the m/z-sorted cube (peak arrays are padded
-# to multiples of QTILE by the shape-bucket lattice: ops/buckets.PEAK_FLOOR
-# and every pow2ish point are multiples of 1024).  Power-of-two scales make
-# the dequantization multiply EXACT in f32 (code * 2**k), so the only loss
-# is the rint to 8 bits — again integer-preserving at every scale step.
+# survives, and the drift vs the f32 residents is a data-level regrid bounded
+# by hmax * max_int * 2**-9 per pixel sum.  That drift is over the
+# benchmark's `correct` limits on the chip (PERF.md section 2): bf16 is kept
+# as the benchmark's failing control, not as a serving mode.
 
-CUBE_DTYPES = ("f32", "bf16", "int8")
-QTILE = 1024  # peaks per int8 scale tile
+CUBE_DTYPES = ("f32", "bf16")
 
 
-def compact_cube(in_s: np.ndarray, cube_dtype: str):
-    """Host-side compaction of the (N,) f32 intensity cube.
-
-    Returns ``(codes, scales)``: ``codes`` is the compact resident array
-    (bf16 or int8), ``scales`` the (N // QTILE,) f32 per-tile power-of-two
-    dequantization factors (None for bf16 — the cast needs none)."""
+def compact_cube(in_s: np.ndarray, cube_dtype: str) -> np.ndarray:
+    """Host-side compaction of the (N,) f32 resident intensities to
+    ``cube_dtype``."""
     if cube_dtype not in CUBE_DTYPES:
         raise ValueError(f"cube_dtype must be one of {CUBE_DTYPES}, "
                          f"got {cube_dtype!r}")
     in_s = np.ascontiguousarray(in_s, dtype=np.float32)
     if cube_dtype == "f32":
-        return in_s, None
-    if cube_dtype == "bf16":
-        import ml_dtypes  # jax dependency; baked into the image
-        return in_s.astype(ml_dtypes.bfloat16), None
-    if in_s.size % QTILE != 0:
-        raise ValueError(
-            f"int8 cube needs a QTILE={QTILE}-aligned peak count "
-            f"(lattice-padded), got {in_s.size}")
-    tiles = in_s.reshape(-1, QTILE)
-    m = np.max(np.abs(tiles), axis=1)
-    # smallest 2**e with m / 2**e <= 127 (m == 0 -> scale 1)
-    e = np.ceil(np.log2(np.maximum(m, 1e-30) / 127.0))
-    scales = np.exp2(np.maximum(e, np.float64(-126.0))).astype(np.float32)
-    codes = np.rint(tiles / scales[:, None]).astype(np.int8)
-    return codes.reshape(-1), scales
+        return in_s
+    import ml_dtypes  # jax dependency; baked into the image
+    return in_s.astype(ml_dtypes.bfloat16)
 
 
-def expand_cube(codes: np.ndarray, scales) -> np.ndarray:
-    """Host-side inverse of :func:`compact_cube` (tests / oracle path)."""
-    if codes.dtype == np.float32:
-        return codes
-    if scales is None:
-        return np.asarray(codes, dtype=np.float32)
-    return (codes.reshape(-1, QTILE).astype(np.float32)
-            * scales[:, None]).reshape(-1)
-
-
-def expand_cube_jnp(codes, scales):
-    """In-graph f32 view of the compact resident cube — the first op of
-    every scoring jit when ``parallel.cube_dtype != "f32"``.  Exact: the
-    bf16->f32 cast is value-preserving, and the int8 path multiplies an
-    integer <= 127 by a power of two."""
+def expand_cube_jnp(codes):
+    """In-graph f32 view of the resident intensities — the first op of
+    every scoring jit.  A value-preserving cast under bf16; under f32 a
+    python-level no-op, so the traced program has no trace of it."""
     import jax.numpy as jnp  # deferred: quantize.py is host-importable
     if codes.dtype == jnp.float32:
         return codes
-    if scales is None:
-        return codes.astype(jnp.float32)
-    return (codes.astype(jnp.float32).reshape(-1, QTILE)
-            * scales[:, None]).reshape(-1)
+    return codes.astype(jnp.float32)

@@ -36,8 +36,7 @@ top of the step), but NOT the fused Pallas scoring kernel — the step's
 all_to_all trades materialized image blocks between pixel shards, and the
 correlation moments need the post-shuffle global-pixel mean, so the fused
 kernel's image-free partials cannot cross the shuffle without a second
-collective pass.  int8 falls back to f32 here: per-tile scale vectors do
-not align with shard rows.
+collective pass.
 """
 
 from __future__ import annotations
@@ -67,7 +66,7 @@ from ..ops.imager_jax import (
 from ..ops.isocalc import IsotopePatternTable
 from ..ops.metrics_jax import batch_metrics
 from ..utils import tracing
-from ..ops.quantize import expand_cube_jnp, quantize_window
+from ..ops.quantize import compact_cube, expand_cube_jnp, quantize_window
 from ..utils.config import DSConfig, SMConfig
 from ..utils.logger import logger
 from .mesh import FORMULAS_AXIS, PIXELS_AXIS, make_mesh
@@ -147,7 +146,7 @@ def build_sharded_score_factory(
         b, k = theor_ints.shape
         # f32 view of a (possibly bf16-compacted) shard row — a no-op for
         # legacy f32 residents, so that HLO is byte-identical (ISSUE 18)
-        in_s = expand_cube_jnp(in_s, None)
+        in_s = expand_cube_jnp(in_s)
         if n_keep:
             px_loc, in_loc = compact_peaks(
                 px_s[0], in_s[0], run_pos[0], run_delta[0], n_b[0, 0],
@@ -256,15 +255,6 @@ class ShardedJaxBackend:
             n_form_shards * n_pix_shards)
         img_cfg = ds_config.image_generation
         self.ppm = img_cfg.ppm
-        if sm_config.parallel.mz_chunk:
-            # a silently-ignored memory knob is exactly how an opaque OOM
-            # happens later — refuse instead of warn (VERDICT r2 weak #3)
-            raise ValueError(
-                "parallel.mz_chunk applies only to the single-device cube "
-                "path; on a multi-device mesh, per-device memory is bounded "
-                f"by sharding (pixels/{n_pix_shards}) — unset mz_chunk, or "
-                "reduce parallel.formula_batch / grow the pixels axis to "
-                "shrink per-shard scratch")
         # HBM guard, per-shard arithmetic (the single-device backend fails
         # early with guidance — msm_jax.py — and an 8-GiB-per-shard scatter
         # scratch OOMs just as opaquely on a mesh; VERDICT r2 weak #3)
@@ -306,20 +296,10 @@ class ShardedJaxBackend:
         if restrict_table is not None:
             mz_s, px_s, in_s = self._restrict_shards(
                 mz_s, px_s, in_s, restrict_table)
-        # resident-cube compaction (ISSUE 18): bf16 halves the per-shard
-        # HBM rows (expanded to f32 in-graph at the top of the step); int8
-        # per-tile scale vectors do not align with shard rows, so the mesh
-        # path falls back to exact f32 rather than silently mis-scale
+        # bf16 halves the per-shard HBM rows (expanded to f32 in-graph at
+        # the top of the step)
         self._cube_dtype = sm_config.parallel.cube_dtype
-        if self._cube_dtype == "int8":
-            logger.warning(
-                "parallel.cube_dtype=int8 is single-device only (per-tile "
-                "scales do not shard); mesh path keeps f32 residents")
-            self._cube_dtype = "f32"
-        if self._cube_dtype == "bf16":
-            import ml_dtypes  # jax dependency; baked into the image
-
-            in_s = in_s.astype(ml_dtypes.bfloat16)
+        in_s = compact_cube(in_s, self._cube_dtype)
         self._compaction = sm_config.parallel.peak_compaction
         self._band_mode = sm_config.parallel.band_slice
         self._n_keep = 0          # sticky compacted capacity (see JaxBackend)
@@ -661,7 +641,7 @@ class ShardedJaxBackend:
 
         def step(px_s, in_s, pos, rlo, rhi):
             return extract_images_flat(
-                px_s[0], expand_cube_jnp(in_s[0], None), pos[0], rlo, rhi,
+                px_s[0], expand_cube_jnp(in_s[0]), pos[0], rlo, rhi,
                 n_pixels=p_loc)
 
         if not hasattr(self, "_extract_fn"):

@@ -51,6 +51,7 @@ from pathlib import Path
 from ..analysis.numerics import numerics_surface
 from ..analysis.surface import compile_surface
 from ..ops import buckets as shape_buckets
+from ..ops.quantize import CUBE_DTYPES
 from ..utils.logger import logger
 
 # No jax.jit call sites live here — the jitted programs are built by
@@ -79,6 +80,18 @@ NUMERICS = numerics_surface(__name__, {
 })
 
 
+def _resident_dtype(spec: dict):
+    """The resident intensity aval's dtype: a spec records ``cube_dtype``
+    only when it is not f32 (``JaxBackend._bucket_spec``)."""
+    import numpy as np
+
+    if spec.get("cube_dtype") == "bf16":
+        import ml_dtypes  # jax dependency; baked into the image
+
+        return ml_dtypes.bfloat16
+    return np.float32
+
+
 def _flat_lower_call(spec: dict):
     """(jitted fn, positional ShapeDtypeStruct avals, static kwargs) for
     one recorded flat-path spec — the exact calling convention of
@@ -100,22 +113,9 @@ def _flat_lower_call(spec: dict):
         "q": float(spec["q"]),
     }
     fn = make_flat_jits(common)[spec["variant"]]
-    # compacted-cube specs (ISSUE 18): the resident intensity aval carries
-    # the recorded dtype, and int8 appends the per-tile scale vector after
-    # the traced n_real scalar — exactly JaxBackend._flat_call's tail
-    cube_dtype = spec.get("cube_dtype") or "f32"
-    in_dtype = {"f32": f32, "bf16": None, "int8": np.int8}[cube_dtype]
-    if in_dtype is None:
-        import ml_dtypes  # jax dependency; baked into the image
-
-        in_dtype = ml_dtypes.bfloat16
-    resident = [S((n,), i32), S((n,), in_dtype)]
+    resident = [S((n,), i32), S((n,), _resident_dtype(spec))]
     plan = [S((c,), i32), S((c, wc), i32), S((c, wc), i32), S((b,), i32),
             S((b, k), f32), S((b,), i32), S((), i32)]
-    if cube_dtype == "int8":
-        from ..ops.quantize import QTILE
-
-        plan = plan + [S((n // QTILE,), f32)]
     statics = dict(gc_width=int(spec["gc_width"]), b=b, k=k)
     if spec["variant"] in ("plain", "fused"):
         # the fused Pallas variant shares the plain call shape exactly —
@@ -172,14 +172,7 @@ def _sharded_lower_call(spec: dict):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, part))
 
-    # bf16-compacted residents (ISSUE 18) record their dtype on the spec;
-    # int8 never reaches the mesh path (ShardedJaxBackend falls back)
-    if spec.get("cube_dtype") == "bf16":
-        import ml_dtypes  # jax dependency; baked into the image
-
-        in_dtype = ml_dtypes.bfloat16
-    else:
-        in_dtype = f32
+    in_dtype = _resident_dtype(spec)
     # run/band plan blocks mirror ShardedJaxBackend._dispatch: compact
     # ships (S, F*r_pad) run lists, band/plain ship (S, F) dummies/starts
     rp_w = form * r_pad if n_keep else form
@@ -211,6 +204,10 @@ def prime_spec(spec: dict, sm_config=None) -> str:
     kind = spec.get("kind")
     if kind not in ("flat", "sharded"):
         return f"skipped:{kind or 'unknown'}"
+    # manifests outlive releases: an entry recorded under a resident dtype
+    # this program no longer has names an executable nothing will look up
+    if (spec.get("cube_dtype") or "f32") not in CUBE_DTYPES:
+        return "skipped:cube_dtype"
     if sm_config is not None:
         from ..parallel.distributed import compile_cache_path, enable_compile_cache
 
@@ -400,7 +397,8 @@ class CachePrimer:
                 logger.info("primer: compiled bucket %s", key)
             else:
                 out["skipped"] += 1
-                self._note(key, status, status.split(":", 1)[-1])
+                if self._note(key, status, status.split(":", 1)[-1]):
+                    logger.info("primer: %s bucket %s", status, key)
         dt = time.perf_counter() - t0
         with self._lock:
             self._cycles += 1
@@ -411,11 +409,14 @@ class CachePrimer:
             self._refresh_gauges()
         return out
 
-    def _note(self, key: str, status: str, skip_reason: str | None) -> None:
+    def _note(self, key: str, status: str, skip_reason: str | None) -> bool:
+        """Record one spec's outcome; True when it is news for this spec."""
         with self._lock:
+            changed = self._status.get(key) != status
             self._status[key] = status
         if skip_reason and self._metrics is not None:
             self.m_skipped.labels(reason=skip_reason).inc()
+        return changed
 
     def _refresh_gauges(self) -> None:
         self.g_known.set(len(self.known_specs()))

@@ -264,6 +264,31 @@ def test_primer_idempotent_and_resumable(recorded_backend):
     assert len(manifest["primed"]) >= len(flat)
 
 
+def test_primer_skips_unknown_cube_dtype(recorded_backend, caplog):
+    """A manifest entry recorded under a resident dtype this program no
+    longer has (int8) is skipped with one log line; the manifest's other
+    specs still prime."""
+    import logging
+
+    from sm_distributed_tpu.service.primer import CachePrimer
+    from sm_distributed_tpu.utils.logger import LOGGER_NAME
+
+    sm, _tmp = recorded_backend
+    specs = buckets.recorded_specs()
+    stale = dict(specs[0], cube_dtype="int8")
+    assert buckets.record_spec(stale)
+    primer = CachePrimer(sm, busy=lambda: False)
+    with caplog.at_level(logging.INFO, logger=LOGGER_NAME):
+        res = primer.prime_once()
+        again = primer.prime_once()
+    assert res == {"compiled": len(specs), "skipped": 1, "errors": 0,
+                   "aborted": False}
+    assert again["compiled"] == 0 and again["errors"] == 0
+    lines = [r.getMessage() for r in caplog.records
+             if "skipped:cube_dtype" in r.getMessage()]
+    assert len(lines) == 1 and "cube_dtype=int8" in lines[0]
+
+
 def test_primer_yields_to_real_work(recorded_backend):
     """A busy service aborts the cycle at the next spec boundary without
     compiling — priming never delays a real job (and touches no

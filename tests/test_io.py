@@ -102,21 +102,6 @@ def test_dataset_unsorted_spectrum_gets_sorted():
     np.testing.assert_array_equal(ds.ints_flat, [1.0, 2.0, 3.0])
 
 
-def test_padded_cube():
-    coords = np.array([[1, 1], [2, 1]])
-    spectra = [
-        (np.array([100.0, 200.0, 300.0]), np.array([1.0, 2.0, 3.0])),
-        (np.array([150.0]), np.array([9.0])),
-    ]
-    ds = SpectralDataset.from_arrays(coords, spectra)
-    mz_cube, int_cube, lens = ds.padded_cube(pad_to_multiple=4, pixels_multiple=8)
-    assert mz_cube.shape == (8, 4)
-    np.testing.assert_array_equal(lens[:2], [3, 1])
-    assert np.all(np.isinf(mz_cube[0, 3:]))          # +inf padding
-    assert np.all(np.isinf(mz_cube[2:]))             # padded pixels fully inf
-    assert int_cube[1, 0] == 9.0 and np.all(int_cube[1, 1:] == 0)
-
-
 def test_synthetic_dataset_end_to_end(tmp_path):
     path, truth = generate_synthetic_dataset(
         tmp_path, nrows=8, ncols=8, formulas=["C6H12O6", "C5H5N5", "C27H46O", "C3H4O3"],

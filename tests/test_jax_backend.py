@@ -93,72 +93,51 @@ def test_hotspot_clip_batch_matches_numpy():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
-def test_extraction_parity(fixture_ds):
+@pytest.mark.parametrize("restricted", [False, True],
+                         ids=["all_peaks", "window_union"])
+@pytest.mark.parametrize("ds_name", ["fixture_ds", "offgrid_ds"])
+def test_extraction_parity(request, ds_name, restricted):
+    """``extract_images_flat`` against the numpy oracle, bit for bit: over
+    every resident peak, and over the peaks ``restrict_flat_to_windows``
+    keeps (what a served backend holds)."""
     import jax.numpy as jnp
     from sm_distributed_tpu.ops.imager_jax import (
-        extract_images, prepare_cube_arrays, window_rank_grid,
+        extract_images_flat, flat_bound_ranks, prepare_flat_sorted_arrays,
+        restrict_flat_to_windows, window_rank_grid,
     )
     from sm_distributed_tpu.ops.imager_np import extract_ion_images
     from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
     from sm_distributed_tpu.ops.quantize import quantize_window
     from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
 
-    ds, truth = fixture_ds
+    ds, truth = request.getfixturevalue(ds_name)
     calc = IsocalcWrapper(IsotopeGenerationConfig(adducts=("+H",)))
     table = calc.pattern_table([(sf, "+H") for sf in truth.formulas[:20]])
 
     want = extract_ion_images(ds, table, ppm=3.0)
 
-    mz_q, int_cube = prepare_cube_arrays(ds, ppm=3.0)
     scale = ds.intensity_quantization(3.0)[1]
     lo, hi = quantize_window(table.mzs, 3.0)
     grid, r_lo, r_hi = window_rank_grid(lo, hi)
+    mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, 3.0)
+    if restricted:
+        n_all = int(np.count_nonzero(in_s))
+        mzk, pxk, ink, n_eff = restrict_flat_to_windows(
+            mz_s[None], px_s[None], in_s[None], lo, hi,
+            overflow_row=ds.n_pixels)
+        mz_s, px_s, in_s = mzk[0], pxk[0], ink[0]
+        assert 0 < n_eff < n_all           # the restriction dropped peaks
     got = np.asarray(
-        extract_images(jnp.asarray(mz_q), jnp.asarray(int_cube),
-                       jnp.asarray(grid), jnp.asarray(r_lo), jnp.asarray(r_hi))
-    ).reshape(table.n_ions, table.max_peaks, -1)[:, :, : ds.n_pixels]
+        extract_images_flat(jnp.asarray(px_s), jnp.asarray(in_s),
+                            jnp.asarray(flat_bound_ranks(mz_s, grid)),
+                            jnp.asarray(r_lo), jnp.asarray(r_hi),
+                            n_pixels=ds.n_pixels)
+    ).reshape(table.n_ions, table.max_peaks, -1)
     # BIT-EXACT image parity: shared m/z + integer-intensity grids make every
     # per-(pixel, window) sum an exactly-representable f32 integer, so any
     # summation order (scatter trees, matmul, bincount) gives the same bits;
     # dequantization is an exact power-of-two division.
     np.testing.assert_array_equal(got / np.float32(scale), want)
-
-
-def test_extraction_flat_bit_identical_to_cube(fixture_ds):
-    """The flat globally-sorted layout (single-device fast path) must produce
-    the SAME BITS as the padded-cube histogram path — same hit sets, same
-    exact-integer sums."""
-    import jax.numpy as jnp
-    from sm_distributed_tpu.ops.imager_jax import (
-        extract_images, extract_images_flat, flat_bound_ranks,
-        prepare_cube_arrays, prepare_flat_sorted_arrays, window_rank_grid,
-    )
-    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
-    from sm_distributed_tpu.ops.quantize import quantize_window
-    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
-
-    ds, truth = fixture_ds
-    calc = IsocalcWrapper(IsotopeGenerationConfig(adducts=("+H",)))
-    table = calc.pattern_table([(sf, "+H") for sf in truth.formulas[:20]])
-    lo, hi = quantize_window(table.mzs, 3.0)
-    grid, r_lo, r_hi = window_rank_grid(lo, hi)
-
-    mz_q, int_cube = prepare_cube_arrays(ds, ppm=3.0)
-    cube = np.asarray(
-        extract_images(jnp.asarray(mz_q), jnp.asarray(int_cube),
-                       jnp.asarray(grid), jnp.asarray(r_lo), jnp.asarray(r_hi))
-    )[:, : ds.n_pixels]
-
-    mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, 3.0)
-    # host-computed bound ranks == the cube path's device-side searchsorted
-    pos = flat_bound_ranks(mz_s, grid)
-    flat = np.asarray(
-        extract_images_flat(jnp.asarray(px_s), jnp.asarray(in_s),
-                            jnp.asarray(pos),
-                            jnp.asarray(r_lo), jnp.asarray(r_hi),
-                            n_pixels=ds.n_pixels)
-    )
-    np.testing.assert_array_equal(flat, cube)
 
 
 def _run(ds, formulas, backend, decoy_n=6, seed=9, batch=64,
@@ -564,6 +543,121 @@ def test_batch_peak_runs_plan_exact():
         np.testing.assert_array_equal(pos_b, want)
 
 
+def test_flat_scratch_guard_names_live_remedies(fixture_ds):
+    """A histogram scratch past 8 GiB fails at construction, before any
+    device allocation, and the message names remedies that exist."""
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+
+    ds, _truth = fixture_ds
+    ds_config = DSConfig.from_dict(
+        {"isotope_generation": {"adducts": ["+H"]},
+         "image_generation": {"ppm": 3.0}})
+    # 4 * (144 + 1) * (2 * B * 4 + 1) bytes at B = 2**28: ~1.2 TiB
+    sm = SMConfig.from_dict(
+        {"backend": "jax_tpu", "parallel": {"formula_batch": 300_000_000}})
+    with pytest.raises(ValueError, match="histogram scratch") as err:
+        JaxBackend(ds, ds_config, sm)
+    msg = str(err.value)
+    assert "parallel.formula_batch" in msg and "parallel.pixels_axis" in msg
+    assert "mz_chunk" not in msg
+
+
+@pytest.mark.parametrize("parallel, names", [
+    ({"mz_chunk": 0}, ("mz_chunk",)),
+    ({"cube_dtype": "int8"}, ("cube_dtype", "'f32', 'bf16'"))],
+    ids=["mz_chunk", "cube_dtype-int8"])
+def test_removed_parallel_values_fail_at_load(parallel, names):
+    """A configuration file written for the cube path or the int8 cube is
+    refused by name at load, not ignored."""
+    with pytest.raises(ValueError) as err:
+        SMConfig.from_dict({"backend": "jax_tpu", "parallel": parallel})
+    for name in names:
+        assert name in str(err.value)
+
+
+@pytest.mark.parametrize("cube_dtype", ["f32", "bf16"])
+def test_probe_phases_run_the_dispatched_program(fixture_ds, cube_dtype):
+    """``probe_phases`` (bench.py, scripts/roofline_probe.py) hands back
+    the call ``score_batch`` makes and its sub-phases on f32 intensities,
+    whatever the resident dtype."""
+    from sm_distributed_tpu.models.msm_jax import _VARIANTS, JaxBackend
+    from sm_distributed_tpu.ops.imager_np import extract_ion_images
+    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
+    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
+
+    ds, truth = fixture_ds
+    calc = IsocalcWrapper(IsotopeGenerationConfig(adducts=("+H",)))
+    table = calc.pattern_table([(sf, "+H") for sf in truth.formulas[:20]])
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
+                             "image_generation": {"ppm": 3.0}})
+    sm = SMConfig.from_dict({"backend": "jax_tpu", "parallel": {
+        "formula_batch": 32, "cube_dtype": cube_dtype}})
+    backend = JaxBackend(ds, dc, sm)
+    phases, info = backend.probe_phases(table)
+    assert set(phases) == {"fused_full", "extract", "moments", "chaos",
+                           "correlation", "pattern"}
+    assert info["variant"] in _VARIANTS
+    np.testing.assert_array_equal(
+        np.asarray(phases["fused_full"]())[: table.n_ions].astype(np.float64),
+        backend.score_batch(table))
+    imgs = np.asarray(phases["extract"]())
+    assert imgs.dtype == np.float32
+    assert imgs.shape == (32 * table.max_peaks, backend._n_pix_b)
+    if cube_dtype == "f32":
+        # the plan sorts ions, so compare per-window totals as multisets:
+        # exact, every sum is an integer below 2**24 times a power of two
+        want = extract_ion_images(ds, table, ppm=3.0).reshape(
+            -1, ds.n_pixels).sum(axis=1, dtype=np.float64)
+        got = imgs.sum(axis=1, dtype=np.float64) / backend.int_scale
+        np.testing.assert_array_equal(
+            np.sort(got)[-want.size:], np.sort(want))
+
+
+def test_window_chunks_plan_covers_all_windows():
+    from sm_distributed_tpu.ops.imager_jax import window_chunks
+
+    rng = np.random.default_rng(0)
+    r_lo = rng.integers(0, 500, 77).astype(np.int32)
+    r_hi = (r_lo + rng.integers(1, 5, 77)).astype(np.int32)
+    starts, r_lo_loc, r_hi_loc, inv, gc_width = window_chunks(r_lo, r_hi, 16)
+    c, wc = r_lo_loc.shape
+    assert c * wc >= 77 and wc == 16
+    # every real window recoverable: local + start == global, inv is a perm
+    order = np.argsort(r_lo, kind="stable")
+    flat_lo = (r_lo_loc + starts[:, None]).ravel()[:77]
+    np.testing.assert_array_equal(flat_lo, r_lo[order])
+    assert sorted(inv.tolist()) == list(range(77))
+    assert r_hi_loc.max() <= gc_width
+    # padded tail windows are empty (lo == hi)
+    tail = (r_lo_loc == r_hi_loc).ravel()[77:]
+    assert tail.all()
+
+
+def test_window_chunks_empty_windows_do_not_blow_band():
+    """Empty windows (lo == hi, e.g. batch padding at rank 0) must sort LAST:
+    chunked together with high-rank real windows they'd stretch a chunk's
+    span to the whole grid (measured 8x gc_width growth -> ~10x slowdown on
+    partially-padded batches)."""
+    from sm_distributed_tpu.ops.imager_jax import window_chunks
+
+    rng = np.random.default_rng(1)
+    # a mostly-padded batch: 48 real windows at HIGH ranks, 464 empties at 0
+    n_real = 48
+    r_lo = np.zeros(512, dtype=np.int32)
+    r_hi = np.zeros(512, dtype=np.int32)
+    r_lo[:n_real] = rng.integers(7000, 8100, n_real)
+    r_hi[:n_real] = r_lo[:n_real] + rng.integers(1, 5, n_real)
+    starts, r_lo_loc, r_hi_loc, inv, gc_width = window_chunks(r_lo, r_hi, 16)
+    # band stays proportional to the REAL windows' local spread, not the
+    # empty-to-real rank gap (the old argsort gave gc_width >= 4096 here)
+    assert gc_width <= 2048
+    # reconstruction still exact for every real window
+    flat_lo = (r_lo_loc + starts[:, None]).ravel()[:512]
+    srt = np.lexsort((r_lo, (r_lo == r_hi).astype(np.int8)))
+    np.testing.assert_array_equal(flat_lo, r_lo[srt])
+    assert sorted(inv.tolist()) == list(range(512))
+
+
 def test_tail_batch_executable_matches(fixture_ds):
     """A stream's small final slice runs through the 256-wide tail
     executable (full-size padding would pay ~8x its cost); results must be
@@ -623,9 +717,9 @@ def test_tail_batch_executable_matches(fixture_ds):
 _EXPORT_BATCH = 128
 
 
-@pytest.fixture(scope="module", params=["flat", "mz_chunk"])
+@pytest.fixture(scope="module", params=["f32", "bf16"])
 def export_backend(request, offgrid_ds):
-    """One backend per extraction branch on the off-lattice 9x11 fixture
+    """One backend per resident dtype on the off-lattice 9x11 fixture
     (99 px in a 110-px bucket), with a 150-ion table whose every third
     ion has ``n_valid`` < k (the mask must zero images the windows fill)."""
     import dataclasses
@@ -644,25 +738,22 @@ def export_backend(request, offgrid_ds):
     table = dataclasses.replace(table, n_valid=n_valid)
     assert table.n_ions > _EXPORT_BATCH
     sm = SMConfig.from_dict({"backend": "jax_tpu", "parallel": {
-        "formula_batch": _EXPORT_BATCH,
-        "mz_chunk": 32 if request.param == "mz_chunk" else 0}})
+        "formula_batch": _EXPORT_BATCH, "cube_dtype": request.param}})
     dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
                              "image_generation": {"ppm": 3.0}})
     backend = JaxBackend(ds, dc, sm)
-    assert bool(backend.mz_chunk) == (request.param == "mz_chunk")
-    if not backend.mz_chunk:                            # off the lattice
-        assert backend._n_pix_b == 110 > ds.n_pixels == 99
+    assert backend._in_s.dtype == {"f32": "float32",
+                                   "bf16": "bfloat16"}[request.param]
+    assert backend._n_pix_b == 110 > ds.n_pixels == 99  # off the lattice
     return backend, table
 
 
 def _full_batch_export(backend, table):
     """The export as it was before PR 27: every call padded to the scoring
     batch, the whole padded block copied to the host, divided in place."""
-    import jax
-
     from sm_distributed_tpu.models.msm_basic import _slice_table
     from sm_distributed_tpu.ops.imager_jax import (
-        extract_images, extract_images_flat, flat_bound_ranks,
+        extract_images_flat, flat_bound_ranks,
     )
 
     b, k = backend.batch, table.max_peaks
@@ -670,14 +761,10 @@ def _full_batch_export(backend, table):
     for s in range(0, table.n_ions, b):
         t = _slice_table(table, s, min(s + b, table.n_ions))
         grid, r_lo, r_hi, _ints, _nv = backend._padded_windows(t, b)
-        if backend.mz_chunk:
-            imgs = extract_images(backend._mz_q, backend._ints,
-                                  jax.device_put(grid), r_lo, r_hi)
-        else:
-            imgs = extract_images_flat(
-                backend._px_s, backend._in_f32(),
-                flat_bound_ranks(backend._mz_host, grid), r_lo, r_hi,
-                n_pixels=backend._n_pix_b)
+        imgs = extract_images_flat(
+            backend._px_s, backend._in_f32(),
+            flat_bound_ranks(backend._mz_host, grid), r_lo, r_hi,
+            n_pixels=backend._n_pix_b)
         imgs = np.array(imgs).reshape(b, k, -1)[
             : t.n_ions, :, : backend.ds.n_pixels]
         imgs /= np.float32(backend.int_scale)
@@ -714,16 +801,19 @@ def test_export_row_bucket_bit_identical(export_backend, tmp_path, n, rows,
     got, attrs = _traced_export(backend, sub, tmp_path)
     assert got.dtype == np.float32
     assert got.shape == (n, table.max_peaks, backend.ds.n_pixels)
-    for want in (extract_ion_images(backend.ds, sub, ppm=3.0),
-                 _full_batch_export(backend, sub)):
+    wants = [_full_batch_export(backend, sub)]
+    if backend._cube_dtype == "f32":
+        # bf16 residents are a coarser grid than the oracle's: there the
+        # bucketed export is held to the full-batch one on the same backend
+        wants.append(extract_ion_images(backend.ds, sub, ppm=3.0))
+    for want in wants:
         np.testing.assert_array_equal(
             got.view(np.uint32), want.view(np.uint32))
     assert got[0, 2:].max() == 0.0                      # n_valid = 2 of k
-    # pixels fetched per window: the row-bucketed grid, or the cube's rows
-    per_px = (backend._ints.shape[0] if backend.mz_chunk
-              else backend._n_pix_b)
+    # pixels fetched per window: the row-bucketed grid
     assert attrs == {"rows": rows, "calls": calls,
-                     "fetched_bytes": rows * table.max_peaks * per_px * 4}
+                     "fetched_bytes":
+                         rows * table.max_peaks * backend._n_pix_b * 4}
 
 
 def _export_traces():

@@ -170,10 +170,9 @@ def test_dryrun_multichip_driver_path():
     dryrun_multichip(4)
 
 
-def test_sharded_hbm_guard_and_mz_chunk_rejection(fixture_ds):
+def test_sharded_hbm_guard(fixture_ds):
     """The mesh path must fail EARLY with guidance (not OOM opaquely) when
-    the per-shard histogram scratch would blow HBM, and must refuse the
-    single-device-only mz_chunk knob instead of silently ignoring it."""
+    the per-shard histogram scratch would blow HBM."""
     from sm_distributed_tpu.parallel.mesh import make_mesh
     from sm_distributed_tpu.parallel.sharded import ShardedJaxBackend
 
@@ -191,14 +190,6 @@ def test_sharded_hbm_guard_and_mz_chunk_rejection(fixture_ds):
     with pytest.raises(ValueError, match="per-shard histogram scratch"):
         ShardedJaxBackend(ds, ds_config, sm_big,
                           mesh=make_mesh(sm_big.parallel))
-
-    sm_chunk = SMConfig.from_dict(
-        {"backend": "jax_tpu",
-         "parallel": {"formula_batch": 16, "pixels_axis": 4,
-                      "formulas_axis": 2, "mz_chunk": 64}})
-    with pytest.raises(ValueError, match="mz_chunk"):
-        ShardedJaxBackend(ds, ds_config, sm_chunk,
-                          mesh=make_mesh(sm_chunk.parallel))
 
 
 @pytest.mark.parametrize("pix,form", [(4, 2), (2, 4)])
