@@ -12,9 +12,10 @@ running service) into the standard perf artifact for this repo:
   time moved (scheduler? token contention? device?);
 - the **slowest batches** — the top score_batch spans with backend/ion
   counts, the needle for per-batch regressions;
-- the **build and store split** — ``backend_build`` with its four
-  ``build_*`` children under ``score``, the four ``store_*`` children under
-  ``store_results``;
+- the **build and store split** — ``prepare_resident`` (the dataset-only
+  half of the build, made before the lease) with its two ``prepare_*``
+  children, ``backend_build`` with its four ``build_*`` children under
+  ``score``, the four ``store_*`` children under ``store_results``;
 - the **device split**, when a ``/debug/profile`` capture overlapped the
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that
@@ -45,12 +46,17 @@ sys.path.insert(0, str(REPO_ROOT))
 from sm_distributed_tpu.utils import tracing  # noqa: E402
 
 # phases in pipeline order (anything else traced as a phase appends after)
-_PHASE_ORDER = ("stage_input", "read_dataset", "decoy_selection",
-                "isotope_patterns", "score", "fdr", "store_results")
+_PHASE_ORDER = ("stage_input", "read_dataset", "prepare_resident",
+                "decoy_selection", "isotope_patterns", "score", "fdr",
+                "store_results")
+# listed among the phases though phase_timer does not emit it: the job's
+# last host-only step before it asks for the chip (engine/search_job.py)
+_STEPS = ("prepare_resident",)
 _TOP_BATCHES = 10
 # the spans that split the two phases a job spends most of its lease in
 # (models/msm_basic.py + models/msm_jax.py, engine/search_job.py)
 _CHILDREN = {
+    "prepare_resident": ("prepare_quantize", "prepare_sort"),
     "score": ("backend_build", "build_sort", "build_restrict",
               "build_pad_compact", "build_device_put"),
     "store_results": ("store_select", "store_extract_images",
@@ -90,7 +96,8 @@ def summarize(records: list[dict]) -> dict:
         if not r.get("parent_id"))
     phases: dict[str, dict] = {}
     for r in _spans(records):
-        if not (r.get("attrs") or {}).get("phase"):
+        if not (r.get("attrs") or {}).get("phase") \
+                and r["name"] not in _STEPS:
             continue
         p = phases.setdefault(r["name"], {"count": 0, "seconds": 0.0})
         p["count"] += 1

@@ -3,11 +3,17 @@
 Reference: the daemon keeps ONE long-lived SparkContext across queue
 messages, so repeat jobs skip cluster spin-up [U] (SURVEY.md #16).  The
 TPU-native analog of that warm state is (a) the host-side CSR dataset
-layout (minutes of parse for a large slide) and (b) the backend object —
-device-resident flat peak arrays plus the compiled fused executable
-(~15-20 s compile + hundreds of MB of HBM transfer).  This cache keeps the
-last N of each across daemon messages with LRU eviction, so a second job on
-the same dataset/shapes skips prepare AND compile (ROADMAP item 3,
+layout (the parse: ~1 s for a 64x64 section, minutes for a large slide)
+with, cached on it, the dataset-only half of a backend build - intensity
+grid, m/z quantization and the stable m/z sort of every peak
+(``SpectralDataset.flat_sorted``, 12 B a peak; a job makes it BEFORE it asks
+for the chip) - and (b) the backend object: the device-resident flat peak
+arrays, whose build under the lease is what is left once (a) is there
+(window restriction against the job's ion table, lattice padding, the
+``device_put``), plus the jitted programs, which a new process loads from
+the persistent compile cache or compiles.  This cache keeps the last N of
+each across daemon messages with LRU eviction, so a second job on the same
+dataset/shapes skips parse, prepare, build AND compile (ROADMAP item 3,
 VERDICT r2 item 7).
 
 Keys carry content identity, not just names: datasets key on the staged

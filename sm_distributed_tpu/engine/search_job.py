@@ -147,6 +147,7 @@ class SearchJob:
                     "dataset %s: %dx%d px, %d spectra, %d peaks",
                     self.ds_id, ds.nrows, ds.ncols, ds.n_spectra, ds.n_peaks,
                 )
+                self._prepare_resident(ds)
                 if self.profile_dir:
                     from ..analysis.profiling import ProfileSession
 
@@ -157,13 +158,16 @@ class SearchJob:
                     tracing.event("jax_profile", dir=str(self.profile_dir))
             import contextlib
 
-            # everything up to here is CPU-bound (staging, parse, formula
-            # lookup) and overlaps freely across scheduler workers; from the
-            # backend build through result storage the device is involved,
-            # so concurrent service jobs serialize on the TPU token.  The
-            # acquisition stays cancellable: a cancelled job must not sit in
-            # the device queue, and the ``with`` exit releases the token on
-            # the cooperative JobCancelledError unwind.
+            # everything up to here needs no chip (staging, parse, formula
+            # lookup, and the half of the backend build that depends on the
+            # dataset alone: _prepare_resident) and overlaps freely across
+            # scheduler workers.  What is left of the build needs the ion
+            # table or the chip (window restriction, padding, device_put),
+            # and from there through result storage the job holds its
+            # lease, so concurrent service jobs serialize on the TPU token.
+            # The acquisition stays cancellable: a cancelled job must not
+            # sit in the device queue, and the ``with`` exit releases the
+            # token on the cooperative JobCancelledError unwind.
             if self.device_token is None and self.cancel is None:
                 token = contextlib.nullcontext()
             else:
@@ -308,6 +312,33 @@ class SearchJob:
         if self.cancel is not None:
             self.cancel.check("read_dataset")
         return ds
+
+    def _prepare_resident(self, ds: SpectralDataset) -> None:
+        """The dataset-only half of the jax backend build (intensity grid,
+        m/z quantization, the stable m/z sort: ``ds.flat_sorted``), made
+        here, BEFORE the job asks for the chip, so the lease does not sit
+        idle through it; ``JaxBackend.__init__`` then finds it cached on
+        the dataset.  Only for a job that will build the single-device
+        layout: the jax backend, one chip (or no pool and a 1x1 mesh), and
+        not every chip it could be granted refused by its breaker.  On a
+        resident dataset it is a dict lookup."""
+        if self.sm_config.backend != "jax_tpu":
+            return
+        from ..models.breaker import every_chip_refuses
+        from ..parallel.sharded import builds_single_device
+
+        if not builds_single_device(
+                self.sm_config, getattr(self.device_token, "n", None)):
+            return
+        pool = getattr(self.device_token, "pool", None)
+        if every_chip_refuses(None if pool is None else range(pool.size)):
+            return
+        ppm = self.ds_config.image_generation.ppm
+        with tracing.span("prepare_resident", peaks=int(ds.n_peaks),
+                          cached=ds.flat_sorted_cached(ppm)):
+            ds.flat_sorted(ppm, site="pre_lease")
+        if self.cancel is not None:
+            self.cancel.check("prepare_resident")
 
     def _read_dataset(self) -> SpectralDataset:
         """Parse the staged imzML — or reuse the residency cache's copy,

@@ -521,6 +521,7 @@ class StreamSearchJob(SearchJob):
                      for c in manifest["chunks"].values())
         try:
             ds = self.chunk_log.assemble_dataset(seqs)
+            self._prepare_resident(ds)   # as the batch pass: before the lease
             token = hold_cancellable(self.device_token, self.cancel,
                                      phase="stream_rescore")
             with tracing.span("stream_rescore"), token:

@@ -13,12 +13,46 @@ flat m/z-sorted peak list; the pixel axis is the sharding axis.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from pathlib import Path
+from typing import NamedTuple
 
 import numpy as np
 
+from ..utils import tracing
 from .imzml import ImzMLReader
+
+
+class FlatSortedPeaks(NamedTuple):
+    """The single-device resident layout before restriction and lattice
+    padding: every peak of the dataset in stable ascending order of its
+    quantized m/z, rounded up to 1024 slots (tail: ``MZ_PAD_Q``, the
+    overflow pixel ``n_pixels``, intensity 0).  12 B a slot."""
+
+    mz_q: np.ndarray      # (N,) int32 ascending
+    pixel: np.ndarray     # (N,) int32
+    ints_q: np.ndarray    # (N,) f32 on the integer grid, in that order
+    int_scale: float
+
+
+# Where each lookup of ``SpectralDataset.flat_sorted`` was answered: a miss
+# counts at the caller's site (``pre_lease``: SearchJob, before it asks for
+# the chip; ``under_lease``: JaxBackend.__init__), a hit as ``cached``.
+# Process-wide (scheduler workers share it); the service's metrics
+# collector pulls it as sm_backend_prepare_total{site=}.
+_FLAT_SORTED_EVENTS = {"pre_lease": 0, "under_lease": 0, "cached": 0}
+_FLAT_SORTED_EVENTS_LOCK = threading.Lock()
+
+
+def _count_flat_sorted(site: str) -> None:
+    with _FLAT_SORTED_EVENTS_LOCK:
+        _FLAT_SORTED_EVENTS[site] += 1
+
+
+def flat_sorted_events() -> dict:
+    with _FLAT_SORTED_EVENTS_LOCK:
+        return dict(_FLAT_SORTED_EVENTS)
 
 
 @dataclass
@@ -47,17 +81,66 @@ class SpectralDataset:
         backend, or shard count (the exact-FDR-rank requirement).  Cached
         per ppm.
         """
+        return self._intensity_quantization(ppm)
+
+    def _pixel_of_peak(self) -> np.ndarray:
+        return np.repeat(
+            np.arange(self.n_pixels, dtype=np.int64), self.row_lengths())
+
+    def _intensity_quantization(self, ppm: float, mz_q=None, pixel_of_peak=None):
+        """``mz_q`` / ``pixel_of_peak``: the caller's own
+        ``quantize_mz(mzs_flat)`` / ``_pixel_of_peak()``, so a miss does not
+        make them a second time."""
         from ..ops.quantize import intensity_scale, quantize_intensities
 
-        cache = getattr(self, "_int_q_cache", None)
-        if cache is None:
-            cache = {}
-            object.__setattr__(self, "_int_q_cache", cache)
+        cache = self.__dict__.setdefault("_int_q_cache", {})
         if ppm not in cache:
-            pixel_of_peak = np.repeat(
-                np.arange(self.n_pixels, dtype=np.int64), self.row_lengths())
-            scale = intensity_scale(self.mzs_flat, self.ints_flat, pixel_of_peak, ppm)
+            if pixel_of_peak is None:
+                pixel_of_peak = self._pixel_of_peak()
+            scale = intensity_scale(self.mzs_flat, self.ints_flat,
+                                    pixel_of_peak, ppm, mz_q=mz_q)
             cache[ppm] = (quantize_intensities(self.ints_flat, scale), scale)
+        return cache[ppm]
+
+    # -- the dataset-only half of the jax backend build ------------------
+
+    def flat_sorted_cached(self, ppm: float) -> bool:
+        return ppm in self.__dict__.get("_flat_sorted_cache", ())
+
+    def flat_sorted(self, ppm: float, site: str = "under_lease") -> FlatSortedPeaks:
+        """The single-device flat layout for ``ppm``: the 1-shard case of
+        ``ops/imager_jax.prepare_flat_sharded_arrays`` plus the intensity
+        scale.  A function of the dataset and ``ppm`` alone, so a job
+        computes it BEFORE it asks for the chip (``site="pre_lease"``,
+        engine/search_job.py) and ``JaxBackend.__init__`` finds it here.
+        Cached per ppm for the dataset's residency, like the intensity
+        grid; a miss computes it in place, whoever asks."""
+        from ..ops.quantize import MZ_PAD_Q, quantize_mz
+
+        cache = self.__dict__.setdefault("_flat_sorted_cache", {})
+        hit = cache.get(ppm)
+        if hit is not None:
+            _count_flat_sorted("cached")
+            return hit
+        _count_flat_sorted(site)
+        with tracing.span("prepare_quantize"):
+            mz_q = quantize_mz(self.mzs_flat)
+            pixel = self._pixel_of_peak()
+            ints_q, scale = self._intensity_quantization(ppm, mz_q, pixel)
+        with tracing.span("prepare_sort"):
+            # the 1-shard case of prepare_flat_sharded_arrays, byte for byte
+            order = np.argsort(mz_q, kind="stable")
+            n = int(mz_q.size)
+            n_max = -(-max(n, 1) // 1024) * 1024
+            mz_s = np.full(n_max, MZ_PAD_Q, dtype=np.int32)
+            px_s = np.full(n_max, self.n_pixels, dtype=np.int32)
+            in_s = np.zeros(n_max, dtype=np.float32)
+            mz_s[:n] = mz_q[order]
+            px_s[:n] = pixel.astype(np.int32)[order]
+            in_s[:n] = ints_q[order]
+            for a in (mz_s, px_s, in_s):
+                a.setflags(write=False)     # shared by every backend built on it
+        cache[ppm] = FlatSortedPeaks(mz_s, px_s, in_s, scale)
         return cache[ppm]
 
     @property

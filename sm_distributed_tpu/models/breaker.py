@@ -105,6 +105,14 @@ class CircuitBreaker:
                 return True
             return False
 
+    def refuses_device(self) -> bool:
+        """Would ``allow_device()`` answer False right now?  Reads the
+        state and admits no probe: for callers deciding BEFORE the lease
+        whether device-only preparation is worth doing."""
+        with self._lock:
+            return self._state == STATE_OPEN and \
+                time.monotonic() - self._opened_at < self.cooldown_s
+
     def record_success(self) -> None:
         """A device scoring group completed cleanly."""
         with self._lock:
@@ -209,6 +217,17 @@ def breaker_for(label) -> CircuitBreaker | None:
     None if this process never touched it — test/harness introspection."""
     with _lock:
         return _breakers.get(str(label))
+
+
+def every_chip_refuses(chips=None) -> bool:
+    """True when a job would be degraded to numpy whichever ONE of
+    ``chips`` its lease grants (``None``: the un-leased ``"*"`` scope).
+    A chip this process never touched has no breaker, so it does not
+    refuse; nothing is created and no half-open probe is admitted."""
+    labels = [GLOBAL_LABEL] if chips is None else [str(int(c)) for c in chips]
+    with _lock:
+        picked = [_breakers.get(lb) for lb in labels]
+    return all(b is not None and b.refuses_device() for b in picked)
 
 
 def breakers_snapshot() -> dict:

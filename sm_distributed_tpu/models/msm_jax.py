@@ -35,7 +35,6 @@ from ..ops.imager_jax import (
     flat_bound_ranks,
     ion_window_chunks,
     ions_per_chunk_for,
-    prepare_flat_sorted_arrays,
     window_rank_grid,
 )
 from ..ops.isocalc import IsotopePatternTable
@@ -616,7 +615,6 @@ class JaxBackend:
         # legacy unpadded program), a host scalar shipped per batch when on
         self._n_real = np.int32(ds.n_pixels) if self._buckets else None
 
-        self.int_scale = ds.intensity_quantization(self.ppm)[1]
         common = dict(
             nrows=self._nrows_b,
             ncols=ds.ncols,
@@ -643,9 +641,13 @@ class JaxBackend:
                 f" x {k_est} peaks); reduce parallel.formula_batch, or shard"
                 " pixels over a mesh (parallel.pixels_axis)")
         # the four build_* spans split the backend_build span of
-        # models/msm_basic.py (PERF.md section 3, backend_build_s)
+        # models/msm_basic.py (PERF.md section 3, backend_build_s).
+        # build_sort is a lookup when the job prepared the layout before it
+        # asked for the chip (engine/search_job.py, span prepare_resident);
+        # on a miss the quantization and the sort run here, under the lease
+        tracing.annotate(prepared=ds.flat_sorted_cached(self.ppm))
         with tracing.span("build_sort"):
-            mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, self.ppm)
+            mz_s, px_s, in_s, self.int_scale = ds.flat_sorted(self.ppm)
         if restrict_table is not None:
             # drop peaks outside EVERY window of the search up front —
             # the reference's "only hits shuffle" property [U]: on noisy

@@ -71,23 +71,6 @@ def window_rank_grid(
 # -- flat globally-sorted layout ----------------------------------------------
 
 
-def prepare_flat_sorted_arrays(
-    ds: SpectralDataset,
-    ppm: float,
-    pad_to_multiple: int = 1024,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host-side: globally m/z-sorted flat peak arrays
-    (mz_q (N,) int32 ascending, pixel (N,) int32, int (N,) f32 integer grid).
-
-    Padding: m/z saturates to the MZ_PAD_Q sentinel, pixel points at an
-    overflow row (``ds.n_pixels``, sliced off before the matmul), intensity 0.
-    The single-device layout IS the 1-shard case of the sharded builder.
-    """
-    mz_s, px_s, in_s, _p_loc = prepare_flat_sharded_arrays(
-        ds, ppm, n_shards=1, pad_to_multiple=pad_to_multiple)
-    return mz_s[0], px_s[0], in_s[0]
-
-
 def flat_bound_ranks(mz_sorted_host: np.ndarray, grid: np.ndarray) -> np.ndarray:
     """Host-side per-batch: rank of each grid bound among the sorted peaks,
     ``pos[g] = #{peaks with mz < grid[g]}``.  G binary searches into the

@@ -95,6 +95,7 @@ def intensity_scale(
     ints_flat: np.ndarray,     # (P,) intensities
     pixel_of_peak: np.ndarray,  # (P,) pixel index per peak (non-decreasing)
     ppm: float,
+    mz_q: np.ndarray | None = None,  # quantize_mz(mzs_flat), when the caller has it
 ) -> float:
     """Power-of-two scale 2**k such that hmax * max(rint(i*2**k)) < 2**24,
     where hmax bounds the peak count inside any ppm window of any pixel."""
@@ -106,8 +107,9 @@ def intensity_scale(
     # exact per-pixel sliding-window occupancy on the quantized m/z grid:
     # key = pixel * 2**32 + mz_q is globally ascending; a window never spans
     # the 2**32 inter-pixel gap
-    mz_q = quantize_mz(mzs_flat).astype(np.int64)
-    key = pixel_of_peak.astype(np.int64) * (1 << 32) + mz_q
+    if mz_q is None:
+        mz_q = quantize_mz(mzs_flat)
+    key = pixel_of_peak.astype(np.int64) * (1 << 32) + mz_q.astype(np.int64)
     # generous window bound (2.5x ppm covers any window whose left edge is
     # at this peak, including the center-to-edge asymmetry)
     width = np.ceil(np.asarray(mzs_flat, np.float64)

@@ -69,7 +69,7 @@ from ..utils import tracing
 from ..ops.quantize import compact_cube, expand_cube_jnp, quantize_window
 from ..utils.config import DSConfig, SMConfig
 from ..utils.logger import logger
-from .mesh import FORMULAS_AXIS, PIXELS_AXIS, make_mesh
+from .mesh import FORMULAS_AXIS, PIXELS_AXIS, make_mesh, resolve_axis_sizes
 
 # Declared compile surface (ISSUE 12, analysis/surface.py): the sharded
 # step's statics ride in through make()'s partial closure, so the whole
@@ -758,6 +758,25 @@ class ShardedJaxBackend:
                 to_numpy_global(self._dispatch(t, plan)[0])
 
 
+def builds_single_device(sm_config: SMConfig, n_devices: int | None) -> bool:
+    """Does a job over ``n_devices`` chips (a lease's; ``None``: no pool,
+    every device of the runtime) score on the single-device ``JaxBackend``?
+    One chip does; so do several under a config mesh that resolves to 1x1.
+    ``make_jax_backend``'s rule, also asked BEFORE the lease exists by the
+    job that prepares the single-device layout ahead of it
+    (engine/search_job.py)."""
+    if n_devices is None:
+        from .distributed import maybe_initialize_distributed
+
+        # before the first jax.devices(): it latches the runtime
+        maybe_initialize_distributed(sm_config.parallel)  # no-op single-process
+        n_devices = len(jax.devices())
+    if n_devices == 1:
+        return True
+    pix, form = resolve_axis_sizes(n_devices, sm_config.parallel)
+    return pix * form == 1
+
+
 def make_jax_backend(ds: SpectralDataset, ds_config: DSConfig,
                      sm_config: SMConfig, restrict_table=None,
                      device_indices=None):
@@ -795,17 +814,12 @@ def make_jax_backend(ds: SpectralDataset, ds_config: DSConfig,
         pool_size = resolve_pool_size(sm_config.service)
         hosts = max(1, len(host_topology(
             device_indices, split_host_ranges(pool_size, pool_hosts))))
-    if devices is not None and len(devices) == 1:
-        from ..models.msm_jax import JaxBackend
-
-        return JaxBackend(ds, ds_config, sm_config,
-                          restrict_table=restrict_table, device=devices[0])
-    mesh = make_mesh(sm_config.parallel, devices=devices, hosts=hosts)
-    if mesh.size == 1:
+    if builds_single_device(sm_config, len(devices) if devices else None):
         from ..models.msm_jax import JaxBackend
 
         return JaxBackend(ds, ds_config, sm_config,
                           restrict_table=restrict_table,
                           device=devices[0] if devices else None)
+    mesh = make_mesh(sm_config.parallel, devices=devices, hosts=hosts)
     return ShardedJaxBackend(ds, ds_config, sm_config, mesh=mesh,
                              restrict_table=restrict_table)

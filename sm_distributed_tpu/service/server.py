@@ -206,6 +206,7 @@ class AnnotationService:
         process_collector(self.metrics)
         if residency is not None:
             self.metrics.add_collector(self._collect_residency)
+        self.metrics.add_collector(self._collect_prepare)
         self.api = AdminAPI(self, host=cfg.http_host,
                             port=cfg.http_port) if with_api else None
         # fleet observability plane (ISSUE 20, service/fleetview.py):
@@ -252,6 +253,22 @@ class AnnotationService:
             # counters only move forward; set via delta from the live stats
             h.inc(max(0.0, stats[f"{cache}_hits"] - h.value))
             miss.inc(max(0.0, stats[f"{cache}_misses"] - miss.value))
+
+    @staticmethod
+    def _collect_prepare(m: MetricsRegistry) -> None:
+        """Where the dataset-only half of a backend build ran
+        (``SpectralDataset.flat_sorted``): before the job asked for the
+        chip, under its lease, or not at all (``cached``).  Pulled like the
+        residency stats above, of whose family it is."""
+        from ..io.dataset import flat_sorted_events
+
+        prepares = m.counter(
+            "sm_backend_prepare_total",
+            "Lookups of a dataset's resident flat layout, by where a miss "
+            "was computed", ("site",))
+        for site, n in flat_sorted_events().items():
+            c = prepares.labels(site=site)
+            c.inc(max(0.0, n - c.value))
 
     def queue_depths(self) -> dict:
         root = self.queue_dir / self.queue

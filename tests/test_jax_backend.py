@@ -102,8 +102,8 @@ def test_extraction_parity(request, ds_name, restricted):
     keeps (what a served backend holds)."""
     import jax.numpy as jnp
     from sm_distributed_tpu.ops.imager_jax import (
-        extract_images_flat, flat_bound_ranks, prepare_flat_sorted_arrays,
-        restrict_flat_to_windows, window_rank_grid,
+        extract_images_flat, flat_bound_ranks, restrict_flat_to_windows,
+        window_rank_grid,
     )
     from sm_distributed_tpu.ops.imager_np import extract_ion_images
     from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
@@ -119,7 +119,7 @@ def test_extraction_parity(request, ds_name, restricted):
     scale = ds.intensity_quantization(3.0)[1]
     lo, hi = quantize_window(table.mzs, 3.0)
     grid, r_lo, r_hi = window_rank_grid(lo, hi)
-    mz_s, px_s, in_s = prepare_flat_sorted_arrays(ds, 3.0)
+    mz_s, px_s, in_s, _scale = ds.flat_sorted(3.0)
     if restricted:
         n_all = int(np.count_nonzero(in_s))
         mzk, pxk, ink, n_eff = restrict_flat_to_windows(
