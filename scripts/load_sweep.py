@@ -87,6 +87,7 @@ sys.path.insert(0, str(REPO_ROOT))
 
 from scripts.chaos_sweep import _debris  # noqa: E402 — shared invariant
 from sm_distributed_tpu.engine.daemon import annotate_callback  # noqa: E402
+from sm_distributed_tpu.engine.residency import DatasetResidency  # noqa: E402
 from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset  # noqa: E402
 from sm_distributed_tpu.models import breaker as breaker_mod  # noqa: E402
 from sm_distributed_tpu.service import AnnotationService  # noqa: E402
@@ -153,9 +154,15 @@ class Harness:
         if sm_overrides:
             sm = _merge(sm, sm_overrides)
         self.sm_config = SMConfig.from_dict(sm)
+        # the residency cache handed to the service too, as ``cli serve``
+        # does: /metrics then has sm_residency_{hits,misses}_total
+        n = self.sm_config.parallel.resident_datasets
+        residency = (DatasetResidency(max_datasets=n, max_backends=n)
+                     if n > 0 else None)
         self.service = AnnotationService(
-            self.queue_dir, annotate_callback(self.sm_config),
-            sm_config=self.sm_config)
+            self.queue_dir,
+            annotate_callback(self.sm_config, residency=residency),
+            sm_config=self.sm_config, residency=residency)
         self.service.start()
         host, port = self.service.api.address
         self.base = f"http://{host}:{port}"

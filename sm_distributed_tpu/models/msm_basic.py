@@ -899,7 +899,8 @@ class MSMBasicSearch:
         else:
             # the span behind backend_build_s (PERF.md section 3); the jax
             # backend splits it into build_sort / build_restrict /
-            # build_pad_compact / build_device_put
+            # build_pad_compact / build_device_put, and says through
+            # build_attrs which kernel geometry it runs (hit or miss)
             with tracing.span("backend_build", cache_hit=True):
                 if self.backend_cache is not None:
                     par = self.sm_config.parallel
@@ -917,7 +918,8 @@ class MSMBasicSearch:
                 tracing.annotate(
                     peaks_in=int(self.ds.n_peaks),
                     peaks_resident=getattr(backend, "resident_peaks", None),
-                    resident_bytes=getattr(backend, "resident_bytes", None))
+                    resident_bytes=getattr(backend, "resident_bytes", None),
+                    **getattr(backend, "build_attrs", {}))
         self.last_backend = backend
         batch = self._batch_eff
         if batch < max(1, self.sm_config.parallel.formula_batch) and \

@@ -12,6 +12,7 @@ The graph compiles ONCE per dataset: formula batches are padded to the static
 
 from __future__ import annotations
 
+import threading
 from functools import partial, update_wrapper
 
 import jax
@@ -41,6 +42,7 @@ from ..ops.isocalc import IsotopePatternTable
 from ..ops.metrics_jax import (
     batch_metrics,
     batch_metrics_from_partials,
+    chaos_dispatch,
     correlation_from_moments,
     isotope_pattern_match_batch,
     measure_of_chaos_batch,
@@ -569,6 +571,25 @@ def pallas_interpret_events() -> int:
     return _PALLAS_INTERPRET_EVENTS["traced"]
 
 
+# Ion images sent through each chaos geometry, counted on the host where a
+# batch is enqueued: {(route, images_per_program): ions}.  Scheduler workers
+# share it, hence the lock; the service pulls it at scrape as
+# sm_chaos_images_total{route=, images_per_program=}.
+_CHAOS_IMAGES: dict[tuple[str, int], int] = {}
+_CHAOS_IMAGES_LOCK = threading.Lock()
+
+
+def _count_chaos_images(geometry, n_ions: int) -> None:
+    key = (geometry.route, geometry.images_per_program)
+    with _CHAOS_IMAGES_LOCK:
+        _CHAOS_IMAGES[key] = _CHAOS_IMAGES.get(key, 0) + n_ions
+
+
+def chaos_image_events() -> dict:
+    with _CHAOS_IMAGES_LOCK:
+        return dict(_CHAOS_IMAGES)
+
+
 class JaxBackend:
     """Fused-graph scorer selected by ``SMConfig.backend == 'jax_tpu'``."""
 
@@ -634,6 +655,19 @@ class JaxBackend:
         # rows are the BUCKETED pixel count — that is what allocates
         scratch = 4 * (self._n_pix_b + 1) * max(
             2 * self.batch * k_est + 1, 4098)
+        # what the backend_build span says of this backend's kernels
+        # (models/msm_basic.py); the geometry is the one the chaos dispatch
+        # of every batch resolves again inside the jitted scorer
+        geo = self.chaos_geometry = chaos_dispatch(self._nrows_b, ds.ncols)
+        self.build_attrs = {
+            "pixels": int(ds.n_pixels),
+            "rows_bucket": int(self._nrows_b),
+            "chaos_route": geo.route,
+            "chaos_block": [geo.rows_pad, geo.cols_pad,
+                            geo.images_per_program],
+            "chaos_lane_fill_pct": geo.fill_pct,
+            "hist_scratch_bytes": int(scratch),
+        }
         if scratch > (8 << 30):
             raise ValueError(
                 f"flat-path histogram scratch would be ~{scratch / 2**30:.0f}"
@@ -992,6 +1026,7 @@ class JaxBackend:
                       b=int(statics["b"]))
         fn = getattr(self, _VARIANTS[variant][0])
         out = fn(self._px_s, self._in_s, *args, **statics)
+        _count_chaos_images(self.chaos_geometry, table.n_ions)
         return out, table.n_ions
 
     def probe_phases(self, table: IsotopePatternTable):

@@ -34,6 +34,7 @@ counts [U] (SURVEY.md #11); oracle: ops/metrics_np.py::measure_of_chaos.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -241,10 +242,22 @@ def _pack_geometry(nrows: int, ncols: int, lane_width: int,
     return rp, cp, ib
 
 
+def _packed_block(nrows: int, ncols: int,
+                  lane_width: int) -> tuple[int, int, int, bool]:
+    """(R_pad, C_pad, IB, lean): the block ``chaos_count_sums`` runs.  The
+    hoisted-flag kernel where its budget holds the block, else the block
+    re-packed against the lean kernel's larger one (wide images)."""
+    rp, cp, ib = _pack_geometry(nrows, ncols, lane_width)
+    lean = rp * cp * ib > _MAX_CELLS
+    if lean:
+        rp, cp, ib = _pack_geometry(nrows, ncols, lane_width, _MAX_CELLS_LEAN)
+    return rp, cp, ib, lean
+
+
 def fits_vmem(nrows: int, ncols: int, lane_width: int = 512) -> bool:
     """True when one program's block fits SOME kernel variant's budget
     (packed fast kernel, or the lean wide-image kernel)."""
-    rp, cp, ib = _pack_geometry(nrows, ncols, lane_width, _MAX_CELLS_LEAN)
+    rp, cp, ib, _lean = _packed_block(nrows, ncols, lane_width)
     return rp * cp * ib <= _MAX_CELLS_LEAN
 
 
@@ -269,11 +282,7 @@ def chaos_count_sums(
     sums are small integers, f32-representable).
     """
     n = principal.shape[0]
-    rp, cp, ib = _pack_geometry(nrows, ncols, lane_width)
-    lean = rp * cp * ib > _MAX_CELLS
-    if lean:
-        # wide image: re-pack against the lean kernel's larger budget
-        rp, cp, ib = _pack_geometry(nrows, ncols, lane_width, _MAX_CELLS_LEAN)
+    rp, cp, ib, lean = _packed_block(nrows, ncols, lane_width)
     if rp * cp * ib > _MAX_CELLS_LEAN and not interpret:
         raise ValueError(
             f"chaos kernel block ({rp}x{cp * ib} cells) exceeds the scoped-"
@@ -486,16 +495,42 @@ def _strip_geometry(nrows: int, ncols: int,
     return rp, cp, strip
 
 
-def chaos_route(nrows: int, ncols: int, lane_width: int = 512) -> str:
-    """'packed' (whole image(s) in VMEM), 'strips' (HBM-resident labels,
-    strips through VMEM), or 'scan' (associative-scan fallback)."""
-    if fits_vmem(nrows, ncols, lane_width):
-        return "packed"
-    try:
-        _strip_geometry(nrows, ncols)
-        return "strips"
-    except ValueError:
-        return "scan"
+class ChaosGeometry(NamedTuple):
+    """Which chaos route an image shape takes and the VMEM block one
+    program of it holds (what the span ``backend_build`` reports)."""
+
+    route: str                # 'packed' | 'strips' | 'scan'
+    rows_pad: int             # block rows (a strip with its halos on 'strips')
+    cols_pad: int             # lanes ONE image takes in the block
+    images_per_program: int   # 0 on 'scan': no Pallas program, whole batch
+    lean: bool                # the flag-rematerializing packed variant
+    fill_pct: float           # real image cells over the padded cells the
+                              # kernel sweeps (pad rows and columns are
+                              # swept like real ones)
+
+
+def chaos_geometry(nrows: int, ncols: int, lane_width: int = 512, *,
+                   pallas: bool = True) -> ChaosGeometry:
+    """The route and block for ``(nrows, ncols)`` images: 'packed' (whole
+    image(s) in VMEM), 'strips' (HBM-resident labels, strips through VMEM),
+    or 'scan' (associative-scan fallback; always with ``pallas=False``, a
+    platform without Mosaic).  ``measure_of_chaos_batch`` routes by it and
+    ``chaos_count_sums`` packs by the same ``_packed_block``, so what a
+    trace says of a backend is what its kernels run."""
+    def fill(rows: int, cols: int) -> float:
+        return round(100.0 * nrows * ncols / (rows * cols), 1)
+
+    if pallas:
+        rp, cp, ib, lean = _packed_block(nrows, ncols, lane_width)
+        if rp * cp * ib <= _MAX_CELLS_LEAN:
+            return ChaosGeometry("packed", rp, cp, ib, lean, fill(rp, cp))
+        try:
+            rp, cp, strip = _strip_geometry(nrows, ncols)
+            return ChaosGeometry("strips", strip + 2 * _HALO, cp, 1, False,
+                                 fill(rp, cp))
+        except ValueError:
+            pass
+    return ChaosGeometry("scan", nrows, ncols, 0, False, 100.0)
 
 
 @functools.partial(jax.jit, static_argnames=(

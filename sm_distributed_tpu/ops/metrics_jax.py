@@ -128,6 +128,22 @@ def refine_quotient(q: jnp.ndarray, a: jnp.ndarray,
     return q + r / b
 
 
+def chaos_dispatch(nrows: int, ncols: int, use_pallas: bool | None = None):
+    """The ``ChaosGeometry`` (ops/chaos_pallas.py) this process runs for
+    ``(nrows, ncols)`` images: ``measure_of_chaos_batch`` routes by it (its
+    docstring has the three routes and ``use_pallas``), and a backend
+    reports it on its ``backend_build`` span."""
+    from .chaos_pallas import chaos_geometry
+
+    pallas = (jax.default_backend() == "tpu" if use_pallas is None
+              else use_pallas)
+    geometry = chaos_geometry(nrows, ncols, pallas=pallas)
+    if use_pallas and geometry.route == "scan":
+        raise ValueError(
+            f"no pallas chaos route fits {nrows}x{ncols} images")
+    return geometry
+
+
 def measure_of_chaos_batch(
     principal: jnp.ndarray,   # (N, n_pix) f32, n_pix == nrows*ncols
     nrows: int,
@@ -149,24 +165,7 @@ def measure_of_chaos_batch(
     ``use_pallas=True`` forces a pallas route and raises ValueError when
     no pallas route fits the shape; ``False`` forces the scan path.
     """
-    from .chaos_pallas import chaos_route
-
-    if use_pallas is None:
-        # 'packed': whole image(s) resident in one VMEM block (fast path);
-        # 'strips': beyond the lean whole-image budget (>~288k cells, e.g.
-        # 1024x1024 whole-slide DESI) — HBM-resident labels, row strips
-        # through VMEM; 'scan': associative-scan fallback (CPU meshes,
-        # interpreters, absurd widths).  All three are exact, so the
-        # dispatch cannot change results.
-        route = (chaos_route(nrows, ncols)
-                 if jax.default_backend() == "tpu" else "scan")
-    elif use_pallas:
-        route = chaos_route(nrows, ncols)
-        if route == "scan":
-            raise ValueError(
-                f"no pallas chaos route fits {nrows}x{ncols} images")
-    else:
-        route = "scan"
+    route = chaos_dispatch(nrows, ncols, use_pallas).route
     principal = jnp.maximum(principal, 0.0)
     if vmax is None:
         # smlint: masked-ok[lattice pad pixels are exact zeros, below every positive max — vmax is the real-pixel maximum]

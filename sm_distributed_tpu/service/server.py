@@ -12,6 +12,7 @@ pending backlog.
 from __future__ import annotations
 
 import signal
+import sys
 import threading
 import time
 from pathlib import Path
@@ -207,6 +208,7 @@ class AnnotationService:
         if residency is not None:
             self.metrics.add_collector(self._collect_residency)
         self.metrics.add_collector(self._collect_prepare)
+        self.metrics.add_collector(self._collect_chaos_images)
         self.api = AdminAPI(self, host=cfg.http_host,
                             port=cfg.http_port) if with_api else None
         # fleet observability plane (ISSUE 20, service/fleetview.py):
@@ -268,6 +270,21 @@ class AnnotationService:
             "was computed", ("site",))
         for site, n in flat_sorted_events().items():
             c = prepares.labels(site=site)
+            c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_chaos_images(m: MetricsRegistry) -> None:
+        """Ion images sent through each chaos kernel geometry
+        (``models/msm_jax.py::chaos_image_events``, counted where a batch is
+        enqueued).  Pulled like the prepare sites above, and only if the jax
+        backend was ever imported: a numpy-only service never pays for it."""
+        images = m.counter(
+            "sm_chaos_images_total",
+            "Ion images sent through each measure-of-chaos route and "
+            "block geometry", ("route", "images_per_program"))
+        mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
+        for (route, ib), n in (mod.chaos_image_events() if mod else {}).items():
+            c = images.labels(route=route, images_per_program=str(ib))
             c.inc(max(0.0, n - c.value))
 
     def queue_depths(self) -> dict:

@@ -170,18 +170,93 @@ def test_chaos_route_geometry():
     """Dispatch: packed for in-budget images, strips past the lean budget,
     scan only when even strips can't fit (absurd widths)."""
     from sm_distributed_tpu.ops.chaos_pallas import (
-        _HALO, _MAX_CELLS_STRIP, _strip_geometry, chaos_route,
+        _HALO, _MAX_CELLS_STRIP, _strip_geometry, chaos_geometry,
     )
+
+    def chaos_route(nrows, ncols):
+        return chaos_geometry(nrows, ncols).route
 
     assert chaos_route(64, 64) == "packed"
     assert chaos_route(512, 512) == "packed"      # lean kernel
     assert chaos_route(1024, 1024) == "strips"    # whole-slide DESI
     assert chaos_route(2048, 2048) == "strips"
     assert chaos_route(8, 1024 * 1024) == "scan"  # 1M-col monster
+    # a platform without Mosaic scans whatever the shape
+    assert chaos_geometry(64, 64, pallas=False) == (
+        "scan", 64, 64, 0, False, 100.0)
 
     rp, cp, strip = _strip_geometry(1024, 1024)
     assert rp >= 1024 and rp % strip == 0 and cp == 1024 and strip % 8 == 0
     assert (strip + 2 * _HALO) * cp <= _MAX_CELLS_STRIP
+
+
+def test_slide256_block_is_one_padded_image_and_matches_scipy(rng):
+    """256x256 (the whole-slide cell, PERF.md section 4): at 256 rows the
+    lane budget is 98304 // 256 = 384, so 256 columns pad to 384 and ONE
+    image fills a program's block — exactly ``_MAX_CELLS``, one cell short
+    of the lean variant, a third of it padding.  The padding columns sit
+    INSIDE the image's own lane span here (cp > ncols with ib == 1), so the
+    boundary guards and the per-lane count reduction see a geometry no
+    smaller case has; two images make the grid step across programs."""
+    from sm_distributed_tpu.ops.chaos_pallas import (
+        _MAX_CELLS, _pack_geometry, chaos_geometry,
+    )
+
+    assert _pack_geometry(256, 256, 512) == (256, 384, 1)
+    assert 256 * 384 * 1 == _MAX_CELLS
+    geo = chaos_geometry(256, 256)
+    assert geo == ("packed", 256, 384, 1, False, 66.7)
+
+    r = c = 256
+    img = np.where(rng.random((2, r * c)) < 0.4,
+                   rng.random((2, r * c)), 0).astype(np.float32)
+    got = np.asarray(chaos_count_sums(img, nrows=r, ncols=c, nlevels=3,
+                                      interpret=True))
+    for i in range(2):
+        assert got[i] == _oracle_count_sum(img[i].reshape(r, c), 3)
+
+
+def _pallas_kernels(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["jaxpr"]
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_kernels(inner)
+
+
+@pytest.mark.parametrize("side,want", [
+    (64, ("packed", 64, 64, 8, False, 100.0)),
+    (128, ("packed", 128, 128, 4, False, 100.0)),
+    (256, ("packed", 256, 384, 1, False, 66.7)),
+    (512, ("packed", 512, 512, 1, True, 100.0)),
+    (1024, ("strips", 192, 1024, 1, False, 97.0)),
+])
+def test_chaos_geometry_is_what_the_kernels_use(side, want):
+    """``chaos_geometry`` (what a backend's ``backend_build`` span reports)
+    against the kernel itself: the first VMEM block of the traced
+    ``pallas_call`` of the route it names is the block it names."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from sm_distributed_tpu.ops import chaos_pallas as cp
+
+    geo = cp.chaos_geometry(side, side)
+    assert geo == want
+    fn = {"packed": cp.chaos_count_sums,
+          "strips": cp.chaos_count_sums_strips}[geo.route]
+    traced = jax.make_jaxpr(functools.partial(
+        fn, nrows=side, ncols=side, nlevels=3, interpret=True))(
+        jax.ShapeDtypeStruct((2, side * side), jnp.float32))
+    kernel, = _pallas_kernels(traced.jaxpr)
+    vmem = [v.aval for v in kernel.invars if "vmem" in str(v.aval)]
+    assert vmem[0].shape == (geo.rows_pad,
+                             geo.cols_pad * geo.images_per_program)
+    assert geo.lean == (cp._packed_block(side, side, 512)[3]
+                        and geo.route == "packed")
 
 
 def test_strip_kernel_full_metric_parity(rng):
