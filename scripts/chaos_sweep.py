@@ -134,8 +134,9 @@ class Scenario:
 SCENARIOS: list[Scenario] = [
     Scenario("io.imzml_parse", "consume", "io.imzml_parse=crash@1",
              "crash mid-parse; restart requeues and re-reads"),
-    Scenario("io.ibd_read", "consume", "io.ibd_read=crash@3",
-             "crash mid-ingest after some spectra"),
+    Scenario("io.ibd_read", "consume", "io.ibd_read=crash@1",
+             "crash at the ingest's first ibd read call (a bulk ingest "
+             "issues a handful); restart requeues and re-reads"),
     Scenario("workdir.fetch", "consume", "workdir.fetch=crash@2",
              "crash between staged files; per-file resume refetches the rest"),
     Scenario("workdir.stage_rename", "consume", "workdir.stage_rename=torn@1",

@@ -12,7 +12,9 @@ running service) into the standard perf artifact for this repo:
   time moved (scheduler? token contention? device?);
 - the **slowest batches** — the top score_batch spans with backend/ion
   counts, the needle for per-batch regressions;
-- the **build and store split** — ``prepare_resident`` (the dataset-only
+- the **build and store split** — ``read_dataset`` with the ingest's two
+  spans (``parse_index``: the imzML index; ``read_ibd``: the bulk read of
+  the ibd), ``prepare_resident`` (the dataset-only
   half of the build, made before the lease) with its two ``prepare_*``
   children, ``backend_build`` with its four ``build_*`` children under
   ``score``, the four ``store_*`` children under ``store_results``;
@@ -56,6 +58,7 @@ _TOP_BATCHES = 10
 # the spans that split the two phases a job spends most of its lease in
 # (models/msm_basic.py + models/msm_jax.py, engine/search_job.py)
 _CHILDREN = {
+    "read_dataset": ("parse_index", "read_ibd"),
     "prepare_resident": ("prepare_quantize", "prepare_sort"),
     "score": ("backend_build", "build_sort", "build_restrict",
               "build_pad_compact", "build_device_put"),

@@ -102,7 +102,7 @@ class JobLedger:
             f"PRAGMA busy_timeout={int(self.BUSY_TIMEOUT_S * 1000)}")
         # WAL lets readers proceed under a writer (index replace vs /jobs
         # queries); falls back gracefully where the filesystem can't do WAL
-        mode = self._conn.execute("PRAGMA journal_mode=WAL").fetchone()[0]
+        mode = self._set_wal()
         if str(mode).lower() != "wal":
             logger.warning(
                 "ledger %s: journal_mode=WAL unavailable (got %r); "
@@ -112,6 +112,22 @@ class JobLedger:
             self._conn.execute("PRAGMA synchronous=NORMAL")
         self._conn.executescript(_SCHEMA)
         self._conn.commit()
+
+    def _set_wal(self) -> str:
+        """``PRAGMA journal_mode=WAL``, waited for.  The switch of a fresh
+        database needs it to itself, and sqlite answers a second connection
+        that arrives meanwhile with "database is locked" at once, whatever
+        the busy timeout says: two jobs that start together on a new results
+        directory, and one of them died of it."""
+        deadline = time.monotonic() + self.BUSY_TIMEOUT_S
+        while True:
+            try:
+                return self._conn.execute(
+                    "PRAGMA journal_mode=WAL").fetchone()[0]
+            except sqlite3.OperationalError as exc:
+                if "locked" not in str(exc) or time.monotonic() > deadline:
+                    raise
+                time.sleep(0.01)
 
     def upsert_dataset(self, ds_id: str, name: str, input_path: str,
                        ds_config: dict) -> None:

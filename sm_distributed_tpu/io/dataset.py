@@ -242,18 +242,23 @@ class SpectralDataset:
 
     @classmethod
     def from_imzml(cls, path: str | Path) -> "SpectralDataset":
-        """STREAMING ingest: peak host memory stays ~(12 bytes x total peaks)
-        plus one spectrum, instead of the eager build's ~4x that.
+        """BULK ingest: peak host memory stays ~(12 bytes x total peaks)
+        plus one bounded read chunk (at most ``io/imzml._IBD_CHUNK_BYTES``
+        or an eighth of the data, whichever is less), with no second copy of
+        the dataset, instead of the eager build's ~4x.
 
         The reference streams spectrum-by-spectrum through its converter and
         reader (``imzml_txt_converter``/``dataset_reader`` [U], SURVEY.md
         #4-5); a >200k-pixel DESI slide (BASELINE #5) can exceed host RAM
         under an eager whole-dataset materialization long before HBM matters.
-        Here: pass 1 reads per-spectrum peak COUNTS from the XML metadata
-        and preallocates the exact CSR arrays; pass 2 streams each
-        spectrum's bytes directly into its CSR slot (no intermediate list,
-        no concat, no full-array lexsort — per-row m/z order is verified
-        and repaired only where violated).  Bit-identical to from_arrays."""
+        Here: the reader's index gives every spectrum's peak COUNT and the
+        place of its arrays in the ibd without touching it; the exact CSR
+        arrays are preallocated from the counts and filled by
+        ``ImzMLReader.read_into`` in a few large reads (no intermediate
+        list, no concat, no full-array lexsort, no read call a spectrum:
+        a thread that gives up the interpreter twice a spectrum queues for
+        it behind every other ingest; per-row m/z order is verified and
+        repaired only where violated).  Bit-identical to from_arrays."""
         with ImzMLReader(path) as rd:
             lens = rd.spectrum_lengths()
             nrows, ncols, pixel_inds, mask = cls._pixel_grid(
@@ -262,15 +267,7 @@ class SpectralDataset:
             total = int(lens.sum())
             mzs_flat = np.empty(total, dtype=np.float64)
             ints_flat = np.empty(total, dtype=np.float32)
-            for i in range(rd.n_spectra):
-                m, t = rd.read_spectrum(i)
-                s = row_ptr[pixel_inds[i]]
-                if m.size != lens[i]:
-                    raise ValueError(
-                        f"spectrum {i}: ibd length {m.size} != XML metadata "
-                        f"length {lens[i]}")
-                mzs_flat[s : s + m.size] = m
-                ints_flat[s : s + t.size] = t
+            rd.read_into(mzs_flat, ints_flat, row_ptr[pixel_inds])
             cls._sort_rows_inplace(mzs_flat, ints_flat, row_ptr)
             return cls(
                 nrows=nrows,

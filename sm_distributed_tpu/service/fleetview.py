@@ -434,6 +434,9 @@ class FleetView:
 
 
 # --------------------------------------------------------- device profiling
+_MTIME_MARGIN_S = 1.0
+
+
 class DeviceProfiler:
     """Single-flight ``jax.profiler`` capture behind ``/debug/profile``."""
 
@@ -498,9 +501,13 @@ class DeviceProfiler:
             return []
         running = {j.get("trace_id") for j in self.service.scheduler.jobs()
                    if j["state"] == "running"}
+        # a file's mtime comes from the kernel's coarse clock and can read
+        # several ms EARLIER than a time.time() taken before the write, so a
+        # record written right after the capture began needs the margin; a
+        # file too many costs a read, a file too few loses its job
+        since = capture["t0_wall"] - _MTIME_MARGIN_S
         return [p for p in Path(trace_dir).glob("*.jsonl")
-                if p.stem in running
-                or p.stat().st_mtime >= capture["t0_wall"]]
+                if p.stem in running or p.stat().st_mtime >= since]
 
     def _inject_device_spans(self, inject: list[dict]) -> int:
         """Append the reduction's per-hold spans (``device_scope`` per chip

@@ -208,6 +208,7 @@ class AnnotationService:
         if residency is not None:
             self.metrics.add_collector(self._collect_residency)
         self.metrics.add_collector(self._collect_prepare)
+        self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
         self.api = AdminAPI(self, host=cfg.http_host,
                             port=cfg.http_port) if with_api else None
@@ -271,6 +272,25 @@ class AnnotationService:
         for site, n in flat_sorted_events().items():
             c = prepares.labels(site=site)
             c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_ingest(m: MetricsRegistry) -> None:
+        """How each imzML ingest made its index (``scan``: one pass of a
+        pattern over the XML's bytes; ``xml``: the file was handed whole to
+        the XML parser) and the ibd read calls issued (``io/imzml.py``).
+        Pulled like the prepare sites above."""
+        from ..io.imzml import ingest_events
+
+        events = ingest_events()
+        ingests = m.counter(
+            "sm_imzml_ingest_total",
+            "imzML files indexed, by how the index was made", ("index",))
+        for index in ("scan", "xml"):
+            c = ingests.labels(index=index)
+            c.inc(max(0.0, events[index] - c.value))
+        reads = m.counter(
+            "sm_imzml_ibd_reads_total", "ibd read calls issued").labels()
+        reads.inc(max(0.0, events["ibd_reads"] - reads.value))
 
     @staticmethod
     def _collect_chaos_images(m: MetricsRegistry) -> None:

@@ -190,6 +190,45 @@ def test_ledger_concurrent_writers_no_database_locked(tmp_path):
         ledger.close()
 
 
+def test_ledger_waits_for_the_wal_switch_of_a_fresh_database(
+        tmp_path, monkeypatch):
+    """Two jobs that start together on a new results directory: sqlite
+    answers the second connection's ``PRAGMA journal_mode=WAL`` with
+    "database is locked" at once while the first makes the switch (the busy
+    timeout is not consulted there).  The ledger waits and asks again."""
+    import sqlite3
+
+    from sm_distributed_tpu.engine import storage
+
+    refused = []
+
+    class _BusyAtTheSwitch(sqlite3.Connection):
+        def execute(self, sql, *args):
+            if sql.startswith("PRAGMA journal_mode=WAL") and len(refused) < 3:
+                refused.append(sql)
+                raise sqlite3.OperationalError("database is locked")
+            return super().execute(sql, *args)
+
+    real = sqlite3.connect
+    monkeypatch.setattr(
+        storage.sqlite3, "connect",
+        lambda *a, **kw: real(*a, factory=_BusyAtTheSwitch, **kw))
+    ledger = JobLedger(tmp_path)
+    try:
+        assert len(refused) == 3
+        mode = ledger._conn.execute("PRAGMA journal_mode").fetchone()[0]
+        assert str(mode).lower() == "wal"
+        ledger.upsert_dataset("a", "a", "/in", {})
+        assert ledger.job_status(ledger.start_job("a")) == "STARTED"
+    finally:
+        ledger.close()
+    # any other operational error is not waited for
+    monkeypatch.setattr(_BusyAtTheSwitch, "execute", lambda self, sql, *a: (
+        _ for _ in ()).throw(sqlite3.OperationalError("disk I/O error")))
+    with pytest.raises(sqlite3.OperationalError, match="disk I/O"):
+        JobLedger(tmp_path / "other")
+
+
 def test_ledger_fail_stale_started_scoped(tmp_path):
     ledger = JobLedger(tmp_path)
     try:

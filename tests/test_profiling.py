@@ -244,3 +244,30 @@ def test_cpu_capture_has_no_chips_and_injects_nothing(tmp_path):
     assert ann["n"] == ann["matched"] == 1 and ann["max_err_us"] < 1000
     assert tracing._capture is None
     assert "attribution" not in body
+
+
+def test_trace_files_allow_for_the_coarse_mtime_clock(tmp_path):
+    """A record written within a tick after the capture began can carry an
+    mtime a few ms BEFORE ``t0_wall`` (the kernel stamps files from its
+    coarse clock): such a file is still the capture's, a file last written
+    seconds before it is not, a running job's always is."""
+    import os
+
+    class _Sched:
+        def jobs(self):
+            return [{"trace_id": "running", "state": "running"}]
+
+    class _Svc:
+        metrics = MetricsRegistry()
+        scheduler = _Sched()
+        trace_dir = str(tmp_path)
+        sm_config = SMConfig.from_dict({"work_dir": str(tmp_path / "work")})
+
+    t0 = 1_700_000_000.0
+    for name, mtime in (("tick", t0 - 0.006), ("old", t0 - 5.0),
+                        ("running", t0 - 500.0), ("new", t0 + 1.0)):
+        path = tmp_path / f"{name}.jsonl"
+        path.write_text("")
+        os.utime(path, (mtime, mtime))
+    got = DeviceProfiler(_Svc(), ProfileConfig())._trace_files({"t0_wall": t0})
+    assert sorted(p.stem for p in got) == ["new", "running", "tick"]
