@@ -10,17 +10,20 @@ repeated same-shaped traffic, through the REAL service stack:
    XLA compilation must be attributed to a call site whose module carries
    a ``COMPILE_SURFACE`` registration (**zero unattributed compiles**;
    driver/test frames and ``<external>`` sites fail the gate);
-2. a SECOND identical-shape job (new dataset id, same geometry) re-runs —
-   it may re-request compiles (fresh backend) but must add **zero new
-   signatures**: the signature set is closed, which is exactly the
-   property cold-start annihilation (ROADMAP item 1) needs;
-3. cross-SIZE closure (ISSUE 13 shape-bucket lattice): a job on a
-   DIFFERENT dataset geometry (6x8 px vs 8x8 px) that shares the lattice
-   bucket (row_bucket(6) == row_bucket(8) == 8; both peak counts under
-   the 4096-slot floor) must add **zero compile events** — every
-   executable request resolves as a persistent-cache load
-   (``cache_hits`` in the retrace census), proving the signature set is
-   closed across dataset SIZES, not just identical shapes;
+2. a SECOND identical job (new dataset id, same file) re-runs on the
+   resident backend of the first: it must add **zero census events of any
+   kind** — no compile, no persistent-cache load, no new signature;
+3. cross-SIZE closure (ISSUE 13 shape-bucket lattice, ISSUE 34 shared
+   jits): a job on a DIFFERENT dataset (6x8 px vs 8x8 px) that shares the
+   lattice bucket (row_bucket(6) == row_bucket(8) == 8; both peak counts
+   under the 4096-slot floor) builds a FRESH backend, which finds the
+   jitted scorers of its metric geometry in the process
+   (``models/msm_jax.make_flat_jits``) and asks them for signatures they
+   have run.  It must add **zero events of any kind** as well — before
+   ISSUE 34 it re-traced, re-lowered and re-loaded every executable from
+   the persistent cache — and the registry must count its backend
+   ``shared`` (the stage is not vacuous): the signature set is closed
+   across dataset SIZES, and the process keeps what it loaded;
 4. a ``devices: 2`` submit on a virtual 2-chip CPU mesh exercises the
    pjit/shard_map SHARDED path — its compiles must attribute to the
    registered ``parallel/sharded.py`` surface the same way;
@@ -88,6 +91,44 @@ def _sig_set(snap: dict) -> set[tuple[str, str]]:
             for sig in ent["signatures"]}
 
 
+def _quiet_job(h, msg: dict, what: str, fresh_backend: bool) -> str | None:
+    """Run one job whose geometry and signatures the process has already
+    served; the reason it was not quiet, or None.  Quiet = the census
+    records nothing at all (no compile, no persistent-cache load, hence no
+    new signature).  With ``fresh_backend`` the job must also have BUILT a
+    backend, one the registry counted as ``shared`` — the proof that the
+    stage is not vacuous; without, it must have built none."""
+    from sm_distributed_tpu.models.msm_jax import scoring_jit_events
+
+    before, jits0 = retrace.snapshot(), scoring_jit_events()
+    status, _hd, body = h.submit(msg)
+    if status != 202:
+        return f"{what} submit returned {status}: {body}"
+    row = h.wait_terminal([body["msg_id"]])[body["msg_id"]]
+    if row["state"] != "done":
+        return f"{what} job state {row['state']}: {row['error']!r}"
+    after, jits1 = retrace.snapshot(), scoring_jit_events()
+    events = after["events_total"] - before["events_total"]
+    loads = after["cache_hits_total"] - before["cache_hits_total"]
+    new_sigs = _sig_set(after) - _sig_set(before)
+    if events or loads or new_sigs:
+        return (f"{what} job was NOT quiet on a geometry the process had "
+                f"served: {events} compile(s), {loads} persistent-cache "
+                f"load(s), {len(new_sigs)} new signature(s) "
+                f"{sorted(new_sigs)[:5]} — its backend did not call the "
+                f"jits the last one traced (models/msm_jax._SHARED_JITS)")
+    shared = jits1["shared"] - jits0["shared"]
+    built = jits1["built"] - jits0["built"]
+    if shared != int(fresh_backend) or built:
+        return (f"{what} job's backends were counted shared +{shared}, "
+                f"built +{built}, not shared +{int(fresh_backend)} built +0 "
+                f"— the stage did not run on the backend it is about")
+    print(f"compile_census: {what} OK — "
+          f"{'fresh backend, shared jits' if fresh_backend else 'resident backend'}"
+          f", 0 compiles, 0 cache loads")
+    return None
+
+
 def run(work: Path) -> int:
     fx = build_fixtures(work)
     h = Harness(work, "compile_census", sm_overrides={
@@ -117,57 +158,30 @@ def run(work: Path) -> int:
                 "unattributed compiles — call sites outside any "
                 f"COMPILE_SURFACE-registered module: {sorted(bad)}")
 
-        # ---- phase 2: identical-shape traffic adds ZERO new signatures
-        status, _hd, body2 = h.submit(_msg(fx, "fast", "census2"))
-        if status != 202:
-            return fail(f"submit 2 returned {status}: {body2}")
-        rows = h.wait_terminal([body2["msg_id"]])
-        if rows[body2["msg_id"]]["state"] != "done":
-            return fail(f"job 2 state {rows[body2['msg_id']]['state']}")
-        snap2 = retrace.snapshot()
-        new_sigs = _sig_set(snap2) - _sig_set(snap1)
-        if new_sigs:
-            return fail(
-                f"signature set NOT closed — a second identical-shape job "
-                f"minted {len(new_sigs)} new signature(s): "
-                f"{sorted(new_sigs)[:5]}")
+        # ---- phase 2: the identical job again (the resident backend)
+        # adds ZERO events
+        err = _quiet_job(h, _msg(fx, "fast", "census2"), "identical",
+                         fresh_backend=False)
+        if err:
+            return fail(err)
 
         # ---- phase 2b: closure across dataset SIZES sharing a bucket
         # (ISSUE 13): a 6x8 fixture row-buckets to the same 8-row lattice
         # point as the 8x8 one (and both peak counts sit under the
-        # 4096-slot floor), so with the persistent cache warm from phase
-        # 1 its job must pay ZERO compiles — only cache loads
+        # 4096-slot floor), so its FRESH backend asks the shared jits for
+        # exactly the signatures phase 1 left in the process: nothing is
+        # traced, lowered, loaded or compiled again
         from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset
 
         mid_path, _mid_truth = generate_synthetic_dataset(
             work / "fx_mid", nrows=6, ncols=8, formulas=None,
             present_fraction=0.5, noise_peaks=30, seed=12)
-        before = retrace.snapshot()
         msg_x = dict(_msg(fx, "fast", "census_xsize"))
         msg_x["input_path"] = str(mid_path)      # same formulas, new size
-        status, _hd, body_x = h.submit(msg_x)
-        if status != 202:
-            return fail(f"cross-size submit returned {status}: {body_x}")
-        rows = h.wait_terminal([body_x["msg_id"]])
-        if rows[body_x["msg_id"]]["state"] != "done":
-            return fail(f"cross-size job state "
-                        f"{rows[body_x['msg_id']]['state']}: "
-                        f"{rows[body_x['msg_id']]['error']!r}")
-        after = retrace.snapshot()
-        new_events = after["events_total"] - before["events_total"]
-        new_hits = after["cache_hits_total"] - before["cache_hits_total"]
-        if new_events:
-            return fail(
-                f"signature set NOT closed across dataset sizes: the 6x8 "
-                f"job (same bucket as 8x8) paid {new_events} compile(s) "
-                f"instead of resolving from the persistent cache")
-        if new_hits <= 0:
-            return fail(
-                "cross-size job neither compiled nor loaded from the "
-                "persistent cache — the census saw nothing (vacuous "
-                "cross-size stage)")
-        print(f"compile_census: cross-size closure OK — 6x8 job resolved "
-              f"{new_hits} executable(s) as cache loads, 0 compiles")
+        err = _quiet_job(h, msg_x, "cross-size (6x8 in the 8x8 bucket)",
+                         fresh_backend=True)
+        if err:
+            return fail(err)
 
         # ---- phase 3: the sharded path attributes the same way
         status, _hd, body3 = h.submit(

@@ -13,6 +13,7 @@ The graph compiles ONCE per dataset: formula batches are padded to the static
 from __future__ import annotations
 
 import threading
+from collections import OrderedDict
 from functools import partial, update_wrapper
 
 import jax
@@ -454,18 +455,51 @@ def named_partial(fn, **kwargs) -> partial:
     return p
 
 
-def make_flat_jits(common: dict) -> dict:
-    """The flat-path jitted scorers for one metric geometry, keyed by
-    variant name.  ``common`` is the closure dict (nrows — row-bucketed
-    under the lattice — ncols, nlevels, do_preprocessing, q).
+# The jitted callables of one geometry, shared by every backend of that
+# geometry in the process: JAX keys its trace and executable caches on the
+# function object, so a backend that built its own jits traced, lowered and
+# loaded again what the last upload of a like-sized section had just used.
+# Keyed by the closure's items; the values close over those scalars alone,
+# never over a backend, a dataset or a device array.  Scheduler workers
+# build backends at once, hence the lock.  ``ncols`` is exact, so a
+# long-lived server sees an open set of geometries: least recently used
+# entries go at the bound, and their executables with them once no live
+# backend holds the callables.
+SHARED_JITS_MAX = 16
+_SHARED_JITS: OrderedDict[tuple, object] = OrderedDict()
+_SHARED_JITS_LOCK = threading.Lock()
+# backends constructed, by whether their geometry's scorers were found
+# ("shared") or made ("built"); the service pulls it at scrape as
+# sm_scoring_jits_total{result=}
+_SCORING_JITS = {"shared": 0, "built": 0}
 
-    THE one place these jits are constructed: ``JaxBackend.__init__``
-    binds them to ``self._fn*`` and the AOT cache primer
-    (``service/primer.py``) builds byte-identical programs from a recorded
-    BucketSpec — same function objects, same partial closure, same
-    static_argnames — so a primed persistent-cache entry is exactly the
-    entry a later real job looks up (ISSUE 13)."""
-    return {
+
+def _shared_jits(closure: dict, build, count: bool = False):
+    """(the registry's entry for ``closure``, whether it was found);
+    ``build`` makes it on a miss.  ``count`` tallies a backend."""
+    key = tuple(sorted(closure.items()))
+    with _SHARED_JITS_LOCK:
+        entry = _SHARED_JITS.get(key)
+        found = entry is not None
+        if found:
+            _SHARED_JITS.move_to_end(key)
+        else:
+            entry = _SHARED_JITS[key] = build()
+            while len(_SHARED_JITS) > SHARED_JITS_MAX:
+                _SHARED_JITS.popitem(last=False)
+        if count:
+            _SCORING_JITS["shared" if found else "built"] += 1
+    return entry, found
+
+
+def scoring_jit_events() -> dict:
+    with _SHARED_JITS_LOCK:
+        return dict(_SCORING_JITS)
+
+
+def _flat_jits(common: dict, count: bool = False) -> tuple[dict, bool]:
+    """(``make_flat_jits(common)``, whether the registry had them)."""
+    return _shared_jits(common, lambda: {
         "plain": jax.jit(
             named_partial(fused_score_fn_flat_banded, **common),
             static_argnames=("gc_width", "b", "k")),
@@ -478,7 +512,31 @@ def make_flat_jits(common: dict) -> dict:
         "fused": jax.jit(
             named_partial(fused_score_fn_flat_fused, **common),
             static_argnames=("gc_width", "b", "k")),
-    }
+    }, count=count)
+
+
+def make_flat_jits(common: dict) -> dict:
+    """The flat-path jitted scorers for one metric geometry, keyed by
+    variant name.  ``common`` is the closure dict (nrows — row-bucketed
+    under the lattice — ncols, nlevels, do_preprocessing, q).
+
+    THE one place these jits come from, and for equal ``common`` they are
+    the SAME four objects (until the registry above drops the geometry):
+    ``JaxBackend.__init__`` binds them to ``self._fn*`` and the AOT cache
+    primer (``service/primer.py``) lowers them against a recorded
+    BucketSpec.  So a primed persistent-cache entry is exactly the entry a
+    later real job looks up (ISSUE 13), and a backend of a geometry the
+    process has served calls what the last one traced and loaded: it
+    traces, lowers and loads nothing of a signature already seen."""
+    return _flat_jits(common)[0]
+
+
+def make_extract_jit(n_pixels: int):
+    """The image export's extraction jit for one (bucketed) pixel count,
+    from the same registry and under the same promise of identity."""
+    closure = {"n_pixels": int(n_pixels)}
+    return _shared_jits(closure, lambda: jax.jit(
+        named_partial(extract_images_flat, **closure)))[0]
 
 
 def to_numpy_global(arr) -> np.ndarray:
@@ -736,7 +794,8 @@ class JaxBackend:
             mz_s.size, self.resident_bytes / 1e6,
             self._cube_dtype, self._px_s.devices(),
         )
-        fns = make_flat_jits(common)
+        fns, shared = _flat_jits(common, count=True)
+        tracing.annotate(jits_shared=shared)    # onto the backend_build span
         self._fn = fns["plain"]
         self._fn_c = fns["compact"]
         self._fn_bs = fns["band"]
@@ -1130,9 +1189,7 @@ class JaxBackend:
             # bucketed extraction grid (lattice): the host-side slice
             # below takes the exact-pixel prefix, so the export is
             # bit-identical while the executable is shared per bucket
-            self._extract_fn = jax.jit(
-                named_partial(extract_images_flat,
-                              n_pixels=self._n_pix_b))
+            self._extract_fn = make_extract_jit(self._n_pix_b)
         pos = flat_bound_ranks(self._mz_host, grid)
         imgs = self._extract_fn(
             self._px_s, self._in_f32(), jax.device_put(pos),

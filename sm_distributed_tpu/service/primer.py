@@ -7,9 +7,10 @@ This module walks that set and compiles it into the persistent XLA cache
 **before traffic arrives**, so a cold submit loads executables from disk
 instead of paying the cold XLA compile:
 
-- :func:`prime_spec` AOT-compiles ONE spec: it rebuilds the exact jitted
-  program a real backend would construct (``models/msm_jax.make_flat_jits``
-  — same function objects, same closure, same static_argnames) and lowers
+- :func:`prime_spec` AOT-compiles ONE spec: it takes the very jit a real
+  backend of the spec's geometry calls (``models/msm_jax.make_flat_jits``
+  keeps one set of ``jax.jit`` objects a geometry — the same objects, not
+  equal ones, so closure and static_argnames cannot drift) and lowers
   it against ``jax.ShapeDtypeStruct`` avals derived from the spec, so the
   persistent-cache entry it writes is byte-for-byte the entry a later job
   looks up.  No device arrays are materialized and no device time is
@@ -54,8 +55,9 @@ from ..ops import buckets as shape_buckets
 from ..ops.quantize import CUBE_DTYPES
 from ..utils.logger import logger
 
-# No jax.jit call sites live here — the jitted programs are built by
-# models/msm_jax.make_flat_jits (registered in THAT module's surface).
+# No jax.jit call sites live here — the jitted programs come from
+# models/msm_jax.make_flat_jits (registered in THAT module's surface), which
+# hands the primer the objects the backends of a geometry call.
 # This declaration attributes the AOT ``.compile()`` frames the retrace
 # tracer sees when the primer pays a compile (scripts/compile_census.py
 # requires every observed site's module to carry a registry).

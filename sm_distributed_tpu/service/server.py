@@ -210,6 +210,7 @@ class AnnotationService:
         self.metrics.add_collector(self._collect_prepare)
         self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
+        self.metrics.add_collector(self._collect_scoring_jits)
         self.api = AdminAPI(self, host=cfg.http_host,
                             port=cfg.http_port) if with_api else None
         # fleet observability plane (ISSUE 20, service/fleetview.py):
@@ -305,6 +306,22 @@ class AnnotationService:
         mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
         for (route, ib), n in (mod.chaos_image_events() if mod else {}).items():
             c = images.labels(route=route, images_per_program=str(ib))
+            c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_scoring_jits(m: MetricsRegistry) -> None:
+        """Backends constructed, by whether the jitted scorers of their
+        geometry were already in the process (``shared``: nothing of a
+        signature seen before is traced, lowered or loaded again) or had to
+        be made (``built``) — ``models/msm_jax.py::scoring_jit_events``.
+        Pulled like the chaos images above."""
+        jits = m.counter(
+            "sm_scoring_jits_total",
+            "Scoring backends constructed, by whether their geometry's "
+            "jitted scorers were shared or built", ("result",))
+        mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
+        for result, n in (mod.scoring_jit_events() if mod else {}).items():
+            c = jits.labels(result=result)
             c.inc(max(0.0, n - c.value))
 
     def queue_depths(self) -> dict:

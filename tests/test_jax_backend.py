@@ -832,6 +832,7 @@ def test_export_traces_once_per_bucket(offgrid_ds, tmp_path):
     bucket traces one more; after an OOM shrink the bucket never exceeds
     the batch."""
     from sm_distributed_tpu.analysis import retrace
+    from sm_distributed_tpu.models import msm_jax
     from sm_distributed_tpu.models.msm_basic import _slice_table
     from sm_distributed_tpu.models.msm_jax import JaxBackend
     from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
@@ -846,6 +847,11 @@ def test_export_traces_once_per_bucket(offgrid_ds, tmp_path):
                              "parallel": {"formula_batch": 96}})
     dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
                              "image_generation": {"ppm": 3.0}})
+    # the export's jit is shared by every backend of one pixel count
+    # (msm_jax.make_extract_jit): start from a registry that has not seen
+    # this one, whatever earlier tests of the process exported
+    with msm_jax._SHARED_JITS_LOCK:
+        msm_jax._SHARED_JITS.clear()
     backend = JaxBackend(ds, dc, sm)
     assert backend.batch == 96
     retrace.enable()
@@ -858,6 +864,13 @@ def test_export_traces_once_per_bucket(offgrid_ds, tmp_path):
         backend.extract_ion_images(_slice_table(table, 0, 70))    # 80
         assert _export_traces() == 2
         backend.extract_ion_images(_slice_table(table, 0, 90))    # 96 = batch
+        assert _export_traces() == 3
+        # a second backend of the geometry calls the same jit object: the
+        # three buckets are traced, and it traces none of them again
+        again = JaxBackend(ds, dc, sm)
+        for n in (3, 70, 90):
+            again.extract_ion_images(_slice_table(table, 0, n))
+        assert again._extract_fn is backend._extract_fn
         assert _export_traces() == 3
     finally:
         retrace.disable()
