@@ -22,6 +22,13 @@ running service) into the standard perf artifact for this repo:
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that
   covers each (``device_scope`` / ``device_busy`` / ``device_idle`` spans);
+- the **CPU split**: beside every wall time the seconds the span's thread
+  was on a core (``cpu``) and off it (``dur - cpu``: blocked on the chip, a
+  file, a lock, or queued for the interpreter), a table of every span name
+  in order of first appearance, and one line that splits the lease hold
+  after the grant into ran / ``device_sync`` / stalled / unnamed (``hold``
+  in ``--json``; ``benchmarks/layers/hold_stall_s.py`` and
+  ``hold_unnamed_s.py`` read the same two numbers);
 - attempts (with timeout/abandon flags) and event counts (retries,
   cancels, failpoints, breaker flips).
 
@@ -90,6 +97,97 @@ def _events(records, name=None):
             yield r
 
 
+# appended under device_hold by a /debug/profile capture: device time, not
+# spans of the job's thread
+_INJECTED = ("device_scope", "device_busy", "device_idle")
+
+
+def _granted_hold(records: list[dict]) -> tuple[dict, dict] | None:
+    """The first ``device_hold`` span with its ``device_token_acquired``
+    event inside it."""
+    for hold in sorted(_spans(records, "device_hold"), key=lambda r: r["ts"]):
+        end = hold["ts"] + float(hold["dur"])
+        for e in _events(records, "device_token_acquired"):
+            if hold["ts"] <= e["ts"] <= end:
+                return hold, e
+    return None
+
+
+def _descendants(records: list[dict], root_id: str) -> list[dict]:
+    """The spans below ``root_id``, the ``device_*`` spans a capture appended
+    left out."""
+    kids: dict[str, list[dict]] = {}
+    for r in _spans(records):
+        if r["name"] not in _INJECTED:
+            kids.setdefault(r.get("parent_id", ""), []).append(r)
+    out, todo = [], [root_id]
+    while todo:
+        found = kids.get(todo.pop(), ())
+        out.extend(found)
+        todo.extend(r["span_id"] for r in found)
+    return out
+
+
+def hold_split(records: list[dict]) -> dict | None:
+    """The lease hold after the grant, split four ways (seconds):
+
+    - ``ran``: the job's thread on a core (``device_hold.cpu`` less the
+      event ``device_token_acquired``'s ``wait_cpu_s``);
+    - ``device_sync``: the thread waiting for the chip's scores;
+    - ``stalled``: the rest, the thread neither running nor in
+      ``device_sync`` (the export's fetch, a file, a lock, the GIL);
+    - ``unnamed``: what no descendant span of ``device_hold`` covers
+      (independent of the three above, which sum to ``held``).
+
+    None without a grant inside a ``device_hold`` span; ``ran`` and
+    ``stalled`` None on a trace whose spans carry no ``cpu``."""
+    found = _granted_hold(records)
+    if found is None:
+        return None
+    hold, grant = found
+    t0, end = grant["ts"], hold["ts"] + float(hold["dur"])
+    held = end - t0
+    under = _descendants(records, hold["span_id"])
+    covered, edge = 0.0, t0
+    for a, b in sorted((max(t0, r["ts"]), min(end, r["ts"] + float(r["dur"])))
+                       for r in under):
+        if b > edge:
+            covered += b - max(a, edge)
+            edge = b
+    sync = sum(float(r["dur"]) for r in under if r["name"] == "device_sync")
+    out = {"held_s": held, "device_sync_s": sync, "ran_s": None,
+           "stalled_s": None, "unnamed_s": held - covered}
+    if "cpu" in hold:
+        ran = float(hold["cpu"]) - float(
+            (grant.get("attrs") or {}).get("wait_cpu_s", 0.0))
+        out.update(ran_s=ran, stalled_s=held - ran - sync)
+    return {k: None if v is None else round(v, 6) for k, v in out.items()}
+
+
+def _agg(found: list[dict]) -> dict:
+    """Count, wall seconds and thread CPU seconds of some spans (``cpu_s``
+    None where none of them carries ``cpu``)."""
+    cpus = [float(r["cpu"]) for r in found if "cpu" in r]
+    return {"count": len(found),
+            "seconds": round(sum(float(r["dur"]) for r in found), 6),
+            "cpu_s": round(sum(cpus), 6) if cpus else None}
+
+
+def span_table(records: list[dict]) -> list[dict]:
+    """Every span name in order of first appearance: count, wall, cpu, and
+    whether it ran under the lease hold."""
+    found = _granted_hold(records)
+    under = {r["span_id"] for r in _descendants(
+        records, found[0]["span_id"])} if found else set()
+    by_name: dict[str, list[dict]] = {}
+    for r in sorted(_spans(records), key=lambda r: r["ts"]):
+        if r["name"] not in _INJECTED:
+            by_name.setdefault(r["name"], []).append(r)
+    return [{"name": name, **_agg(group),
+             "under_hold": group[0]["span_id"] in under}
+            for name, group in by_name.items()]
+
+
 def summarize(records: list[dict]) -> dict:
     """The report's data model (also what --json prints)."""
     root = max(_spans(records, "submit"),
@@ -97,14 +195,11 @@ def summarize(records: list[dict]) -> dict:
     total = float(root["dur"]) if root else sum(
         float(r.get("dur", 0.0)) for r in _spans(records)
         if not r.get("parent_id"))
-    phases: dict[str, dict] = {}
+    by_phase: dict[str, list[dict]] = {}
     for r in _spans(records):
-        if not (r.get("attrs") or {}).get("phase") \
-                and r["name"] not in _STEPS:
-            continue
-        p = phases.setdefault(r["name"], {"count": 0, "seconds": 0.0})
-        p["count"] += 1
-        p["seconds"] += float(r["dur"])
+        if (r.get("attrs") or {}).get("phase") or r["name"] in _STEPS:
+            by_phase.setdefault(r["name"], []).append(r)
+    phases = {name: _agg(found) for name, found in by_phase.items()}
     attempts = sorted(_spans(records, "attempt"), key=lambda r: r["ts"])
     # queue wait: submit start -> first attempt start (requeues/retries put
     # later attempts' wait inside the root too, reported via attempts[])
@@ -128,10 +223,9 @@ def summarize(records: list[dict]) -> dict:
     worker_spans = list(_spans(records, "isocalc_chunk"))
     children = {}
     for name in (n for names in _CHILDREN.values() for n in names):
-        found = [float(r["dur"]) for r in _spans(records, name)]
+        found = list(_spans(records, name))
         if found:
-            children[name] = {"count": len(found),
-                              "seconds": round(sum(found), 6)}
+            children[name] = _agg(found)
     device = {"scopes": {}, "busy": [], "idle": []}
     for r in _spans(records, "device_scope"):
         a = r["attrs"]
@@ -150,9 +244,9 @@ def summarize(records: list[dict]) -> dict:
         "job_id": next((r["job_id"] for r in records if r.get("job_id")), ""),
         "state": (root.get("attrs") or {}).get("state", "") if root else "",
         "total_s": total,
-        "phases": {k: {"count": v["count"],
-                       "seconds": round(v["seconds"], 6)}
-                   for k, v in phases.items()},
+        "phases": phases,
+        "hold": hold_split(records),
+        "spans": span_table(records),
         "accounting": {
             "queue_wait_s": round(queue_wait, 6)
             if queue_wait is not None else None,
@@ -267,6 +361,15 @@ def _pct(part: float, total: float) -> str:
     return f"{100.0 * part / total:5.1f}%" if total > 0 else "    -"
 
 
+def _cpu(v: dict) -> str:
+    """`` cpu 0.123s off 0.456s`` beside a wall time (nothing for records
+    from before spans carried ``cpu``)."""
+    if v.get("cpu_s") is None:
+        return ""
+    return (f"  cpu {v['cpu_s']:8.3f}s "
+            f"off {max(0.0, v['seconds'] - v['cpu_s']):8.3f}s")
+
+
 def render(s: dict) -> str:
     lines = []
     head = f"trace {s['trace_id']}"
@@ -285,16 +388,36 @@ def render(s: dict) -> str:
     for p in ordered:
         v = s["phases"][p]
         lines.append(f"  {p:<22} {v['seconds']:9.3f}s "
-                     f"{_pct(v['seconds'], total)}  x{v['count']}")
+                     f"{_pct(v['seconds'], total)}  x{v['count']:<3}{_cpu(v)}")
         for c in _CHILDREN.get(p, ()):
             if c in s.get("children", {}):
                 v = s["children"][c]
                 pad = "      " if c.startswith("build_") else "    "
                 lines.append(f"{pad}{c:<{26 - len(pad)}}{v['seconds']:8.3f}s "
-                             f"{_pct(v['seconds'], total)}  x{v['count']}")
+                             f"{_pct(v['seconds'], total)}  x{v['count']:<3}"
+                             f"{_cpu(v)}")
     if not ordered:
         lines.append("  (no phase spans)")
     lines.append("")
+    if s.get("spans"):
+        lines.append("spans (wall, thread cpu, off-core = wall - cpu; "
+                     "* = under the lease hold):")
+        for v in s["spans"]:
+            lines.append(f"  {'*' if v['under_hold'] else ' '} "
+                         f"{v['name']:<22} {v['seconds']:9.3f}s  "
+                         f"x{v['count']:<3}{_cpu(v)}")
+        lines.append("")
+    h = s.get("hold")
+    if h:
+        def sec(v):
+            return "n/a" if v is None else f"{v:.3f}s"
+
+        lines.append(
+            f"lease hold after the grant {h['held_s']:.3f}s = ran "
+            f"{sec(h['ran_s'])} + device_sync {sec(h['device_sync_s'])} + "
+            f"stalled {sec(h['stalled_s'])}; under no named span "
+            f"{sec(h['unnamed_s'])}")
+        lines.append("")
     if s.get("device"):
         d = s["device"]
         lines.append("device (from a /debug/profile capture):")

@@ -10,6 +10,13 @@ annotation (``utils/tracing.py::set_capture``), and the session emits an
 reduction maps profiler time to the wall clock of the job traces through
 those two events, not through the first device event or anybody's send time.
 
+For the same length of time one daemon thread, the interpreter-wait probe
+(``InterpProbe``), asks for the interpreter every 10 ms and adds up how late
+it got it: what a thread that wants the GIL for microseconds waits to get
+it.  CPython hands the GIL over after its 5 ms switch interval, so a mean of
+~0.1 ms reads idle, ~5 ms one thread hogging, more than that a queue.
+Outside a capture the thread does not exist.
+
 ``reduce_capture`` turns the ``.xplane.pb`` this JAX writes on a TPU into
 device time, in a ``JAX_PLATFORMS=cpu`` helper process (``python -m
 sm_distributed_tpu.analysis.profiling <request.json>``) so the serving
@@ -45,6 +52,7 @@ import re
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 from functools import partial
 from pathlib import Path
@@ -77,6 +85,58 @@ class _OpenSpan:
         self.session.open.pop(self.span_id, None)
 
 
+# ------------------------------------------------ the interpreter-wait probe
+# process totals over every capture so far: read by ``interp_probe_events``
+# (``service/server.py::_collect_interp_probe`` pulls them at a scrape)
+_probe_lock = threading.Lock()
+_probe_totals = {"wakeups": 0, "late_s": 0.0}
+
+
+def interp_probe_events() -> dict:
+    """``{"wakeups", "late_s"}``: the probe's wakes and the seconds they came
+    late, summed over the process's captures, the running one included."""
+    with _probe_lock:
+        return dict(_probe_totals)
+
+
+class InterpProbe:
+    """One daemon thread that sleeps ``PERIOD_S`` on an Event and measures
+    how long after the deadline it is running again.  The wait releases the
+    GIL and the wake has to take it back, so the lateness is the time a
+    thread queues for the interpreter (plus the kernel's wake-up, ~0.1 ms).
+    ``stop`` joins the thread and returns what the process's totals grew by
+    since ``start`` (one probe runs at a time, as one capture does)."""
+
+    PERIOD_S = 0.010
+
+    def __init__(self):
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._run, name="interp-probe", daemon=True)
+        self._at_start: dict = {}
+
+    def start(self) -> None:
+        self._at_start = interp_probe_events()
+        self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            deadline = time.perf_counter() + self.PERIOD_S
+            if self._stop.wait(self.PERIOD_S):
+                return
+            late = max(0.0, time.perf_counter() - deadline)
+            with _probe_lock:
+                _probe_totals["wakeups"] += 1
+                _probe_totals["late_s"] += late
+
+    def stop(self) -> dict:
+        self._stop.set()
+        self._thread.join()
+        now = interp_probe_events()
+        return {"wakeups": now["wakeups"] - self._at_start["wakeups"],
+                "late_s": round(now["late_s"] - self._at_start["late_s"], 6)}
+
+
 class ProfileSession:
     """One ``jax.profiler`` capture into ``profile_dir``.  ``start()`` raises
     ``RuntimeError`` when jax is missing — callers surface that as a
@@ -88,6 +148,7 @@ class ProfileSession:
         self.open: dict[str, dict] = {}     # span_id -> record, still open
         self.annotation = None
         self._preexisting: set[Path] = set()
+        self._probe: InterpProbe | None = None
 
     def _clock(self) -> None:
         with self.annotation(CLOCK, wall_ns=time.time_ns()):
@@ -107,17 +168,22 @@ class ProfileSession:
         self._clock()
         self.t0_wall = time.time()
         tracing.set_capture(partial(_OpenSpan, self))
+        self._probe = InterpProbe()
+        self._probe.start()
 
     def stop(self) -> dict:
         """Stop the capture; returns ``{"xplane", "t0_wall", "duration_s",
-        "open_spans"}`` — the file the profiler wrote ("" when it wrote
-        none) and the records of the spans still open, which no job trace
-        holds yet."""
+        "open_spans", "interp_probe"}`` — the file the profiler wrote (""
+        when it wrote none), the records of the spans still open, which no
+        job trace holds yet, and the interpreter-wait probe's wakes and late
+        seconds over this capture."""
         if self.annotation is None:
             raise RuntimeError("ProfileSession.stop() before start()")
         import jax
 
         tracing.set_capture(None)
+        probe = self._probe.stop()
+        self._probe = None
         open_spans = [dict(r) for r in list(self.open.values())]
         self._clock()
         t1 = time.time()
@@ -128,7 +194,7 @@ class ProfileSession:
         return {"xplane": str(new[-1]) if new else "",
                 "t0_wall": self.t0_wall,
                 "duration_s": round(t1 - self.t0_wall, 6),
-                "open_spans": open_spans}
+                "open_spans": open_spans, "interp_probe": probe}
 
 
 def reduce_capture(capture: dict, trace_files=()) -> dict:

@@ -322,46 +322,49 @@ def annotate_callback(sm_config: SMConfig, residency=None):
         from ..utils import tracing
         from .search_job import SearchJob
 
-        ds_config = (
-            DSConfig.from_dict(msg["ds_config"]) if msg.get("ds_config") else DSConfig()
-        )
-        # live-acquisition streaming (ISSUE 19, engine/stream.py): a
-        # mode=stream message runs the long-lived stream attempt — same
-        # constructor contract, input comes from the chunk log instead of
-        # the message's input_path (a "stream://<ds_id>" sentinel)
-        job_cls = SearchJob
-        if msg.get("mode") == "stream":
-            from .stream import StreamSearchJob
-
-            job_cls = StreamSearchJob
-        job = job_cls(
-            ds_id=msg["ds_id"],
-            ds_name=msg.get("ds_name", msg["ds_id"]),
-            input_path=msg["input_path"],
-            ds_config=ds_config,
-            sm_config=sm_config,
-            formulas=msg.get("formulas"),
-            residency=residency,
-            # service scheduler: serialize the device-bound phases across
-            # worker threads while staging/parse overlap
-            device_token=getattr(ctx, "device_token", None),
-            # cooperative cancellation: the job checks this at phase and
-            # checkpoint-group boundaries (utils/cancel.py)
-            cancel=getattr(ctx, "cancel", None),
-            # fenced-lease gate (service/leases.py): checked before the
-            # result store and the ledger commit, so a replica fenced out
-            # by a peer takeover never double-commits
-            fence=getattr(ctx, "fence", None),
-            # streamed first results (ISSUE 13): provisional annotations
-            # from the first scored group surface on the job record's
-            # ``partial`` field while later batches still run
-            on_partial=getattr(ctx, "set_partial", None),
-            workers_busy=getattr(ctx, "workers_busy", None),
-        )
         # the scheduler's attempt-span context (already ambient when the
         # scheduler ran this in an _Attempt thread; attached here too so the
         # plain blocking daemon's traced messages behave the same)
         with tracing.attach(getattr(ctx, "trace", None) or tracing.current()):
+            # attempt_setup: what the callback does before SearchJob.run (the
+            # configs, the ledger's and the store's sqlite connections)
+            with tracing.span("attempt_setup"):
+                ds_config = (DSConfig.from_dict(msg["ds_config"])
+                             if msg.get("ds_config") else DSConfig())
+                # live-acquisition streaming (ISSUE 19, engine/stream.py): a
+                # mode=stream message runs the long-lived stream attempt —
+                # same constructor contract, input comes from the chunk log
+                # instead of the message's input_path (a "stream://<ds_id>"
+                # sentinel)
+                job_cls = SearchJob
+                if msg.get("mode") == "stream":
+                    from .stream import StreamSearchJob
+
+                    job_cls = StreamSearchJob
+                job = job_cls(
+                    ds_id=msg["ds_id"],
+                    ds_name=msg.get("ds_name", msg["ds_id"]),
+                    input_path=msg["input_path"],
+                    ds_config=ds_config,
+                    sm_config=sm_config,
+                    formulas=msg.get("formulas"),
+                    residency=residency,
+                    # service scheduler: serialize the device-bound phases
+                    # across worker threads while staging/parse overlap
+                    device_token=getattr(ctx, "device_token", None),
+                    # cooperative cancellation: the job checks this at phase
+                    # and checkpoint-group boundaries (utils/cancel.py)
+                    cancel=getattr(ctx, "cancel", None),
+                    # fenced-lease gate (service/leases.py): checked before
+                    # the result store and the ledger commit, so a replica
+                    # fenced out by a peer takeover never double-commits
+                    fence=getattr(ctx, "fence", None),
+                    # streamed first results (ISSUE 13): provisional
+                    # annotations from the first scored group surface on the
+                    # job record's ``partial`` field while later batches run
+                    on_partial=getattr(ctx, "set_partial", None),
+                    workers_busy=getattr(ctx, "workers_busy", None),
+                )
             job.run(clean=bool(msg.get("clean")))
 
     return cb

@@ -15,6 +15,7 @@ index entries for the dataset are removed, and re-running is idempotent.
 
 from __future__ import annotations
 
+import time
 import traceback
 from pathlib import Path
 
@@ -114,11 +115,14 @@ class SearchJob:
         """Stage → read → search → store; job row tracks status."""
         import dataclasses
 
-        self.ledger.upsert_dataset(
-            self.ds_id, self.ds_name, str(self.input_path),
-            dataclasses.asdict(self.ds_config),
-        )
-        job_id = self.ledger.start_job(self.ds_id)
+        # the ledger's two rows: what run() does before pre_lease opens
+        # (the callback's own preamble is span attempt_setup, daemon.py)
+        with tracing.span("job_start"):
+            self.ledger.upsert_dataset(
+                self.ds_id, self.ds_name, str(self.input_path),
+                dataclasses.asdict(self.ds_config),
+            )
+            job_id = self.ledger.start_job(self.ds_id)
         logger.info("job %d started for ds %s (%s)", job_id, self.ds_id, self.ds_name)
         prof = None
         succeeded = False
@@ -174,7 +178,11 @@ class SearchJob:
                 token = hold_cancellable(self.device_token, self.cancel)
             # trace accounting: the device_hold span covers token WAIT +
             # HOLD; the acquired event inside marks the boundary, so
-            # trace_report can split queue-wait vs token-wait vs compute
+            # trace_report can split queue-wait vs token-wait vs compute.
+            # wait_cpu_s on the event is the CPU this thread used waiting
+            # (hold_cancellable's polling): device_hold.cpu less it is the
+            # CPU the thread used UNDER the lease
+            wait_c0 = time.thread_time()
             with tracing.span("device_hold"), token, \
                     retrace.lease(self.device_token):
                 # a DeviceLease exposes the granted chip indices; a plain
@@ -183,18 +191,21 @@ class SearchJob:
                 lease_devs = getattr(self.device_token, "devices", None)
                 tracing.event(
                     "device_token_acquired",
+                    wait_cpu_s=time.thread_time() - wait_c0,
                     **({"devices": [int(i) for i in lease_devs]}
                        if lease_devs else {}))
-                search = MSMBasicSearch(
-                    ds, formulas, self.ds_config, self.sm_config,
-                    isocalc_cache_dir=str(Path(self.sm_config.work_dir) / "isocalc_cache"),
-                    checkpoint_dir=str(self.work_dir.path),
-                    backend_cache=self.residency,
-                    prefetch=prefetch,
-                    cancel=self.cancel,
-                    device_indices=lease_devs,
-                    partial_observer=self._note_partial,
-                )
+                with tracing.span("search_init"):
+                    search = MSMBasicSearch(
+                        ds, formulas, self.ds_config, self.sm_config,
+                        isocalc_cache_dir=str(
+                            Path(self.sm_config.work_dir) / "isocalc_cache"),
+                        checkpoint_dir=str(self.work_dir.path),
+                        backend_cache=self.residency,
+                        prefetch=prefetch,
+                        cancel=self.cancel,
+                        device_indices=lease_devs,
+                        partial_observer=self._note_partial,
+                    )
                 prefetch = None   # ownership passed: search() consumes/cancels
                 bundle = search.search()
                 if search.isocalc is not None:
@@ -239,13 +250,15 @@ class SearchJob:
                 # ledger-commit fence: a stale replica must not flip the
                 # job row FINISHED under the takeover replica's run
                 self.fence()
-            self.ledger.finish_job(job_id)
+            with tracing.span("finish_job"):
+                self.ledger.finish_job(job_id)
             if search.last_checkpoint is not None:
                 # only after results are durably persisted: a storage failure
                 # above must leave the checkpoint for the rerun to resume
                 # from; and a failed cleanup must not FAIL a finished job
                 try:
-                    search.last_checkpoint.finalize()
+                    with tracing.span("checkpoint_finalize"):
+                        search.last_checkpoint.finalize()
                 except OSError:
                     logger.warning(
                         "could not remove search checkpoint shards under %s",
@@ -277,7 +290,8 @@ class SearchJob:
             # on failure the work dir survives even with clean=True: it holds
             # the checkpoint shards + staged input the rerun resumes from
             if clean and succeeded:
-                self.work_dir.clean()
+                with tracing.span("workdir_clean"):
+                    self.work_dir.clean()
             elif clean:
                 logger.info(
                     "job failed: keeping work dir %s for resume",

@@ -807,7 +807,8 @@ class MSMBasicSearch:
         if self.prefetch is not None:
             # SearchJob started decoys + generation before staging; by the
             # time search() runs, the stream has been computing all along
-            fdr, assignment, stream = self.prefetch.result()
+            with tracing.span("prefetch_join"):
+                fdr, assignment, stream = self.prefetch.result()
             self.isocalc = self.prefetch.isocalc
             timings.update(self.prefetch.timings)
         else:
@@ -866,8 +867,9 @@ class MSMBasicSearch:
                 "oom: starting at learned safe batch %d (config %d) for %s",
                 safe, self._batch_eff, self._oom_key())
             self._batch_eff = safe
-        fingerprint = (self._fingerprint_pairs(table) if overlap
-                       else self._fingerprint(table))
+        with tracing.span("table_fingerprint"):
+            fingerprint = (self._fingerprint_pairs(table) if overlap
+                           else self._fingerprint(table))
 
         def build():
             tracing.annotate(cache_hit=False)    # onto the backend_build span
@@ -952,8 +954,9 @@ class MSMBasicSearch:
                 ckpt = SearchCheckpoint(
                     self.checkpoint_dir, fingerprint, process_id=pid)
                 row_ranges = [(g[0][0], g[-1][1]) for g in groups]
-                done = self._agree_resume_point(
-                    ckpt.load(metrics, len(groups), row_ranges))
+                with tracing.span("checkpoint_load", groups=len(groups)):
+                    done = self._agree_resume_point(
+                        ckpt.load(metrics, len(groups), row_ranges))
                 if done:
                     logger.info(
                         "resuming search from checkpoint: %d/%d batch groups "
@@ -977,8 +980,9 @@ class MSMBasicSearch:
                 # per-group score_batches calls would otherwise pre-size
                 # static shapes per GROUP and recompile when a later group
                 # needs a wider band (models/msm_jax.py::presize)
-                backend.presize(
-                    _slice_table(table, s, e) for s, e in slices)
+                with tracing.span("presize", batches=len(slices)):
+                    backend.presize(
+                        _slice_table(table, s, e) for s, e in slices)
             first_scored = False
             for gi, group in enumerate(groups):
                 if gi < done:
@@ -1018,10 +1022,11 @@ class MSMBasicSearch:
                     # over the scored prefix, exposed on the job trace +
                     # the scheduler's `partial` field while later batches
                     # still run
-                    self._emit_partial(
-                        fdr, assignment, table, metrics,
-                        row_ranges[gi][1] if row_ranges else table.n_ions,
-                        gi)
+                    with tracing.span("partial_fdr", group=gi):
+                        self._emit_partial(
+                            fdr, assignment, table, metrics,
+                            row_ranges[gi][1] if row_ranges
+                            else table.n_ions, gi)
                 if ckpt is not None:
                     with tracing.span("checkpoint_save", group=gi):
                         ckpt.save(metrics, gi, len(groups), row_ranges)

@@ -1394,8 +1394,9 @@ class JaxBackend:
         # compaction capacities) to the stream's max so ONE executable serves
         # every batch (a mid-stream growth would recompile), and each plan
         # is reused by its dispatch
-        plans = [self._flat_plan(t) for t in tables]
-        self._grow_for_stream(plans)
+        with tracing.span("score_plan", batches=len(tables)):
+            plans = [self._flat_plan(t) for t in tables]
+            self._grow_for_stream(plans)
         pending = [self._enqueue_traced(t, plan)
                    for t, plan in zip(tables, plans)]
         with tracing.span("device_sync", batches=len(pending)):

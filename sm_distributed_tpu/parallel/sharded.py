@@ -685,8 +685,9 @@ class ShardedJaxBackend:
         tables = list(tables)
         if cancel is not None:
             cancel.check("score_batches")
-        plans = [self._flat_plan(t) for t in tables]
-        self._grow_static_shapes(plans)
+        with tracing.span("score_plan", batches=len(tables)):
+            plans = [self._flat_plan(t) for t in tables]
+            self._grow_static_shapes(plans)
         pending = []
         mesh_ids = [int(d.id) for d in self.mesh.devices.flat]
         for t, plan in zip(tables, plans):

@@ -488,6 +488,10 @@ class DeviceProfiler:
                 "trace_file": capture["xplane"],
                 **reduced,
                 "injected_spans": injected,
+                # the interpreter-wait probe over this capture: late_s /
+                # wakeups is the mean wait for the GIL (~0.1 ms idle, ~5 ms
+                # one thread hogging, more a queue)
+                "interp_probe": capture["interp_probe"],
             }
         finally:
             self._busy.release()

@@ -388,6 +388,35 @@ def process_collector(registry: "MetricsRegistry") -> None:
     registry.add_collector(collect)
 
 
+def process_cpu_collector(registry: "MetricsRegistry") -> None:
+    """Scrape-time pair: ``sm_process_cpu_seconds_total`` (``os.times()``
+    user + system of THIS process, every thread of it, children excluded)
+    and ``sm_process_clock_seconds_total`` (``time.monotonic()`` since the
+    collector was registered, read at the same instant).  The window delta
+    of the first over jobs finished is the host CPU a job costs; over the
+    delta of the second it is the cores the process kept busy (1.0 = one
+    interpreter's worth)."""
+    import os
+    import time
+
+    cpu = registry.counter(
+        "sm_process_cpu_seconds_total",
+        "User + system CPU seconds of the service process, at the scrape")
+    clock = registry.counter(
+        "sm_process_clock_seconds_total",
+        "Seconds on the monotonic clock since start-up, read with "
+        "sm_process_cpu_seconds_total")
+    t_start = time.monotonic()
+
+    def collect(_reg: "MetricsRegistry") -> None:
+        t, now = os.times(), time.monotonic()
+        # counters only move forward: both are set by their step
+        cpu.labels().inc(max(0.0, t.user + t.system - cpu.labels().value))
+        clock.labels().inc(max(0.0, now - t_start - clock.labels().value))
+
+    registry.add_collector(collect)
+
+
 class MetricsRegistry:
     """Registry: owns metric families + scrape-time collect callbacks."""
 

@@ -28,7 +28,8 @@ from ..utils.logger import add_phase_observer, logger, remove_phase_observer
 from .admission import AdmissionController
 from .api import AdminAPI
 from .device_pool import DevicePool, resolve_pool_size
-from .metrics import MetricsRegistry, build_info_collector, process_collector
+from .metrics import (MetricsRegistry, build_info_collector,
+                      process_collector, process_cpu_collector)
 from .resources import ResourceGovernor, set_governor
 from .scheduler import JobScheduler
 from .telemetry import DeviceMonitor, SLOTracker
@@ -205,12 +206,14 @@ class AnnotationService:
         # threads, FDs) the load sweep only catches in tests
         build_info_collector(self.metrics, backend=self.sm_config.backend)
         process_collector(self.metrics)
+        process_cpu_collector(self.metrics)
         if residency is not None:
             self.metrics.add_collector(self._collect_residency)
         self.metrics.add_collector(self._collect_prepare)
         self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
         self.metrics.add_collector(self._collect_scoring_jits)
+        self.metrics.add_collector(self._collect_interp_probe)
         self.api = AdminAPI(self, host=cfg.http_host,
                             port=cfg.http_port) if with_api else None
         # fleet observability plane (ISSUE 20, service/fleetview.py):
@@ -324,6 +327,27 @@ class AnnotationService:
             c = jits.labels(result=result)
             c.inc(max(0.0, n - c.value))
 
+    @staticmethod
+    def _collect_interp_probe(m: MetricsRegistry) -> None:
+        """The interpreter-wait probe's totals (``analysis/profiling.py::
+        InterpProbe``: one thread, alive only during a capture, that asks
+        for the interpreter every 10 ms).  late / wakeups over a window is
+        the mean wait for the GIL; both stand still outside a capture.
+        Pulled like the prepare sites above."""
+        from ..analysis.profiling import interp_probe_events
+
+        events = interp_probe_events()
+        wakeups = m.counter(
+            "sm_interp_probe_wakeups_total",
+            "Wakes of the interpreter-wait probe (10 ms apart, during a "
+            "profile capture only)").labels()
+        late = m.counter(
+            "sm_interp_probe_late_seconds_total",
+            "Seconds the probe's wakes came after their deadlines: its "
+            "wait for the interpreter").labels()
+        wakeups.inc(max(0.0, events["wakeups"] - wakeups.value))
+        late.inc(max(0.0, events["late_s"] - late.value))
+
     def queue_depths(self) -> dict:
         root = self.queue_dir / self.queue
         return {s: len(list(root.glob(f"{s}/*.json"))) for s in _STATES}
@@ -343,8 +367,8 @@ class AnnotationService:
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> None:
-        # additive registration (ISSUE 5 satellite): the old single-slot
-        # set_phase_observer silently evicted any other observer
+        # additive registration (ISSUE 5 satellite): a single slot would
+        # silently evict any other observer
         add_phase_observer(self._observe_phase)
         # first-annotation SLI: msm_basic notifies once per search when the
         # first checkpoint group's metrics land (producer-side observer

@@ -17,9 +17,14 @@ Model
 Two record kinds, each one JSON object (see docs/OBSERVABILITY.md for the
 schema):
 
-- ``span``:  ``{kind, trace_id, span_id, parent_id, name, ts, dur, pid,
-  tid, attrs}`` — a timed operation.  ``ts`` is epoch seconds at entry,
-  ``dur`` wall seconds.
+- ``span``:  ``{kind, trace_id, span_id, parent_id, name, ts, dur, cpu,
+  pid, tid, attrs}`` — a timed operation.  ``ts`` is epoch seconds at
+  entry, ``dur`` wall seconds, ``cpu`` the seconds the span's thread was on
+  a core between entry and exit (``time.thread_time``; absent on a span
+  emitted with explicit timing, whose body ran elsewhere).  ``dur - cpu``
+  is the time the thread was OFF a core: blocked on the chip
+  (``device_sync``, the export's fetch), on a file, on a lock, or queued
+  for the interpreter.
 - ``event``: ``{kind, trace_id, span_id, name, ts, pid, tid, attrs}`` — an
   instant attached to its owning span (``span_id`` = the span it happened
   under; both ids empty for traceless service-level events, which still
@@ -56,8 +61,16 @@ Overhead
 --------
 ``span()``/``event()`` with no ambient context and no explicit one return a
 no-op immediately — untraced hot paths (bench floors, raw backend calls)
-pay one ContextVar read.  File emission caches one append handle per path
-and writes a single flushed line per record.
+pay one ContextVar read.  A traced span costs two ``perf_counter`` and two
+``thread_time`` reads, one dict, one ``json.dumps`` and one flushed line:
+tens of microseconds, 35-45 spans a served job.  ``thread_time`` is the
+host's: 0.3 us a read and nanosecond steps where the kernel serves it from
+the vDSO; 5.9 us a read (34-48 us with eight threads reading at once) and
+steps of 10 ms on the sandboxed chip host (PERF.md section 6, PR 35), where
+a span's ``cpu`` is therefore a multiple of 10 ms: right in sums over many
+spans or jobs, and up to one step above ``dur`` on a short span.  File
+emission caches one append handle per path and writes a single flushed
+line per record.
 
 Device captures
 ---------------
@@ -384,7 +397,10 @@ def span(name: str, /, ctx: TraceContext | None = None, **attrs):
     rec["attrs"] = attrs
     mark = _capture(rec) if _capture is not None else None
     token = _CTX.set(child)
+    # the CPU clock is read INSIDE the wall clock's interval at both ends,
+    # so cpu <= dur holds up to one step of the host's thread clock
     t0 = time.perf_counter()
+    c0 = time.thread_time()
     try:
         yield child
     except BaseException as exc:
@@ -392,6 +408,7 @@ def span(name: str, /, ctx: TraceContext | None = None, **attrs):
         raise
     finally:
         _CTX.reset(token)
+        rec["cpu"] = time.thread_time() - c0
         rec["dur"] = time.perf_counter() - t0
         if mark is not None:
             mark.close()
@@ -416,7 +433,7 @@ def emit_span(ctx: TraceContext, name: str, /, ts: float = 0.0,
     """Emit a span record with explicit timing — for spans whose body ran
     elsewhere (the scheduler's attempt span measured around a join, the
     root job span closed at the terminal outcome, bench's retroactive
-    phase spans)."""
+    phase spans).  Such a record carries no ``cpu``: no one thread ran it."""
     if ctx is None or not _enabled:
         return
     rec = {
@@ -522,6 +539,9 @@ def validate_records(records: list[dict]) -> list[str]:
                             f"missing {missing}")
         if kind == "span" and not isinstance(rec.get("dur"), (int, float)):
             problems.append(f"record {i}: span dur not numeric")
+        if kind == "span" and "cpu" in rec \
+                and not isinstance(rec["cpu"], (int, float)):
+            problems.append(f"record {i}: span cpu not numeric")
         if "attrs" in rec and not isinstance(rec["attrs"], dict):
             problems.append(f"record {i}: attrs not an object")
     return problems
@@ -552,6 +572,8 @@ def to_chrome_trace(records: list[dict]) -> dict:
         if rec.get("kind") == "span":
             base["ph"] = "X"
             base["dur"] = round(float(rec.get("dur", 0.0)) * 1e6, 3)
+            if "cpu" in rec:
+                base["args"]["cpu"] = rec["cpu"]
             if rec.get("parent_id"):
                 base["args"]["parent_id"] = rec["parent_id"]
         else:

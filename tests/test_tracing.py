@@ -329,12 +329,11 @@ def test_phase_observers_multi_and_exception_safe():
         with logmod.phase_timer("p2"):
             pass
         assert seen_a == ["p1"] and len(seen_b) == 2
-        # legacy single-slot semantics still replace everything
-        logmod.set_phase_observer(obs_a)
-        assert logmod._phase_observers == [obs_a]
     finally:
-        logmod.set_phase_observer(None)
-    assert logmod._phase_observers == []
+        logmod.remove_phase_observer(obs_a)
+        logmod.remove_phase_observer(obs_b)
+    assert obs_a not in logmod._phase_observers
+    assert obs_b not in logmod._phase_observers
 
 
 def test_phase_timer_emits_span(tmp_path):
@@ -557,9 +556,14 @@ def test_build_and_store_spans_of_a_real_job(tmp_path):
 
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
     from scripts.load_sweep import Harness, _msg, build_fixtures
+    from sm_distributed_tpu.models import msm_jax
     from sm_distributed_tpu.ops import buckets
     from sm_distributed_tpu.utils.config import SMConfig
 
+    # the scoring jits are the process's (PR 34): a file that scored this
+    # geometry earlier on the worker would leave the first job no compile
+    with msm_jax._SHARED_JITS_LOCK:
+        msm_jax._SHARED_JITS.clear()
     fx = build_fixtures(tmp_path)
     h = Harness(tmp_path, "svc", {
         "backend": "jax_tpu", "storage": {"store_images": True},
