@@ -16,8 +16,9 @@ running service) into the standard perf artifact for this repo:
   spans (``parse_index``: the imzML index; ``read_ibd``: the bulk read of
   the ibd), ``prepare_resident`` (the dataset-only
   half of the build, made before the lease) with its two ``prepare_*``
-  children, ``backend_build`` with its four ``build_*`` children under
-  ``score``, the four ``store_*`` children under ``store_results``;
+  children and the road each took (``hmax``, ``occupancy``, ``sort``),
+  ``backend_build`` with its four ``build_*`` children under ``score``,
+  the four ``store_*`` children under ``store_results``;
 - the **device split**, when a ``/debug/profile`` capture overlapped the
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that
@@ -72,6 +73,9 @@ _CHILDREN = {
     "store_results": ("store_select", "store_extract_images",
                       "store_write_images", "store_tables"),
 }
+# children printed with their attrs: which road the layout took
+# (io/dataset.py: ``{hmax, occupancy: walk|search}``, ``{sort: packed}``)
+_CHILD_ATTRS = _CHILDREN["prepare_resident"]
 
 
 def load_records(args) -> list[dict]:
@@ -226,6 +230,8 @@ def summarize(records: list[dict]) -> dict:
         found = list(_spans(records, name))
         if found:
             children[name] = _agg(found)
+            if name in _CHILD_ATTRS and found[0].get("attrs"):
+                children[name]["attrs"] = found[0]["attrs"]
     device = {"scopes": {}, "busy": [], "idle": []}
     for r in _spans(records, "device_scope"):
         a = r["attrs"]
@@ -393,9 +399,11 @@ def render(s: dict) -> str:
             if c in s.get("children", {}):
                 v = s["children"][c]
                 pad = "      " if c.startswith("build_") else "    "
+                road = "".join(f"  {k}={a}" for k, a in
+                               v.get("attrs", {}).items() if k != "error")
                 lines.append(f"{pad}{c:<{26 - len(pad)}}{v['seconds']:8.3f}s "
                              f"{_pct(v['seconds'], total)}  x{v['count']:<3}"
-                             f"{_cpu(v)}")
+                             f"{_cpu(v)}{road}")
     if not ordered:
         lines.append("  (no phase spans)")
     lines.append("")

@@ -5,7 +5,7 @@ messages, so repeat jobs skip cluster spin-up [U] (SURVEY.md #16).  The
 TPU-native analog of that warm state is (a) the host-side CSR dataset
 layout (the parse: ~1 s for a 64x64 section, minutes for a large slide)
 with, cached on it, the dataset-only half of a backend build - intensity
-grid, m/z quantization and the stable m/z sort of every peak
+grid, m/z quantization and every peak in stable m/z order
 (``SpectralDataset.flat_sorted``, 12 B a peak; a job makes it BEFORE it asks
 for the chip) - and (b) the backend object: the device-resident flat peak
 arrays, whose build under the lease is what is left once (a) is there

@@ -328,11 +328,12 @@ class SearchJob:
         return ds
 
     def _prepare_resident(self, ds: SpectralDataset) -> None:
-        """The dataset-only half of the jax backend build (intensity grid,
-        m/z quantization, the stable m/z sort: ``ds.flat_sorted``), made
-        here, BEFORE the job asks for the chip, so the lease does not sit
-        idle through it; ``JaxBackend.__init__`` then finds it cached on
-        the dataset.  Only for a job that will build the single-device
+        """The dataset-only half of the jax backend build (``ds.flat_sorted``:
+        m/z quantization, the intensity grid from the walked window
+        occupancy, and m/z order by one sort of packed ``mz_q << 32 | index``
+        keys, which is the stable order), made here, BEFORE the job asks for
+        the chip, so the lease does not sit idle through it;
+        ``JaxBackend.__init__`` then finds it cached on the dataset.  Only for a job that will build the single-device
         layout: the jax backend, one chip (or no pool and a 1x1 mesh), and
         not every chip it could be granted refused by its breaker.  On a
         resident dataset it is a dict lookup."""

@@ -26,9 +26,10 @@ from .imzml import ImzMLReader
 
 class FlatSortedPeaks(NamedTuple):
     """The single-device resident layout before restriction and lattice
-    padding: every peak of the dataset in stable ascending order of its
-    quantized m/z, rounded up to 1024 slots (tail: ``MZ_PAD_Q``, the
-    overflow pixel ``n_pixels``, intensity 0).  12 B a slot."""
+    padding: every peak of the dataset in ascending order of its quantized
+    m/z, equal m/z in CSR order (the order a stable sort gives), rounded up
+    to 1024 slots (tail: ``MZ_PAD_Q``, the overflow pixel ``n_pixels``,
+    intensity 0).  12 B a slot."""
 
     mz_q: np.ndarray      # (N,) int32 ascending
     pixel: np.ndarray     # (N,) int32
@@ -42,17 +43,27 @@ class FlatSortedPeaks(NamedTuple):
 # Process-wide (scheduler workers share it); the service's metrics
 # collector pulls it as sm_backend_prepare_total{site=}.
 _FLAT_SORTED_EVENTS = {"pre_lease": 0, "under_lease": 0, "cached": 0}
+# Which road measured each dataset's window occupancy, one per intensity
+# grid computed (``ops/quantize.window_occupancy``: ``walk`` = shifted
+# compares, ``search`` = the binary search past the walk's cap); pulled
+# beside the sites as sm_prepare_occupancy_total{route=}.
+_OCCUPANCY_EVENTS = {"walk": 0, "search": 0}
 _FLAT_SORTED_EVENTS_LOCK = threading.Lock()
 
 
-def _count_flat_sorted(site: str) -> None:
+def _count(events: dict, label: str) -> None:
     with _FLAT_SORTED_EVENTS_LOCK:
-        _FLAT_SORTED_EVENTS[site] += 1
+        events[label] += 1
 
 
 def flat_sorted_events() -> dict:
     with _FLAT_SORTED_EVENTS_LOCK:
         return dict(_FLAT_SORTED_EVENTS)
+
+
+def occupancy_events() -> dict:
+    with _FLAT_SORTED_EVENTS_LOCK:
+        return dict(_OCCUPANCY_EVENTS)
 
 
 @dataclass
@@ -81,25 +92,27 @@ class SpectralDataset:
         backend, or shard count (the exact-FDR-rank requirement).  Cached
         per ppm.
         """
-        return self._intensity_quantization(ppm)
+        return self._intensity_quantization(ppm)[:2]
 
     def _pixel_of_peak(self) -> np.ndarray:
         return np.repeat(
-            np.arange(self.n_pixels, dtype=np.int64), self.row_lengths())
+            np.arange(self.n_pixels, dtype=np.int32), self.row_lengths())
 
     def _intensity_quantization(self, ppm: float, mz_q=None, pixel_of_peak=None):
-        """``mz_q`` / ``pixel_of_peak``: the caller's own
-        ``quantize_mz(mzs_flat)`` / ``_pixel_of_peak()``, so a miss does not
-        make them a second time."""
+        """(grid, scale, hmax, occupancy route), cached per ppm.  ``mz_q`` /
+        ``pixel_of_peak``: the caller's own ``quantize_mz(mzs_flat)`` /
+        ``_pixel_of_peak()``, so a miss does not make them a second time."""
         from ..ops.quantize import intensity_scale, quantize_intensities
 
         cache = self.__dict__.setdefault("_int_q_cache", {})
         if ppm not in cache:
             if pixel_of_peak is None:
                 pixel_of_peak = self._pixel_of_peak()
-            scale = intensity_scale(self.mzs_flat, self.ints_flat,
-                                    pixel_of_peak, ppm, mz_q=mz_q)
-            cache[ppm] = (quantize_intensities(self.ints_flat, scale), scale)
+            scale, hmax, route = intensity_scale(
+                self.mzs_flat, self.ints_flat, pixel_of_peak, ppm, mz_q=mz_q)
+            _count(_OCCUPANCY_EVENTS, route)
+            cache[ppm] = (quantize_intensities(self.ints_flat, scale), scale,
+                          hmax, route)
         return cache[ppm]
 
     # -- the dataset-only half of the jax backend build ------------------
@@ -110,34 +123,45 @@ class SpectralDataset:
     def flat_sorted(self, ppm: float, site: str = "under_lease") -> FlatSortedPeaks:
         """The single-device flat layout for ``ppm``: the 1-shard case of
         ``ops/imager_jax.prepare_flat_sharded_arrays`` plus the intensity
-        scale.  A function of the dataset and ``ppm`` alone, so a job
-        computes it BEFORE it asks for the chip (``site="pre_lease"``,
-        engine/search_job.py) and ``JaxBackend.__init__`` finds it here.
-        Cached per ppm for the dataset's residency, like the intensity
-        grid; a miss computes it in place, whoever asks."""
+        scale, byte for byte, made in a few linear passes and ONE sort of
+        values: the key ``mz_q << 32 | index`` orders equal m/z by index,
+        which is the stable order, and carries the permutation in its low
+        half (``n < 2**32``).  A function of the dataset and ``ppm`` alone,
+        so a job computes it BEFORE it asks for the chip
+        (``site="pre_lease"``, engine/search_job.py) and
+        ``JaxBackend.__init__`` finds it here.  Cached per ppm for the
+        dataset's residency, like the intensity grid; a miss computes it in
+        place, whoever asks."""
         from ..ops.quantize import MZ_PAD_Q, quantize_mz
 
         cache = self.__dict__.setdefault("_flat_sorted_cache", {})
         hit = cache.get(ppm)
         if hit is not None:
-            _count_flat_sorted("cached")
+            _count(_FLAT_SORTED_EVENTS, "cached")
             return hit
-        _count_flat_sorted(site)
+        _count(_FLAT_SORTED_EVENTS, site)
         with tracing.span("prepare_quantize"):
             mz_q = quantize_mz(self.mzs_flat)
             pixel = self._pixel_of_peak()
-            ints_q, scale = self._intensity_quantization(ppm, mz_q, pixel)
-        with tracing.span("prepare_sort"):
-            # the 1-shard case of prepare_flat_sharded_arrays, byte for byte
-            order = np.argsort(mz_q, kind="stable")
+            ints_q, scale, hmax, route = self._intensity_quantization(
+                ppm, mz_q, pixel)
+            tracing.annotate(hmax=hmax, occupancy=route)
+        with tracing.span("prepare_sort", sort="packed"):
             n = int(mz_q.size)
+            packed = mz_q.astype(np.int64)
+            packed <<= 32
+            packed |= np.arange(n, dtype=np.uint32)
+            packed.sort()
             n_max = -(-max(n, 1) // 1024) * 1024
-            mz_s = np.full(n_max, MZ_PAD_Q, dtype=np.int32)
-            px_s = np.full(n_max, self.n_pixels, dtype=np.int32)
-            in_s = np.zeros(n_max, dtype=np.float32)
-            mz_s[:n] = mz_q[order]
-            px_s[:n] = pixel.astype(np.int32)[order]
-            in_s[:n] = ints_q[order]
+            mz_s = np.empty(n_max, dtype=np.int32)
+            px_s = np.empty(n_max, dtype=np.int32)
+            in_s = np.empty(n_max, dtype=np.float32)
+            mz_s[n:], px_s[n:], in_s[n:] = MZ_PAD_Q, self.n_pixels, 0.0
+            np.right_shift(packed, 32, out=mz_s[:n], casting="unsafe")
+            packed &= 0xFFFFFFFF                  # the permutation
+            # every index is in range: "clip" only spares take's copy of out
+            np.take(pixel, packed, out=px_s[:n], mode="clip")
+            np.take(ints_q, packed, out=in_s[:n], mode="clip")
             for a in (mz_s, px_s, in_s):
                 a.setflags(write=False)     # shared by every backend built on it
         cache[ppm] = FlatSortedPeaks(mz_s, px_s, in_s, scale)

@@ -60,9 +60,12 @@ MZ_PAD_Q = np.int32(2**31 - 1)
 def quantize_mz(mz: np.ndarray) -> np.ndarray:
     """Host-side f64 -> int32 grid. Values beyond MZ_MAX (incl. +inf padding)
     saturate to the padding sentinel."""
-    mz = np.asarray(mz, dtype=np.float64)
-    q = np.rint(mz * MZ_SCALE)
-    return np.where(q >= MZ_PAD_Q, MZ_PAD_Q, q).astype(np.int32)
+    # one f64 buffer, three passes over it (a dataset's flat m/z array is
+    # tens of MB: every fresh temporary of that size is mapped and faulted in)
+    q = np.asarray(np.multiply(np.asarray(mz, dtype=np.float64), MZ_SCALE))
+    np.rint(q, out=q)
+    np.minimum(q, float(MZ_PAD_Q), out=q)
+    return q.astype(np.int32)
 
 
 def quantize_window(mzs: np.ndarray, ppm: float) -> tuple[np.ndarray, np.ndarray]:
@@ -90,39 +93,82 @@ def quantize_window(mzs: np.ndarray, ppm: float) -> tuple[np.ndarray, np.ndarray
 INT_SUM_BITS = 24  # f32 exact-integer range
 
 
+# Steps of the occupancy walk before ``window_occupancy`` hands over to the
+# binary search.  A step is one compare over the keys; the search and its
+# ``hi - arange`` cost 70-100 of them (PERF.md section 6, PR 36, on the chip
+# host for 4.37 M peaks: 2.6-3.5 ms a step, 0.23-0.35 s the search), so a
+# dataset that has to search after all has lost at most a third of that to
+# the walk.  Centroided sections stop at 2-6; profile-like or very dense
+# spectra take the search.  Either road gives the same integer.
+OCCUPANCY_WALK_CAP = 32
+
+
+def window_occupancy(
+    mzs_flat: np.ndarray,       # (P,) f64, m/z per peak, sorted within pixel
+    pixel_of_peak: np.ndarray,  # (P,) pixel index per peak (non-decreasing)
+    ppm: float,
+    mz_q: np.ndarray | None = None,  # quantize_mz(mzs_flat), when the caller has it
+) -> tuple[int, str]:
+    """(hmax, route): the most peaks any 2.5 x ppm window of any pixel holds
+    on the quantized m/z grid, exactly, and the road that found it.
+
+    key = pixel * 2**32 + mz_q is globally ascending and a window never
+    spans the 2**32 inter-pixel gap, so the peaks inside peak i's window
+    [key[i], key[i] + width[i]] are i .. hi[i]-1 and
+    ``hi[i] - i >= h`` iff ``key[i+h-1] <= key[i] + width[i]``.  ``walk``:
+    one shifted compare per h until no i qualifies, a linear pass each.
+    ``search``: past ``OCCUPANCY_WALK_CAP`` steps, ``hi`` by a binary search
+    of the reaches into the keys, as every dataset was measured before PR 36."""
+    n = int(mzs_flat.size)
+    if n == 0:
+        return 0, "walk"
+    if mz_q is None:
+        mz_q = quantize_mz(mzs_flat)
+    key = pixel_of_peak.astype(np.int64)
+    key <<= 32
+    key += mz_q
+    # generous window bound (2.5x ppm covers any window whose left edge is
+    # at this peak, including the center-to-edge asymmetry)
+    width = np.multiply(np.asarray(mzs_flat, np.float64), 2.5 * ppm * 1e-6)
+    width *= MZ_SCALE
+    np.ceil(width, out=width)
+    reach = width.astype(np.int64)
+    del width
+    reach += key
+    hmax = 1
+    while hmax < n and (key[hmax:] <= reach[:n - hmax]).any():
+        hmax += 1
+        if hmax > OCCUPANCY_WALK_CAP:
+            hi = np.searchsorted(key, reach, side="right")
+            hi -= np.arange(n)
+            return int(hi.max()), "search"
+    return hmax, "walk"
+
+
 def intensity_scale(
     mzs_flat: np.ndarray,      # (P,) f64, m/z per peak, sorted within pixel
     ints_flat: np.ndarray,     # (P,) intensities
     pixel_of_peak: np.ndarray,  # (P,) pixel index per peak (non-decreasing)
     ppm: float,
     mz_q: np.ndarray | None = None,  # quantize_mz(mzs_flat), when the caller has it
-) -> float:
-    """Power-of-two scale 2**k such that hmax * max(rint(i*2**k)) < 2**24,
-    where hmax bounds the peak count inside any ppm window of any pixel."""
-    if ints_flat.size == 0:
-        return 1.0
-    max_raw = float(np.max(ints_flat))
+) -> tuple[float, int, str]:
+    """(scale, hmax, route): the power-of-two scale 2**k such that
+    hmax * max(rint(i*2**k)) < 2**24, where hmax bounds the peak count
+    inside any ppm window of any pixel (``window_occupancy``, whose route
+    is handed on for the span and the counter)."""
+    hmax, route = window_occupancy(mzs_flat, pixel_of_peak, ppm, mz_q=mz_q)
+    max_raw = float(np.max(ints_flat)) if ints_flat.size else 0.0
     if max_raw <= 0:
-        return 1.0
-    # exact per-pixel sliding-window occupancy on the quantized m/z grid:
-    # key = pixel * 2**32 + mz_q is globally ascending; a window never spans
-    # the 2**32 inter-pixel gap
-    if mz_q is None:
-        mz_q = quantize_mz(mzs_flat)
-    key = pixel_of_peak.astype(np.int64) * (1 << 32) + mz_q.astype(np.int64)
-    # generous window bound (2.5x ppm covers any window whose left edge is
-    # at this peak, including the center-to-edge asymmetry)
-    width = np.ceil(np.asarray(mzs_flat, np.float64)
-                    * (2.5 * ppm * 1e-6) * MZ_SCALE).astype(np.int64)
-    hi = np.searchsorted(key, key + width, side="right")
-    hmax = int(np.max(hi - np.arange(key.size)))
+        return 1.0, hmax, route
     target = (2**INT_SUM_BITS - 1) / (max(hmax, 1) + 1) / max_raw
-    return float(2.0 ** np.floor(np.log2(target)))
+    return float(2.0 ** np.floor(np.log2(target))), hmax, route
 
 
 def quantize_intensities(ints_flat: np.ndarray, scale: float) -> np.ndarray:
     """Snap to the integer grid; values stay integer-valued float32."""
-    return np.rint(np.asarray(ints_flat, np.float64) * scale).astype(np.float32)
+    q = np.asarray(np.multiply(ints_flat, scale, dtype=np.float64))
+    np.rint(q, out=q)
+    return q.astype(np.float32)
 
 
 # -- resident-intensity compaction ---------------------------------------------

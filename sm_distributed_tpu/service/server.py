@@ -265,17 +265,23 @@ class AnnotationService:
     def _collect_prepare(m: MetricsRegistry) -> None:
         """Where the dataset-only half of a backend build ran
         (``SpectralDataset.flat_sorted``): before the job asked for the
-        chip, under its lease, or not at all (``cached``).  Pulled like the
-        residency stats above, of whose family it is."""
-        from ..io.dataset import flat_sorted_events
+        chip, under its lease, or not at all (``cached``), and which road
+        measured each dataset's window occupancy (``walk``: shifted
+        compares; ``search``: the binary search past the walk's cap).
+        Pulled like the residency stats above, of whose family it is."""
+        from ..io.dataset import flat_sorted_events, occupancy_events
 
-        prepares = m.counter(
-            "sm_backend_prepare_total",
-            "Lookups of a dataset's resident flat layout, by where a miss "
-            "was computed", ("site",))
-        for site, n in flat_sorted_events().items():
-            c = prepares.labels(site=site)
-            c.inc(max(0.0, n - c.value))
+        for name, text, label, events in (
+                ("sm_backend_prepare_total",
+                 "Lookups of a dataset's resident flat layout, by where a "
+                 "miss was computed", "site", flat_sorted_events()),
+                ("sm_prepare_occupancy_total",
+                 "Intensity grids computed, by the road that measured the "
+                 "window occupancy", "route", occupancy_events())):
+            family = m.counter(name, text, (label,))
+            for value, n in events.items():
+                c = family.labels(**{label: value})
+                c.inc(max(0.0, n - c.value))
 
     @staticmethod
     def _collect_ingest(m: MetricsRegistry) -> None:

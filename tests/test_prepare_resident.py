@@ -4,7 +4,10 @@ builder's one-shard layout byte for byte, ``SearchJob`` makes it inside
 ``pre_lease`` (span ``prepare_resident``) exactly when it will build the
 single-device ``JaxBackend``, ``JaxBackend.__init__`` finds it there
 (``backend_build {prepared: true}``), and nothing a job stores depends on
-which side of the lease computed it.
+which side of the lease computed it.  Since ISSUE 36 the layout is made in a
+few linear passes (window occupancy by shifted compares, m/z order by one
+packed-key sort): section (h) holds it to the binary search and the stable
+``argsort`` it replaced, which live on here and not in the package.
 """
 
 import hashlib
@@ -23,12 +26,24 @@ from sm_distributed_tpu.engine.stream import (
     StreamSearchJob,
     stream_root,
 )
-from sm_distributed_tpu.io.dataset import SpectralDataset, flat_sorted_events
+from sm_distributed_tpu.io.dataset import (
+    SpectralDataset,
+    flat_sorted_events,
+    occupancy_events,
+)
 from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset
 from sm_distributed_tpu.io.imzml import ImzMLReader
 from sm_distributed_tpu.models import breaker as breaker_mod
 from sm_distributed_tpu.ops.imager_jax import prepare_flat_sharded_arrays
-from sm_distributed_tpu.ops.quantize import quantize_mz
+from sm_distributed_tpu.ops.quantize import (
+    INT_SUM_BITS,
+    MZ_MAX,
+    MZ_PAD_Q,
+    MZ_SCALE,
+    OCCUPANCY_WALK_CAP,
+    intensity_scale,
+    quantize_mz,
+)
 from sm_distributed_tpu.service.device_pool import DevicePool
 from sm_distributed_tpu.utils import tracing
 from sm_distributed_tpu.utils.cancel import CancelToken, JobCancelledError
@@ -388,8 +403,8 @@ def test_served_upload_prepares_before_its_lease_and_counts_it(
         "service": {"device_pool_size": 2, "job_timeout_s": 120.0,
                     "max_attempts": 1}})
     try:
-        def count(text, site):
-            line = f'sm_backend_prepare_total{{site="{site}"}} '
+        def count(text, site, family="sm_backend_prepare_total", label="site"):
+            line = f'{family}{{{label}="{site}"}} '
             return float(text.split(line)[1].split()[0])
 
         before = h.metrics_text()
@@ -415,6 +430,11 @@ def test_served_upload_prepares_before_its_lease_and_counts_it(
     assert count(after, "pre_lease") - count(before, "pre_lease") == 1
     assert count(after, "under_lease") - count(before, "under_lease") == 0
     assert count(after, "cached") - count(before, "cached") == 2
+    # the one miss measured its window occupancy once, by the walk
+    roads = {r: count(after, r, "sm_prepare_occupancy_total", "route")
+             - count(before, r, "sm_prepare_occupancy_total", "route")
+             for r in ("walk", "search")}
+    assert roads == {"walk": 1, "search": 0}
     for records, cached in zip(traces, (False, True)):
         (prep,) = _spans(records, "prepare_resident")
         (hold,) = _spans(records, "device_hold")
@@ -422,3 +442,190 @@ def test_served_upload_prepares_before_its_lease_and_counts_it(
         assert prep["attrs"]["cached"] is cached
         assert prep["ts"] + prep["dur"] <= hold["ts"] + 1e-3
         assert len(acq["attrs"]["devices"]) == 1
+
+
+# ------- (h) the linear passes against the search and the sort they replaced
+def _searched_hmax(mzs_flat, pixel_of_peak, ppm):
+    """The parent's occupancy: every key searched into the keys."""
+    if mzs_flat.size == 0:
+        return 0
+    key = pixel_of_peak.astype(np.int64) * (1 << 32) \
+        + quantize_mz(mzs_flat).astype(np.int64)
+    width = np.ceil(np.asarray(mzs_flat, np.float64)
+                    * (2.5 * ppm * 1e-6) * MZ_SCALE).astype(np.int64)
+    hi = np.searchsorted(key, key + width, side="right")
+    return int(np.max(hi - np.arange(key.size)))
+
+
+def _searched_scale(mzs_flat, ints_flat, pixel_of_peak, ppm):
+    """The parent's ``intensity_scale``, early returns and all."""
+    if ints_flat.size == 0:
+        return 1.0
+    max_raw = float(np.max(ints_flat))
+    if max_raw <= 0:
+        return 1.0
+    hmax = _searched_hmax(mzs_flat, pixel_of_peak, ppm)
+    target = (2**INT_SUM_BITS - 1) / (max(hmax, 1) + 1) / max_raw
+    return float(2.0 ** np.floor(np.log2(target)))
+
+
+def _width_q(mz):
+    return int(np.ceil(mz * (2.5 * PPM * 1e-6) * MZ_SCALE))
+
+
+def _occupancy_case(kind, tmp_path):
+    """(dataset, hmax it must report or None, route)."""
+    rng = np.random.default_rng(36)
+
+    def grid(spectra, ncols=3):
+        coords = [(i % ncols, i // ncols) for i in range(len(spectra))]
+        return SpectralDataset.from_arrays(
+            np.array(coords), [(np.asarray(m, np.float64),
+                                np.asarray(i, np.float32))
+                               for m, i in spectra])
+
+    if kind == "fixture_9x11":
+        path, _truth = generate_synthetic_dataset(
+            tmp_path, nrows=9, ncols=11, present_fraction=0.5,
+            noise_peaks=60, seed=36)
+        return SpectralDataset.from_imzml(path), None, "walk"
+    if kind == "equal_mz":
+        # one m/z five times inside a pixel, and again in the next pixels
+        same = [250.0] * 5 + [600.0, 600.0]
+        return grid([(same, rng.uniform(1, 9, 7))] * 4
+                    + [([250.0], [3.0])]), 5, "walk"
+    if kind == "window_ends_on_a_key":
+        # the second peak sits on the last grid step the first one's window
+        # reaches (counted: side="right"), the third one step past it
+        lo = 400.0
+        edge = (int(quantize_mz(lo)) + _width_q(lo)) / MZ_SCALE
+        past = (int(quantize_mz(lo)) + _width_q(lo) + 1) / MZ_SCALE
+        assert int(quantize_mz(edge)) - int(quantize_mz(lo)) == _width_q(lo)
+        assert int(quantize_mz(past)) - int(quantize_mz(edge)) == 1
+        return grid([([lo, edge], [5.0, 6.0]), ([lo, past], [7.0, 8.0]),
+                     ([lo], [1.0])]), 2, "walk"
+    if kind == "dense_pixel":
+        # a profile-like pixel: more peaks in one window than the walk takes
+        n = OCCUPANCY_WALK_CAP + 9
+        dense = 500.0 + np.arange(n) / MZ_SCALE
+        assert _width_q(500.0) > n
+        return grid([(dense, rng.uniform(1, 9, n)),
+                     (np.sort(rng.uniform(100, 900, 30)),
+                      rng.uniform(1, 9, 30))]), n, "search"
+    if kind == "at_the_cap":
+        # the last occupancy the walk still answers itself
+        n = OCCUPANCY_WALK_CAP
+        return grid([(500.0 + np.arange(n) / MZ_SCALE,
+                      rng.uniform(1, 9, n))]), n, "walk"
+    if kind == "one_peak":
+        return grid([([321.5], [4.0])]), 1, "walk"
+    if kind == "no_peaks":
+        return grid([([], [])] * 4), 0, "walk"
+    if kind == "all_zero_intensities":
+        return grid([([100.0, 100.0002, 100.0004], [0.0, 0.0, 0.0]),
+                     ([200.0], [0.0])]), 3, "walk"
+    if kind == "saturated_mz":
+        # beyond MZ_MAX every peak sits on the padding sentinel
+        far = [MZ_MAX + 1.0, 2 * MZ_MAX, 5 * MZ_MAX]
+        ds = grid([(far, [1.0, 2.0, 3.0]), ([150.0] + far[:2], [4.0, 5.0, 6.0])])
+        assert (quantize_mz(ds.mzs_flat) == MZ_PAD_Q).sum() == 5
+        return ds, 3, "walk"
+    raise AssertionError(kind)
+
+
+@pytest.mark.parametrize("kind", [
+    "fixture_9x11", "equal_mz", "window_ends_on_a_key", "dense_pixel",
+    "at_the_cap", "one_peak", "no_peaks", "all_zero_intensities",
+    "saturated_mz"])
+def test_walked_occupancy_and_scale_are_the_searched_ones(kind, tmp_path):
+    ds, want_hmax, want_route = _occupancy_case(kind, tmp_path)
+    pixel = np.repeat(np.arange(ds.n_pixels), ds.row_lengths())
+    ref_hmax = _searched_hmax(ds.mzs_flat, pixel, PPM)
+    ref_scale = _searched_scale(ds.mzs_flat, ds.ints_flat, pixel, PPM)
+    if want_hmax is not None:
+        assert ref_hmax == want_hmax             # the case is what it says
+    for px in (pixel, pixel.astype(np.int32)):
+        for mz_q in (None, quantize_mz(ds.mzs_flat)):
+            scale, hmax, route = intensity_scale(
+                ds.mzs_flat, ds.ints_flat, px, PPM, mz_q=mz_q)
+            assert (hmax, route) == (ref_hmax, want_route)
+            assert scale == ref_scale and isinstance(scale, float)
+    # through the dataset: the layout's scale, the numpy backend's grid
+    before = occupancy_events()
+    got = ds.flat_sorted(PPM)
+    ints_q, scale = ds.intensity_quantization(PPM)
+    after = occupancy_events()
+    assert got.int_scale == scale == ref_scale
+    assert ints_q.tobytes() == np.rint(
+        ds.ints_flat.astype(np.float64) * ref_scale).astype(np.float32).tobytes()
+    other = "search" if want_route == "walk" else "walk"
+    assert after[want_route] == before[want_route] + 1     # once a dataset
+    assert after[other] == before[other]
+
+
+@pytest.mark.parametrize("pool,n_peaks", [(3, 5000), (40, 3000), (1, 1024)])
+def test_packed_order_is_the_stable_argsort_with_many_ties(pool, n_peaks):
+    rng = np.random.default_rng(pool)
+    values = np.round(rng.uniform(100.0, 900.0, pool), 4)
+    coords = [(x, y) for y in range(6) for x in range(5)]
+    cuts = np.sort(rng.integers(0, n_peaks + 1, len(coords) - 1))
+    lens = np.diff(np.concatenate([[0], cuts, [n_peaks]]))
+    spectra = [(np.sort(rng.choice(values, n)),
+                rng.uniform(1.0, 1e4, n).astype(np.float32)) for n in lens]
+    ds = SpectralDataset.from_arrays(np.array(coords), spectra)
+    mz_q = quantize_mz(ds.mzs_flat)
+    assert np.unique(mz_q).size <= pool < ds.n_peaks == n_peaks
+    order = np.argsort(mz_q, kind="stable")
+    pixel = np.repeat(np.arange(ds.n_pixels, dtype=np.int32),
+                      ds.row_lengths())
+    got = ds.flat_sorted(PPM)
+    n_max = -(-n_peaks // 1024) * 1024
+    for have, want, pad in ((got.mz_q, mz_q[order], MZ_PAD_Q),
+                            (got.pixel, pixel[order], ds.n_pixels),
+                            (got.ints_q,
+                             ds.intensity_quantization(PPM)[0][order], 0.0)):
+        assert have.shape == (n_max,) and have.dtype == want.dtype
+        assert have[:n_peaks].tobytes() == want.tobytes()
+        assert (have[n_peaks:] == pad).all()
+        assert not have.flags.writeable
+        with pytest.raises(ValueError):
+            have[0] = 0
+
+
+def test_a_miss_says_which_roads_it_took_and_the_report_prints_them(tmp_path):
+    import sys
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from scripts import trace_report
+
+    ds, _hmax, _route = _occupancy_case("equal_mz", tmp_path)
+    dense, n_dense, _route = _occupancy_case("dense_pixel", tmp_path)
+    ctx = tracing.new_trace(job_id="roads", trace_dir=tmp_path / "traces")
+    before = occupancy_events()
+    with tracing.attach(ctx):
+        for d in (ds, dense, ds):            # the third lookup is a hit
+            with tracing.span("prepare_resident", peaks=d.n_peaks,
+                              cached=d.flat_sorted_cached(PPM)):
+                d.flat_sorted(PPM, site="pre_lease")
+    tracing.close_file(ctx.file)
+    records = tracing.read_trace(ctx.file)
+    after = occupancy_events()
+    assert {r: after[r] - before[r] for r in after} == {"walk": 1, "search": 1}
+    quant, sort = (_spans(records, "prepare_quantize"),
+                   _spans(records, "prepare_sort"))
+    assert [q["attrs"] for q in quant] == [
+        {"hmax": 5, "occupancy": "walk"},
+        {"hmax": n_dense, "occupancy": "search"}]
+    assert [s["attrs"] for s in sort] == [{"sort": "packed"}] * 2
+    preps = _spans(records, "prepare_resident")
+    assert [p["attrs"]["cached"] for p in preps] == [False, False, True]
+    for q, s, p in zip(quant, sort, preps):
+        assert q["parent_id"] == s["parent_id"] == p["span_id"]
+    text = trace_report.render(trace_report.summarize(records))
+    # in the phase breakdown, under prepare_resident (the span table below
+    # it lists the two names once more, without attrs)
+    q_line, s_line = (
+        next(ln for ln in text.splitlines() if ln.strip().startswith(name))
+        for name in ("prepare_quantize", "prepare_sort"))
+    assert "x2" in q_line and "hmax=5" in q_line and "occupancy=walk" in q_line
+    assert "x2" in s_line and "sort=packed" in s_line
