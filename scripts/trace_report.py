@@ -18,7 +18,13 @@ running service) into the standard perf artifact for this repo:
   half of the build, made before the lease) with its two ``prepare_*``
   children and the road each took (``hmax``, ``occupancy``, ``sort``),
   ``backend_build`` with its four ``build_*`` children under ``score``,
-  the four ``store_*`` children under ``store_results``;
+  the four ``store_*`` children under ``store_results``; what a table of
+  the job's size cost: ``isotope_prefetch_setup {formulas, ions, cache}``
+  with ``decoy_selection`` and ``pattern_cache_load {shards, entries,
+  bytes}``, ``isotope_patterns {ions, cached, computed, gen_s}``,
+  ``presize`` / ``score_plan {batches, executables, band_buckets,
+  variants}``, ``fdr {ions, targets, decoys}``, ``store_tables {rows,
+  bytes}``;
 - the **device split**, when a ``/debug/profile`` capture overlapped the
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that
@@ -57,25 +63,32 @@ from sm_distributed_tpu.utils import tracing  # noqa: E402
 
 # phases in pipeline order (anything else traced as a phase appends after)
 _PHASE_ORDER = ("stage_input", "read_dataset", "prepare_resident",
-                "decoy_selection", "isotope_patterns", "score", "fdr",
-                "store_results")
+                "isotope_prefetch_setup", "decoy_selection",
+                "isotope_patterns", "score", "fdr", "store_results")
 # listed among the phases though phase_timer does not emit it: the job's
 # last host-only step before it asks for the chip (engine/search_job.py)
-_STEPS = ("prepare_resident",)
+_STEPS = ("prepare_resident", "isotope_prefetch_setup")
 _TOP_BATCHES = 10
 # the spans that split the two phases a job spends most of its lease in
 # (models/msm_basic.py + models/msm_jax.py, engine/search_job.py)
 _CHILDREN = {
     "read_dataset": ("parse_index", "read_ibd"),
     "prepare_resident": ("prepare_quantize", "prepare_sort"),
+    "isotope_prefetch_setup": ("decoy_selection", "pattern_cache_load"),
     "score": ("backend_build", "build_sort", "build_restrict",
-              "build_pad_compact", "build_device_put"),
+              "build_pad_compact", "build_device_put", "presize",
+              "score_plan"),
     "store_results": ("store_select", "store_extract_images",
                       "store_write_images", "store_tables"),
 }
 # children printed with their attrs: which road the layout took
-# (io/dataset.py: ``{hmax, occupancy: walk|search}``, ``{sort: packed}``)
-_CHILD_ATTRS = _CHILDREN["prepare_resident"]
+# (io/dataset.py: ``{hmax, occupancy: walk|search}``, ``{sort: packed}``),
+# and what a table of the job's size cost (models/msm_basic.py, ops/
+# isocalc.py, models/msm_jax.py, engine/storage.py: the cache read back,
+# the batch plans and the executables they mint, the tables' rows)
+_CHILD_ATTRS = _CHILDREN["prepare_resident"] + (
+    "decoy_selection", "pattern_cache_load", "presize", "score_plan",
+    "store_tables")
 
 
 def load_records(args) -> list[dict]:
@@ -203,7 +216,13 @@ def summarize(records: list[dict]) -> dict:
     for r in _spans(records):
         if (r.get("attrs") or {}).get("phase") or r["name"] in _STEPS:
             by_phase.setdefault(r["name"], []).append(r)
-    phases = {name: _agg(found) for name, found in by_phase.items()}
+    phases = {}
+    for name, found in by_phase.items():
+        phases[name] = _agg(found)
+        attrs = {k: v for k, v in (found[0].get("attrs") or {}).items()
+                 if k != "phase"}
+        if attrs:
+            phases[name]["attrs"] = attrs
     attempts = sorted(_spans(records, "attempt"), key=lambda r: r["ts"])
     # queue wait: submit start -> first attempt start (requeues/retries put
     # later attempts' wait inside the root too, reported via attempts[])
@@ -230,8 +249,13 @@ def summarize(records: list[dict]) -> dict:
         found = list(_spans(records, name))
         if found:
             children[name] = _agg(found)
-            if name in _CHILD_ATTRS and found[0].get("attrs"):
-                children[name]["attrs"] = found[0]["attrs"]
+            if name in _CHILD_ATTRS:
+                # the first's; of a job's score_plans (one a group) the one
+                # that planned the most batches
+                said = max(found, key=lambda r: (r.get("attrs") or {}).get(
+                    "batches", 0))
+                if said.get("attrs"):
+                    children[name]["attrs"] = said["attrs"]
     device = {"scopes": {}, "busy": [], "idle": []}
     for r in _spans(records, "device_scope"):
         a = r["attrs"]
@@ -376,6 +400,11 @@ def _cpu(v: dict) -> str:
             f"off {max(0.0, v['seconds'] - v['cpu_s']):8.3f}s")
 
 
+def _road(v: dict) -> str:
+    return "".join(f"  {k}={a}" for k, a in v.get("attrs", {}).items()
+                   if k != "error")
+
+
 def render(s: dict) -> str:
     lines = []
     head = f"trace {s['trace_id']}"
@@ -394,16 +423,15 @@ def render(s: dict) -> str:
     for p in ordered:
         v = s["phases"][p]
         lines.append(f"  {p:<22} {v['seconds']:9.3f}s "
-                     f"{_pct(v['seconds'], total)}  x{v['count']:<3}{_cpu(v)}")
+                     f"{_pct(v['seconds'], total)}  x{v['count']:<3}{_cpu(v)}"
+                     + _road(v))
         for c in _CHILDREN.get(p, ()):
             if c in s.get("children", {}):
                 v = s["children"][c]
                 pad = "      " if c.startswith("build_") else "    "
-                road = "".join(f"  {k}={a}" for k, a in
-                               v.get("attrs", {}).items() if k != "error")
                 lines.append(f"{pad}{c:<{26 - len(pad)}}{v['seconds']:8.3f}s "
                              f"{_pct(v['seconds'], total)}  x{v['count']:<3}"
-                             f"{_cpu(v)}{road}")
+                             f"{_cpu(v)}{_road(v)}")
     if not ordered:
         lines.append("  (no phase spans)")
     lines.append("")

@@ -354,6 +354,10 @@ class SearchResultsStore:
             tmp = d / (name + ".tmp")
             df.to_parquet(tmp, index=False)
             tmps.append((tmp, d / name))
+        # onto the caller's store_tables span
+        tracing.annotate(
+            rows=len(bundle.annotations) + len(bundle.all_metrics),
+            bytes=sum(tmp.stat().st_size for tmp, _dst in tmps))
         tmp_t = d / "timings.json.tmp"
         tmp_t.write_text(json.dumps(bundle.timings, indent=2))
         tmps.append((tmp_t, d / "timings.json"))

@@ -279,15 +279,25 @@ class IsotopePrefetch:
             seed=fdr_cfg.seed,
         )
         t0 = time.perf_counter()
-        self.assignment = self.fdr.decoy_adduct_selection(self.formulas)
-        self.pairs, self.flags = self.assignment.all_ion_tuples(
-            self.formulas, iso_cfg.adducts)
+        with tracing.span("decoy_selection", formulas=len(self.formulas),
+                          decoys=fdr_cfg.decoy_sample_size):
+            self.assignment = self.fdr.decoy_adduct_selection(self.formulas)
+            self.pairs, self.flags = self.assignment.all_ion_tuples(
+                self.formulas, iso_cfg.adducts)
         self.timings["decoy_selection"] = time.perf_counter() - t0
         # wrapper construction loads the cache shards (warm: seconds at
-        # 1.68M ions) — deliberately inside this thread too
+        # 1.68M ions; span pattern_cache_load) — deliberately inside this
+        # thread too
         self.isocalc = make_isocalc(
             self.ds_config, self.sm_config, self.cache_dir)
         self.stream = self.isocalc.stream_table(self.pairs, self.flags)
+        # onto isotope_prefetch_setup: were all, none or some of the
+        # table's patterns in the shards
+        missing, ions = self.stream.n_missing, self.stream.n_ions
+        tracing.annotate(
+            formulas=len(self.formulas), ions=ions,
+            cache="warm" if not missing else
+            "cold" if missing == ions else "partial")
 
     def result(self):
         """(fdr, assignment, stream) — blocks on setup only."""
@@ -844,6 +854,11 @@ class MSMBasicSearch:
                 table = stream.table_view()   # rows fill in as chunks land
             else:
                 table = stream.result_table()
+                # what the wrapper's last_stats say of this generation
+                tracing.annotate(
+                    ions=table.n_ions, computed=stream.cold_patterns,
+                    cached=table.n_ions - stream.cold_patterns,
+                    gen_s=round(stream.gen_seconds, 3))
                 # m/z-localized batch unions (see maybe_order_table):
                 # per-ion results are order-independent, so this only
                 # changes which extraction variant each batch's plan picks
@@ -851,10 +866,11 @@ class MSMBasicSearch:
                     table, self.sm_config.parallel.order_ions,
                     self.sm_config.parallel.formula_batch)
         self.last_table = table
+        n_targets = int(table.targets.sum())
         logger.info(
             "scoring %d ions (%d targets, %d decoys) with backend=%s%s",
-            table.n_ions, int(table.targets.sum()),
-            int((~table.targets).sum()), self.sm_config.backend,
+            table.n_ions, n_targets, table.n_ions - n_targets,
+            self.sm_config.backend,
             " (overlapping isocalc)" if overlap else "",
         )
         # OOM memory (ISSUE 10): a previous job on this (dataset shape,
@@ -1048,6 +1064,8 @@ class MSMBasicSearch:
         if self.cancel is not None:
             self.cancel.check("fdr")
         with phase_timer("fdr", timings):
+            tracing.annotate(ions=table.n_ions, targets=n_targets,
+                             decoys=table.n_ions - n_targets)
             all_df = pd.DataFrame(
                 {
                     "sf": table.sfs,

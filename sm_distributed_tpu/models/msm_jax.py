@@ -13,7 +13,7 @@ The graph compiles ONCE per dataset: formula batches are padded to the static
 from __future__ import annotations
 
 import threading
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from functools import partial, update_wrapper
 
 import jax
@@ -1213,7 +1213,30 @@ class JaxBackend:
         wider window-chunk span would otherwise grow gc_width mid-search
         and recompile.  The orchestrator calls
         this once with every slice before the group loop."""
-        self._grow_for_stream([self._flat_plan(t) for t in tables])
+        plans = [self._flat_plan(t) for t in tables]
+        self._grow_for_stream(plans)
+        tracing.annotate(**self._plan_census(plans))   # onto span presize
+
+    def _plan_kind(self, plan) -> tuple[str, int, int]:
+        """(variant, static batch, band bucket) of one planned batch under
+        the capacities in effect: what tells its executable from another's
+        (each band w_cap bucket is its own executable; the other statics
+        are sticky per static batch)."""
+        b_eff = plan[8]
+        gc_eff = self._gc_width if b_eff == self.batch else self._gc_tail
+        variant = self._maybe_fuse(
+            self._variant_for(plan[7], plan[9]),
+            plan[5][1].shape[1], gc_eff, plan[3].shape[1])
+        bucket = self._band_bucket(plan[9][1]) if variant == "band" else 0
+        return variant, b_eff, bucket
+
+    def _plan_census(self, plans) -> dict:
+        """What a planned stream mints, as span attrs: distinct executables,
+        distinct band buckets, batches per extraction variant."""
+        kinds = [self._plan_kind(plan) for plan in plans]
+        return {"executables": len(set(kinds)),
+                "band_buckets": len({w for v, _b, w in kinds if v == "band"}),
+                "variants": dict(Counter(v for v, _b, _w in kinds))}
 
     def _grow_for_stream(self, plans) -> None:
         """Grow the sticky capacities over ``plans`` to a FIXPOINT.
@@ -1262,15 +1285,7 @@ class JaxBackend:
         self._grow_for_stream(plans)
         reps, seen = [], set()
         for t, plan in zip(tables, plans):
-            b_eff = plan[8]
-            gc_eff = self._gc_width if b_eff == self.batch else self._gc_tail
-            variant = self._maybe_fuse(
-                self._variant_for(plan[7], plan[9]),
-                plan[5][1].shape[1], gc_eff, t.max_peaks)
-            # each band w_cap bucket is its own executable
-            bucket = (self._band_bucket(plan[9][1])
-                      if variant == "band" else 0)
-            kind = (variant, b_eff, bucket)
+            kind = self._plan_kind(plan)
             if kind not in seen:
                 seen.add(kind)
                 reps.append((t, plan))
@@ -1397,6 +1412,7 @@ class JaxBackend:
         with tracing.span("score_plan", batches=len(tables)):
             plans = [self._flat_plan(t) for t in tables]
             self._grow_for_stream(plans)
+            tracing.annotate(**self._plan_census(plans))
         pending = [self._enqueue_traced(t, plan)
                    for t, plan in zip(tables, plans)]
         with tracing.span("device_sync", batches=len(pending)):

@@ -463,6 +463,9 @@ def _compute_chunk_body(ci, pairs, params, device):
 _metrics_lock = threading.Lock()
 _metrics_registry = None
 _patterns_total = 0
+_entries_loaded_total = 0
+_ENTRIES_LOADED = ("sm_isocalc_cache_entries_loaded_total",
+                   "Isotope patterns read back from cache shards")
 
 
 def attach_metrics(registry) -> None:
@@ -472,16 +475,29 @@ def attach_metrics(registry) -> None:
     with _metrics_lock:
         _metrics_registry = registry
         total = _patterns_total
+        loaded = _entries_loaded_total
     c = registry.counter("sm_isocalc_patterns_total",
                          "Isotope patterns computed (cold, not cache hits)")
     if total:
         c.inc(total)
+    c = registry.counter(*_ENTRIES_LOADED)
+    if loaded:
+        c.inc(loaded)
 
 
 def patterns_total() -> int:
     """Monotone count of cold-computed patterns (service rate collector)."""
     with _metrics_lock:
         return _patterns_total
+
+
+def _count_entries_loaded(n: int) -> None:
+    global _entries_loaded_total
+    with _metrics_lock:
+        _entries_loaded_total += n
+        reg = _metrics_registry
+    if reg is not None and n:
+        reg.counter(*_ENTRIES_LOADED).inc(n)
 
 
 def _count_patterns(n: int, workers: int, rate: float) -> None:
@@ -891,26 +907,38 @@ class IsocalcWrapper:
         if self.cache_dir is not None:
             self.cache_dir.mkdir(parents=True, exist_ok=True)
             self._sweep_stale_tmps()
-            for path in self._shard_paths():
-                # tolerate (a) a concurrent compactor unlinking a shard
-                # between the glob and the load, (b) a corrupt/truncated
-                # shard from a crashed writer — skip it; entries recompute
-                try:
-                    failpoint(FP_ISO_SHARD_LOAD, path=path)
-                    self._cache.update(self._load_shard(path))
-                except (zipfile.BadZipFile, ValueError, KeyError) as e:
-                    # definitively corrupt (bad zip / bad checksum / bad
-                    # members): recompute AND unlink, so the poison file
-                    # does not outlive its entries
-                    record_recovery("isocalc.corrupt_shard")
-                    logger.warning(
-                        "removing corrupt isocalc shard %s: %s", path, e)
-                    path.unlink(missing_ok=True)
-                except (FileNotFoundError, OSError) as e:
-                    # possibly-transient read error: skip but KEEP the file
-                    record_recovery("isocalc.unreadable_shard")
-                    logger.warning(
-                        "skipping unreadable isocalc shard %s: %s", path, e)
+            # the whole parameter set is read back, whatever table the
+            # job asks for
+            with tracing.span("pattern_cache_load"):
+                shards = entries = nbytes = 0
+                for path in self._shard_paths():
+                    # tolerate (a) a concurrent compactor unlinking a shard
+                    # between the glob and the load, (b) a corrupt/truncated
+                    # shard from a crashed writer — skip it; entries recompute
+                    try:
+                        failpoint(FP_ISO_SHARD_LOAD, path=path)
+                        size = path.stat().st_size
+                        loaded = self._load_shard(path)
+                    except (zipfile.BadZipFile, ValueError, KeyError) as e:
+                        # definitively corrupt (bad zip / bad checksum / bad
+                        # members): recompute AND unlink, so the poison file
+                        # does not outlive its entries
+                        record_recovery("isocalc.corrupt_shard")
+                        logger.warning(
+                            "removing corrupt isocalc shard %s: %s", path, e)
+                        path.unlink(missing_ok=True)
+                    except (FileNotFoundError, OSError) as e:
+                        # possibly-transient read error: skip but KEEP the file
+                        record_recovery("isocalc.unreadable_shard")
+                        logger.warning(
+                            "skipping unreadable isocalc shard %s: %s", path, e)
+                    else:
+                        self._cache.update(loaded)
+                        shards += 1
+                        entries += len(loaded)
+                        nbytes += size
+                _count_entries_loaded(entries)
+                tracing.annotate(shards=shards, entries=entries, bytes=nbytes)
 
     def _sweep_stale_tmps(self, max_age_s: float = 3600.0) -> None:
         """Remove orphaned tmp files a crashed writer left behind (age-gated
