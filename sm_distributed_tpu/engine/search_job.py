@@ -145,7 +145,8 @@ class SearchJob:
                 if self.sm_config.parallel.overlap_isocalc != "off":
                     prefetch = IsotopePrefetch(
                         formulas, self.ds_config, self.sm_config,
-                        str(Path(self.sm_config.work_dir) / "isocalc_cache"))
+                        str(Path(self.sm_config.work_dir) / "isocalc_cache"),
+                        residency=self.residency)
                 ds = self._prepare_dataset(timings)
                 logger.info(
                     "dataset %s: %dx%d px, %d spectra, %d peaks",

@@ -27,6 +27,7 @@ from ..ops.isocalc import (
     ISOCALC_PATTERN_VERSION,
     IsocalcWrapper,
     IsotopePatternTable,
+    resolve_device_blur,
 )
 from ..utils import tracing
 from ..utils.cancel import JobCancelledError
@@ -208,6 +209,15 @@ def make_backend(name: str, ds: SpectralDataset, ds_config: DSConfig,
     raise ValueError(f"unknown backend {name!r}")
 
 
+def isocalc_device_blur(sm_config: SMConfig) -> bool:
+    """The oracle-or-device mode the engine's wrappers run in."""
+    # "on" forces the device stage; "off" leaves the decision to the
+    # SM_ISOCALC_DEVICE env (None), so ad-hoc probes can opt in without
+    # a config edit
+    return resolve_device_blur(
+        True if sm_config.parallel.isocalc_device == "on" else None)
+
+
 def make_isocalc(ds_config: DSConfig, sm_config: SMConfig,
                  cache_dir: str | None) -> IsocalcWrapper:
     """IsocalcWrapper wired to the engine's parallel.* isocalc knobs."""
@@ -216,12 +226,70 @@ def make_isocalc(ds_config: DSConfig, sm_config: SMConfig,
         ds_config.isotope_generation,
         cache_dir=cache_dir,
         n_procs=par.isocalc_workers or None,
-        # "on" forces the device stage; "off" leaves the decision to the
-        # SM_ISOCALC_DEVICE env (None), so ad-hoc probes can opt in without
-        # a config edit
-        device_blur=True if par.isocalc_device == "on" else None,
+        device_blur=isocalc_device_blur(sm_config),
         chunk_size=par.isocalc_chunk,
     )
+
+
+@dataclass(frozen=True)
+class ResidentIonTable:
+    """A finished ion table as ``engine/residency.DatasetResidency`` keeps it
+    across jobs of one parameter set: what ``MSMBasicSearch.search()`` needs
+    of an ``IsotopePrefetch`` and nothing else.  The table's arrays are
+    read-only (two workers may score it at once); ``device_blur`` is the
+    mode its patterns were made in, which the pairs fingerprint hashes."""
+
+    fdr: FDR
+    assignment: DecoyAssignment
+    table: IsotopePatternTable
+    device_blur: bool
+
+
+def ion_table_key(formulas: list[str], ds_config: DSConfig,
+                  sm_config: SMConfig) -> tuple:
+    """Content identity of the ion table a job scores: the de-duplicated
+    formula list in order, and everything the decoy draw (target adducts,
+    ``decoy_sample_size``, ``seed``) and the patterns (charge, sigma,
+    pts_per_mz, n_peaks, oracle-or-device mode, pattern version) are a
+    function of.  Nothing of the dataset: every upload against one
+    database shares the entry."""
+    iso = ds_config.isotope_generation
+    fdr = sm_config.fdr
+    return ("ion_table",
+            hashlib.sha256("\x00".join(formulas).encode()).hexdigest(),
+            len(formulas), tuple(iso.adducts), iso.charge, iso.isocalc_sigma,
+            iso.isocalc_pts_per_mz, iso.n_peaks,
+            fdr.decoy_sample_size, fdr.seed,
+            isocalc_device_blur(sm_config),
+            ISOCALC_PATTERN_VERSION)
+
+
+def _table_bytes(table: IsotopePatternTable) -> int:
+    return int(table.mzs.nbytes + table.ints.nbytes + table.n_valid.nbytes
+               + table.targets.nbytes)
+
+
+class ResidentStream:
+    """The consumer side of a ``PatternStream`` over a table that is whole
+    already (a residency hit): nothing to wait for, to cancel or to count."""
+
+    gen_seconds = 0.0
+    cold_patterns = 0
+
+    def __init__(self, table: IsotopePatternTable):
+        self._table = table
+        self.n_ions = table.n_ions
+
+    def wait_rows(self, n: int, timeout: float | None = None) -> int:
+        return self.n_ions
+
+    def table_view(self) -> IsotopePatternTable:
+        return self._table
+
+    result_table = table_view
+
+    def cancel(self) -> None:
+        pass
 
 
 class IsotopePrefetch:
@@ -229,7 +297,12 @@ class IsotopePrefetch:
     layer 3).  SearchJob starts this BEFORE staging/parsing the input, so
     the dominant cold-path cost — pattern generation — overlaps the input
     pipeline instead of following it.  Everything here depends only on the
-    formula list and configs, never on the dataset.
+    formula list and configs, never on the dataset - so with a ``residency``
+    the finished table of an earlier job of the same parameter set is asked
+    for first (``ion_table_key``).  A hit starts no thread and builds no
+    wrapper and no stream: ``result()`` returns at once, ``isocalc`` stays
+    None.  A miss runs as without a residency, and ``keep()`` offers the
+    table once its stream is whole.
 
     ``result()`` joins the setup thread (decoy sampling + cache-shard load +
     stream start — the generation itself keeps running inside the returned
@@ -238,19 +311,25 @@ class IsotopePrefetch:
     """
 
     def __init__(self, formulas: list[str], ds_config: DSConfig,
-                 sm_config: SMConfig, cache_dir: str | None):
+                 sm_config: SMConfig, cache_dir: str | None,
+                 residency=None):
         import threading
 
         self.formulas = list(dict.fromkeys(formulas))
         self.ds_config = ds_config
         self.sm_config = sm_config
         self.cache_dir = cache_dir
+        self.residency = residency
         self.timings: dict[str, float] = {}
         self.fdr: FDR | None = None
         self.assignment: DecoyAssignment | None = None
         self.isocalc: IsocalcWrapper | None = None
+        self.device_blur = isocalc_device_blur(sm_config)
         self.stream = None
         self._error: BaseException | None = None
+        self._thread = None
+        if residency is not None and self._take_resident():
+            return
         # thread hop: capture the caller's (SearchJob attempt) trace context
         # so prefetch setup + the generation stream trace into the job
         self._trace = tracing.current()
@@ -258,9 +337,46 @@ class IsotopePrefetch:
             target=self._run, name="isotope-prefetch", daemon=True)
         self._thread.start()
 
-    def _run(self) -> None:
+    def _take_resident(self) -> bool:
+        """Ask the residency for this parameter set's table.  On a hit this
+        is the job's whole ``isotope_prefetch_setup``: the span is emitted
+        here with explicit timing, since only a hit has it on this thread
+        (a miss opens it around ``_setup``, on the prefetch thread)."""
         import time
 
+        ts, t0, c0 = time.time(), time.perf_counter(), time.thread_time()
+        self._key = ion_table_key(self.formulas, self.ds_config,
+                                  self.sm_config)
+        entry = self.residency.ion_table(self._key)
+        if entry is None:
+            return False
+        self.fdr, self.assignment = entry.fdr, entry.assignment
+        self.device_blur = entry.device_blur
+        self.stream = ResidentStream(entry.table)
+        ctx = tracing.current()
+        if ctx is not None:
+            tracing.emit_span(
+                ctx, "isotope_prefetch_setup", ts=ts,
+                cpu=time.thread_time() - c0,
+                dur=time.perf_counter() - t0, parent_id=ctx.span_id,
+                formulas=len(self.formulas), ions=entry.table.n_ions,
+                cache="resident", table_bytes=_table_bytes(entry.table))
+        return True
+
+    def keep(self) -> None:
+        """Offer a miss's table to the residency - only once its stream is
+        whole: a failed, cancelled or partial table is never kept.  From
+        here on the arrays are read-only, for this job's scoring too."""
+        if (self.residency is None or self._thread is None
+                or not self.stream.complete()):
+            return
+        table = self.stream.table_view()
+        for arr in (table.mzs, table.ints, table.n_valid, table.targets):
+            arr.flags.writeable = False
+        self.residency.keep_ion_table(self._key, ResidentIonTable(
+            self.fdr, self.assignment, table, self.device_blur))
+
+    def _run(self) -> None:
         try:
             with tracing.attach(self._trace), \
                     tracing.span("isotope_prefetch_setup"):
@@ -297,17 +413,20 @@ class IsotopePrefetch:
         tracing.annotate(
             formulas=len(self.formulas), ions=ions,
             cache="warm" if not missing else
-            "cold" if missing == ions else "partial")
+            "cold" if missing == ions else "partial",
+            table_bytes=_table_bytes(self.stream.table_view()))
 
     def result(self):
         """(fdr, assignment, stream) — blocks on setup only."""
-        self._thread.join()
+        if self._thread is not None:
+            self._thread.join()
         if self._error is not None:
             raise self._error
         return self.fdr, self.assignment, self.stream
 
     def cancel(self) -> None:
-        self._thread.join()
+        if self._thread is not None:
+            self._thread.join()
         if self.stream is not None:
             self.stream.cancel()
 
@@ -452,8 +571,10 @@ class MSMBasicSearch:
         # scores through the pjit-sharded sub-mesh; None = all devices
         self.device_indices = (tuple(int(i) for i in device_indices)
                                if device_indices else None)
-        self.isocalc = None if prefetch is not None else make_isocalc(
-            ds_config, self.sm_config, isocalc_cache_dir)
+        self.isocalc_cache_dir = isocalc_cache_dir
+        # the prefetch's wrapper, once search() has joined it: None on an
+        # ion-table residency hit, which builds none
+        self.isocalc: IsocalcWrapper | None = None
         # populated by search(); the orchestrator reads these to persist ion
         # images / m/z values for annotated ions (engine/search_job.py) —
         # last_backend lets the jax path export DEVICE images instead of
@@ -538,7 +659,7 @@ class MSMBasicSearch:
         h.update("\x00".join(table.adducts).encode())
         h.update(repr((iso.charge, iso.isocalc_sigma, iso.isocalc_pts_per_mz,
                        iso.n_peaks, ISOCALC_PATTERN_VERSION,
-                       bool(self.isocalc.device_blur))).encode())
+                       self._device_blur)).encode())
         return h.hexdigest()
 
     def _agree_resume_point(self, done: int) -> int:
@@ -813,34 +934,29 @@ class MSMBasicSearch:
                 annotations=pd.DataFrame(columns=self._ANN_COLUMNS),
                 all_metrics=pd.DataFrame(columns=self._ALL_COLUMNS),
             )
-        iso_cfg = self.ds_config.isotope_generation
-        if self.prefetch is not None:
-            # SearchJob started decoys + generation before staging; by the
-            # time search() runs, the stream has been computing all along
-            with tracing.span("prefetch_join"):
-                fdr, assignment, stream = self.prefetch.result()
-            self.isocalc = self.prefetch.isocalc
-            timings.update(self.prefetch.timings)
-        else:
-            fdr = FDR(
-                decoy_sample_size=self.sm_config.fdr.decoy_sample_size,
-                target_adducts=iso_cfg.adducts,
-                seed=self.sm_config.fdr.seed,
-            )
-            with phase_timer("decoy_selection", timings):
-                assignment: DecoyAssignment = fdr.decoy_adduct_selection(
-                    self.formulas)
-                pairs, flags = assignment.all_ion_tuples(
-                    self.formulas, iso_cfg.adducts)
-            stream = self.isocalc.stream_table(pairs, flags)
+        # SearchJob started decoys + generation before staging: by the time
+        # search() runs, the stream has been computing all along.  Without
+        # one (overlap_isocalc "off", direct callers) the same prefetch is
+        # made here and joined at once: one way to get a table, and one
+        # lookup of the resident one in front of it
+        prefetch = self.prefetch or IsotopePrefetch(
+            self.formulas, self.ds_config, self.sm_config,
+            self.isocalc_cache_dir, residency=self.backend_cache)
+        with tracing.span("prefetch_join"):
+            fdr, assignment, stream = prefetch.result()
+        self.isocalc = prefetch.isocalc
+        self._device_blur = prefetch.device_blur
+        timings.update(prefetch.timings)
         try:
-            return self._score_and_rank(stream, fdr, assignment, timings)
+            return self._score_and_rank(stream, fdr, assignment, timings,
+                                        keep=prefetch.keep)
         except BaseException:
             stream.cancel()
             raise
 
     def _score_and_rank(self, stream, fdr: FDR, assignment: DecoyAssignment,
-                        timings: dict[str, float]) -> SearchResultsBundle:
+                        timings: dict[str, float],
+                        keep) -> SearchResultsBundle:
         # Overlapped scoring (ISSUE 3 layer 3): with the host backend, the
         # leading checkpoint groups score as soon as their pattern rows are
         # published — generation and scoring run concurrently.  The device
@@ -854,6 +970,7 @@ class MSMBasicSearch:
                 table = stream.table_view()   # rows fill in as chunks land
             else:
                 table = stream.result_table()
+                keep()       # whole now: the next job's residency hit
                 # what the wrapper's last_stats say of this generation
                 tracing.annotate(
                     ions=table.n_ions, computed=stream.cold_patterns,
@@ -1060,6 +1177,7 @@ class MSMBasicSearch:
                 # join generation (shard commits/compaction may trail the
                 # last row) and surface any late stream error before FDR
                 stream.result_table()
+                keep()
         timings["isocalc_gen"] = stream.gen_seconds
         if self.cancel is not None:
             self.cancel.check("fdr")

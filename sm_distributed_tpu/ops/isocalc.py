@@ -651,6 +651,14 @@ class PatternStream:
                 raise self._error
         return self.table_view()
 
+    def complete(self) -> bool:
+        """Every row holds its pattern and generation ended without error:
+        false while it runs, after a failure, and after a ``cancel()`` that
+        cut it short (which ends the driver thread cleanly, rows missing)."""
+        with self._cond:
+            return (self._done and self._error is None
+                    and self._ready_rows == self.n_ions)
+
     def cancel(self) -> None:
         """Abort generation (job failed upstream): stop submitting chunks,
         drop pending work, join the driver thread."""
@@ -845,6 +853,15 @@ class PatternStream:
             commit_ready()
 
 
+def resolve_device_blur(device_blur: bool | None) -> bool:
+    """The oracle-or-device mode a wrapper built with ``device_blur`` runs
+    in (None: env ``SM_ISOCALC_DEVICE`` decides).  Apart from the wrapper
+    so that a key over the mode needs no wrapper (``msm_basic.ion_table_key``)."""
+    if device_blur is None:
+        return os.environ.get("SM_ISOCALC_DEVICE", "") not in ("", "0")
+    return bool(device_blur)
+
+
 class IsocalcWrapper:
     """Same responsibility & knobs as the reference class of the same name [U].
 
@@ -888,15 +905,11 @@ class IsocalcWrapper:
         device_blur: bool | None = None,
         chunk_size: int = 0,
     ):
-        import os
-
         self.cfg = cfg
         self.cache_dir = Path(cache_dir) if cache_dir else None
         self.n_procs = n_procs
         self.chunk_size = chunk_size
-        if device_blur is None:
-            device_blur = os.environ.get("SM_ISOCALC_DEVICE", "") not in ("", "0")
-        self.device_blur = bool(device_blur)
+        self.device_blur = resolve_device_blur(device_blur)
         self._device = None
         self._lock = threading.RLock()
         self._cache: dict[str, tuple[np.ndarray, np.ndarray]] = {}

@@ -254,7 +254,7 @@ class AnnotationService:
                          "Residency cache hits", ("cache",))
         misses = m.counter("sm_residency_misses_total",
                            "Residency cache misses", ("cache",))
-        for cache in ("dataset", "backend"):
+        for cache in ("dataset", "backend", "ion_table"):
             h = hits.labels(cache=cache)
             miss = misses.labels(cache=cache)
             # counters only move forward; set via delta from the live stats

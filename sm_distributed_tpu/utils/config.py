@@ -176,8 +176,8 @@ class ParallelConfig:
     # still computing.  "off" restores strictly serial phases.
     overlap_isocalc: str = "auto"
     # daemon service mode: how many datasets' parsed layouts + compiled
-    # backends stay resident across queue messages (LRU; 0 disables) —
-    # engine/residency.py
+    # backends + finished ion tables stay resident across queue messages
+    # (LRU; 0 disables) — engine/residency.py
     resident_datasets: int = 2
     # shape-bucket lattice (ISSUE 13, ops/buckets.py): "auto"/"on" snap
     # dataset-dependent shapes (pixel rows, resident peak slots, pad-to

@@ -429,11 +429,14 @@ def annotate(**attrs) -> None:
 def emit_span(ctx: TraceContext, name: str, /, ts: float = 0.0,
               dur: float = 0.0,
               span_id: str | None = None, parent_id: str = "",
-              **attrs) -> None:
+              cpu: float | None = None, **attrs) -> None:
     """Emit a span record with explicit timing — for spans whose body ran
     elsewhere (the scheduler's attempt span measured around a join, the
     root job span closed at the terminal outcome, bench's retroactive
-    phase spans).  Such a record carries no ``cpu``: no one thread ran it."""
+    phase spans).  Such a record carries no ``cpu``: no one thread ran it.
+    A caller that did run the body on its own thread, and learns only at
+    its end that it was a span (a residency hit's
+    ``isotope_prefetch_setup``), passes the ``thread_time`` it measured."""
     if ctx is None or not _enabled:
         return
     rec = {
@@ -450,6 +453,8 @@ def emit_span(ctx: TraceContext, name: str, /, ts: float = 0.0,
         rec["process"] = _process_id
     if _host:
         rec["host"] = _host
+    if cpu is not None:
+        rec["cpu"] = cpu
     if attrs:
         rec["attrs"] = attrs
     _emit(rec, ctx.file)

@@ -637,11 +637,13 @@ def test_daemon_residency_second_job_skips_prepare_and_compile(fixture_path, tmp
     consumer.run(max_messages=1)
     t1 = json.loads((tmp_path / "res" / "warm" / "timings.json").read_text())
     assert residency.stats == {"dataset_hits": 0, "dataset_misses": 1,
-                               "backend_hits": 0, "backend_misses": 1}
+                               "backend_hits": 0, "backend_misses": 1,
+                               "ion_table_hits": 0, "ion_table_misses": 1}
     consumer.run(max_messages=1)
     t2 = json.loads((tmp_path / "res" / "warm" / "timings.json").read_text())
     assert residency.stats == {"dataset_hits": 1, "dataset_misses": 1,
-                               "backend_hits": 1, "backend_misses": 1}
+                               "backend_hits": 1, "backend_misses": 1,
+                               "ion_table_hits": 1, "ion_table_misses": 1}
     # warm job: no parse — the phase is a cache lookup (generous absolute
     # bound; the substantive reuse proof is the stats assert above)
     assert t1["read_dataset"] > t2["read_dataset"]

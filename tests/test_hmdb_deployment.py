@@ -3,7 +3,9 @@ in-process service with ``benchmarks/configs/maldi-section-64-hmdb.json``'s
 own ``sm_config`` and ``ds_config`` and a table of 400 formulas x 21 = 8,400
 ions in 33 batches of 256.  One job makes the section resident and computes
 the table's patterns cold; two resubmits under the same ``ds_id`` (the
-cell's traffic) read them back from the isocalc cache.  Every stored report
+cell's traffic) score the table it left resident (ISSUE 40; the read-back
+from the isocalc cache, a new process's first job, is held by
+``test_ion_table_residency.py``).  Every stored report
 is compared with the benchmark's plain reference (``benchmarks/oracle.py``,
 numpy/scipy, nothing of the program) and the resubmits are bit-identical to
 the first.  The same jobs' traces and ``/metrics`` hold what the deployment
@@ -32,6 +34,7 @@ import jobtrace  # noqa: E402
 import oracle  # noqa: E402
 from serve import metric_sum  # noqa: E402  (benchmarks/serve.py)
 from scripts.load_sweep import Harness  # noqa: E402
+from sm_distributed_tpu.utils.config import DSConfig  # noqa: E402
 
 CONFIGS = REPO / "benchmarks" / "configs"
 HMDB = json.loads((CONFIGS / "maldi-section-64-hmdb.json").read_text())
@@ -172,16 +175,25 @@ def test_the_traces_say_what_the_table_cost(served):
     for i, msg_id in enumerate(IDS):
         rec = served["traces"][msg_id]
         setup = _one(rec, "isotope_prefetch_setup")
-        assert setup["attrs"] == {"formulas": 400, "ions": N_IONS,
-                                  "cache": "cold" if i == 0 else "warm"}
-        decoys = _one(rec, "decoy_selection")
-        assert decoys["attrs"] == {"formulas": 400, "decoys": 20}
-        load = _one(rec, "pattern_cache_load")
-        assert decoys["parent_id"] == load["parent_id"] == setup["span_id"]
-        # the whole cache is read back on every job that finds one
-        assert load["attrs"]["entries"] == (0 if i == 0 else N_IONS)
-        assert (load["attrs"]["shards"] > 0) is (i > 0)
-        assert (load["attrs"]["bytes"] > 0) is (i > 0)
+        n_peaks = DSConfig.from_dict(
+            SMALL["ds_config"]).isotope_generation.n_peaks
+        assert setup["attrs"] == {
+            "formulas": 400, "ions": N_IONS,
+            "cache": "cold" if i == 0 else "resident",
+            # two f64 blocks, an i32 and a bool an ion
+            "table_bytes": N_IONS * (16 * n_peaks + 5)}
+        if i == 0:
+            decoys = _one(rec, "decoy_selection")
+            assert decoys["attrs"] == {"formulas": 400, "decoys": 20}
+            load = _one(rec, "pattern_cache_load")
+            assert (decoys["parent_id"] == load["parent_id"]
+                    == setup["span_id"])
+            assert load["attrs"] == {"shards": 0, "entries": 0, "bytes": 0}
+        else:
+            # a resubmit scores the table the first job left resident: no
+            # decoy draw, no wrapper, no shard read back
+            for gone in ("decoy_selection", "pattern_cache_load"):
+                assert not jobtrace.spans(rec, gone), gone
         patterns = _one(rec, "isotope_patterns")["attrs"]
         assert patterns["phase"] is True and patterns["ions"] == N_IONS
         assert (patterns["computed"], patterns["cached"]) == (
@@ -211,15 +223,17 @@ def test_the_traces_say_what_the_table_cost(served):
         assert tables["rows"] == N_IONS + 400 and tables["bytes"] > 0
 
 
-def test_metrics_count_the_cold_table_once_and_its_reload_every_job(served):
-    def deltas(name):
-        vals = [metric_sum(s, name) for s in served["scrapes"]]
+def test_metrics_count_the_cold_table_once_and_a_hit_every_resubmit(served):
+    def deltas(name, label=""):
+        vals = [metric_sum(s, name, label) for s in served["scrapes"]]
         assert None not in vals, name    # exposed before the first job
         return [b - a for a, b in zip(vals, vals[1:])]
 
     assert deltas("sm_isocalc_patterns_total") == [N_IONS, 0, 0]
-    assert deltas("sm_isocalc_cache_entries_loaded_total") == [
-        0, N_IONS, N_IONS]
+    assert deltas("sm_isocalc_cache_entries_loaded_total") == [0, 0, 0]
+    ion_table = 'cache="ion_table"'
+    assert deltas("sm_residency_misses_total", ion_table) == [1, 0, 0]
+    assert deltas("sm_residency_hits_total", ion_table) == [0, 1, 1]
 
 
 def _reader(name):
@@ -265,13 +279,16 @@ def test_the_three_readers_read_those_jobs(served):
 def test_trace_report_prints_the_new_attrs(served):
     from scripts import trace_report
 
-    text = trace_report.render(
-        trace_report.summarize(served["traces"][IDS[1]]))
-    for want in ("cache=warm", f"entries={N_IONS}", "decoys=20",
-                 f"cached={N_IONS}", "computed=0", "executables=",
-                 "band_buckets=", "variants=", "targets=400",
-                 f"rows={N_IONS + 400}"):
-        assert want in text, (want, text)
+    wants = {IDS[0]: ("cache=cold", "entries=0", "decoys=20", "cached=0",
+                      f"computed={N_IONS}"),
+             IDS[1]: ("cache=resident", "table_bytes=", f"cached={N_IONS}",
+                      "computed=0")}
+    for msg_id, said in wants.items():
+        text = trace_report.render(
+            trace_report.summarize(served["traces"][msg_id]))
+        for want in said + ("executables=", "band_buckets=", "variants=",
+                            "targets=400", f"rows={N_IONS + 400}"):
+            assert want in text, (msg_id, want, text)
 
 
 def test_one_batch_and_33_batches_score_the_table_alike(section, tmp_path):

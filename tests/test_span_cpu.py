@@ -103,11 +103,18 @@ def test_phase_timer_span_has_cpu(tmp_path):
     assert rec["attrs"]["phase"] is True and 0.01 <= rec["cpu"] <= rec["dur"]
 
 
-def test_emit_span_writes_no_cpu(tmp_path):
+@pytest.mark.parametrize("cpu", [None, 0.25])
+def test_emit_span_writes_no_cpu_but_the_callers_own(tmp_path, cpu):
+    """``cpu``: a body the caller ran on its own thread and timed itself (a
+    residency hit's ``isotope_prefetch_setup``)."""
     ctx = tracing.new_trace(trace_dir=tmp_path)
-    tracing.emit_span(ctx, "attempt", ts=time.time(), dur=1.5, attempt=1)
+    more = {} if cpu is None else {"cpu": cpu}
+    tracing.emit_span(ctx, "attempt", ts=time.time(), dur=1.5, attempt=1,
+                      **more)
     (rec,) = tracing.read_trace(ctx.file)
-    assert rec["dur"] == 1.5 and "cpu" not in rec
+    assert rec["dur"] == 1.5 and rec.get("cpu") == cpu
+    assert ("cpu" in rec) is (cpu is not None)
+    assert rec["attrs"] == {"attempt": 1}
     assert not tracing.validate_records([rec])
 
 
