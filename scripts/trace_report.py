@@ -23,8 +23,9 @@ running service) into the standard perf artifact for this repo:
   with ``decoy_selection`` and ``pattern_cache_load {shards, entries,
   bytes}``, ``isotope_patterns {ions, cached, computed, gen_s}``,
   ``presize`` / ``score_plan {batches, executables, band_buckets,
-  variants}``, ``fdr {ions, targets, decoys}``, ``store_tables {rows,
-  bytes}``;
+  variants, slots, peaks}`` (the last two: the capacity slots the planned
+  extractions are handed and the peaks really inside them),
+  ``fdr {ions, targets, decoys}``, ``store_tables {rows, bytes}``;
 - the **device split**, when a ``/debug/profile`` capture overlapped the
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that

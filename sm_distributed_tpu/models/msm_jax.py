@@ -648,6 +648,27 @@ def chaos_image_events() -> dict:
         return dict(_CHAOS_IMAGES)
 
 
+# What extraction was handed, per variant, counted beside the chaos images:
+# {variant: [capacity slots dispatched, peaks really inside them]}.  Slots
+# less peaks is what the band floor, the band ladder and the sticky compact
+# capacity pad; the service pulls it at scrape as
+# sm_extract_slots_total{variant=} / sm_extract_peaks_total{variant=}.
+_EXTRACT_LOAD: dict[str, list[int]] = {}
+_EXTRACT_LOAD_LOCK = threading.Lock()
+
+
+def _count_extract_load(variant: str, slots: int, peaks: int) -> None:
+    with _EXTRACT_LOAD_LOCK:
+        load = _EXTRACT_LOAD.setdefault(variant, [0, 0])
+        load[0] += slots
+        load[1] += peaks
+
+
+def extract_load_events() -> dict:
+    with _EXTRACT_LOAD_LOCK:
+        return {v: tuple(load) for v, load in _EXTRACT_LOAD.items()}
+
+
 class JaxBackend:
     """Fused-graph scorer selected by ``SMConfig.backend == 'jax_tpu'``."""
 
@@ -906,6 +927,8 @@ class JaxBackend:
         with per-slot constants (scatter ~14 ns/slot, packed-run gather
         ~23 ns/slot -> compact ~37 ns per capacity slot) that predate this
         round's chip and are UNVERIFIED on it (PERF.md design notes);
+        PERF.md section 6, PR 41, has the chip's readings of each forced
+        side at 128x128 px x 84,000 ions, where the two estimates meet;
         'on' modes force a variant for tests, band first.
 
         The compact estimate charges the sticky ``_n_keep`` capacity, so
@@ -1076,9 +1099,27 @@ class JaxBackend:
             spec["cube_dtype"] = self._cube_dtype
         return spec
 
+    def _extract_load(self, variant: str, plan) -> tuple[int, int]:
+        """(capacity slots, peaks inside them) of one planned batch's
+        extraction under the capacities in effect: the band's ``w_cap``
+        over its width, the sticky ``_n_keep`` over the window-union runs,
+        or every resident slot over whichever of the two the plan knows."""
+        runs, band = plan[7], plan[9]
+        n = int(self._mz_host.size)
+        if variant == "band":
+            return min(self._band_bucket(band[1]), n), band[1]
+        if variant == "compact":
+            return self._n_keep, runs[2]
+        if runs is not None:
+            return n, runs[2]
+        return n, band[1] if band is not None else n
+
     def _dispatch(self, table: IsotopePatternTable, flat_plan=None):
         """Async: enqueue one padded batch on device, return (device_out, n)."""
+        if flat_plan is None:
+            flat_plan = self._flat_plan(table)
         variant, args, statics = self._flat_call(table, flat_plan)
+        _count_extract_load(variant, *self._extract_load(variant, flat_plan))
         # lands on the ambient score_batch span: which extraction
         # variant THIS batch ran (chip_smoke.py prints it per batch)
         tracing.event("batch_variant", variant=variant,
@@ -1232,11 +1273,17 @@ class JaxBackend:
 
     def _plan_census(self, plans) -> dict:
         """What a planned stream mints, as span attrs: distinct executables,
-        distinct band buckets, batches per extraction variant."""
+        distinct band buckets, batches per extraction variant, and the
+        capacity slots its extractions will be handed over the peaks inside
+        them (``_extract_load``, what ``_dispatch`` counts a batch)."""
         kinds = [self._plan_kind(plan) for plan in plans]
+        loads = [self._extract_load(kind[0], plan)
+                 for kind, plan in zip(kinds, plans)]
         return {"executables": len(set(kinds)),
                 "band_buckets": len({w for v, _b, w in kinds if v == "band"}),
-                "variants": dict(Counter(v for v, _b, _w in kinds))}
+                "variants": dict(Counter(v for v, _b, _w in kinds)),
+                "slots": sum(s for s, _p in loads),
+                "peaks": sum(p for _s, p in loads)}
 
     def _grow_for_stream(self, plans) -> None:
         """Grow the sticky capacities over ``plans`` to a FIXPOINT.

@@ -212,6 +212,7 @@ class AnnotationService:
         self.metrics.add_collector(self._collect_prepare)
         self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
+        self.metrics.add_collector(self._collect_extract_load)
         self.metrics.add_collector(self._collect_scoring_jits)
         self.metrics.add_collector(self._collect_interp_probe)
         self.api = AdminAPI(self, host=cfg.http_host,
@@ -316,6 +317,28 @@ class AnnotationService:
         for (route, ib), n in (mod.chaos_image_events() if mod else {}).items():
             c = images.labels(route=route, images_per_program=str(ib))
             c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_extract_load(m: MetricsRegistry) -> None:
+        """Capacity slots handed to extraction and the peaks really inside
+        them, by extraction variant (``models/msm_jax.py::
+        extract_load_events``, counted where a batch is enqueued): their
+        ratio is what the band floor, the band ladder and the sticky
+        compact capacity pad.  Pulled like the chaos images above."""
+        slots = m.counter(
+            "sm_extract_slots_total",
+            "Resident-peak capacity slots dispatched to extraction (band "
+            "w_cap, sticky compact capacity, or every resident slot)",
+            ("variant",))
+        peaks = m.counter(
+            "sm_extract_peaks_total",
+            "Peaks inside the dispatched batches' bands or window-union "
+            "runs", ("variant",))
+        mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
+        for variant, load in (mod.extract_load_events() if mod else {}).items():
+            for family, n in zip((slots, peaks), load):
+                c = family.labels(variant=variant)
+                c.inc(max(0.0, n - c.value))
 
     @staticmethod
     def _collect_scoring_jits(m: MetricsRegistry) -> None:
