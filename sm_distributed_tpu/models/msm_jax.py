@@ -37,6 +37,7 @@ from ..ops.imager_jax import (
     flat_bound_ranks,
     ion_window_chunks,
     ions_per_chunk_for,
+    window_chunks,
     window_rank_grid,
 )
 from ..ops.isocalc import IsotopePatternTable
@@ -187,10 +188,12 @@ def fused_score_fn_flat_banded(
     the batch, so large batches amortize the histogram scatter — see
     ops/imager_jax.py::extract_images_flat_banded) + MSM metrics.
 
-    The chunk plan is ION-MAJOR (ion_window_chunks): extraction emits the
-    (b, k, P) block directly — no multi-GB image-row gather; ``inv`` is
-    the (b,) ion inverse permutation applied to the (b, 4) METRIC rows,
-    and theor_ints / n_valid arrive already ion-sorted.
+    The chunk plan is WINDOW-MAJOR (window_chunks): the windows
+    themselves are sorted by m/z, so a chunk's band stays near 2 x 512
+    rows whatever the table's ions per Da, and ``inv`` is the (b*k,) row
+    inverse extraction gathers the image block back to the table's order
+    by: theor_ints / n_valid arrive, and the metric rows leave, in that
+    order.
 
     Shape-bucket lattice (ISSUE 13): ``nrows`` is the ROW-BUCKETED grid
     (ops/buckets.row_bucket) and the resident peak arrays are padded to a
@@ -206,16 +209,14 @@ def fused_score_fn_flat_banded(
     with jax.named_scope("sm_extract"):
         int_sorted = expand_cube_jnp(int_sorted)
         imgs = extract_images_flat_banded(
-            pixel_sorted, int_sorted, pos, starts, r_lo_loc, r_hi_loc, None,
+            pixel_sorted, int_sorted, pos, starts, r_lo_loc, r_hi_loc, inv,
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    out = batch_metrics(
+    return batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    with jax.named_scope("sm_epilogue"):
-        return jnp.take(out, inv, axis=0)
 
 
 def fused_score_fn_flat_fused(
@@ -225,7 +226,7 @@ def fused_score_fn_flat_fused(
     starts: jnp.ndarray,       # (C,) chunk grid offsets
     r_lo_loc: jnp.ndarray,     # (C, Wc)
     r_hi_loc: jnp.ndarray,     # (C, Wc)
-    inv: jnp.ndarray,          # (B*K,)
+    inv: jnp.ndarray,          # (B,) ion inverse permutation
     theor_ints: jnp.ndarray,
     n_valid: jnp.ndarray,
     n_real=None,               # () i32 traced: REAL pixel count (lattice)
@@ -246,9 +247,10 @@ def fused_score_fn_flat_fused(
     principal rows (chaos needs their spatial layout) are written back.
 
     Same argument layout and statics as ``fused_score_fn_flat_banded``
-    (the 'plain' variant) — the routing in ``JaxBackend._flat_call`` just
-    swaps the jit.  Metric rows come back in the plan's chunk-sorted ion
-    order and ``inv`` un-permutes them, exactly like the other variants.
+    (the 'plain' variant), but the chunk plan is ION-MAJOR
+    (ion_window_chunks): the kernel reduces an ion's K windows together,
+    so they share a chunk; theor_ints / n_valid arrive ion-sorted, metric
+    rows come back in that order and ``inv`` (b,) un-permutes them.
 
     Numerics: principal images / chaos / spectral / vmax / nn are
     bit-exact vs the plain variant (exact integer-grid sums in any
@@ -299,13 +301,11 @@ def _extract_sliced(
     starts, r_lo_loc, r_hi_loc, inv, *, w_cap, gc_width, n_pixels,
 ):
     """Band slice + banded extraction (the first half of
-    fused_score_fn_flat_banded_sliced) as a standalone probe phase.
-    ``inv`` (the ion un-permutation) is unused here — probe consumers work
-    in the plan's ion-sorted order with matching permuted side inputs."""
+    fused_score_fn_flat_banded_sliced) as a standalone probe phase."""
     px_b = jax.lax.dynamic_slice(pixel_sorted, (w_start,), (w_cap,))
     in_b = jax.lax.dynamic_slice(int_sorted, (w_start,), (w_cap,))
     return extract_images_flat_banded(
-        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, inv,
         gc_width=gc_width, n_pixels=n_pixels)
 
 
@@ -350,16 +350,14 @@ def fused_score_fn_flat_banded_sliced(
         px_b = jax.lax.dynamic_slice(pixel_sorted, (w_start,), (w_cap,))
         in_b = jax.lax.dynamic_slice(int_sorted, (w_start,), (w_cap,))
         imgs = extract_images_flat_banded(
-            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, inv,
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    out = batch_metrics(
+    return batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    with jax.named_scope("sm_epilogue"):
-        return jnp.take(out, inv, axis=0)
 
 
 def _extract_compact(
@@ -367,13 +365,12 @@ def _extract_compact(
     starts, r_lo_loc, r_hi_loc, inv, *, n_keep, gc_width, n_pixels,
 ):
     """Compaction + banded extraction (the first half of
-    fused_score_fn_flat_banded_compact) as a standalone probe phase.
-    ``inv`` unused — see _extract_sliced."""
+    fused_score_fn_flat_banded_compact) as a standalone probe phase."""
     px_b, in_b = compact_peaks(
         pixel_sorted, int_sorted, run_pos, run_delta, n_b,
         n_keep=n_keep, n_pixels=n_pixels)
     return extract_images_flat_banded(
-        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+        px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, inv,
         gc_width=gc_width, n_pixels=n_pixels)
 
 
@@ -415,16 +412,14 @@ def fused_score_fn_flat_banded_compact(
             pixel_sorted, int_sorted, run_pos, run_delta, n_b,
             n_keep=n_keep, n_pixels=nrows * ncols)
         imgs = extract_images_flat_banded(
-            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, None,
+            px_b, in_b, pos_b, starts, r_lo_loc, r_hi_loc, inv,
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    out = batch_metrics(
+    return batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-    with jax.named_scope("sm_epilogue"):
-        return jnp.take(out, inv, axis=0)
 
 
 # One row per extraction variant so the dispatch/probe sites cannot drift:
@@ -831,6 +826,8 @@ class JaxBackend:
         # serves (almost) all batches instead of recompiling per batch
         self._gc_width = 0
         self._gc_tail = 0         # band width of the small-batch variant
+        self._gf_width = 0        # ... of the fused kernel's ion-major plan
+        self._gf_tail = 0
         self._n_keep = 0          # compacted peak capacity
         self._r_pad = 0           # compaction run-list capacity
         self._compaction = sm_config.parallel.peak_compaction
@@ -892,13 +889,16 @@ class JaxBackend:
         shapes, then reuses them)."""
         b_eff = self._batch_for(table.n_ions)
         grid, r_lo, r_hi, ints_p, nv_p = self._padded_windows(table, b_eff)
-        # ion-major plan: whole ions per chunk (largest divisor of the
-        # static batch within the BAND_WINDOWS budget), so extraction
-        # emits (b, k, P) directly and only metric rows get un-permuted
+        # window-major plan: the windows themselves sorted by m/z, so a
+        # chunk's band spans its own 512 neighbours at any table density
+        chunks = window_chunks(r_lo, r_hi, _BAND_WINDOWS)
+        # the fused kernel alone reads ION-MAJOR chunks (whole ions per
+        # chunk: largest divisor of the static batch within BAND_WINDOWS)
         k_eff = max(1, table.max_peaks)
-        chunks = ion_window_chunks(
+        ion_chunks = ion_window_chunks(
             r_lo, r_hi, b_eff, k_eff,
-            ions_per_chunk_for(b_eff, k_eff, _BAND_WINDOWS))
+            ions_per_chunk_for(b_eff, k_eff, _BAND_WINDOWS)
+        ) if self._may_fuse() else None
         pos = flat_bound_ranks(self._mz_host, grid)
         runs, band = None, None
         if self._compaction != "off" or self._band_mode != "off":
@@ -908,7 +908,7 @@ class JaxBackend:
             if self._band_mode != "off":
                 band = batch_peak_band(self._mz_host, lo_q, hi_q)
         return (grid, r_lo, r_hi, ints_p, nv_p, chunks, pos, runs, b_eff,
-                band)
+                band, ion_chunks)
 
     # band-slice w_cap buckets: the shared {1, 1.5} x pow-2 ladder
     # (ops/imager_jax.band_bucket — the sharded backend uses the same one)
@@ -958,16 +958,44 @@ class JaxBackend:
                 est["band"] = 14.0 * cap
         return min(est, key=est.get)
 
-    def _maybe_fuse(self, variant: str, wc: int, gc_eff: int, k: int) -> str:
+    def _band_widths(self, b_eff: int) -> tuple[int, int]:
+        """Sticky band widths of a static batch: (the window-major plan's,
+        the fused kernel's ion-major plan's).  The tail executable keeps
+        its own: sharing the full-size band would blow the small batch's
+        matmul cost."""
+        if b_eff == self.batch:
+            return self._gc_width, self._gf_width
+        return self._gc_tail, self._gf_tail
+
+    def _grow_band_widths(self, plan) -> None:
+        gc = plan[5][4]
+        gf = plan[10][4] if plan[10] is not None else 0
+        if plan[8] == self.batch:
+            self._gc_width = max(self._gc_width, gc)
+            self._gf_width = max(self._gf_width, gf)
+        else:
+            self._gc_tail = max(self._gc_tail, gc)
+            self._gf_tail = max(self._gf_tail, gf)
+
+    def _may_fuse(self) -> bool:
+        """Whether any batch of this backend can route to the fused kernel
+        (``_maybe_fuse``): only then does a plan hold ion-major chunks.
+        Hotspot preprocessing needs materialized images, so it excludes
+        fusion entirely; 'auto' fuses on a real TPU only."""
+        if self._fused_mode == "off" or self._common["do_preprocessing"]:
+            return False
+        return self._fused_mode == "on" or jax.default_backend() == "tpu"
+
+    def _maybe_fuse(self, variant: str, plan) -> str:
         """Fused-kernel routing (ISSUE 18).  'on' forces the fused variant
         from ANY cost-model choice (tests/sentinel: interpret mode on CPU);
         'auto' upgrades only the plain variant — band/compact reshape the
         resident cube before scatter, which the fused kernel's unblocked
-        band staging does not model — and only on a real TPU where the
-        (wc, cols_p, pt) plan fits the kernel's VMEM budget (fused_fit).
-        Hotspot preprocessing needs materialized images, so it excludes
-        fusion entirely."""
-        if self._fused_mode == "off" or self._common["do_preprocessing"]:
+        band staging does not model — and only where the (wc, cols_p, pt)
+        shape of the plan's ion-major chunks fits the kernel's VMEM budget
+        (fused_fit)."""
+        ion_chunks = plan[10]
+        if ion_chunks is None:
             return variant
         if self._fused_mode == "on":
             if jax.default_backend() == "cpu" and not self._interpret_warned:
@@ -977,8 +1005,10 @@ class JaxBackend:
                     "runs the Pallas kernel in INTERPRET mode — a test "
                     "vehicle, orders of magnitude slower than any device")
             return "fused"
-        if (variant == "plain" and jax.default_backend() == "tpu"
-                and fused_fit(wc, wc // max(k, 1), self._n_pix_b, gc_eff)):
+        wc, k = ion_chunks[1].shape[1], plan[3].shape[1]
+        if (variant == "plain"
+                and fused_fit(wc, wc // max(k, 1), self._n_pix_b,
+                              self._band_widths(plan[8])[1])):
             return "fused"
         return variant
 
@@ -1012,22 +1042,20 @@ class JaxBackend:
         if flat_plan is None:
             flat_plan = self._flat_plan(table)
         (_grid, _r_lo, _r_hi, ints_p, nv_p, chunks, pos, runs,
-         b_eff, band) = flat_plan
-        starts, r_lo_loc, r_hi_loc, inv, gc_width, order = chunks
-        # per-ion side inputs follow the plan's ion sort; the fused fn
-        # un-permutes the metric rows with ``inv``
-        ints_p = ints_p[order]
-        nv_p = nv_p[order]
-        # the tail executable keeps its own sticky band width: sharing
-        # the full-size band would blow the small batch's matmul cost
-        if b_eff == self.batch:
-            self._gc_width = max(self._gc_width, gc_width)
-            gc_eff = self._gc_width
+         b_eff, band, ion_chunks) = flat_plan
+        self._grow_band_widths(flat_plan)
+        variant = self._maybe_fuse(self._variant_for(runs, band), flat_plan)
+        if variant == "fused":
+            # per-ion side inputs follow the plan's ion sort; the fused fn
+            # un-permutes the metric rows with ``inv``
+            starts, r_lo_loc, r_hi_loc, inv, _gf, order = ion_chunks
+            ints_p, nv_p = ints_p[order], nv_p[order]
+            gc_eff = self._band_widths(b_eff)[1]
         else:
-            self._gc_tail = max(self._gc_tail, gc_width)
-            gc_eff = self._gc_tail
-        variant = self._maybe_fuse(
-            self._variant_for(runs, band), r_lo_loc.shape[1], gc_eff, k)
+            # extraction gathers the image rows back with ``inv``: side
+            # inputs and metric rows stay in the table's order
+            starts, r_lo_loc, r_hi_loc, inv, _gc = chunks
+            gc_eff = self._band_widths(b_eff)[0]
         # explicit async device_put: the transfers overlap device compute
         # of previously enqueued batches instead of blocking dispatch
         if variant == "band":
@@ -1091,6 +1119,7 @@ class JaxBackend:
             "w_cap": int(statics.get("w_cap", 0)),
             "g": int(args[pos_ix].shape[0]),
             "c": int(rlo.shape[0]), "wc": int(rlo.shape[1]),
+            "w": int(args[pos_ix + 4].shape[0]),
             "devices": 1,
         }
         # recorded only when compacted: legacy f32 spec strings (and the
@@ -1151,10 +1180,12 @@ class JaxBackend:
         ext_fn = jax.jit(named_partial(
             ext_base, n_pixels=self._n_pix_b, **ext_statics))
         # extraction args = everything before (theor_ints, n_valid[,
-        # n_real]); the trailing ``inv`` is the ION un-permutation consumed
-        # by the fused fn's metric output, not by extraction — probes keep
-        # the plan's ion-sorted order (side inputs below permuted to match)
-        ext_args = list(args[: n_ext - 1]) + [None]
+        # n_real]), the trailing ``inv`` its row gather.  The fused
+        # variant's is the ION un-permutation of the metric rows instead:
+        # its probes keep the plan's ion-sorted order (the side inputs
+        # below are permuted to match)
+        ext_args = list(args[: n_ext - 1]) + [
+            None if variant == "fused" else args[n_ext - 1]]
         phases["extract"] = lambda: ext_fn(
             self._px_s, in_probe, *ext_args)
         # the metric probes run on the PRODUCTION image block: the padded
@@ -1264,18 +1295,17 @@ class JaxBackend:
         (each band w_cap bucket is its own executable; the other statics
         are sticky per static batch)."""
         b_eff = plan[8]
-        gc_eff = self._gc_width if b_eff == self.batch else self._gc_tail
         variant = self._maybe_fuse(
-            self._variant_for(plan[7], plan[9]),
-            plan[5][1].shape[1], gc_eff, plan[3].shape[1])
+            self._variant_for(plan[7], plan[9]), plan)
         bucket = self._band_bucket(plan[9][1]) if variant == "band" else 0
         return variant, b_eff, bucket
 
     def _plan_census(self, plans) -> dict:
         """What a planned stream mints, as span attrs: distinct executables,
-        distinct band buckets, batches per extraction variant, and the
+        distinct band buckets, batches per extraction variant, the
         capacity slots its extractions will be handed over the peaks inside
-        them (``_extract_load``, what ``_dispatch`` counts a batch)."""
+        them (``_extract_load``, what ``_dispatch`` counts a batch), and
+        the widest band a batch's membership products run over."""
         kinds = [self._plan_kind(plan) for plan in plans]
         loads = [self._extract_load(kind[0], plan)
                  for kind, plan in zip(kinds, plans)]
@@ -1283,7 +1313,10 @@ class JaxBackend:
                 "band_buckets": len({w for v, _b, w in kinds if v == "band"}),
                 "variants": dict(Counter(v for v, _b, _w in kinds)),
                 "slots": sum(s for s, _p in loads),
-                "peaks": sum(p for _s, p in loads)}
+                "peaks": sum(p for _s, p in loads),
+                "gc_width": max(
+                    (self._band_widths(b)[v == "fused"]
+                     for v, b, _w in kinds), default=0)}
 
     def _grow_for_stream(self, plans) -> None:
         """Grow the sticky capacities over ``plans`` to a FIXPOINT.
@@ -1305,10 +1338,7 @@ class JaxBackend:
                 return
 
     def _grow_from_plan(self, plan) -> None:
-        if plan[8] == self.batch:
-            self._gc_width = max(self._gc_width, plan[5][4])
-        else:
-            self._gc_tail = max(self._gc_tail, plan[5][4])
+        self._grow_band_widths(plan)
         if self._variant_for(plan[7], plan[9]) == "compact":
             self._grow_compact_capacity(plan[7])
 
@@ -1367,7 +1397,8 @@ class JaxBackend:
         dev = jax.devices()[0]
         blob = repr((
             sorted(kinds),
-            (self._gc_width, self._gc_tail, self._n_keep, self._r_pad),
+            (self._gc_width, self._gc_tail, self._gf_width, self._gf_tail,
+             self._n_keep, self._r_pad),
             (self._nrows_b, self.ds.ncols, int(self._mz_host.size),
              self.batch, bool(self._buckets)),
             (self.ds_config.image_generation.nlevels,

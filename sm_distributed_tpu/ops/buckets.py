@@ -170,7 +170,9 @@ _SPEC_KEYS = (
     # so pre-existing manifest keys stay stable within a kind:
     "mesh_pix", "mesh_form",  # mesh axis sizes (pixels x formulas)
     "p_loc",              # per-shard pixel capacity (whole bucketed rows)
-    "w",                  # total window count (the inv permutation length)
+    # both kinds: the rows ``inv`` permutes (the total window count; the
+    # flat fused variant's ion-major plan permutes the b ions)
+    "w",
     # recorded only when parallel.cube_dtype != "f32", so f32 spec keys
     # stay byte-stable:
     "cube_dtype",         # "bf16" resident intensity dtype

@@ -130,10 +130,19 @@ def extract_images_flat_banded(
     window's bins live in the narrow band (r_lo, r_hi] of the grid, so with
     windows m/z-sorted and chunked (the ``window_chunks`` plan), chunk c's
     512 windows only need grid columns [start_c, start_c + gc_width + 2):
-    flops drop to 2*P*gc*W — LINEAR in the batch.  The histogram is built
-    ONCE at full width (its cost is per-peak, not per-window), then each
-    chunk dynamic-slices its band and runs a small MXU matmul.  Images are
-    bit-identical: out-of-band bins have zero membership in the dense form.
+    flops drop to 2*P*gc*W — LINEAR in the batch — times the three bf16
+    passes the product takes on the MXU (see the chunk body).  The
+    histogram is built ONCE at full width (its cost is per-peak, not
+    per-window), then each chunk dynamic-slices its band and runs a small
+    MXU matmul.  Images are bit-identical: out-of-band bins have zero
+    membership in the dense form.
+
+    ``gc_width`` is the plan's sticky maximum of the chunks' rank spans.
+    ``window_chunks`` sorts the windows themselves, so 512 neighbours span
+    little more than their own bounds (1536 rows at any table size) and
+    the image rows come out in m/z order: ``inv`` (W,) gathers them back,
+    one pass over the image block.  With ``inv=None`` the rows stay in the
+    plan's order.
     """
     n = pixel_sorted.shape[0]
     g = pos.shape[0]
@@ -171,17 +180,23 @@ def extract_images_flat_banded(
             whp, (start_eff, jnp.int32(0)), (gc_width + 2, n_pixels))
         d = ((gg > (rlo + shift)[None, :])
              & (gg <= (rhi + shift)[None, :])).astype(jnp.float32)
+        # THREE bf16 MXU passes, not HIGHEST's six: XLA:TPU folds the
+        # cast of the compares away and feeds the convolution a pred
+        # operand, which is one exact piece; only ``band`` is cut in three
+        # (PERF.md section 6, PR 42: 90% of the matrix unit's peak for
+        # three passes; explicit bf16 or int8 pieces read 8-66% slower)
         return None, jnp.dot(
             d.T, band, precision=jax.lax.Precision.HIGHEST)
 
     _, imgs = jax.lax.scan(chunk, None, (starts, r_lo_loc, r_hi_loc))
     imgs = imgs.reshape(-1, n_pixels)                  # (C*Wc, P) sorted order
     if inv is None:
-        # ion-major plans (ion_window_chunks): rows are already grouped
-        # by ion — the caller un-permutes the tiny metric rows instead of
-        # gathering the multi-GB image block
+        # the plan's own row order (probes of an ion_window_chunks plan,
+        # whose rows are already grouped by ion)
         return imgs
-    return jnp.take(imgs, inv, axis=0)                 # (W, P) input order
+    # (W, P) input order; ``inv`` is the plan's own permutation of the
+    # sorted rows, so the gather needs no out-of-range fill pass
+    return imgs.at[inv].get(mode="promise_in_bounds", unique_indices=True)
 
 
 def prepare_flat_sharded_arrays(
@@ -483,10 +498,10 @@ def compact_peaks(
 # of it does work quadratic in the batch.  Windows are therefore sorted by m/z
 # and cut into chunks whose LOCAL slice of the bound grid is gc_width wide:
 # each chunk's matmul reads only its band.  ``window_chunks`` is the plan of
-# the sharded backend, ``ion_window_chunks`` the single-device one.  Images
-# are bit-identical to an unchunked extraction: hit sets are exact
-# integer-grid matches and sums are exact integers (ops/quantize.py) in any
-# grouping.
+# both backends' XLA extraction, ``ion_window_chunks`` the fused Pallas
+# kernel's (ops/score_pallas.py).  Images are bit-identical to an unchunked
+# extraction: hit sets are exact integer-grid matches and sums are exact
+# integers (ops/quantize.py) in any grouping.
 
 
 def window_chunks(
@@ -550,6 +565,14 @@ def ion_window_chunks(
     (theor_ints, n_valid) by ``order`` to match.  Exact: each window
     still sums exactly its own bins (integer grid, any order/grouping).
 
+    The price is the band: an ion's K windows reach 3 Da up, so a chunk's
+    rank span takes in every other ion's bounds inside that reach and
+    ``gc_width`` grows with the table's ions per Da (3072 rows at 10,500
+    ions, 16384 at 126,000), where ``window_chunks`` of the same windows
+    stays near 2 x 512.  On a v5e the gather costs less than the wider
+    band at every shape timed (PERF.md section 6, PR 42), so only the
+    fused kernel, which reduces an ion's K windows together, plans so.
+
     Requires ``ions_per_chunk`` to divide ``b`` (static batches are
     powers of two; callers clamp).  gc_width uses the same {1, 1.5} x
     pow-2 ladder as window_chunks."""
@@ -612,7 +635,13 @@ def fused_score_cost_model(
     - scratch zero-init: XLA scatter's fixed cost is the operand
       zero-init/copy (ROADMAP A3: to be re-measured on the chip) — one
       (P+1) x max(G+1, gc+2) f32 block per batch.
-    - membership matmul: wh (P, G+1) @ D (G+1, B) per batch at f32.
+    - membership matmul: wh (P, G+1) @ D (G+1, B) per batch, f32 at
+      ``Precision.HIGHEST``: THREE bf16 MXU passes on the TPU, not six
+      (the compiler feeds the 0/1 side as a pred operand, one exact
+      piece; measured at 90% of the matrix unit's peak for three, PERF.md
+      section 6, PR 42).  ``matmul_flops`` stays the plain 2mnk:
+      scripts/roofline_probe.py prices it against the device's MEASURED
+      f32-HIGHEST matmul rate, which holds the passes.
     - image block: (n_ions, K, P) f32 written by extraction, then read by
       the moments pass (1x) and the chaos sweeps (>= ~2 effective passes of
       the label plane at span-32 with the cheap certificate).

@@ -116,7 +116,10 @@ def _flat_lower_call(spec: dict):
     }
     fn = make_flat_jits(common)[spec["variant"]]
     resident = [S((n,), i32), S((n,), _resident_dtype(spec))]
-    plan = [S((c,), i32), S((c, wc), i32), S((c, wc), i32), S((b,), i32),
+    # ``w``: the rows ``inv`` permutes (b*k image rows; the fused
+    # variant's ion-major plan permutes the b ions)
+    plan = [S((c,), i32), S((c, wc), i32), S((c, wc), i32),
+            S((int(spec["w"]),), i32),
             S((b, k), f32), S((b,), i32), S((), i32)]
     statics = dict(gc_width=int(spec["gc_width"]), b=b, k=k)
     if spec["variant"] in ("plain", "fused"):

@@ -211,3 +211,55 @@ def test_every_pallas_kernel_compiles_for_v5e():
                     + proc.stdout.strip()[-200:])
     assert proc.returncode == 0 and "AOT-OK" in proc.stdout, \
         (proc.stdout + proc.stderr)[-3000:]
+
+
+# --------------------------- the membership product's passes on a v5e
+_MEMBERSHIP_AOT = textwrap.dedent('''
+    import os, re, sys
+    os.environ["TPU_ACCELERATOR_TYPE"] = "v5litepod-4"
+    os.environ["TPU_WORKER_HOSTNAMES"] = "localhost"
+    from functools import partial
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        dev = topologies.get_topology_desc(
+            topology_name="v5e:1x1", platform="tpu",
+            chips_per_host_bounds=(1, 1, 1)).devices[0]
+    except Exception as exc:
+        print("NO-TOPOLOGY", exc); sys.exit(77)
+    from sm_distributed_tpu.ops.imager_jax import extract_images_flat_banded
+
+    sh = SingleDeviceSharding(dev)
+    i32, f32, gc, p, w = jnp.int32, jnp.float32, 1536, 4096, 8192
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in (
+        ((262144,), i32), ((262144,), f32), ((2 * w,), i32), ((16,), i32),
+        ((16, 512), i32), ((16, 512), i32), ((w,), i32))]
+    text = jax.jit(partial(
+        extract_images_flat_banded, gc_width=gc, n_pixels=p)).trace(
+            *avals).lower(lowering_platforms=("tpu",)).compile().as_text()
+    convs = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert len(convs) == 1, convs
+    lhs = re.search(r"convolution\\(%([\\w.]+),", convs[0]).group(1)
+    made = next(ln for ln in text.splitlines()
+                if ln.lstrip().startswith("%" + lhs + " = "))
+    assert re.search(r"= pred\\[", made), made
+    print("AOT-OK")
+''')
+
+
+def test_membership_product_meets_a_pred_operand_on_v5e():
+    """What makes the f32 dot at ``Precision.HIGHEST`` of
+    ``extract_images_flat_banded`` THREE bf16 MXU passes and not six
+    (PERF.md section 6, PR 42): XLA:TPU folds the cast of the two compares
+    away and hands the convolution the 0/1 side as a pred operand, one
+    exact piece.  A compiler that stops doing so doubles the largest op of
+    extraction; then explicit bf16 pieces are worth timing again."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _MEMBERSHIP_AOT], cwd=str(REPO_ROOT),
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode == 77:
+        pytest.skip("libtpu offers no compile-only topology here: "
+                    + proc.stdout.strip()[-200:])
+    assert proc.returncode == 0 and "AOT-OK" in proc.stdout, \
+        (proc.stdout + proc.stderr)[-3000:]
