@@ -6,7 +6,10 @@ running service) into the standard perf artifact for this repo:
 
 - the **phase breakdown** — wall clock per pipeline phase, as a share of
   the root ``submit`` span (submit → terminal);
-- the **accounting split** — queue wait (submit → first attempt), device-
+- the **accounting split** — queue wait (submit → first attempt), one
+  ``claim`` line a claim (seconds after the submit, and ``woken_by``:
+  ``submit`` = the POST woke the dispatcher, ``poll`` = its timed scan
+  found the message), device-
   token wait (device_hold start → token acquired), device-token hold, and
   compute (the ``score`` phase), so a throughput cliff shows WHERE the
   time moved (scheduler? token contention? device?);
@@ -281,6 +284,16 @@ def summarize(records: list[dict]) -> dict:
         "accounting": {
             "queue_wait_s": round(queue_wait, 6)
             if queue_wait is not None else None,
+            # one entry a claim (a retry or a takeover claims again): how
+            # long after the submit, and what ended the dispatcher's idle
+            # wait for it (``submit`` = woken by the POST, ``poll`` = found
+            # by the timed scan; absent on traces from before PR 43)
+            "claims": [{
+                "after_submit_s": round(e["ts"] - root["ts"], 6)
+                if root else None,
+                "woken_by": (e.get("attrs") or {}).get("woken_by"),
+            } for e in sorted(_events(records, "claim"),
+                              key=lambda r: r["ts"])],
             "device_token_wait_s": round(token_wait, 6),
             "device_token_hold_s": round(token_hold, 6),
             "compute_s": round(phases.get("score", {}).get("seconds", 0.0), 6),
@@ -475,6 +488,12 @@ def render(s: dict) -> str:
     if a["queue_wait_s"] is not None:
         lines.append(f"  queue wait             {a['queue_wait_s']:9.3f}s "
                      f"{_pct(a['queue_wait_s'], total)}")
+    for c in a.get("claims", ()):
+        at = c["after_submit_s"]
+        lines.append("  claim                  "
+                     + (f"{at:9.3f}s {_pct(at, total)}" if at is not None
+                        else "      n/a")
+                     + f"  woken_by={c['woken_by'] or '-'}")
     lines.append(f"  device-token wait      {a['device_token_wait_s']:9.3f}s "
                  f"{_pct(a['device_token_wait_s'], total)}")
     lines.append(f"  device-token hold      {a['device_token_hold_s']:9.3f}s "

@@ -431,6 +431,10 @@ class AdminAPI:
         tracing.event("submit", ctx=ctx, tenant=tenant,
                       ds_id=str(msg.get("ds_id", "")),
                       priority=str(msg.get("priority", "normal")))
+        # the message is in pending/ (publish returned after its rename):
+        # wake the dispatcher of this process instead of leaving the job to
+        # its next timed scan, up to service.poll_interval_s away
+        svc.scheduler.notify_pending()
         return 202, {"msg_id": dst.stem, "spooled": str(dst),
                      "trace_id": trace["trace_id"]}, None
 

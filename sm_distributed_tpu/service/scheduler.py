@@ -393,6 +393,12 @@ class JobScheduler:
         # unstarted, so a SIGTERM drain requeues a bounded set
         self._handoff: _queue_mod.Queue = _queue_mod.Queue(maxsize=max(1, self.cfg.workers))
         self._stop = threading.Event()
+        # the dispatcher's idle wait: notify_pending() sets it when this
+        # process renamed a message into pending/ (POST /submit), shutdown
+        # sets it with _stop; the timed scan at poll_interval_s stays as the
+        # fallback for what no event announces (a peer's or a script's
+        # publish, a retry whose back-off elapsed, a takeover requeue)
+        self._wake = threading.Event()
         self._drained = threading.Event()
         self._threads: list[threading.Thread] = []
         self._inflight_by_tenant: dict[str, int] = {}
@@ -434,6 +440,12 @@ class JobScheduler:
         self.m_backoff = m.histogram(
             "sm_retry_backoff_seconds", "Backoff delays scheduled before retries",
             buckets=(0.01, 0.05, 0.25, 1.0, 5.0, 30.0, 120.0))
+        self.m_wakes = m.counter(
+            "sm_scheduler_dispatch_wakes_total",
+            "Idle waits of the dispatcher ended by an in-process publish "
+            "(submit) or by the poll_interval_s timeout (poll)", ("by",))
+        for by in ("submit", "poll"):
+            self.m_wakes.labels(by=by).inc(0)
         # per-chip in_use gauge + grant/wait metrics (idempotent when the
         # service already attached them to the shared pool)
         self.device_pool.attach_metrics(m)
@@ -693,15 +705,38 @@ class JobScheduler:
         except FileNotFoundError:
             return None               # another scheduler/daemon won the race
 
+    def notify_pending(self) -> None:
+        """Wake the dispatcher now: this process just put a message into
+        pending/.  Call it AFTER the rename has returned — a wake before it
+        scans an empty directory and sleeps the whole interval.  A wake for
+        a message the scan then does not admit (a peer replica owns its
+        shard) costs one scan and is otherwise harmless."""
+        self._wake.set()
+
     def _dispatch_loop(self) -> None:
+        # what ended the idle wait this run of scans began with; pending/
+        # as start() found it was announced by no event
+        woken_by = "poll"
         while not self._stop.is_set():
+            # clear, THEN scan, and wait only when nothing was admitted: a
+            # publish that lands after the scan's glob leaves the event set
+            # and the wait returns at once, so no wake-up is lost
+            self._wake.clear()
             try:
-                admitted = self._admit_one()
+                admitted = self._admit_one(woken_by)
             except Exception:         # the dispatcher must never die
                 logger.error("scheduler: dispatcher error", exc_info=True)
                 admitted = False
             if not admitted:
-                self._stop.wait(self.cfg.poll_interval_s)
+                # a wake that lands after the timeout fired but before this
+                # thread has the interpreter back still counts as a wake
+                woke = self._wake.wait(self.cfg.poll_interval_s) \
+                    or self._wake.is_set()
+                if self._stop.is_set():
+                    break
+                woken_by = "submit" if woke else "poll"
+                if self.metrics:
+                    self.m_wakes.labels(by=woken_by).inc()
         self._drain_handoff()
         self._drained.set()
 
@@ -727,10 +762,12 @@ class JobScheduler:
                            claimed.name, exc_info=True)
         return updated
 
-    def _admit_one(self) -> bool:
+    def _admit_one(self, woken_by: str = "poll") -> bool:
         """Claim and hand off the single best eligible message, then return
         so the next admission re-scans with FRESH fairness keys (per-tenant
-        in-flight counts move with every claim)."""
+        in-flight counts move with every claim).  ``woken_by`` goes onto the
+        ``claim`` event: ``submit`` = the dispatcher was woken for it,
+        ``poll`` = the timed scan found it."""
         for _key, p, msg in self._scan_pending(time.time()):
             if self._stop.is_set() or self._draining:
                 return False
@@ -762,7 +799,8 @@ class JobScheduler:
             tracing.event("claim", ctx=ctx, tenant=rec.tenant,
                           attempts=rec.attempts, replica=self.replica_id,
                           fence=lease.fence,
-                          claims=int(msg.get("service", {}).get("claims", 0)))
+                          claims=int(msg.get("service", {}).get("claims", 0)),
+                          woken_by=woken_by)
             with self._records_lock:
                 self._inflight_by_tenant[rec.tenant] = (
                     self._inflight_by_tenant.get(rec.tenant, 0) + 1)
@@ -1813,6 +1851,7 @@ class JobScheduler:
         wait for running jobs.  Returns True when fully drained in time."""
         timeout_s = self.cfg.drain_timeout_s if timeout_s is None else timeout_s
         self._stop.set()
+        self._wake.set()              # the dispatcher's idle wait is on it
         # a live acquisition waits on the instrument indefinitely: unwind
         # it into the hand-off path so the worker join below can finish
         self._cancel_live_streams("drain: service shutting down")

@@ -417,7 +417,13 @@ class ServiceConfig:
     workers: int = 2                     # concurrent job slots (CPU phases
                                          # overlap; device phases serialize
                                          # through the scheduler's TPU token)
-    poll_interval_s: float = 0.5         # pending/ scan cadence when idle
+    poll_interval_s: float = 0.5         # fallback scan cadence of pending/:
+                                         # how late the idle dispatcher finds
+                                         # what ANOTHER process published (a
+                                         # peer's API, a script, a requeue)
+                                         # or a retry whose back-off ended;
+                                         # this process's POST /submit wakes
+                                         # it at once
     job_timeout_s: float = 21600.0       # per-attempt wall clock (6 h — the
                                          # 80k-formula DESI job is 32-67 min)
     max_attempts: int = 3                # attempts before dead-letter

@@ -291,6 +291,181 @@ def test_shutdown_requeues_claimed_but_unstarted(tmp_path):
     assert done + pending == 4 and done >= 1
 
 
+# ---- the dispatcher's wake on POST /submit (PR 43): an in-process publish
+# ends the idle wait at once; the timed scan at poll_interval_s stays the
+# fallback for what no event announces
+
+def _post_submit(base: str, msg: dict) -> str:
+    req = urllib.request.Request(
+        base + "/submit", method="POST", data=json.dumps(msg).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=5.0) as r:
+        assert r.status == 202
+        return json.loads(r.read())["msg_id"]
+
+
+def _wait_claims(msg_ids, timeout_s: float = 5.0) -> dict[str, dict]:
+    """msg_id -> its first ``claim`` event in the flight recorder, waiting
+    until every one of ``msg_ids`` has been claimed (or the timeout)."""
+    from sm_distributed_tpu.utils import tracing
+
+    want = set(msg_ids)
+    deadline = time.time() + timeout_s
+    while True:
+        found: dict[str, dict] = {}
+        for r in tracing.flight_recorder.recent():
+            if r.get("kind") == "event" and r["name"] == "claim" \
+                    and r.get("job_id") in want:
+                found.setdefault(r["job_id"], r)
+        if len(found) == len(want) or time.time() >= deadline:
+            return found
+        time.sleep(0.005)
+
+
+def _wakes(service, by: str) -> float:
+    return service.scheduler.m_wakes.labels(by=by).value
+
+
+def test_submit_wakes_the_idle_dispatcher(tmp_path):
+    """(a) With a 30 s poll a POST /submit is claimed at once, and the
+    claim says what woke the dispatcher for it."""
+    service = AnnotationService(tmp_path / "q", FakeJobs(),
+                                sm_config=_sm(tmp_path, poll_interval_s=30.0))
+    service.start()
+    try:
+        host, port = service.api.address
+        time.sleep(0.05)              # the dispatcher is in its idle wait
+        msg_id = _post_submit(f"http://{host}:{port}",
+                              {"ds_id": "wake_a", "input_path": "/in"})
+        t_posted = time.time()
+        claim = _wait_claims([msg_id]).get(msg_id)
+        assert claim is not None, "never claimed: the wake-up was lost"
+        assert claim["attrs"]["woken_by"] == "submit"
+        assert claim["ts"] - t_posted < 0.5
+        assert service.scheduler.wait_for_terminal(1, timeout_s=5.0)
+        assert _wakes(service, "submit") == 1 and _wakes(service, "poll") == 0
+        # the job's own trace says it too, and the report prints it
+        from scripts import trace_report
+        from sm_distributed_tpu.utils import tracing
+
+        recs = tracing.read_trace(
+            tracing.trace_path(service.trace_dir, claim["trace_id"]))
+        lines = trace_report.render(trace_report.summarize(recs)).splitlines()
+        said = [ln for ln in lines if ln.strip().startswith("claim ")]
+        assert len(said) == 1 and said[0].endswith("woken_by=submit"), lines
+    finally:
+        assert service.shutdown()
+
+
+def test_submit_burst_loses_no_wakeup_and_keeps_priority(tmp_path):
+    """(b) 20 submits from 4 threads against one worker and a 30 s poll:
+    every one is claimed without a poll elapsing, and those that were
+    pending together still run by priority class."""
+    gate = threading.Event()
+    order = []
+    lock = threading.Lock()
+
+    def cb(msg, ctx=None):
+        with lock:
+            order.append(msg["ds_id"])
+        if msg["ds_id"] == "blocker":
+            gate.wait(10.0)
+
+    service = AnnotationService(
+        tmp_path / "q", cb,
+        sm_config=_sm(tmp_path, poll_interval_s=30.0, workers=1))
+    service.start()
+    try:
+        host, port = service.api.address
+        base = f"http://{host}:{port}"
+        t0 = time.time()
+        ids = [_post_submit(base, {"ds_id": "blocker", "input_path": "/in"})]
+        while not order and time.time() < t0 + 5.0:
+            time.sleep(0.005)         # the one worker now sits in the gate
+        prio = ("low", "normal", "high", "normal", "low")
+        got = [[] for _ in range(4)]
+
+        def client(k):
+            for i, p in enumerate(prio):
+                got[k].append(_post_submit(base, {
+                    "ds_id": f"{p}-{k}-{i}", "input_path": "/in",
+                    "priority": p}))
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        ids += [m for g in got for m in g]
+        gate.set()
+        assert service.scheduler.wait_for_terminal(21, timeout_s=10.0), \
+            service.scheduler.stats()
+        assert time.time() - t0 < 15.0
+        claims = _wait_claims(ids)
+        assert len(claims) == 21
+        assert {c["attrs"]["woken_by"] for c in claims.values()} == {"submit"}
+        assert _wakes(service, "poll") == 0, "a job waited for the poll"
+        # blocker + the one in the hand-off buffer + the one the dispatcher
+        # held in its blocked put were claimed as they arrived; the other
+        # 18 were pending together when the gate opened
+        ranks = [{"high": 0, "normal": 1, "low": 2}[d.split("-")[0]]
+                 for d in order[3:]]
+        assert len(ranks) == 18 and ranks == sorted(ranks), order
+    finally:
+        gate.set()
+        assert service.shutdown()
+
+
+def test_foreign_publish_is_found_by_the_poll(tmp_path):
+    """(c) A bare QueuePublisher (another process's publish: no event) is
+    still claimed within one poll_interval_s, and reads ``poll``."""
+    service = AnnotationService(tmp_path / "q", FakeJobs(),
+                                sm_config=_sm(tmp_path, poll_interval_s=0.05),
+                                with_api=False)
+    service.start()
+    try:
+        time.sleep(0.1)               # idle: a couple of polls elapse
+        t0 = time.time()
+        QueuePublisher(tmp_path / "q").publish(
+            {"ds_id": "wake_c", "input_path": "/in", "msg_id": "wake_c"})
+        claim = _wait_claims(["wake_c"]).get("wake_c")
+        assert claim is not None
+        assert claim["attrs"]["woken_by"] == "poll"
+        assert claim["ts"] - t0 < 0.05 + 0.5
+        assert _wakes(service, "submit") == 0 and _wakes(service, "poll") >= 1
+    finally:
+        assert service.shutdown()
+
+
+def test_shutdown_ends_the_idle_wait_and_wakes_are_counted(tmp_path):
+    """(d) shutdown() during a 30 s idle wait returns at once, not a poll
+    later; /metrics counts wakes by both causes."""
+    service = AnnotationService(tmp_path / "q", FakeJobs(),
+                                sm_config=_sm(tmp_path, poll_interval_s=0.05))
+    service.start()
+    host, port = service.api.address
+    _post_submit(f"http://{host}:{port}",
+                 {"ds_id": "wake_d", "input_path": "/in"})
+    assert service.scheduler.wait_for_terminal(1, timeout_s=5.0)
+    time.sleep(0.15)
+    text = service.metrics.expose()
+    assert 'sm_scheduler_dispatch_wakes_total{by="submit"} 1' in text
+    assert _wakes(service, "poll") >= 1
+    assert service.shutdown()
+
+    idle = JobScheduler(tmp_path / "q2", FakeJobs(),
+                        config=_fast_cfg(poll_interval_s=30.0),
+                        metrics=MetricsRegistry())
+    idle.start()
+    time.sleep(0.05)                  # the dispatcher is in its 30 s wait
+    t0 = time.time()
+    assert idle.shutdown(timeout_s=5.0)
+    assert time.time() - t0 < 2.0
+    assert idle.m_wakes.labels(by="submit").value == 0, \
+        "shutdown's wake was counted as a submit"
+
+
 def test_retry_policy_backoff_shape():
     pol = RetryPolicy(max_attempts=5, base_s=1.0, max_s=8.0, jitter=0.0)
     assert [pol.backoff_s(n) for n in (1, 2, 3, 4, 5)] == \
