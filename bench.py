@@ -377,8 +377,6 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
     ``resident_cube_bytes`` is the HBM footprint of the compacted
     intensity cube — the acceptance criterion pins desi at <= half the
     f32 baseline, reported alongside as ``resident_cube_bytes_f32``."""
-    import jax
-
     from sm_distributed_tpu.ops.imager_jax import fused_score_cost_model
     from sm_distributed_tpu.utils.logger import logger
 
@@ -390,10 +388,6 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
         prep["ds"].n_peaks)
     cube_dtype = getattr(backend, "_cube_dtype", "f32")
     int_bytes = {"f32": 4, "bf16": 2}[cube_dtype]
-    # price the variant that actually dispatched: parallel.fused_metrics
-    # defaults to "auto", which engages the fused kernel on a real TPU
-    fused_active = (getattr(backend, "_fused_mode", "off") != "off"
-                    and jax.default_backend() == "tpu")
     model = fused_score_cost_model(
         n_pixels=prep["ds"].n_pixels,
         resident_peaks=resident_peaks,
@@ -401,7 +395,7 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
         max_peaks=prep["table"].max_peaks,
         formula_batch=cfg.formula_batch,
         nlevels=prep["ds_config"].image_generation.nlevels,
-        ordered=True, fused=fused_active, cube_dtype=cube_dtype)
+        ordered=True, cube_dtype=cube_dtype)
     peaks = measure_device_peaks(bw_mb=64, mm_n=1024)
     t_bw = model["total_bytes"] / (peaks["peak_bw_gbps"] * 1e9)
     t_fl = model["matmul_flops"] / (peaks["peak_matmul_gflops"] * 1e9)
@@ -418,7 +412,7 @@ def measure_roofline(cfg: BenchConfig, prep: dict, backend,
         roofline_frac=round(frac, 4),
         roofline_floor_s=round(floor_s, 4),
         roofline_bound="bandwidth" if t_bw >= t_fl else "compute",
-        fused=fused_active, cube_dtype=cube_dtype,
+        cube_dtype=cube_dtype,
         resident_cube_bytes=int(resident_peaks * int_bytes),
         resident_cube_bytes_f32=int(resident_peaks * 4))
 
@@ -690,7 +684,6 @@ def report(prep: dict, floor: dict, jaxr: dict, iso: dict | None = None,
         "measured_roofline_frac": jaxr.get("measured_roofline_frac"),
         "kernel_time_frac": jaxr.get("kernel_time_frac"),
         "device_kernel_s": jaxr.get("device_kernel_s"),
-        "fused": jaxr.get("fused"),
         "cube_dtype": jaxr.get("cube_dtype"),
         "resident_cube_bytes": jaxr.get("resident_cube_bytes"),
         "resident_cube_bytes_f32": jaxr.get("resident_cube_bytes_f32"),

@@ -29,8 +29,7 @@ starts:
    process with the shipped ``parallel.*`` defaults, driven over HTTP:
    identity from ``GET /debug/devices``; four submits (256x256 cold, the
    same again, 64x64, and 64x64 against a 97-formula DB — one batch, the
-   ``plain`` family, which ``fused_metrics: auto`` turns into the fused
-   Pallas variant on a TPU); per job the backend and extraction variant of
+   ``plain`` variant); per job the backend and extraction variant of
    every batch, compiles vs persistent-cache hits; two reads per dataset;
    ``/metrics``; SIGTERM -> exit 0 with ``running/`` empty.
 3. ``four_chip`` when the service reported >= 4 TPU chips: four concurrent
@@ -271,99 +270,14 @@ def kernel_chaos(side: int, interpret: bool, strips: bool, n: int = 6,
     return f"{route}: {n} images x {nlevels} levels bit-exact vs scipy"
 
 
-def kernel_fused(p: int, b: int, interpret: bool, k: int = 4,
-                 gc_width: int = 1024, window_budget: int | None = None) -> str:
-    """The fused band-matmul + moments kernel against the unfused jnp
-    chain (dynamic-slice band, HIGHEST matmul, masked jnp moments) on the
-    same device-generated integer-grid histogram."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from sm_distributed_tpu.ops import score_pallas as sp
-    from sm_distributed_tpu.ops.imager_jax import (
-        BAND_WINDOWS,
-        ions_per_chunk_for,
-    )
-    from sm_distributed_tpu.ops.moments_pallas import batch_moments_jnp
-
-    ipc = ions_per_chunk_for(b, k, window_budget or BAND_WINDOWS)
-    wc, n_chunks, g = ipc * k, b // ipc, 2 * b * k
-    cols_p = sp.cols_padded(g, gc_width)
-    if not interpret:
-        check(sp.fused_fit(wc, ipc, p, gc_width),
-              f"fused kernel refuses P={p} b={b} gc={gc_width}")
-    n_real = p - p // 8
-
-    @jax.jit
-    def histogram(key):
-        k1, k2 = jax.random.split(key)
-        live = ((jnp.arange(cols_p) <= g)[:, None]
-                & (jnp.arange(p) < n_real)[None, :]
-                & (jax.random.uniform(k1, (cols_p, p)) < 0.4))
-        return jnp.where(
-            live, jax.random.randint(k2, (cols_p, p), 0, 50), 0
-        ).astype(jnp.float32)
-
-    whp = histogram(jax.random.PRNGKey(SEED))
-    rng = np.random.default_rng(SEED)
-    starts = np.sort(rng.integers(0, g - gc_width, n_chunks)).astype(np.int32)
-    r_lo = rng.integers(-1, gc_width - 3, (n_chunks, wc)).astype(np.int32)
-    r_hi = (r_lo + rng.integers(1, 3, (n_chunks, wc))).astype(np.int32)
-
-    partials, principal = sp.fused_window_moments(
-        whp, starts, r_lo, r_hi, np.int32(n_real),
-        gc_width=gc_width, k=k, interpret=interpret)
-    gg = jnp.arange(gc_width + 2, dtype=jnp.int32)[:, None]
-
-    @jax.jit
-    def against_unfused(whp, start, rlo, rhi, part, prin):
-        """One chunk through the unfused chain, compared ON the device:
-        only verdicts and (ipc, k) moment columns come back."""
-        band = jax.lax.dynamic_slice(
-            whp, (start, jnp.int32(0)), (gc_width + 2, p))
-        d = ((gg > rlo[None, :]) & (gg <= rhi[None, :])).astype(jnp.float32)
-        imgs = jnp.dot(d.T, band, precision=jax.lax.Precision.HIGHEST)
-        imgs = imgs.reshape(ipc, k, p)
-        sums, normsq, dots, vmax, nn = batch_moments_jnp(
-            imgs, n_real=jnp.int32(n_real))
-        part = part.reshape(ipc, k, 5)
-        exact = (jnp.array_equal(prin, imgs[:, 0])
-                 & jnp.array_equal(part[..., 0], sums)
-                 & jnp.array_equal(part[:, 0, 3], vmax)
-                 & jnp.array_equal(part[:, 0, 4], nn))
-        return exact, part[..., 1], part[..., 2], normsq, dots
-
-    ulps = _contract(sp, "fused_window_moments")
-    worst = 0.0
-    for c in range(n_chunks):
-        exact, k_normsq, k_dots, normsq, dots = against_unfused(
-            whp, starts[c], r_lo[c], r_hi[c], partials[c], principal[c])
-        check(bool(exact), f"fused P={p} b={b} chunk {c}: principal images "
-              "/ sums / vmax / nn differ from the unfused chain")
-        worst = max(worst, float(np.abs(
-            _corr(np.asarray(k_normsq), np.asarray(k_dots))
-            - _corr(np.asarray(normsq), np.asarray(dots))).max()))
-    check(worst <= _corr_atol(ulps),
-          f"fused P={p} b={b}: correlation off by {worst:.3g} > ulp({ulps})")
-    pt = sp.pick_tile(p, wc, ipc, gc_width)
-    return (f"grid ({n_chunks}, 2, {p // pt if pt else 1}) tile {pt}: "
-            f"principal/sums/vmax/nn exact, correlation err {worst:.2g} "
-            f"<= ulp({ulps})")
-
-
 def kernel_cases(interpret: bool) -> list[tuple[str, object]]:
     """(label, thunk) per kernel x shape.  ``interpret`` selects the CPU
     test sizes (tests/test_chip_smoke.py); the chip runs the real ones."""
     if interpret:
         pixels, sides, strips = [256], [16], (24, 8)
-        fused = [(256, 32, 64, 32)]               # (P, b, gc, window budget)
     else:
         pixels, sides, strips = [4096, 65536, 262144], [64, 256, 512], \
             (1024, None)
-        fused = [(4096, 2048, 1024, None), (4096, 256, 1024, None),
-                 (65536, 2048, 1024, None), (65536, 256, 1024, None),
-                 (262144, 256, 1024, None)]
     cases: list[tuple[str, object]] = []
     for p in pixels:
         for masked in (False, True):
@@ -376,10 +290,6 @@ def kernel_cases(interpret: bool) -> list[tuple[str, object]]:
     cases.append((f"chaos_strips {strips[0]}x{strips[0]}", lambda:
                   kernel_chaos(strips[0], interpret, strips=True, n=3,
                                strip_rows=strips[1])))
-    for p, b, gc, budget in fused:
-        cases.append((f"fused P={p} b={b}", lambda p=p, b=b, gc=gc, w=budget:
-                      kernel_fused(p, b, interpret, gc_width=gc,
-                                   window_budget=w)))
     return cases
 
 
@@ -645,8 +555,8 @@ def serve_leg(work: Path, platform: str, big: str = "scale",
         run_job(serve, small, small_path, small_formulas, job_timeout)
         one = run_job(serve, f"{small}_small_db", small_path,
                       small_formulas[:SMALL_DB], job_timeout)
-        check(one["variants"] <= {"plain", "fused"},
-              f"the small-DB job left the plain family: {one['variants']}")
+        check(one["variants"] == {"plain"},
+              f"the small-DB job left the plain variant: {one['variants']}")
         for ds_id in (f"{big}_cold", f"{big}_again", small,
                       f"{small}_small_db"):
             read_twice(serve, ds_id)

@@ -98,17 +98,14 @@ if ! env JAX_PLATFORMS=cpu python scripts/ulp_sentinel.py --self-check; then
     exit 1
 fi
 
-# roofline probe gate (ISSUE 18): the tiny bench shape through the FUSED
-# scoring path (interpret-mode Pallas off-TPU) timed against the
-# fused-variant cost-model floor on this host's measured peaks.  The
-# --min-frac band is deliberately loose on CPU (tiny shapes are
-# dispatch-dominated and interpret-mode Pallas replays the grid serially;
-# the measured tiny fused fraction here is ~2e-4) — the gate catches
-# catastrophic fused-path regressions (an order of magnitude off the
-# model), and proves the fused variant + cost model stay runnable end to
-# end on every CI run
+# roofline probe gate (ISSUE 18): the tiny bench shape timed against the
+# cost-model floor on this host's measured peaks.  The --min-frac band is
+# deliberately loose on CPU (tiny shapes are dispatch-dominated) — the
+# gate catches catastrophic regressions (an order of magnitude off the
+# model), and proves the probe + cost model stay runnable end to end on
+# every CI run
 if ! env JAX_PLATFORMS=cpu python scripts/roofline_probe.py --tiny \
-        --fused on --min-frac 0.00002; then
+        --min-frac 0.00002; then
     echo "check_tier1: FAIL — roofline probe gate failed" >&2
     exit 1
 fi

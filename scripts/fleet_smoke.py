@@ -15,8 +15,7 @@ Three phases, all through real service stacks:
    buckets (this script's own parser + the documented attainment
    arithmetic — not the fleetview code under test).
 2. **On-demand device profiling.**  An in-process service on the
-   ``jax_tpu`` backend with the fused Pallas scoring kernel forced on
-   (interpret mode off-TPU) runs real jobs; ``GET /debug/profile``
+   ``jax_tpu`` backend runs real jobs; ``GET /debug/profile``
    during one must list that job among the lease holds it overlaps and
    map the ``sm:`` annotations of the job's spans, through the ``sm_clock``
    events, to within 1 ms of the job-trace records (device time itself
@@ -379,10 +378,7 @@ def phase_profile(work: Path) -> int:
         present_fraction=0.5, noise_peaks=20, seed=13)
     h = Harness(base, "svc", sm_overrides={
         "backend": "jax_tpu",
-        # force the fused Pallas scoring kernel (interpret mode off-TPU):
-        # the capture must attribute device time to it BY NAME
-        "parallel": {"formula_batch": 4, "checkpoint_every": 1,
-                     "fused_metrics": "on"},
+        "parallel": {"formula_batch": 4, "checkpoint_every": 1},
     })
     try:
         def submit(i: int) -> str:

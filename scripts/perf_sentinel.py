@@ -158,14 +158,21 @@ def normalize(data: dict) -> dict[str, tuple[float, str]]:
                 out[f"numerics.max_ulp.{comp}"] = (v, "down")
         if (v := _num(data.get("fdr_rank_mismatches"))) is not None:
             out["numerics.fdr_rank_mismatches"] = (v, "down")
-        # ISSUE 18: the fused-kernel + bf16-cube path rides the same
-        # drift series — rising data-level drift regresses
-        for comp, v in (data.get("sm_numerics_max_ulp_fused") or {}).items():
+        # the bf16-cube leg rides the same drift series — rising
+        # data-level drift regresses
+        for comp, v in (bf16_leg(data, "sm_numerics_max_ulp") or {}).items():
             if (v := _num(v)) is not None:
-                out[f"numerics.max_ulp_fused.{comp}"] = (v, "down")
-        if (v := _num(data.get("fdr_rank_mismatches_fused"))) is not None:
-            out["numerics.fdr_rank_mismatches_fused"] = (v, "down")
+                out[f"numerics.max_ulp_bf16.{comp}"] = (v, "down")
+        if (v := _num(bf16_leg(data, "fdr_rank_mismatches"))) is not None:
+            out["numerics.fdr_rank_mismatches_bf16"] = (v, "down")
     return out
+
+
+def bf16_leg(artifact: dict, key: str, default=None):
+    """``<key>_bf16`` of a NUMERICS artifact's compacted-cube leg.
+    NUMERICS_r02 and older wrote the leg (then scored through the fused
+    Pallas kernel, removed in PR 44) under ``<key>_fused``."""
+    return artifact.get(f"{key}_bf16", artifact.get(f"{key}_fused", default))
 
 
 def compare(history: list[dict[str, tuple[float, str]]],

@@ -92,10 +92,6 @@ def main() -> None:
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke shape (16x16 px, 8 formulas, tiny "
                          "microbenches)")
-    ap.add_argument("--fused", choices=("auto", "on", "off"), default="auto",
-                    help="parallel.fused_metrics for the probed backend "
-                         "(ISSUE 18; 'on' forces the Pallas kernel, "
-                         "interpret-mode off-TPU)")
     ap.add_argument("--cube-dtype", choices=("f32", "bf16"),
                     default="f32",
                     help="parallel.cube_dtype for the probed backend")
@@ -130,7 +126,6 @@ def main() -> None:
         {"backend": "jax_tpu",
          "fdr": {"decoy_sample_size": args.decoy_sample_size},
          "parallel": {"formula_batch": args.formula_batch,
-                      "fused_metrics": args.fused,
                       "cube_dtype": args.cube_dtype}})
     backend = make_backend("jax_tpu", ds, prep["ds_config"], sm_config,
                            table=table)
@@ -154,12 +149,6 @@ def main() -> None:
     resident = getattr(backend, "_mz_host", None)
     resident_peaks = int(resident.size) if resident is not None else int(
         ds.n_peaks)
-    # price the variant that actually dispatched: 'on' forces the fused
-    # kernel everywhere; 'auto' engages it only on a real TPU
-    import jax
-
-    fused_active = args.fused == "on" or (
-        args.fused == "auto" and jax.default_backend() == "tpu")
     model = fused_score_cost_model(
         n_pixels=ds.n_pixels,
         resident_peaks=resident_peaks,
@@ -168,7 +157,6 @@ def main() -> None:
         formula_batch=args.formula_batch,
         nlevels=prep["ds_config"].image_generation.nlevels,
         ordered=True,
-        fused=fused_active,
         cube_dtype=args.cube_dtype,
     )
     t_bw = model["total_bytes"] / (peaks["peak_bw_gbps"] * 1e9)
@@ -188,7 +176,6 @@ def main() -> None:
         "bound": "bandwidth" if t_bw >= t_fl else "compute",
         "headroom_x": round(measured_s / floor_s, 2) if floor_s > 0 else None,
         "roofline_frac": round(frac, 4),
-        "fused": bool(fused_active),
         "cube_dtype": args.cube_dtype,
         "resident_cube_bytes": int(resident_peaks * int_bytes),
         "n_ions": int(table.n_ions),

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """ULP-contract numerics sentinel (ISSUE 15 — the runtime half of numlint).
 
-Fused Pallas scoring and bf16 intensity compaction are gated on FDR ranks
-staying bit-identical — or within a *declared* tolerance — to the
-fp32/numpy oracle.  The static half of that gate is
+bf16 intensity compaction (and any other change of the scoring numerics)
+is gated on FDR ranks staying bit-identical — or within a *declared*
+tolerance — to the fp32/numpy oracle.  The static half of that gate is
 the ``NUMERICS`` contract registries + the three numlint rules; this
 script is the measurement:
 
@@ -115,22 +115,22 @@ def measure(workdir: str | Path | None = None) -> dict:
     want = score_all(NumpyBackend(ds, dc))   # the fp32/numpy oracle
     drift = numerics.component_drift(got, want)
 
-    # fused+compacted path (ISSUE 18): the fused Pallas scoring kernel
-    # (interpret-mode off-TPU) over the bf16-compacted resident cube.
-    # Its drift vs the plain-f32 jax path is DATA-level (the cube lost
-    # mantissa bits), so it gates against ops/quantize.py's declared
-    # compact_cube contract — not the same-data COMPONENT_CONTRACTS —
-    # plus the same HARD FDR-rank-identity bar vs the numpy oracle.
+    # compacted path: the same scoring chain over the bf16-compacted
+    # resident cube.  Its drift vs the plain-f32 jax path is DATA-level
+    # (the cube lost mantissa bits), so it gates against
+    # ops/quantize.py's declared compact_cube contract — not the
+    # same-data COMPONENT_CONTRACTS — plus the same HARD
+    # FDR-rank-identity bar vs the numpy oracle.
     from sm_distributed_tpu.ops.quantize import NUMERICS as _QN
 
     cube_ulps = numerics.contract_ulps(
         numerics.parse_policy(_QN["compact_cube"])["contract"])
-    sm_fused = SMConfig.from_dict({
+    sm_bf16 = SMConfig.from_dict({
         "backend": "jax_tpu",
         "parallel": {"formula_batch": fx["formula_batch"],
-                     "fused_metrics": "on", "cube_dtype": "bf16"}})
-    got_fused = score_all(JaxBackend(ds, dc, sm_fused))
-    drift_fused = numerics.component_drift(got_fused, got)
+                     "cube_dtype": "bf16"}})
+    got_bf16 = score_all(JaxBackend(ds, dc, sm_bf16))
+    drift_bf16 = numerics.component_drift(got_bf16, got)
 
     def ranks(metrics: np.ndarray):
         df = pd.DataFrame({"sf": table.sfs, "adduct": table.adducts,
@@ -148,7 +148,7 @@ def measure(workdir: str | Path | None = None) -> dict:
 
     r_np = ranks(want)
     mismatches = rank_mismatches(ranks(got), r_np)
-    mismatches_fused = rank_mismatches(ranks(got_fused), r_np)
+    mismatches_bf16 = rank_mismatches(ranks(got_bf16), r_np)
 
     reg = numerics.registered()
     return {
@@ -161,15 +161,14 @@ def measure(workdir: str | Path | None = None) -> dict:
         "sm_numerics_max_ulp": drift,
         "fdr_rank_mismatches": mismatches,
         "fdr_ranks_identical": mismatches == 0,
-        # fused Pallas kernel + bf16 cube (ISSUE 18): drift vs plain-f32
-        # jax, gated by the compact_cube data-level contract; rank
-        # identity vs the numpy oracle stays the HARD bar
-        "fused_metrics": "on",
+        # bf16 cube: drift vs plain-f32 jax, gated by the compact_cube
+        # data-level contract; rank identity vs the numpy oracle stays
+        # the HARD bar
         "cube_dtype": "bf16",
         "cube_contract_ulps": int(cube_ulps),
-        "sm_numerics_max_ulp_fused": drift_fused,
-        "fdr_rank_mismatches_fused": mismatches_fused,
-        "fdr_ranks_identical_fused": mismatches_fused == 0,
+        "sm_numerics_max_ulp_bf16": drift_bf16,
+        "fdr_rank_mismatches_bf16": mismatches_bf16,
+        "fdr_ranks_identical_bf16": mismatches_bf16 == 0,
         "component_contracts": dict(numerics.COMPONENT_CONTRACTS),
         "declared_contracts": sum(len(e) for e in reg.values()),
         "declared_modules": len(reg),
@@ -191,11 +190,11 @@ def gate(artifact: dict, history_paths: list[str], tolerance: float,
               f"mismatch(es)); rank identity is the HARD contract",
               file=sys.stderr)
         rc = 1
-    if artifact.get("fdr_rank_mismatches_fused", 0) != 0 or \
-            not artifact.get("fdr_ranks_identical_fused", True):
-        print(f"ulp_sentinel: {label}: FAIL — fused+compacted-vs-numpy "
+    if perf_sentinel.bf16_leg(artifact, "fdr_rank_mismatches", 0) != 0 or \
+            not perf_sentinel.bf16_leg(artifact, "fdr_ranks_identical", True):
+        print(f"ulp_sentinel: {label}: FAIL — compacted-vs-numpy "
               f"FDR ranks diverge "
-              f"({artifact.get('fdr_rank_mismatches_fused')} mismatch(es)); "
+              f"({perf_sentinel.bf16_leg(artifact, 'fdr_rank_mismatches')} mismatch(es)); "
               f"rank identity is the HARD contract", file=sys.stderr)
         rc = 1
     ceilings = {**numerics.COMPONENT_CONTRACTS,
@@ -207,13 +206,13 @@ def gate(artifact: dict, history_paths: list[str], tolerance: float,
                   f"ULPs exceeds its declared contract of {ceiling}",
                   file=sys.stderr)
             rc = 1
-    # fused+bf16 drift is data-level — its ceiling is the compact_cube
+    # bf16 drift is data-level — its ceiling is the compact_cube
     # contract the artifact itself carries (ops/quantize.py NUMERICS)
     cube_ceiling = artifact.get("cube_contract_ulps")
-    for comp, ulps in (artifact.get("sm_numerics_max_ulp_fused")
+    for comp, ulps in (perf_sentinel.bf16_leg(artifact, "sm_numerics_max_ulp")
                        or {}).items():
         if cube_ceiling is not None and ulps > cube_ceiling:
-            print(f"ulp_sentinel: {label}: FAIL — fused+compacted {comp} "
+            print(f"ulp_sentinel: {label}: FAIL — compacted {comp} "
                   f"drift {ulps} ULPs exceeds the compact_cube contract "
                   f"of {cube_ceiling}", file=sys.stderr)
             rc = 1
@@ -237,14 +236,15 @@ def degrade(artifact: dict) -> dict:
     ceilings = bad.get("component_contracts") or {}
     for comp in ulp:
         ulp[comp] = 2 * int(ceilings.get(comp, 0)) + 8
-    ulp_fused = bad.get("sm_numerics_max_ulp_fused") or {}
-    for comp in ulp_fused:
-        ulp_fused[comp] = 2 * int(bad.get("cube_contract_ulps", 0)) + 8
+    ulp_bf16 = perf_sentinel.bf16_leg(bad, "sm_numerics_max_ulp") or {}
+    for comp in ulp_bf16:
+        ulp_bf16[comp] = 2 * int(bad.get("cube_contract_ulps", 0)) + 8
     bad["fdr_rank_mismatches"] = 1
     bad["fdr_ranks_identical"] = False
-    if "fdr_ranks_identical_fused" in bad:
-        bad["fdr_rank_mismatches_fused"] = 1
-        bad["fdr_ranks_identical_fused"] = False
+    for suffix in ("bf16", "fused"):
+        if f"fdr_ranks_identical_{suffix}" in bad:
+            bad[f"fdr_rank_mismatches_{suffix}"] = 1
+            bad[f"fdr_ranks_identical_{suffix}"] = False
     return bad
 
 

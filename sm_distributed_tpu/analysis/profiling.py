@@ -29,7 +29,7 @@ time (children taken out of parents).  The arithmetic is the one
 ``benchmarks/trace_reduce.py`` proved on a real trace.
 
 Device time attributes by the ``jax.named_scope`` an op was traced under
-(``sm_extract``, ``sm_moments``, ``sm_chaos``, ``sm_epilogue``, ``sm_fused``,
+(``sm_extract``, ``sm_moments``, ``sm_chaos``, ``sm_epilogue``,
 ``sm_store_extract``; else ``unscoped``).  The scope path is the ``tf_op``
 stat of the op's XEventMetadata (``jit(f)/jit(main)/sm_chaos/while/...``),
 which ``jax.profiler.ProfileData`` does not expose, so ``op_paths`` reads

@@ -990,8 +990,7 @@ def compile_surface_census(project: Project) -> dict[str, int]:
 # distinct value — the unbounded-signature family behind r4's 81-308 s
 # cold compiles.  Static values must pass a bucketing/padding helper
 # first so every dataset size lands in a small closed set.
-_BUCKET_HELPERS = ("ions_per_chunk_for", "shape_key", "window_chunks",
-                   "ion_window_chunks")
+_BUCKET_HELPERS = ("shape_key", "window_chunks")
 
 _RH_FIXTURE_FAIL = {
     "sm_distributed_tpu/ops/x_jax.py": (

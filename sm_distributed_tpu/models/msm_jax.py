@@ -35,22 +35,18 @@ from ..ops.imager_jax import (
     extract_images_flat,
     extract_images_flat_banded,
     flat_bound_ranks,
-    ion_window_chunks,
-    ions_per_chunk_for,
     window_chunks,
     window_rank_grid,
 )
 from ..ops.isocalc import IsotopePatternTable
 from ..ops.metrics_jax import (
     batch_metrics,
-    batch_metrics_from_partials,
     chaos_dispatch,
     correlation_from_moments,
     isotope_pattern_match_batch,
     measure_of_chaos_batch,
 )
 from ..ops.quantize import compact_cube, expand_cube_jnp, quantize_window
-from ..ops.score_pallas import cols_padded, fused_fit, fused_window_moments
 from ..utils.config import DSConfig, SMConfig
 from ..utils.logger import logger
 
@@ -73,12 +69,6 @@ COMPILE_SURFACE = compile_surface(__name__, {
         "statics=gc_width,b,k,w_cap; buckets=flat-banded statics + w_cap on "
         "the {1,1.125..1.875}x pow-2 band_bucket ladder "
         "(ops/imager_jax.band_bucket)",
-    "fused_score_fn_flat_fused":
-        "statics=gc_width,b,k; buckets=flat-banded statics (ISSUE 18): the "
-        "fused Pallas kernel's grid/tiling derive from the same lattice "
-        "shapes, starts/n_real ride as traced (scalar-prefetch) operands, "
-        "and the cube dtype is a per-backend constant — so the fused "
-        "family is exactly the plain family's size",
     "expand_cube_jnp":
         "statics=none; buckets=probe-only — one f32 expansion of the "
         "compact resident cube per probed backend (production expands "
@@ -120,11 +110,8 @@ NUMERICS = numerics_surface(__name__, {
     "fused_score_fn_flat_banded_sliced":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
         "test_band_slice_bit_exact; padded=pixel_sorted,int_sorted",
-    "fused_score_fn_flat_fused":
-        "contract=ulp(16); test=tests/test_score_pallas.py::"
-        "test_fused_variant_matches_plain; padded=pixel_sorted,int_sorted",
     "expand_cube_jnp":
-        "contract=bit_exact; test=tests/test_score_pallas.py::"
+        "contract=bit_exact; test=tests/test_cube_compaction.py::"
         "test_compact_expand_roundtrip",
     "extract_images_flat":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
@@ -217,83 +204,6 @@ def fused_score_fn_flat_banded(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
     )
-
-
-def fused_score_fn_flat_fused(
-    pixel_sorted: jnp.ndarray,  # (N,) int32
-    int_sorted: jnp.ndarray,   # (N,) f32/bf16 resident intensities
-    pos: jnp.ndarray,          # (G,) int32 host-computed bound ranks
-    starts: jnp.ndarray,       # (C,) chunk grid offsets
-    r_lo_loc: jnp.ndarray,     # (C, Wc)
-    r_hi_loc: jnp.ndarray,     # (C, Wc)
-    inv: jnp.ndarray,          # (B,) ion inverse permutation
-    theor_ints: jnp.ndarray,
-    n_valid: jnp.ndarray,
-    n_real=None,               # () i32 traced: REAL pixel count (lattice)
-    *,
-    gc_width: int,
-    b: int,
-    k: int,
-    nrows: int,
-    ncols: int,
-    nlevels: int,
-    do_preprocessing: bool,
-    q: float,
-) -> jnp.ndarray:
-    """Flat-path scoring through the ONE-PASS fused Pallas kernel
-    (ops/score_pallas.py, ISSUE 18): the banded membership matmul and
-    every per-window moment reduction happen on VMEM-staged tiles of the
-    histogram — the (b, k, P) image block never round-trips HBM; only the
-    principal rows (chaos needs their spatial layout) are written back.
-
-    Same argument layout and statics as ``fused_score_fn_flat_banded``
-    (the 'plain' variant), but the chunk plan is ION-MAJOR
-    (ion_window_chunks): the kernel reduces an ion's K windows together,
-    so they share a chunk; theor_ints / n_valid arrive ion-sorted, metric
-    rows come back in that order and ``inv`` (b,) un-permutes them.
-
-    Numerics: principal images / chaos / spectral / vmax / nn are
-    bit-exact vs the plain variant (exact integer-grid sums in any
-    association order); the spatial correlation's centered reductions
-    re-associate per pixel tile — within the declared ulp(16) ceiling.
-    The fused route requires ``do_preprocessing=False`` (hotspot clipping
-    needs the full materialized image block); routing enforces it."""
-    if do_preprocessing:
-        raise ValueError(
-            "the fused scoring kernel cannot apply hotspot preprocessing "
-            "(no materialized image block); route via the plain variant")
-    n_pix = nrows * ncols
-    n = pixel_sorted.shape[0]
-    g = pos.shape[0]
-    # the same bins-major histogram as extract_images_flat_banded, with
-    # the scratch rows padded to whole super-rows (score_pallas.SC) plus
-    # the spare band the unclamped super-row fetch may touch — spare rows
-    # are zero-initialized and outside every window's rank range
-    with jax.named_scope("sm_extract"):
-        int_sorted = expand_cube_jnp(int_sorted)
-        delta = jnp.zeros(n + 1, jnp.int32).at[pos].add(1)
-        bins = jnp.cumsum(delta[:-1])
-        cols_p = cols_padded(g, gc_width)
-        wh = jnp.zeros((cols_p, n_pix + 1), jnp.float32).at[
-            bins, pixel_sorted].add(int_sorted)
-        whp = wh[:, :n_pix]
-    nr = n_real if n_real is not None else np.int32(n_pix)
-    # the Pallas interpreter is a CPU test vehicle (fused_metrics="on" in
-    # tests and the ulp sentinel): same kernel schedule, no Mosaic.  Every
-    # accelerator compiles the kernel; traces that interpret are counted
-    # (sm_pallas_interpret_total) so a served path can prove it made none
-    interpret = jax.default_backend() == "cpu"
-    if interpret:
-        _PALLAS_INTERPRET_EVENTS["traced"] += 1
-    with jax.named_scope("sm_fused"):
-        partials, principal = fused_window_moments(
-            whp, starts, r_lo_loc, r_hi_loc, nr,
-            gc_width=gc_width, k=k, interpret=interpret)
-    out = batch_metrics_from_partials(
-        partials.reshape(b, k, 5), principal.reshape(b, n_pix),
-        theor_ints, n_valid, nrows, ncols, nlevels)
-    with jax.named_scope("sm_epilogue"):
-        return jnp.take(out, inv, axis=0)
 
 
 def _extract_sliced(
@@ -430,11 +340,6 @@ _VARIANTS = {
     "plain": ("_fn", extract_images_flat_banded, 5, 0),
     "compact": ("_fn_c", _extract_compact, 8, 3),
     "band": ("_fn_bs", _extract_sliced, 6, 1),
-    # the fused Pallas scorer (ISSUE 18) shares the plain variant's
-    # argument layout and statics — only the jit differs; its extraction
-    # probe is the plain banded extraction (the fused kernel has no
-    # standalone image phase — that is the point)
-    "fused": ("_fn_f", extract_images_flat_banded, 5, 0),
 }
 
 
@@ -504,9 +409,6 @@ def _flat_jits(common: dict, count: bool = False) -> tuple[dict, bool]:
         "band": jax.jit(
             named_partial(fused_score_fn_flat_banded_sliced, **common),
             static_argnames=("w_cap", "gc_width", "b", "k")),
-        "fused": jax.jit(
-            named_partial(fused_score_fn_flat_fused, **common),
-            static_argnames=("gc_width", "b", "k")),
     }, count=count)
 
 
@@ -516,7 +418,7 @@ def make_flat_jits(common: dict) -> dict:
     under the lattice — ncols, nlevels, do_preprocessing, q).
 
     THE one place these jits come from, and for equal ``common`` they are
-    the SAME four objects (until the registry above drops the geometry):
+    the SAME three objects (until the registry above drops the geometry):
     ``JaxBackend.__init__`` binds them to ``self._fn*`` and the AOT cache
     primer (``service/primer.py``) lowers them against a recorded
     BucketSpec.  So a primed persistent-cache entry is exactly the entry a
@@ -613,15 +515,6 @@ _WARMUP_CACHE_EVENTS = {"hit": 0, "miss": 0}
 
 def warmup_cache_events() -> dict:
     return dict(_WARMUP_CACHE_EVENTS)
-
-
-# Scoring programs traced with a Pallas kernel in INTERPRET mode (CPU only,
-# see fused_score_fn_flat_fused) — same pull pattern as the warmup events.
-_PALLAS_INTERPRET_EVENTS = {"traced": 0}
-
-
-def pallas_interpret_events() -> int:
-    return _PALLAS_INTERPRET_EVENTS["traced"]
 
 
 # Ion images sent through each chaos geometry, counted on the host where a
@@ -815,19 +708,10 @@ class JaxBackend:
         self._fn = fns["plain"]
         self._fn_c = fns["compact"]
         self._fn_bs = fns["band"]
-        self._fn_f = fns["fused"]
-        # fused-kernel routing (ISSUE 18): "auto" fuses on TPU when
-        # the plan shape fits the kernel's VMEM budget; "on" forces
-        # the fused variant everywhere (interpret mode on CPU — the
-        # tests/sentinel path); hotspot preprocessing excludes fusion
-        self._fused_mode = sm_config.parallel.fused_metrics
-        self._interpret_warned = False
         # sticky static shapes: grow to the max seen so one executable
         # serves (almost) all batches instead of recompiling per batch
         self._gc_width = 0
         self._gc_tail = 0         # band width of the small-batch variant
-        self._gf_width = 0        # ... of the fused kernel's ion-major plan
-        self._gf_tail = 0
         self._n_keep = 0          # compacted peak capacity
         self._r_pad = 0           # compaction run-list capacity
         self._compaction = sm_config.parallel.peak_compaction
@@ -892,13 +776,6 @@ class JaxBackend:
         # window-major plan: the windows themselves sorted by m/z, so a
         # chunk's band spans its own 512 neighbours at any table density
         chunks = window_chunks(r_lo, r_hi, _BAND_WINDOWS)
-        # the fused kernel alone reads ION-MAJOR chunks (whole ions per
-        # chunk: largest divisor of the static batch within BAND_WINDOWS)
-        k_eff = max(1, table.max_peaks)
-        ion_chunks = ion_window_chunks(
-            r_lo, r_hi, b_eff, k_eff,
-            ions_per_chunk_for(b_eff, k_eff, _BAND_WINDOWS)
-        ) if self._may_fuse() else None
         pos = flat_bound_ranks(self._mz_host, grid)
         runs, band = None, None
         if self._compaction != "off" or self._band_mode != "off":
@@ -908,7 +785,7 @@ class JaxBackend:
             if self._band_mode != "off":
                 band = batch_peak_band(self._mz_host, lo_q, hi_q)
         return (grid, r_lo, r_hi, ints_p, nv_p, chunks, pos, runs, b_eff,
-                band, ion_chunks)
+                band)
 
     # band-slice w_cap buckets: the shared {1, 1.5} x pow-2 ladder
     # (ops/imager_jax.band_bucket — the sharded backend uses the same one)
@@ -958,59 +835,18 @@ class JaxBackend:
                 est["band"] = 14.0 * cap
         return min(est, key=est.get)
 
-    def _band_widths(self, b_eff: int) -> tuple[int, int]:
-        """Sticky band widths of a static batch: (the window-major plan's,
-        the fused kernel's ion-major plan's).  The tail executable keeps
+    def _band_width(self, b_eff: int) -> int:
+        """Sticky band width of a static batch.  The tail executable keeps
         its own: sharing the full-size band would blow the small batch's
         matmul cost."""
-        if b_eff == self.batch:
-            return self._gc_width, self._gf_width
-        return self._gc_tail, self._gf_tail
+        return self._gc_width if b_eff == self.batch else self._gc_tail
 
-    def _grow_band_widths(self, plan) -> None:
+    def _grow_band_width(self, plan) -> None:
         gc = plan[5][4]
-        gf = plan[10][4] if plan[10] is not None else 0
         if plan[8] == self.batch:
             self._gc_width = max(self._gc_width, gc)
-            self._gf_width = max(self._gf_width, gf)
         else:
             self._gc_tail = max(self._gc_tail, gc)
-            self._gf_tail = max(self._gf_tail, gf)
-
-    def _may_fuse(self) -> bool:
-        """Whether any batch of this backend can route to the fused kernel
-        (``_maybe_fuse``): only then does a plan hold ion-major chunks.
-        Hotspot preprocessing needs materialized images, so it excludes
-        fusion entirely; 'auto' fuses on a real TPU only."""
-        if self._fused_mode == "off" or self._common["do_preprocessing"]:
-            return False
-        return self._fused_mode == "on" or jax.default_backend() == "tpu"
-
-    def _maybe_fuse(self, variant: str, plan) -> str:
-        """Fused-kernel routing (ISSUE 18).  'on' forces the fused variant
-        from ANY cost-model choice (tests/sentinel: interpret mode on CPU);
-        'auto' upgrades only the plain variant — band/compact reshape the
-        resident cube before scatter, which the fused kernel's unblocked
-        band staging does not model — and only where the (wc, cols_p, pt)
-        shape of the plan's ion-major chunks fits the kernel's VMEM budget
-        (fused_fit)."""
-        ion_chunks = plan[10]
-        if ion_chunks is None:
-            return variant
-        if self._fused_mode == "on":
-            if jax.default_backend() == "cpu" and not self._interpret_warned:
-                self._interpret_warned = True
-                logger.warning(
-                    "jax_tpu backend: fused_metrics=on on a CPU platform "
-                    "runs the Pallas kernel in INTERPRET mode — a test "
-                    "vehicle, orders of magnitude slower than any device")
-            return "fused"
-        wc, k = ion_chunks[1].shape[1], plan[3].shape[1]
-        if (variant == "plain"
-                and fused_fit(wc, wc // max(k, 1), self._n_pix_b,
-                              self._band_widths(plan[8])[1])):
-            return "fused"
-        return variant
 
     def _in_f32(self):
         """f32 view of the (possibly compacted) resident intensity cube for
@@ -1042,20 +878,13 @@ class JaxBackend:
         if flat_plan is None:
             flat_plan = self._flat_plan(table)
         (_grid, _r_lo, _r_hi, ints_p, nv_p, chunks, pos, runs,
-         b_eff, band, ion_chunks) = flat_plan
-        self._grow_band_widths(flat_plan)
-        variant = self._maybe_fuse(self._variant_for(runs, band), flat_plan)
-        if variant == "fused":
-            # per-ion side inputs follow the plan's ion sort; the fused fn
-            # un-permutes the metric rows with ``inv``
-            starts, r_lo_loc, r_hi_loc, inv, _gf, order = ion_chunks
-            ints_p, nv_p = ints_p[order], nv_p[order]
-            gc_eff = self._band_widths(b_eff)[1]
-        else:
-            # extraction gathers the image rows back with ``inv``: side
-            # inputs and metric rows stay in the table's order
-            starts, r_lo_loc, r_hi_loc, inv, _gc = chunks
-            gc_eff = self._band_widths(b_eff)[0]
+         b_eff, band) = flat_plan
+        self._grow_band_width(flat_plan)
+        variant = self._variant_for(runs, band)
+        # extraction gathers the image rows back with ``inv``: side inputs
+        # and metric rows stay in the table's order
+        starts, r_lo_loc, r_hi_loc, inv, _gc = chunks
+        gc_eff = self._band_width(b_eff)
         # explicit async device_put: the transfers overlap device compute
         # of previously enqueued batches instead of blocking dispatch
         if variant == "band":
@@ -1180,12 +1009,8 @@ class JaxBackend:
         ext_fn = jax.jit(named_partial(
             ext_base, n_pixels=self._n_pix_b, **ext_statics))
         # extraction args = everything before (theor_ints, n_valid[,
-        # n_real]), the trailing ``inv`` its row gather.  The fused
-        # variant's is the ION un-permutation of the metric rows instead:
-        # its probes keep the plan's ion-sorted order (the side inputs
-        # below are permuted to match)
-        ext_args = list(args[: n_ext - 1]) + [
-            None if variant == "fused" else args[n_ext - 1]]
+        # n_real]), the trailing ``inv`` its row gather
+        ext_args = list(args[:n_ext])
         phases["extract"] = lambda: ext_fn(
             self._px_s, in_probe, *ext_args)
         # the metric probes run on the PRODUCTION image block: the padded
@@ -1295,8 +1120,7 @@ class JaxBackend:
         (each band w_cap bucket is its own executable; the other statics
         are sticky per static batch)."""
         b_eff = plan[8]
-        variant = self._maybe_fuse(
-            self._variant_for(plan[7], plan[9]), plan)
+        variant = self._variant_for(plan[7], plan[9])
         bucket = self._band_bucket(plan[9][1]) if variant == "band" else 0
         return variant, b_eff, bucket
 
@@ -1315,8 +1139,8 @@ class JaxBackend:
                 "slots": sum(s for s, _p in loads),
                 "peaks": sum(p for _s, p in loads),
                 "gc_width": max(
-                    (self._band_widths(b)[v == "fused"]
-                     for v, b, _w in kinds), default=0)}
+                    (self._band_width(b) for _v, b, _w in kinds),
+                    default=0)}
 
     def _grow_for_stream(self, plans) -> None:
         """Grow the sticky capacities over ``plans`` to a FIXPOINT.
@@ -1338,7 +1162,7 @@ class JaxBackend:
                 return
 
     def _grow_from_plan(self, plan) -> None:
-        self._grow_band_widths(plan)
+        self._grow_band_width(plan)
         if self._variant_for(plan[7], plan[9]) == "compact":
             self._grow_compact_capacity(plan[7])
 
@@ -1397,14 +1221,12 @@ class JaxBackend:
         dev = jax.devices()[0]
         blob = repr((
             sorted(kinds),
-            (self._gc_width, self._gc_tail, self._gf_width, self._gf_tail,
-             self._n_keep, self._r_pad),
+            (self._gc_width, self._gc_tail, self._n_keep, self._r_pad),
             (self._nrows_b, self.ds.ncols, int(self._mz_host.size),
              self.batch, bool(self._buckets)),
             (self.ds_config.image_generation.nlevels,
              self.ds_config.image_generation.do_preprocessing),
-            # ISSUE 18 knobs change the compiled program family
-            (self._cube_dtype, self._fused_mode),
+            self._cube_dtype,   # changes the compiled program family
             (jax.__version__, dev.platform, str(dev.device_kind)),
         ))
         return hashlib.sha256(blob.encode()).hexdigest()

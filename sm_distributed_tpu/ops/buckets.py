@@ -156,7 +156,7 @@ def effective_batch(parallel_cfg) -> int:
 _SPEC_KEYS = (
     # identity of one concrete executable in the lattice
     "kind",               # "flat" | "sharded" | "chunked"
-    "variant",            # "plain" | "compact" | "band" | "fused" | "step"
+    "variant",            # "plain" | "compact" | "band" | "step"
     "nrows", "ncols",     # bucketed rows x exact columns (metric geometry)
     "nlevels", "do_preprocessing", "q",
     "n_resident",         # bucketed resident peak slots (per shard row)
@@ -170,8 +170,7 @@ _SPEC_KEYS = (
     # so pre-existing manifest keys stay stable within a kind:
     "mesh_pix", "mesh_form",  # mesh axis sizes (pixels x formulas)
     "p_loc",              # per-shard pixel capacity (whole bucketed rows)
-    # both kinds: the rows ``inv`` permutes (the total window count; the
-    # flat fused variant's ion-major plan permutes the b ions)
+    # both kinds: the rows ``inv`` permutes (the total window count)
     "w",
     # recorded only when parallel.cube_dtype != "f32", so f32 spec keys
     # stay byte-stable:

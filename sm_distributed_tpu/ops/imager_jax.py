@@ -141,8 +141,7 @@ def extract_images_flat_banded(
     ``window_chunks`` sorts the windows themselves, so 512 neighbours span
     little more than their own bounds (1536 rows at any table size) and
     the image rows come out in m/z order: ``inv`` (W,) gathers them back,
-    one pass over the image block.  With ``inv=None`` the rows stay in the
-    plan's order.
+    one pass over the image block.
     """
     n = pixel_sorted.shape[0]
     g = pos.shape[0]
@@ -190,10 +189,6 @@ def extract_images_flat_banded(
 
     _, imgs = jax.lax.scan(chunk, None, (starts, r_lo_loc, r_hi_loc))
     imgs = imgs.reshape(-1, n_pixels)                  # (C*Wc, P) sorted order
-    if inv is None:
-        # the plan's own row order (probes of an ion_window_chunks plan,
-        # whose rows are already grouped by ion)
-        return imgs
     # (W, P) input order; ``inv`` is the plan's own permutation of the
     # sorted rows, so the gather needs no out-of-range fill pass
     return imgs.at[inv].get(mode="promise_in_bounds", unique_indices=True)
@@ -258,23 +253,12 @@ def prepare_flat_sharded_arrays(
 
 def gc_ladder(span: int) -> int:
     """Static chunk band width for a window span: smallest {1, 1.5} x
-    pow-2 point >= span (shared by window_chunks and ion_window_chunks so
-    the driver entry and the backend can never disagree on the plan)."""
+    pow-2 point >= span."""
     cap = 2
     while cap < span:
         cap <<= 1
     mid = (cap >> 2) * 3
     return mid if span <= mid and mid >= 2 else cap
-
-
-def ions_per_chunk_for(b: int, k: int, window_budget: int) -> int:
-    """Largest divisor of the static batch ``b`` whose k-window block
-    stays within ``window_budget`` windows per chunk (the shared rule for
-    ion-major chunk plans)."""
-    ipc = max(1, min(window_budget // max(k, 1), b))
-    while b % ipc:
-        ipc -= 1
-    return ipc
 
 
 def band_bucket(width: int, floor: int = 1 << 21) -> int:
@@ -498,8 +482,7 @@ def compact_peaks(
 # of it does work quadratic in the batch.  Windows are therefore sorted by m/z
 # and cut into chunks whose LOCAL slice of the bound grid is gc_width wide:
 # each chunk's matmul reads only its band.  ``window_chunks`` is the plan of
-# both backends' XLA extraction, ``ion_window_chunks`` the fused Pallas
-# kernel's (ops/score_pallas.py).  Images are bit-identical to an unchunked
+# both backends' extraction.  Images are bit-identical to an unchunked
 # extraction: hit sets are exact integer-grid matches and sums are exact
 # integers (ops/quantize.py) in any grouping.
 
@@ -548,66 +531,6 @@ def window_chunks(
     return starts, r_lo_loc, r_hi_loc, inv, gc_width
 
 
-def ion_window_chunks(
-    r_lo: np.ndarray, r_hi: np.ndarray, b: int, k: int,
-    ions_per_chunk: int,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int, np.ndarray]:
-    """ION-MAJOR chunk plan: (starts (C,), r_lo_loc (C, Wc), r_hi_loc
-    (C, Wc), inv_ions (b,), gc_width, order (b,)).
-
-    Like ``window_chunks`` but whole IONS are sorted (by their first real
-    window's lo rank; all-empty padding ions last) and chunked, all K
-    windows of an ion staying adjacent — so the banded matmul emits image
-    rows already ION-MAJOR: the (b, k, P) block needs NO (W, P) gather
-    (``jnp.take`` of a 1 GB block per DESI batch, ~2.1 GB of pure HBM
-    permutation traffic), only the final (b, 4) METRIC rows are
-    un-permuted by ``inv_ions``.  Callers permute the per-ion side inputs
-    (theor_ints, n_valid) by ``order`` to match.  Exact: each window
-    still sums exactly its own bins (integer grid, any order/grouping).
-
-    The price is the band: an ion's K windows reach 3 Da up, so a chunk's
-    rank span takes in every other ion's bounds inside that reach and
-    ``gc_width`` grows with the table's ions per Da (3072 rows at 10,500
-    ions, 16384 at 126,000), where ``window_chunks`` of the same windows
-    stays near 2 x 512.  On a v5e the gather costs less than the wider
-    band at every shape timed (PERF.md section 6, PR 42), so only the
-    fused kernel, which reduces an ion's K windows together, plans so.
-
-    Requires ``ions_per_chunk`` to divide ``b`` (static batches are
-    powers of two; callers clamp).  gc_width uses the same {1, 1.5} x
-    pow-2 ladder as window_chunks."""
-    # smlint: host-sync-ok[host chunk planning over the host bound-rank arrays]
-    r_lo2 = np.asarray(r_lo).reshape(b, k)
-    # smlint: host-sync-ok[host chunk planning over the host bound-rank arrays]
-    r_hi2 = np.asarray(r_hi).reshape(b, k)
-    empty = r_lo2 >= r_hi2
-    all_empty = empty.all(axis=1)
-    first_real = np.argmax(~empty, axis=1)
-    first_lo = np.where(all_empty, 0, r_lo2[np.arange(b), first_real])
-    order = np.lexsort((first_lo, all_empty.astype(np.int8)))
-    ipc = ions_per_chunk
-    c = b // ipc
-    wc = ipc * k
-    r_lo_s = r_lo2[order].reshape(c, wc)
-    r_hi_s = r_hi2[order].reshape(c, wc)
-    real_s = ~empty[order].reshape(c, wc)
-    # chunk offset: min lo rank over the chunk's REAL windows (an all-
-    # padding chunk keeps 0); empty windows' local ranks may go negative,
-    # which the membership test already treats as empty
-    big = np.int64(1) << 40
-    lo_real = np.where(real_s, r_lo_s, big)
-    starts = np.where(real_s.any(axis=1), lo_real.min(axis=1), 0).astype(
-        np.int32)
-    r_lo_loc = (r_lo_s - starts[:, None]).astype(np.int32)
-    r_hi_loc = (r_hi_s - starts[:, None]).astype(np.int32)
-    span = int(np.where(real_s, r_hi_loc, 0).max()) if b else 1
-    gc_width = gc_ladder(max(span, wc, 2))
-    inv_ions = np.empty(b, dtype=np.int32)
-    inv_ions[order] = np.arange(b, dtype=np.int32)
-    return (starts, r_lo_loc, r_hi_loc, inv_ions, gc_width,
-            order.astype(np.int32))
-
-
 # -- roofline cost model ------------------------------------------------------
 
 def fused_score_cost_model(
@@ -618,7 +541,6 @@ def fused_score_cost_model(
     formula_batch: int,
     nlevels: int = 30,
     ordered: bool = True,
-    fused: bool = False,
     cube_dtype: str = "f32",
 ) -> dict:
     """Minimum-work estimate of one full scoring rep (all ions once), for
@@ -651,15 +573,8 @@ def fused_score_cost_model(
     prices no padding, no recompiles, no host/dispatch), so
     measured/modeled is an upper bound on remaining headroom.
 
-    ``fused=True`` prices the ISSUE 18 single-pass Pallas variant
-    (ops/score_pallas.py) instead of the unfused gather/segment-sum chain:
-    the (B, K, P) image block never round-trips HBM — the kernel stages
-    the histogram band in VMEM (two passes: moments, then centered
-    epilogue), writes only the (C, Wc, 5) moment partials plus the (B, P)
-    principal images the chaos sweep needs, and the epilogue reads
-    principal rather than the full K-peak block.  ``cube_dtype`` prices
-    the resident intensity read of the histogram scatter at the compacted
-    width (ops/quantize.py: bf16 2 B per peak).
+    ``cube_dtype`` prices the resident intensity read of the histogram
+    scatter at the compacted width (ops/quantize.py: bf16 2 B per peak).
     """
     n_batches = max(1, -(-n_ions // formula_batch))
     g = 2 * formula_batch * max_peaks
@@ -670,30 +585,6 @@ def fused_score_cost_model(
     # per slot: intensity read + index read + f32 scratch read-modify-write
     scatter_bytes = (int_bytes + 8) * scatter_slots
     init_bytes = 4 * n_batches * (n_pixels + 1) * scratch_cols
-    if fused:
-        # two VMEM-staged passes over the (g+1, P) histogram band; chunk
-        # band overlap (~16 rows per chunk) is noise at this granularity
-        band_read_bytes = 2 * 4 * n_batches * (g + 1) * n_pixels
-        image_bytes = 4 * n_ions * n_pixels          # principal write only
-        metric_read_bytes = 2 * image_bytes          # chaos ~2 passes
-        # membership dot runs in BOTH kernel passes; the centered-epilogue
-        # dots add 2*2 flops per (ion, peak, pixel) cell
-        matmul_flops = (2 * 2.0 * n_batches * n_pixels * (g + 1)
-                        * formula_batch
-                        + 4.0 * n_ions * max_peaks * n_pixels)
-        total_bytes = (scatter_bytes + init_bytes + band_read_bytes
-                       + image_bytes + metric_read_bytes)
-        return dict(
-            n_batches=n_batches,
-            scatter_slots=int(scatter_slots),
-            scatter_bytes=int(scatter_bytes),
-            scratch_init_bytes=int(init_bytes),
-            band_read_bytes=int(band_read_bytes),
-            image_bytes=int(image_bytes),
-            metric_read_bytes=int(metric_read_bytes),
-            total_bytes=int(total_bytes),
-            matmul_flops=float(matmul_flops),
-        )
     image_bytes = 4 * n_ions * max_peaks * n_pixels
     metric_read_bytes = 3 * image_bytes    # moments 1x + chaos ~2 passes
     matmul_flops = 2.0 * n_batches * n_pixels * (g + 1) * formula_batch

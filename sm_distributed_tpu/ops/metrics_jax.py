@@ -50,15 +50,9 @@ NUMERICS = numerics_surface(__name__, {
     "correlation_from_moments":
         "contract=ulp(16); test=tests/test_jax_backend.py::"
         "test_backend_parity_metrics_and_ranks",
-    "isotope_image_correlation_batch":
-        "contract=ulp(16); test=tests/test_jax_backend.py::"
-        "test_backend_parity_metrics_and_ranks; padded=images",
     "isotope_pattern_match_batch":
         "contract=ulp(16); test=tests/test_jax_backend.py::"
         "test_backend_parity_metrics_and_ranks",
-    "batch_metrics_from_partials":
-        "contract=bit_exact; test=tests/test_score_pallas.py::"
-        "test_epilogue_matches_batch_metrics; padded=principal",
 })
 
 # numpy scalar, NOT jnp: a module-level jnp value would initialize the XLA
@@ -213,36 +207,14 @@ def correlation_from_moments(
     weights: jnp.ndarray,     # (N, K) theoretical intensities
     valid: jnp.ndarray,       # (N, K) bool
 ) -> jnp.ndarray:
-    """isotope_image_correlation_batch's exact epilogue, from precomputed
-    moments (ops/moments_pallas.py) — the two must stay in lockstep."""
+    """(N,) weighted mean Pearson correlation of peaks 1..K-1 vs peak 0
+    from precomputed moments (ops/moments_pallas.py), NaN-free (constant
+    images count 0), clipped to [0,1]."""
     norm = jnp.sqrt(normsq)
     denom = norm[:, 0:1] * norm
     corr = jnp.where(denom > 0,
                      dots / jnp.maximum(denom, np.float32(1e-30)), 0.0)
     w = jnp.where(valid, weights, 0.0).at[:, 0].set(0.0)
-    wsum = w.sum(axis=1)
-    out = jnp.where(
-        wsum > 0,
-        (corr * w).sum(axis=1) / jnp.maximum(wsum, np.float32(1e-30)), 0.0)
-    return jnp.clip(out, 0.0, 1.0)
-
-
-def isotope_image_correlation_batch(
-    images: jnp.ndarray,      # (N, K, P) f32
-    weights: jnp.ndarray,     # (N, K) theoretical intensities (weights[:,1:] used)
-    valid: jnp.ndarray,       # (N, K) bool
-) -> jnp.ndarray:
-    """(N,) weighted mean Pearson correlation of peaks 1..K-1 vs peak 0,
-    NaN-free (constant images count 0), clipped to [0,1]."""
-    mean = images.mean(axis=-1, keepdims=True)
-    cent = images - mean
-    norm = jnp.sqrt(jnp.sum(cent * cent, axis=-1))          # (N, K)
-    base = cent[:, 0, :]                                    # (N, P)
-    dots = jnp.einsum("np,nkp->nk", base, cent)             # (N, K)
-    denom = norm[:, 0:1] * norm                             # (N, K)
-    corr = jnp.where(denom > 0,
-                     dots / jnp.maximum(denom, np.float32(1e-30)), 0.0)
-    w = jnp.where(valid, weights, 0.0).at[:, 0].set(0.0)    # exclude principal
     wsum = w.sum(axis=1)
     out = jnp.where(
         wsum > 0,
@@ -350,60 +322,6 @@ def batch_metrics(
         spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
 
         alive = (n_valid > 0) & (vmax > 0)
-        chaos = jnp.where(alive, chaos, 0.0)
-        spatial = jnp.where(alive, spatial, 0.0)
-        spectral = jnp.where(alive, spectral, 0.0)
-        msm = chaos * spatial * spectral
-        return jnp.stack([chaos, spatial, spectral, msm], axis=1)
-
-
-def batch_metrics_from_partials(
-    partials: jnp.ndarray,    # (N, K, 5) moment columns (sums, normsq,
-                              # dots, vmax, nn) per window row
-    principal: jnp.ndarray,   # (N, n_pix) f32 principal (peak-0) images
-    theor_ints: jnp.ndarray,  # (N, K) f32
-    n_valid: jnp.ndarray,     # (N,) i32
-    nrows: int,
-    ncols: int,
-    nlevels: int = 30,
-) -> jnp.ndarray:
-    """``batch_metrics`` epilogue from PRECOMPUTED moments — the fused
-    Pallas scoring kernel's exit (ops/score_pallas.py, ISSUE 18).
-
-    ``batch_metrics`` masks invalid window rows to zero BEFORE the
-    moment pass; the fused kernel computes moments unmasked, so the mask
-    moves here onto the moment columns — exactly equivalent: an invalid
-    row's masked image is all-zero, hence its sums/normsq/dots are
-    exactly 0.0, which is what the ``where`` below writes; valid rows'
-    moments never see the mask in either order.  ``vmax``/``nn``/the
-    principal image come from window 0, valid iff ``n_valid > 0`` — the
-    same predicate the alive gate applies — so masking them by that
-    predicate reproduces the masked-image values bit-for-bit.  The pad
-    columns of ``principal`` are exact zeros (pad peaks scatter 0.0 and
-    pad pixels receive nothing), so chaos needs no ``n_real`` masking —
-    the same argument as ``batch_metrics``'s padded-grid chaos.  No
-    hotspot preprocessing: the fused route is gated on
-    ``do_preprocessing=False`` (clipping needs full materialized images).
-    """
-    k = partials.shape[1]
-    valid = jnp.arange(k, dtype=jnp.int32)[None, :] < n_valid[:, None]
-    # smlint: masked-ok[moment columns are per-row scalars; the pixel axis was already reduced under the kernel's n_real mask]
-    sums = jnp.where(valid, partials[..., 0], 0.0)
-    normsq = jnp.where(valid, partials[..., 1], 0.0)
-    dots = jnp.where(valid, partials[..., 2], 0.0)
-    alive0 = n_valid > 0
-    vmax = jnp.where(alive0, partials[:, 0, 3], 0.0)
-    n_notnull = jnp.where(alive0, partials[:, 0, 4], 0.0)
-    principal = jnp.where(alive0[:, None], principal, 0.0)
-
-    with jax.named_scope("sm_chaos"):
-        chaos = measure_of_chaos_batch(
-            principal, nrows, ncols, nlevels, vmax=vmax, n_notnull=n_notnull)
-    with jax.named_scope("sm_epilogue"):
-        spatial = correlation_from_moments(normsq, dots, theor_ints, valid)
-        spectral = isotope_pattern_match_batch(sums, theor_ints, valid)
-
-        alive = alive0 & (vmax > 0)
         chaos = jnp.where(alive, chaos, 0.0)
         spatial = jnp.where(alive, spatial, 0.0)
         spectral = jnp.where(alive, spectral, 0.0)

@@ -44,10 +44,10 @@ NUMERICS = numerics_surface(__name__, {
     # What the test asserts hard is the RANKING: FDR ranks bit-identical on
     # the sentinel fixture.
     "compact_cube":
-        "contract=ulp(4096); test=tests/test_score_pallas.py::"
+        "contract=ulp(4096); test=tests/test_cube_compaction.py::"
         "test_quantized_cube_rank_identity",
     "expand_cube_jnp":
-        "contract=bit_exact; test=tests/test_score_pallas.py::"
+        "contract=bit_exact; test=tests/test_cube_compaction.py::"
         "test_compact_expand_roundtrip",
 })
 

@@ -36,9 +36,9 @@ it — including the SHRUNKEN meshes a post-quarantine re-lease produces,
 which record their own topology-keyed spec at first dispatch and are warm
 for every later job of that lease shape.  A host with fewer visible
 devices than the mesh skips the spec (``skipped:devices``); legacy
-manifest entries recorded before the topology fields exist skip as
-``skipped:legacy_spec``.  The ``sm_prime_*`` metric family is documented
-in docs/OBSERVABILITY.md.
+manifest entries (recorded before the topology fields exist, or naming a
+variant since removed) skip as ``skipped:legacy_spec``.  The
+``sm_prime_*`` metric family is documented in docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations
@@ -116,15 +116,12 @@ def _flat_lower_call(spec: dict):
     }
     fn = make_flat_jits(common)[spec["variant"]]
     resident = [S((n,), i32), S((n,), _resident_dtype(spec))]
-    # ``w``: the rows ``inv`` permutes (b*k image rows; the fused
-    # variant's ion-major plan permutes the b ions)
+    # ``w``: the b*k image rows ``inv`` permutes
     plan = [S((c,), i32), S((c, wc), i32), S((c, wc), i32),
             S((int(spec["w"]),), i32),
             S((b, k), f32), S((b,), i32), S((), i32)]
     statics = dict(gc_width=int(spec["gc_width"]), b=b, k=k)
-    if spec["variant"] in ("plain", "fused"):
-        # the fused Pallas variant shares the plain call shape exactly —
-        # only the jitted program differs (models/msm_jax._VARIANTS)
+    if spec["variant"] == "plain":
         args = resident + [S((g,), i32)] + plan
     elif spec["variant"] == "band":
         args = resident + [S((), i32), S((g,), i32)] + plan
@@ -210,9 +207,12 @@ def prime_spec(spec: dict, sm_config=None) -> str:
     if kind not in ("flat", "sharded"):
         return f"skipped:{kind or 'unknown'}"
     # manifests outlive releases: an entry recorded under a resident dtype
-    # this program no longer has names an executable nothing will look up
+    # or a scoring variant (the fused Pallas one, until PR 44) this program
+    # no longer has names an executable nothing will look up
     if (spec.get("cube_dtype") or "f32") not in CUBE_DTYPES:
         return "skipped:cube_dtype"
+    if spec.get("variant") == "fused":
+        return "skipped:legacy_spec"
     if sm_config is not None:
         from ..parallel.distributed import compile_cache_path, enable_compile_cache
 

@@ -376,6 +376,12 @@ class JobScheduler:
         self.leases = LeaseStore(self.root, self.replica_id,
                                  epoch=self.epoch, metrics=metrics)
         self._lease_by_msg: dict[str, object] = {}
+        # the message the dispatcher is renaming into running/ right now:
+        # it sits there with its publish-time mtime and no lease until
+        # _admit_one has registered one, and this replica's own takeover
+        # scan must not take that for a dead claim.  Dispatcher-written,
+        # read racily (as _owned is).
+        self._claiming: str | None = None
         self._owned: set[int] = set(range(self.cfg.spool_shards))
         self._fenced_count = 0
         # zero-loss drain (ISSUE 11): once a drain request is noticed the
@@ -768,11 +774,14 @@ class JobScheduler:
         in-flight counts move with every claim).  ``woken_by`` goes onto the
         ``claim`` event: ``submit`` = the dispatcher was woken for it,
         ``poll`` = the timed scan found it."""
+        self._claiming = None
         for _key, p, msg in self._scan_pending(time.time()):
             if self._stop.is_set() or self._draining:
                 return False
+            self._claiming = p.stem   # BEFORE the rename shows it to a scan
             claimed = self._claim(p)
             if claimed is None:
+                self._claiming = None
                 continue              # another scheduler/daemon won the race
             msg_id = claimed.stem
             # the rename is the mutex; the lease is the fence.  Claiming
@@ -1817,6 +1826,8 @@ class JobScheduler:
             with self._records_lock:
                 if msg_id in self._lease_by_msg:
                     continue          # our own live claim
+            if msg_id == self._claiming:
+                continue              # ... or one the dispatcher is making
             hb = heartbeat_path(p)
             try:
                 ref = hb.stat().st_mtime if hb.exists() else p.stat().st_mtime

@@ -129,10 +129,14 @@ class DeviceMonitor:
             "Backend warmups by persistent-cache outcome (hit = manifest "
             "proved warm, executions skipped)", ("result",))
 
-        self.c_interpret = m.counter(
+        # exposed at 0 and never incremented: no served program can
+        # interpret a Pallas kernel since PR 44, but benchmarks/serve.py::
+        # hidden_routes and chip_smoke.py::check_served_state fail a run
+        # when the name is missing (ROADMAP C4 lets it go)
+        m.counter(
             "sm_pallas_interpret_total",
             "Scoring programs traced with a Pallas kernel in interpret "
-            "mode (CPU test vehicle; must stay 0 on an accelerator)")
+            "mode (none can be; stays 0)").labels()
         # pulled at SCRAPE time: a scrape right after a job must already
         # count what that job did
         m.add_collector(self._collect_backend_events)
@@ -297,18 +301,15 @@ class DeviceMonitor:
         return snap
 
     def _collect_backend_events(self, _registry=None) -> None:
-        """Pull warmup cache hit/miss and interpret-mode trace counts from
-        the jax backend module — lazily, ONLY if it was ever imported (a
-        CPU-only service never pays for it).  Counters move by delta, same
-        as the residency collector."""
-        interp = self.c_interpret.labels()       # exposed from the start
+        """Pull warmup cache hit/miss counts from the jax backend module —
+        lazily, ONLY if it was ever imported (a CPU-only service never pays
+        for it).  Counters move by delta, same as the residency collector."""
         mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
         if mod is None:
             return
         for result, count in mod.warmup_cache_events().items():
             child = self.c_warmup_cache.labels(result=result)
             child.inc(max(0.0, count - child.value))
-        interp.inc(max(0.0, mod.pallas_interpret_events() - interp.value))
 
     def timeseries(self, n: int | None = None) -> list[dict]:
         with self._lock:

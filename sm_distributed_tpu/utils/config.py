@@ -192,12 +192,11 @@ class ParallelConfig:
     # (PERF.md section 2), so it is kept only as the benchmark's failing
     # control (benchmarks/tests/control_on_chip.py).
     cube_dtype: str = "f32"
-    # fused Pallas scoring kernel (ISSUE 18, ops/score_pallas.py): one
-    # VMEM-staged pass does window-gather + per-ion MSM moment partials,
-    # replacing the multi-dispatch gather/segment-sum chain.  "auto"
-    # fuses plain-variant batches on TPU when the plan shape fits the
-    # kernel's VMEM budget; "on" forces it everywhere (interpret-mode
-    # off-TPU — tests/sentinel); "off" keeps the unfused XLA chain.
+    # vestigial: the fused Pallas scoring variant it routed to went in
+    # PR 44.  "auto" and "off" both mean the XLA chain, the only one; the
+    # field stays because benchmarks/configs/*.json spell it out and
+    # from_dict rejects unknown keys (ROADMAP C4 names the PR that lets
+    # it go).
     fused_metrics: str = "auto"
 
 
@@ -762,11 +761,15 @@ class SMConfig:
                             ("overlap_isocalc", ("auto", "on", "off")),
                             ("compile_cache_dir", ("", "off")),
                             ("cube_dtype", ("f32", "bf16")),
-                            ("fused_metrics", ("auto", "on", "off"))):
+                            ("fused_metrics", ("auto", "off"))):
             v = getattr(self.parallel, knob)
             if v not in valid:
+                removed = (
+                    ": the fused Pallas scoring variant was removed in PR 44"
+                    if (knob, v) == ("fused_metrics", "on") else "")
                 raise ValueError(
-                    f"parallel.{knob} must be one of {valid}, got {v!r}")
+                    f"parallel.{knob} must be one of {valid}, "
+                    f"got {v!r}{removed}")
 
     # -- singleton access, mirroring SMConfig.set_path()/get_conf() [U] --
     _instance: ClassVar["SMConfig | None"] = None

@@ -37,7 +37,7 @@ def test_report_schema_and_values():
         "phases",
         # ISSUE 18: roofline + resident-cube-compaction pins
         "roofline_frac", "roofline_floor_s", "roofline_bound",
-        "fused", "cube_dtype", "resident_cube_bytes",
+        "cube_dtype", "resident_cube_bytes",
         "resident_cube_bytes_f32",
         # ISSUE 20: profiler-measured roofline (device time attributed to
         # the scoring kernels by HLO module name, not wall-clock)
@@ -87,13 +87,13 @@ def test_report_schema_and_values():
 def test_report_roofline_fields_pass_through():
     prep, floor, jaxr = _fake_inputs()
     jaxr.update(roofline_frac=0.62, roofline_floor_s=0.484,
-                roofline_bound="bandwidth", fused=True, cube_dtype="bf16",
+                roofline_bound="bandwidth", cube_dtype="bf16",
                 resident_cube_bytes=462_000_000,
                 resident_cube_bytes_f32=924_000_000)
     out = report(prep, floor, jaxr)
     assert out["roofline_frac"] == 0.62
     assert out["roofline_bound"] == "bandwidth"
-    assert out["fused"] is True and out["cube_dtype"] == "bf16"
+    assert out["cube_dtype"] == "bf16"
     # the compaction acceptance pin: compacted bytes at most half of f32
     assert out["resident_cube_bytes"] * 2 <= out["resident_cube_bytes_f32"]
 

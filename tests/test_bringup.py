@@ -160,9 +160,6 @@ _AOT = textwrap.dedent('''
         print("NO-TOPOLOGY", exc); sys.exit(77)
     from sm_distributed_tpu.ops import chaos_pallas as cp
     from sm_distributed_tpu.ops import moments_pallas as mp
-    from sm_distributed_tpu.ops import score_pallas as sp
-    from sm_distributed_tpu.ops.imager_jax import (
-        BAND_WINDOWS, ions_per_chunk_for)
 
     def compile_(fn, *avals):
         sh = SingleDeviceSharding(dev)
@@ -182,20 +179,8 @@ _AOT = textwrap.dedent('''
             x, nrows=s, ncols=s), ((16, side * side), f32))
     compile_(lambda x: cp.chaos_count_sums_strips.__wrapped__(
         x, nrows=1024, ncols=1024), ((2, 1024 * 1024), f32))
-    for p, b, gc in ((4096, 2048, 1024), (4096, 256, 1024),
-                     (65536, 2048, 1024), (65536, 2048, 3072),
-                     (262144, 256, 1024)):
-        ipc = ions_per_chunk_for(b, k, BAND_WINDOWS)
-        wc, c = ipc * k, b // ipc
-        assert sp.fused_fit(wc, ipc, p, gc), (p, b, gc)
-        compile_(lambda w, s, lo, hi, n, gc=gc:
-                 sp.fused_window_moments.__wrapped__(
-                     w, s, lo, hi, n, gc_width=gc, k=k),
-                 ((sp.cols_padded(2 * b * k, gc), p), f32), ((c,), i32),
-                 ((c, wc), i32), ((c, wc), i32), ((), i32))
-    # the budgets refuse what the compiler would refuse
+    # the budget refuses what the compiler would refuse
     assert not mp.moments_fit(8, 524288)
-    assert not sp.fused_fit(512, 128, 65536, 8192)
     print("AOT-OK")
 ''')
 

@@ -264,10 +264,15 @@ def test_primer_idempotent_and_resumable(recorded_backend):
     assert len(manifest["primed"]) >= len(flat)
 
 
-def test_primer_skips_unknown_cube_dtype(recorded_backend, caplog):
-    """A manifest entry recorded under a resident dtype this program no
-    longer has (int8) is skipped with one log line; the manifest's other
-    specs still prime."""
+@pytest.mark.parametrize("field, value, reason", [
+    ("cube_dtype", "int8", "cube_dtype"),
+    ("variant", "fused", "legacy_spec")])
+def test_primer_skips_entries_of_removed_programs(recorded_backend, caplog,
+                                                  field, value, reason):
+    """A manifest entry recorded under a resident dtype (int8) or a scoring
+    variant (the fused Pallas one) this program no longer has is skipped
+    with one log line, never an error; the manifest's other specs still
+    prime."""
     import logging
 
     from sm_distributed_tpu.service.primer import CachePrimer
@@ -275,7 +280,7 @@ def test_primer_skips_unknown_cube_dtype(recorded_backend, caplog):
 
     sm, _tmp = recorded_backend
     specs = buckets.recorded_specs()
-    stale = dict(specs[0], cube_dtype="int8")
+    stale = dict(specs[0], **{field: value})
     assert buckets.record_spec(stale)
     primer = CachePrimer(sm, busy=lambda: False)
     with caplog.at_level(logging.INFO, logger=LOGGER_NAME):
@@ -285,8 +290,8 @@ def test_primer_skips_unknown_cube_dtype(recorded_backend, caplog):
                    "aborted": False}
     assert again["compiled"] == 0 and again["errors"] == 0
     lines = [r.getMessage() for r in caplog.records
-             if "skipped:cube_dtype" in r.getMessage()]
-    assert len(lines) == 1 and "cube_dtype=int8" in lines[0]
+             if f"skipped:{reason}" in r.getMessage()]
+    assert len(lines) == 1 and f"{field}={value}" in lines[0]
 
 
 def test_primer_yields_to_real_work(recorded_backend):
@@ -338,3 +343,54 @@ def test_warmup_manifest_rekeyed_on_buckets(offgrid_ds, tmp_path,
     b2.warmup(batches)
     assert b2.last_warmup_skipped, \
         "same-bucket dataset re-ran warmup executions despite the manifest"
+
+
+def test_warmup_manifest_of_the_parent_misses_once(offgrid_ds, tmp_path,
+                                                   isolated_compile_cache):
+    """A manifest written before PR 44 keyed on the fused kernel's band
+    widths and on ``fused_metrics`` too: the first warmup against it runs
+    its representative batches and rewrites it, the next backend hits."""
+    import hashlib
+
+    import jax
+
+    from sm_distributed_tpu.models.msm_basic import _slice_table
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+
+    ds, truth = offgrid_ds
+    table = _table(truth)
+    batches = [_slice_table(table, s0, min(s0 + 8, table.n_ions))
+               for s0 in range(0, table.n_ions, 8)]
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]}})
+    sm = SMConfig.from_dict(
+        {"backend": "jax_tpu", "work_dir": str(tmp_path / "work"),
+         "parallel": {"formula_batch": 8}})
+    b0 = JaxBackend(ds, dc, sm)
+    plans = [b0._flat_plan(t) for t in batches]
+    b0._grow_for_stream(plans)
+    kinds = sorted({b0._plan_kind(plan) for plan in plans})
+    dev = jax.devices()[0]
+    img = dc.image_generation
+    # the parent's _warmup_manifest_key, on a platform that could not fuse
+    parent_key = hashlib.sha256(repr((
+        kinds,
+        (b0._gc_width, b0._gc_tail, 0, 0, b0._n_keep, b0._r_pad),
+        (b0._nrows_b, ds.ncols, int(b0._mz_host.size), b0.batch, True),
+        (img.nlevels, img.do_preprocessing),
+        ("f32", "auto"),
+        (jax.__version__, dev.platform, str(dev.device_kind)),
+    )).encode()).hexdigest()
+    path = b0._manifest_path()
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(json.dumps(
+        {"keys": [parent_key], "entries": {parent_key: 0}}))
+
+    b1 = JaxBackend(ds, dc, sm)
+    b1.warmup(batches)
+    assert not b1.last_warmup_skipped
+    keys = json.loads(path.read_text())["keys"]
+    assert len(keys) == 2 and keys[0] == parent_key
+    b2 = JaxBackend(ds, dc, sm)
+    b2.warmup(batches)
+    assert b2.last_warmup_skipped
+    assert json.loads(path.read_text())["keys"] == keys

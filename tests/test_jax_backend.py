@@ -2,6 +2,9 @@
 item 8: golden-report comparison between backends — identical FDR ranks,
 metric tolerance)."""
 
+import json
+from pathlib import Path
+
 import numpy as np
 import pandas as pd
 import pytest
@@ -512,6 +515,37 @@ def test_variant_estimator(fixture_ds):
         None, None) == "plain"
 
 
+@pytest.mark.parametrize("resident, n_keep, union, band_width, want", [
+    # hmdb-section128-reannotate (PERF.md section 4, PR 41): 17.45 M
+    # resident peaks, the band at its 2,097,152-slot floor, the compact
+    # capacity sticky at 786,432: 37 x 786,432 < 14 x 2,097,152 by 0.9%
+    (17_450_000, 786_432, 700_000, 1_500_000, "compact"),
+    # ... and a window union one 64k step above it is a `band` batch
+    (17_450_000, 786_432, 800_000, 1_500_000, "band"),
+    # hmdb-section64-reannotate (ledger, PR 43: `compact` on all 62
+    # batches): under the band floor ANY union up to 786,432 is compact
+    (4_370_000, 0, 786_432, 1_000_000, "compact"),
+    (4_370_000, 0, 786_433, 1_000_000, "band"),
+    # a band past the floor pays its own ladder point
+    (58_720_000, 0, 3_000_000, 9_000_000, "compact"),
+    (58_720_000, 0, 4_000_000, 9_000_000, "band"),
+    # a one-batch table over the whole range of a small section: plain
+    (3_670_000, 0, 3_000_000, 3_670_000, "plain")])
+def test_variant_choice_at_the_cells_sizes(resident, n_keep, union,
+                                           band_width, want):
+    """``_variant_for`` under ``auto``, as a table, at the sizes the
+    benchmark's cells hand it: the host decision that picks the program
+    every batch runs."""
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+
+    backend = object.__new__(JaxBackend)   # the decision reads host fields
+    backend._band_mode = backend._compaction = "auto"
+    backend._mz_host = np.empty(resident, np.int8)
+    backend._n_keep = n_keep
+    runs, band = (None, None, union, None), (0, band_width)
+    assert backend._variant_for(runs, band) == want
+
+
 def test_batch_peak_runs_plan_exact():
     """Host compaction plan: kept runs and re-based bound ranks agree with a
     brute-force recomputation on random windows over a random peak list."""
@@ -564,15 +598,118 @@ def test_flat_scratch_guard_names_live_remedies(fixture_ds):
 
 @pytest.mark.parametrize("parallel, names", [
     ({"mz_chunk": 0}, ("mz_chunk",)),
-    ({"cube_dtype": "int8"}, ("cube_dtype", "'f32', 'bf16'"))],
-    ids=["mz_chunk", "cube_dtype-int8"])
+    ({"cube_dtype": "int8"}, ("cube_dtype", "'f32', 'bf16'")),
+    ({"fused_metrics": "on"}, (
+        "parallel.fused_metrics must be one of ('auto', 'off'), got 'on': "
+        "the fused Pallas scoring variant was removed in PR 44",))],
+    ids=["mz_chunk", "cube_dtype-int8", "fused_metrics-on"])
 def test_removed_parallel_values_fail_at_load(parallel, names):
-    """A configuration file written for the cube path or the int8 cube is
-    refused by name at load, not ignored."""
+    """A configuration file written for the cube path, the int8 cube or the
+    fused Pallas variant is refused by name at load, not ignored."""
     with pytest.raises(ValueError) as err:
         SMConfig.from_dict({"backend": "jax_tpu", "parallel": parallel})
     for name in names:
         assert name in str(err.value)
+
+
+_BENCH_CONFIGS = sorted(
+    (Path(__file__).resolve().parent.parent / "benchmarks" / "configs")
+    .glob("*.json"))
+
+
+@pytest.mark.parametrize("path", _BENCH_CONFIGS, ids=lambda p: p.stem)
+def test_every_benchmark_configuration_loads(path):
+    """``from_dict`` rejects unknown keys and values, and no PR but a
+    ``benchmark`` one may edit these files: a field or a value they spell out
+    (``parallel.fused_metrics: auto``) cannot go before they drop it."""
+    block = json.loads(path.read_text())["sm_config"]
+    loaded = SMConfig.from_dict(block)
+    for key, value in block.get("parallel", {}).items():
+        assert getattr(loaded.parallel, key) == value
+    assert len(_BENCH_CONFIGS) >= 7
+
+
+@pytest.mark.parametrize("fused_metrics", ["auto", "off"])
+def test_fused_metrics_values_that_load_build_the_three_programs(
+        fixture_ds, fused_metrics):
+    """The vestigial knob (benchmarks/configs/*.json spell it out) selects
+    nothing: either value that loads binds the geometry's three XLA programs
+    and scores what a configuration without the key scores."""
+    from sm_distributed_tpu.models.msm_jax import (
+        _VARIANTS,
+        JaxBackend,
+        make_flat_jits,
+    )
+    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
+    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
+
+    ds, truth = fixture_ds
+    table = IsocalcWrapper(
+        IsotopeGenerationConfig(adducts=("+H",))).pattern_table(
+        [(sf, "+H") for sf in truth.formulas[:20]])
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
+                             "image_generation": {"ppm": 3.0}})
+
+    def backend(parallel):
+        return JaxBackend(ds, dc, SMConfig.from_dict(
+            {"backend": "jax_tpu",
+             "parallel": {"formula_batch": 32, **parallel}}))
+
+    knob, bare = backend({"fused_metrics": fused_metrics}), backend({})
+    jits = make_flat_jits(knob._common)
+    assert set(jits) == set(_VARIANTS) == {"plain", "band", "compact"}
+    for name, (attr, *_rest) in _VARIANTS.items():
+        assert getattr(knob, attr) is jits[name] is getattr(bare, attr)
+    assert knob._flat_call(table)[0] == bare._flat_call(table)[0]
+    np.testing.assert_array_equal(
+        knob.score_batch(table), bare.score_batch(table))
+
+
+def test_one_batch_table_at_shipped_defaults_runs_the_plain_chain(fixture_ds):
+    """The deployment that used to route to the fused kernel on a TPU: a
+    table of ONE batch over the dataset's whole m/z range with ``parallel.*``
+    at shipped defaults dispatches ``plain``, scores inside the component
+    contracts of ``numpy_ref`` and ranks FDR identically."""
+    from sm_distributed_tpu.analysis.numerics import component_report
+    from sm_distributed_tpu.models.msm_basic import NumpyBackend
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+    from sm_distributed_tpu.ops.fdr import FDR
+    from sm_distributed_tpu.ops.isocalc import IsocalcWrapper
+    from sm_distributed_tpu.utils.config import IsotopeGenerationConfig
+
+    ds, truth = fixture_ds
+    formulas = truth.formulas
+    fdr = FDR(decoy_sample_size=4, target_adducts=("+H",), seed=1)
+    assignment = fdr.decoy_adduct_selection(formulas)
+    pairs, flags = assignment.all_ion_tuples(formulas, ("+H",))
+    table = IsocalcWrapper(
+        IsotopeGenerationConfig(adducts=("+H",))).pattern_table(pairs, flags)
+    dc = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
+                             "image_generation": {"ppm": 3.0}})
+    backend = JaxBackend(ds, dc, SMConfig.from_dict({"backend": "jax_tpu"}))
+    assert table.n_ions <= backend.batch                  # one batch
+    assert table.mzs[table.mzs > 0].min() < ds.mzs_flat.mean() \
+        < table.mzs.max()
+    variant, _args, statics = backend._flat_call(table)
+    assert variant == "plain" and statics["b"] == backend._batch_for(
+        table.n_ions)
+    got = backend.score_batches([table])[0]
+    want = NumpyBackend(ds, dc).score_batch(table)
+    report = component_report(got, want)
+    assert {c: r["outside"] for c, r in report.items()} == dict.fromkeys(
+        report, 0), report
+    assert (got[:, 3] > 0).sum() >= len(truth.present) // 2   # real scores
+
+    def ranks(metrics):
+        df = pd.DataFrame({"sf": table.sfs, "adduct": table.adducts,
+                           "msm": metrics[:, 3]})
+        return fdr.estimate_fdr(df, assignment).sort_values(
+            ["msm", "sf"], ascending=False)
+
+    r_got, r_want = ranks(got), ranks(want)
+    assert list(zip(r_got.sf, r_got.adduct)) == list(
+        zip(r_want.sf, r_want.adduct))
+    np.testing.assert_array_equal(r_got.fdr.to_numpy(), r_want.fdr.to_numpy())
 
 
 @pytest.mark.parametrize("cube_dtype", ["f32", "bf16"])

@@ -1,9 +1,7 @@
 """The membership product of the flat-banded extraction (PR 42): the chunk
-plan that feeds it.  The XLA variants run the WINDOW-MAJOR plan, whose band
+plan that feeds it.  Every variant runs the WINDOW-MAJOR plan, whose band
 stays near 2 x 512 rows on a DENSE table (the HMDB shape at toy size: ions
-0.01 Da apart, so an ion-major chunk's band is several times as wide), and
-give the ion-major plan's bits; the fused kernel alone keeps ion-major
-chunks."""
+0.01 Da apart), and gives the unchunked extraction's bits."""
 
 import numpy as np
 import pytest
@@ -59,66 +57,59 @@ def _backend(ds, **parallel):
          "parallel": {"formula_batch": N_IONS, **parallel}}))
 
 
-def _plans(backend, table):
-    """(grid ranks, the ion-major plan, the window-major plan) of one batch."""
-    from sm_distributed_tpu.ops.imager_jax import (
-        BAND_WINDOWS,
-        ion_window_chunks,
-        ions_per_chunk_for,
-    )
-
-    plan = backend._flat_plan(table)
-    b, k = plan[8], table.max_peaks
-    ion = ion_window_chunks(plan[1], plan[2], b, k,
-                            ions_per_chunk_for(b, k, BAND_WINDOWS))
-    return plan[6], ion, plan[5]
-
-
 def _inv_of(variant, args):
     from sm_distributed_tpu.models.msm_jax import _VARIANTS
 
     return args[_VARIANTS[variant][3] + 4]
 
 
-def _ion_major_scores(backend, table):
-    """The parent's program by hand: ion-major extraction, the metrics of
-    the ion-sorted block, the metric rows un-permuted."""
+def _unchunked_scores(backend, table):
+    """The scoring program by hand and without a chunk plan: the dense
+    ``extract_images_flat`` over the padded windows, then the metrics of
+    that image block."""
     import jax
     import jax.numpy as jnp
 
     from sm_distributed_tpu.models.msm_jax import named_partial
-    from sm_distributed_tpu.ops.imager_jax import extract_images_flat_banded
+    from sm_distributed_tpu.ops.imager_jax import extract_images_flat
     from sm_distributed_tpu.ops.metrics_jax import batch_metrics
 
-    plan = backend._flat_plan(table)
-    pos, ion, _win = _plans(backend, table)
-    starts, rlo, rhi, inv, gc, order = ion
+    _grid, r_lo, r_hi, ints_p, nv_p, _chunks, pos, _runs, b_eff, _band = \
+        backend._flat_plan(table)
     common = backend._common
-    imgs = extract_images_flat_banded(
+    imgs = extract_images_flat(
         backend._px_s, backend._in_f32(), jnp.asarray(pos),
-        jnp.asarray(starts), jnp.asarray(rlo), jnp.asarray(rhi), None,
-        gc_width=gc, n_pixels=common["nrows"] * common["ncols"])
+        jnp.asarray(r_lo), jnp.asarray(r_hi),
+        n_pixels=common["nrows"] * common["ncols"])
     out = jax.jit(named_partial(batch_metrics, **common))(
-        imgs.reshape(plan[8], table.max_peaks, -1),
-        jnp.asarray(plan[3][order]), jnp.asarray(plan[4][order]),
-        n_real=backend._n_real)
-    return np.asarray(out)[inv][: table.n_ions].astype(np.float64)
+        imgs.reshape(b_eff, table.max_peaks, -1),
+        jnp.asarray(ints_p), jnp.asarray(nv_p), n_real=backend._n_real)
+    return np.asarray(out)[: table.n_ions].astype(np.float64)
 
 
-# -- the plans -----------------------------------------------------------------
+# -- the plan ------------------------------------------------------------------
 
-def test_dense_table_widens_the_ion_major_band_only(dense):
+@pytest.mark.parametrize("fused_metrics", ["auto", "off"])
+def test_a_plan_holds_one_window_major_band(dense, fused_metrics):
+    """Ten fields, none of them ion-major, and one band width, under every
+    value of the vestigial knob that loads; the band of a dense table stays
+    narrow (512 neighbours span their own bounds)."""
     ds, table = dense
-    _pos, ion, win = _plans(_backend(ds), table)
-    assert ion[4] >= 3072           # an ion's windows reach 3 Da up
-    assert win[4] <= 1536           # 512 neighbours span their own bounds
-    assert win[3].shape == (N_IONS * K,) and ion[3].shape == (N_IONS,)
+    backend = _backend(ds, fused_metrics=fused_metrics)
+    plan = backend._flat_plan(table)
+    assert len(plan) == 10
+    starts, r_lo_loc, _r_hi_loc, inv, gc_width = plan[5]
+    assert gc_width <= 1536
+    assert inv.shape == (N_IONS * K,)
+    assert r_lo_loc.shape == (starts.size, 512)
+    backend._flat_call(table, plan)
+    assert backend._band_width(plan[8]) == backend._gc_width == gc_width
 
 
-@pytest.mark.parametrize("plan_kind", ["ion_major", "window_major"])
-def test_banded_extraction_on_a_dense_plan_is_the_oracles(dense, plan_kind):
-    """``extract_images_flat_banded`` under each plan against the dense
-    ``extract_images_flat`` and the numpy extraction, bit for bit."""
+def test_banded_extraction_on_a_dense_plan_is_the_oracles(dense):
+    """``extract_images_flat_banded`` under the window-major plan against
+    the dense ``extract_images_flat`` and the numpy extraction, bit for
+    bit."""
     import jax.numpy as jnp
 
     from sm_distributed_tpu.ops.imager_jax import (
@@ -129,27 +120,17 @@ def test_banded_extraction_on_a_dense_plan_is_the_oracles(dense, plan_kind):
 
     ds, table = dense
     backend = _backend(ds)
-    grid, r_lo, r_hi, _ints, _nv = backend._padded_windows(table, N_IONS)
-    pos, ion, win = _plans(backend, table)
+    _grid, r_lo, r_hi, _ints, _nv = backend._padded_windows(table, N_IONS)
+    plan = backend._flat_plan(table)
+    pos, (starts, rlo, rhi, inv, gc) = plan[6], plan[5]
     px, ints = backend._px_s, backend._in_f32()
     want = np.asarray(extract_images_flat(
         px, ints, jnp.asarray(pos), jnp.asarray(r_lo), jnp.asarray(r_hi),
         n_pixels=ds.n_pixels))
-    if plan_kind == "window_major":
-        starts, rlo, rhi, inv, gc = win
-        got = np.asarray(extract_images_flat_banded(
-            px, ints, jnp.asarray(pos), jnp.asarray(starts),
-            jnp.asarray(rlo), jnp.asarray(rhi), jnp.asarray(inv),
-            gc_width=gc, n_pixels=ds.n_pixels))
-    else:
-        starts, rlo, rhi, _inv, gc, order = ion
-        rows = np.asarray(extract_images_flat_banded(
-            px, ints, jnp.asarray(pos), jnp.asarray(starts),
-            jnp.asarray(rlo), jnp.asarray(rhi), None,
-            gc_width=gc, n_pixels=ds.n_pixels))
-        got = np.empty_like(rows).reshape(N_IONS, K, -1)
-        got[order] = rows.reshape(N_IONS, K, -1)      # ion-sorted -> table
-        got = got.reshape(N_IONS * K, -1)
+    got = np.asarray(extract_images_flat_banded(
+        px, ints, jnp.asarray(pos), jnp.asarray(starts),
+        jnp.asarray(rlo), jnp.asarray(rhi), jnp.asarray(inv),
+        gc_width=gc, n_pixels=ds.n_pixels))
     np.testing.assert_array_equal(got, want)
     oracle = extract_ion_images(ds, table, ppm=3.0).reshape(N_IONS * K, -1)
     np.testing.assert_array_equal(
@@ -169,7 +150,7 @@ VARIANT_KNOBS = {
 @pytest.mark.parametrize("variant", sorted(VARIANT_KNOBS))
 def test_backend_scores_a_dense_table_window_major_bit_exact(dense, variant):
     """Each banded variant runs the window-major plan on the dense table,
-    and its metrics are the ion-major program's bits and ``numpy_ref``'s
+    and its metrics are the unchunked program's bits and ``numpy_ref``'s
     within the f32 contracts."""
     ds, table = dense
     backend = _backend(ds, **VARIANT_KNOBS[variant])
@@ -177,20 +158,21 @@ def test_backend_scores_a_dense_table_window_major_bit_exact(dense, variant):
     assert chosen == variant and statics["gc_width"] <= 1536
     assert _inv_of(chosen, args).shape == (N_IONS * K,)     # the rows'
     got = backend.score_batch(table)
-    np.testing.assert_array_equal(got, _ion_major_scores(backend, table))
+    np.testing.assert_array_equal(got, _unchunked_scores(backend, table))
     want = NumpyBackend(ds, DS_CONFIG).score_batch(table)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
     assert (got[:, 2] > 0).sum() > N_IONS // 2        # spectral: real images
 
 
-def test_probe_phases_follow_the_window_major_plan(dense):
-    """``probe_phases`` hands extraction the row inverse, so its image block
-    and side inputs are in the table's order, and the full phase is what
-    ``score_batch`` dispatches."""
+@pytest.mark.parametrize("variant", sorted(VARIANT_KNOBS))
+def test_probe_phases_follow_the_window_major_plan(dense, variant):
+    """``probe_phases`` hands each variant's extraction the row inverse, so
+    its image block and side inputs are in the table's order, and the full
+    phase is what ``score_batch`` dispatches."""
     ds, table = dense
-    backend = _backend(ds)
+    backend = _backend(ds, **VARIANT_KNOBS[variant])
     phases, info = backend.probe_phases(table)
-    assert info["gc_width"] <= 1536
+    assert info["variant"] == variant and info["gc_width"] <= 1536
     np.testing.assert_array_equal(
         np.asarray(phases["fused_full"]())[:N_IONS],
         backend.score_batch(table).astype(np.float32))
@@ -199,25 +181,12 @@ def test_probe_phases_follow_the_window_major_plan(dense):
     np.testing.assert_array_equal(imgs[:, :, : ds.n_pixels], want)
 
 
-def test_fused_kernel_keeps_its_ion_major_chunks(dense):
-    """Only a backend that can route to the fused kernel plans ion-major
-    chunks, and only the fused call takes them."""
-    ds, table = dense
-    assert _backend(ds)._flat_plan(table)[10] is None     # CPU, auto
-    backend = _backend(ds, fused_metrics="on")
-    variant, args, statics = backend._flat_call(table)
-    assert variant == "fused" and statics["gc_width"] >= 3072
-    assert _inv_of(variant, args).shape == (N_IONS,)      # the ions'
-    assert backend._bucket_spec(variant, args, statics)["w"] == N_IONS
-    assert backend._gc_width <= 1536 < backend._gf_width
-
-
 @pytest.mark.parametrize("n_ions,k", [(1024, 1), (1000, 4), (100, 4), (7, 2)])
 def test_short_and_padded_batches_gather_their_own_rows(dense, n_ions, k):
     """One window an ion, a batch short of its static size (empty windows
     sort last and leave the band narrow), a tail batch, and fewer windows
     than one chunk holds (the scan's rows outnumber ``inv``'s): each scores
-    as ``numpy_ref`` does and as the ion-major program did."""
+    as ``numpy_ref`` does and as the unchunked program."""
     from sm_distributed_tpu.models.msm_basic import _slice_table
 
     ds, table = dense
@@ -231,7 +200,7 @@ def test_short_and_padded_batches_gather_their_own_rows(dense, n_ions, k):
     assert _inv_of(variant, args).shape == (statics["b"] * k,)
     assert statics["gc_width"] <= 1536
     got = backend.score_batch(part)
-    np.testing.assert_array_equal(got, _ion_major_scores(backend, part))
+    np.testing.assert_array_equal(got, _unchunked_scores(backend, part))
     want = NumpyBackend(ds, DS_CONFIG).score_batch(part)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
     assert (got[:, 1:3] > 0).any()                    # not a test of zeros

@@ -247,8 +247,9 @@ def test_repo_clean_for_numlint_rules_against_baseline():
     baseline = load_baseline(REPO_ROOT / "conf" / "smlint_baseline.json")
     res = run_lint(_repo_project(), baseline, only=_NUMLINT_RULES)
     assert not res.new, "\n".join(f.render() for f in res.new)
-    # the legacy correlation tripwire stays VISIBLE as suppressed history
-    assert any(f.rule == "masked-reduction" for f in res.suppressed)
+    # ... and with nothing baselined: the one argued entry went with the
+    # legacy correlation it kept firing on (PR 44)
+    assert not res.suppressed
 
 
 def test_jitting_modules_declare_numerics_registries():
@@ -267,8 +268,7 @@ def test_smlint_json_emits_numerics_totals(capsys):
     assert rc == 0
     assert out["sm_numerics_contracts_total"] >= 25
     assert out["sm_numerics_modules_total"] >= 8
-    # the baselined legacy-correlation findings stay visible as totals
-    assert out["sm_numerics_violations_total"] >= 1
+    assert out["sm_numerics_violations_total"] == 0
 
 
 # --------------------------------------------------------------- sentinel

@@ -277,6 +277,38 @@ def test_takeover_requeues_dead_replica_claims(tmp_path):
     assert sched._fenced_count == 0               # the SURVIVOR was clean
 
 
+def test_takeover_scan_spares_a_claim_in_progress(tmp_path):
+    """A message claimed by rename keeps its publish-time mtime and has no
+    lease until the dispatcher writes one: a takeover scan of the SAME
+    replica that lands in between (here: exactly there) leaves the claim
+    alone, and the job completes once, unfenced."""
+    import os
+
+    root = tmp_path / QUEUE
+    _publish(tmp_path, "old1")
+    aged = time.time() - 10.0                     # long past stale_after_s
+    os.utime(root / "pending" / "old1.json", (aged, aged))
+    done = []
+    sched = JobScheduler(tmp_path, lambda m: done.append(m["msg_id"]),
+                         config=_cfg(replica_id="r1"))
+    lease_claim, scanned = sched.leases.claim, []
+
+    def claim_after_a_scan(msg_id):
+        assert (root / "running" / f"{msg_id}.json").exists()
+        sched._takeover_scan()
+        scanned.append(msg_id)
+        return lease_claim(msg_id)
+
+    sched.leases.claim = claim_after_a_scan
+    sched.start()
+    assert sched.wait_for_terminal(1, timeout_s=20.0)
+    sched.shutdown()
+    assert scanned == ["old1"] and done == ["old1"]
+    assert (root / "done" / "old1.json").exists()
+    assert not (root / "pending" / "old1.json").exists()
+    assert sched._fenced_count == 0
+
+
 def test_fence_race_two_replicas_one_completion(tmp_path):
     """The satellite race: two replicas end up claiming the same message
     around a lease expiry — exactly one completes; the loser's spool and

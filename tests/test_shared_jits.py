@@ -58,13 +58,11 @@ NROWS, NCOLS = 7, 9          # 7 rows bucket to 8: zero-row padding is real
 COMMON = dict(nrows=8, ncols=NCOLS, nlevels=30, do_preprocessing=False, q=99.0)
 DC = DSConfig.from_dict({"isotope_generation": {"adducts": ["+H"]},
                          "image_generation": {"ppm": 3.0}})
-# what forces each extraction variant (JaxBackend._variant_for / _maybe_fuse)
+# what forces each extraction variant (JaxBackend._variant_for)
 VARIANTS = {
     "plain": {"peak_compaction": "off", "band_slice": "off"},
     "compact": {"peak_compaction": "on", "band_slice": "off"},
     "band": {"peak_compaction": "off", "band_slice": "on"},
-    "fused": {"peak_compaction": "off", "band_slice": "off",
-              "fused_metrics": "on"},
 }
 # the parent's construction, stated apart from the module under test
 STATICS = {
@@ -73,7 +71,6 @@ STATICS = {
                 ("n_keep", "gc_width", "b", "k")),
     "band": (msm_jax.fused_score_fn_flat_banded_sliced,
              ("w_cap", "gc_width", "b", "k")),
-    "fused": (msm_jax.fused_score_fn_flat_fused, ("gc_width", "b", "k")),
 }
 
 
@@ -260,7 +257,7 @@ def test_a_backend_is_collectable_while_the_registry_lives(sections):
         entries = list(msm_jax._SHARED_JITS.values())
     jits = [f for e in entries for f in (e.values() if isinstance(e, dict)
                                          else [e])]
-    assert len(jits) == 5
+    assert len(jits) == 4
     for fn in jits:
         closure = fn.__wrapped__
         assert closure.args == () and closure.func.__closure__ is None

@@ -283,6 +283,17 @@ def test_acceptance_slo_endpoint_reports_real_attainment(traced_service_job):
     assert "sm_slo_first_annotation_seconds_count 1" in text
 
 
+def test_acceptance_metrics_expose_the_interpret_counter_at_zero(
+        traced_service_job):
+    """``benchmarks/serve.py::hidden_routes`` and ``chip_smoke.py`` fail a
+    run when ``/metrics`` lacks the name: it stays exposed, at 0, though no
+    served program can interpret a Pallas kernel any more."""
+    h, _msg_id, _tid = traced_service_job
+    lines = [ln for ln in h.metrics_text().splitlines()
+             if ln.startswith("sm_pallas_interpret_total")]
+    assert lines == ["sm_pallas_interpret_total 0"]
+
+
 def test_acceptance_timeseries_contains_occupancy_samples(traced_service_job):
     h, _msg_id, _tid = traced_service_job
     body = _get(h, "/debug/timeseries")
