@@ -39,6 +39,9 @@ SEED_FORMULAS = [
 ]
 
 
+ADDUCT_STREAM = 0xADD     # the adduct draw's own stream beside the seed's
+
+
 def formula_list(n: int) -> list[str]:
     """Deterministic list of ``n`` plausible CHNO(PS) sum formulas."""
     out = list(dict.fromkeys(SEED_FORMULAS))
@@ -147,7 +150,9 @@ def _write_imzml(path: Path, nrows: int, ncols: int, lens: np.ndarray,
 
 def generate(cache: Path, params: dict, seed: int) -> dict:
     """The dataset of (``params``, ``seed``) under ``cache``: made or found.
-    Returns {"path", "formulas", "present", "n_peaks", "nrows", "ncols"}."""
+    Returns {"path", "formulas", "present", "n_peaks", "nrows", "ncols"} and,
+    where ``params`` has a list of ``adducts``, "present_ions"
+    ([[sf, adduct], ...], in ``present``'s order)."""
     key = hashlib.sha256(json.dumps(
         {"params": params, "seed": int(seed), "v": 1},
         sort_keys=True).encode()).hexdigest()[:16]
@@ -167,8 +172,17 @@ def generate(cache: Path, params: dict, seed: int) -> dict:
     k = params["n_peaks"]
     pk_mz = np.zeros((n_present, k))
     pk_int = np.zeros((n_present, k))
+    # with ``adducts`` each formula with signal carries it under exactly ONE
+    # adduct of the list: the list cycled to length and shuffled (every seed
+    # the same count an adduct, in another order) by a stream of its own, so
+    # a configuration without the key draws nothing new and keeps its bytes
+    # and its cache key
+    adducts = [params["adduct"]] * n_present if "adducts" not in params \
+        else [str(a) for a in np.random.default_rng(
+            [int(seed), ADDUCT_STREAM]).permutation(
+            np.resize(params["adducts"], n_present))]
     for i, sf in enumerate(present):
-        mzs, ints = isotope_peaks(sf, params["adduct"], n_peaks=k)
+        mzs, ints = isotope_peaks(sf, adducts[i], n_peaks=k)
         pk_mz[i, :mzs.size], pk_int[i, :ints.size] = mzs, ints
     amp = _spatial_patterns(n_present, nrows, ncols, rng)
     f_ix, p_ix = np.nonzero(amp > 0.02)
@@ -191,6 +205,8 @@ def generate(cache: Path, params: dict, seed: int) -> dict:
     meta = {"path": str(path), "formulas": formulas, "present": present,
             "n_peaks": int(mzs.size), "nrows": nrows, "ncols": ncols,
             "seed": int(seed)}
+    if "adducts" in params:
+        meta["present_ions"] = [list(ion) for ion in zip(present, adducts)]
     meta_path.write_text(json.dumps(meta))
     return meta
 

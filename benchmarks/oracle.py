@@ -25,6 +25,10 @@ DECOY_ADDUCTS = {"+" + el for el in (
     "Cs Ba La Ce Pr Nd Sm Eu Gd Tb Dy Ho Ir Th Pt Os Yb Lu Tm Er Pb Tl Hg Au "
     "W Ta Hf Re").split()}
 COMPONENTS = ("chaos", "spatial", "spectral", "msm")
+# upstream's table of that name, which a job MAY store beside its two
+# parquets: one row per sampled (sf, target_adduct, decoy_adduct)
+ASSIGNMENT = "target_decoy_add.parquet"
+ASSIGNMENT_COLUMNS = ["sf", "target_adduct", "decoy_adduct"]
 
 
 def limits(guarantees: dict) -> dict:
@@ -39,6 +43,82 @@ def limits(guarantees: dict) -> dict:
     return out
 
 
+def read_assignment(kept: Path, allm, targets: list[str]):
+    """(the job's decoy assignment as rows of ``ASSIGNMENT_COLUMNS``, whether
+    the job stored it).  With ONE target adduct the file is optional and the
+    assignment implied: every decoy row of a formula belongs to its one
+    target.  With more and no file there is no assignment (every formula
+    then faults)."""
+    import pandas as pd
+
+    if (kept / ASSIGNMENT).exists():
+        return pd.read_parquet(kept / ASSIGNMENT)[ASSIGNMENT_COLUMNS], True
+    if len(targets) == 1:
+        d = allm[~allm.is_target]
+        return pd.DataFrame({"sf": d.sf.to_numpy(),
+                             "target_adduct": targets[0],
+                             "decoy_adduct": d.adduct.to_numpy()}), False
+    return pd.DataFrame(columns=ASSIGNMENT_COLUMNS), False
+
+
+def distinct_ions(kept: Path, n_formulas: int, targets: list[str],
+                  decoys_per: int) -> int:
+    """The DISTINCT ions a job scores, from its kept answer: every formula
+    under each target adduct plus the distinct ``(sf, decoy_adduct)`` of its
+    stored assignment (a decoy two target adducts sampled is scored once).
+    With one target adduct and no file the samples cannot overlap: the
+    nominal product, which is also what stands in for a job of several
+    adducts that stored none (``ion_table_faults`` fails that job)."""
+    if not (kept / ASSIGNMENT).exists():
+        return n_formulas * len(targets) * (1 + decoys_per)
+    import pandas as pd
+
+    assign = pd.read_parquet(kept / ASSIGNMENT)
+    return n_formulas * len(targets) + len(
+        assign[["sf", "decoy_adduct"]].drop_duplicates())
+
+
+def ion_table_faults(allm, assign, formulas: list[str], targets: list[str],
+                     decoys_per: int) -> int:
+    """One for each formula whose rows are not: exactly one target row per
+    target adduct; per ``(sf, target adduct)`` exactly ``decoys_per`` DISTINCT
+    sampled decoy adducts, all of ``DECOY_ADDUCTS`` less the targets; its
+    decoy rows the union of its samples, each once (no sampled decoy without
+    its scored row and none the other way).  One more when the table's
+    formulas are not the dataset's."""
+    import pandas as pd
+
+    decoy_pool = sorted(DECOY_ADDUCTS - set(targets))
+    t, d = allm[allm.is_target], allm[~allm.is_target]
+    sfs = pd.Index(pd.unique(pd.concat([allm.sf, assign.sf])))
+    bad = pd.Series(False, index=sfs)
+
+    def mark(per_sf_ok):
+        bad[:] = bad.to_numpy() | ~per_sf_ok.reindex(
+            sfs, fill_value=False).to_numpy(dtype=bool)
+
+    by = t.assign(known=t.adduct.isin(targets)).groupby("sf", sort=False)
+    mark((by.size() == len(targets))
+         & (by.adduct.nunique() == len(targets)) & by.known.all())
+    sample = assign.assign(
+        known=assign.decoy_adduct.isin(decoy_pool)
+        & assign.target_adduct.isin(targets)).groupby(
+        ["sf", "target_adduct"], sort=False)
+    whole = ((sample.size() == decoys_per)
+             & (sample.decoy_adduct.nunique() == decoys_per)
+             & sample.known.all())
+    per_sf = whole.groupby(level="sf", sort=False)
+    mark(per_sf.all() & (per_sf.size() == len(targets)))
+    mark(~d.duplicated(["sf", "adduct"], keep=False).groupby(
+        d.sf, sort=False).any())
+    scored = d[["sf", "adduct"]].drop_duplicates()
+    sampled = assign[["sf", "decoy_adduct"]].drop_duplicates().rename(
+        columns={"decoy_adduct": "adduct"})
+    sides = scored.merge(sampled, how="outer", indicator=True)
+    bad[sides.sf[sides._merge != "both"].unique()] = True
+    return int(bad.sum()) + int(set(allm.sf) != set(formulas))
+
+
 def compare_job(answers: Path, msg_id: str, dataset: dict, config: dict,
                 seed: int, cache: dict) -> dict:
     """The numbers of one job's kept answer (``limits`` has a limit for
@@ -50,27 +130,17 @@ def compare_job(answers: Path, msg_id: str, dataset: dict, config: dict,
            "n_peaks": config["guarantees"]["isotope_peaks"],
            **{k: v for k, v in ds_cfg["isotope_generation"].items()
               if k != "adducts"}}
-    targets = set(ds_cfg["isotope_generation"]["adducts"])
+    targets = sorted(set(ds_cfg["isotope_generation"]["adducts"]))
     decoys_per = config["guarantees"]["decoys_per_target"]
     allm = pd.read_parquet(answers / msg_id / "all_metrics.parquet")
     ann = pd.read_parquet(answers / msg_id / "annotations.parquet")
+    assign, stored = read_assignment(answers / msg_id, allm, targets)
     out = {}
 
-    # the ion table: every formula once per target adduct, plus its
-    # distinct sampled decoys
-    faults = 0
-    by_sf = allm.groupby("sf", sort=False)
-    if set(by_sf.groups) != set(dataset["formulas"]):
-        faults += 1
-    for _, g in by_sf:
-        t = g[g.is_target]
-        d = g[~g.is_target]
-        if set(t.adduct) != targets or len(t) != len(targets) \
-                or len(d) != decoys_per * len(targets) \
-                or d.adduct.nunique() != len(d) \
-                or not set(d.adduct) <= DECOY_ADDUCTS - targets:
-            faults += 1
-    out["ion_table_faults"] = faults
+    # the ion table: every formula once per target adduct, plus the distinct
+    # decoys its target adducts sampled
+    out["ion_table_faults"] = ion_table_faults(
+        allm, assign, dataset["formulas"], targets, decoys_per)
 
     # components of a seeded sample of ions against the reference
     key = dataset["path"]
@@ -96,13 +166,21 @@ def compare_job(answers: Path, msg_id: str, dataset: dict, config: dict,
         out[f"{c}_max_abs_err"] = float(np.nan_to_num(err[:, i],
                                                       nan=np.inf).max())
 
-    # FDR levels re-derived from the served msm of ALL ions
+    # FDR levels re-derived from the served msm: each target adduct's
+    # targets against THAT adduct's own samples, one entry per sampled
+    # (sf, ta, da) (a decoy two adducts share counts in both rankings, as
+    # upstream's merge on target_decoy_add does)
     mism = 0
-    for ta in sorted(targets):
+    decoys = allm[~allm.is_target]
+    if stored:
+        by_ion = decoys.drop_duplicates(["sf", "adduct"]).rename(
+            columns={"adduct": "decoy_adduct"})[["sf", "decoy_adduct", "msm"]]
+    for ta in targets:
         t = allm[allm.is_target & (allm.adduct == ta)]
-        d = allm[~allm.is_target]
-        levels = scoring.fdr_levels(t.msm.to_numpy(), d.msm.to_numpy(),
-                                    decoys_per)
+        d_msm = assign[assign.target_adduct == ta].merge(
+            by_ion, how="left").msm.fillna(0.0).to_numpy() \
+            if stored else decoys.msm.to_numpy()
+        levels = scoring.fdr_levels(t.msm.to_numpy(), d_msm, decoys_per)
         ref = pd.DataFrame({"sf": t.sf.to_numpy(), "adduct": ta,
                             "level_ref": levels})
         both = ann.merge(ref, on=["sf", "adduct"])
@@ -110,26 +188,40 @@ def compare_job(answers: Path, msg_id: str, dataset: dict, config: dict,
             + int((both.fdr_level != both.level_ref).sum())
     mism += abs(len(ann) - int(allm.is_target.sum()))
     out["fdr_level_mismatches"] = mism
-    found = set(ann[(ann.fdr_level <= 0.1)
-                    & ann.adduct.isin(list(targets))].sf)
-    out["positives_above_fdr"] = len(set(dataset["present"]) - found)
+    hits = ann[(ann.fdr_level <= 0.1) & ann.adduct.isin(targets)]
+    if "present_ions" in dataset:
+        out["positives_above_fdr"] = len(
+            {tuple(ion) for ion in dataset["present_ions"]}
+            - set(zip(hits.sf, hits.adduct)))
+    else:
+        out["positives_above_fdr"] = len(set(dataset["present"])
+                                         - set(hits.sf))
     return out
 
 
-def decide(numbers: dict, lim: dict, say) -> bool:
-    """Print each number beside its limit; True when every one holds."""
+def line(name: str, value, limit) -> str:
+    """A number beside its limit, as every run prints it."""
+    return (f"correct: {name} = {value!r} limit {limit!r} "
+            f"{'ok' if value <= limit else 'OUTSIDE'}")
+
+
+def decide(numbers: dict, lim: dict, say, compared: dict | None = None
+           ) -> bool:
+    """Print each number beside its limit; True when every one holds.
+    ``compared`` collects ``{name: {"value", "limit"}}`` for the result
+    line."""
     ok = True
     for name, value in numbers.items():
         limit = lim[name.split(":")[-1]]
-        good = value <= limit
-        ok &= bool(good)
-        say(f"correct: {name} = {value!r} limit {limit!r} "
-            f"{'ok' if good else 'OUTSIDE'}")
+        ok &= bool(value <= limit)
+        say(line(name, value, limit))
+        if compared is not None:
+            compared[name] = {"value": value, "limit": limit}
     return ok
 
 
 def check_jobs(answers: Path, jobs: list[dict], config: dict, seed: int,
-               say) -> bool:
+               say, compared: dict | None = None) -> bool:
     t0 = time.time()
     cache: dict = {}
     lim = limits(config["guarantees"])
@@ -138,6 +230,6 @@ def check_jobs(answers: Path, jobs: list[dict], config: dict, seed: int,
         nums = compare_job(answers, job["msg_id"], job["dataset"], config,
                            seed, cache)
         ok &= decide({f"{job['msg_id']}:{k}": v for k, v in nums.items()},
-                     lim, say)
+                     lim, say, compared)
     say(f"oracle over {[j['msg_id'] for j in jobs]}: {time.time() - t0:.1f}s")
     return ok

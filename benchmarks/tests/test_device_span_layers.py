@@ -85,10 +85,12 @@ def test_every_new_metric_has_its_manifest_entry():
     import json
 
     manifest = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    new = {m["name"]: m for m in manifest["per_layer"][-6:]}
-    assert list(new) == ["backend_build_s", "store_images_s",
-                         "lease_device_busy_pct", "extract_device_s",
-                         "chaos_device_s", "moments_device_s"]
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    names = ["backend_build_s", "store_images_s", "lease_device_busy_pct",
+             "extract_device_s", "chaos_device_s", "moments_device_s"]
+    order = [n for n in by_name if n in names]      # later metrics append
+    assert order == names
+    new = {n: by_name[n] for n in names}
     assert new["backend_build_s"]["workloads"] == ["section64-uploads"]
     assert all(m["source"] == "device_trace" for n, m in new.items()
                if "device" in n)

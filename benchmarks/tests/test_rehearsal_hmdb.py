@@ -6,7 +6,8 @@ wants every per-layer metric the cell lists but the ``device_trace`` ones.
 The three readers this cell brought are read here from
 ``data/hmdb_job.trace.jsonl``: the raw trace of one in-window job of the
 cell's traced run on the chip (PR 39, the final tree's ``git archive``), as
-``GET /jobs/<id>/trace?raw=1`` served it."""
+``GET /jobs/<id>/trace?raw=1`` served it.  Later cells and metrics append
+their names after this one's, so nothing here asks for a place in a list."""
 
 from __future__ import annotations
 
@@ -32,22 +33,26 @@ def test_the_cell_is_the_deployment_the_issue_names():
     assert cfg["dataset"]["n_formulas"] in (8000, 6000, 4000)
     assert round(cfg["dataset"]["n_formulas"]
                  * cfg["dataset"]["present_fraction"]) == 300
-    listed = {m["name"] for m in MANIFEST["per_layer"]
-              if CELL in m.get("workloads", [])}
-    assert listed == {"store_images_s", "lease_device_busy_pct",
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    listed = {n for n, m in by_name.items() if CELL in m.get("workloads", [])}
+    assert listed >= {"store_images_s", "lease_device_busy_pct",
                       "extract_device_s", "chaos_device_s",
                       "moments_device_s", "chaos_roofline_pct",
                       "hold_stall_s", "hold_unnamed_s",
                       "host_cpu_per_job_s", "interp_late_ms", *NEW}
-    new = {"better": "lower", "workloads": [CELL]}
-    assert MANIFEST["per_layer"][-3:] == [
-        {"name": "pattern_load_s", "unit": "s", "source": "program_span",
-         "layer": "isotope patterns", "moves": "report_s", **new},
-        {"name": "patterns_computed_in_window", "unit": "count",
-         "source": "program_counter", "layer": "isotope patterns",
-         "moves": "report_p95_s", **new},
-        {"name": "batch_host_ms", "unit": "ms", "source": "program_span",
-         "layer": "scoring", "moves": "ions_per_s", **new}]
+    want = {"pattern_load_s": {
+                "unit": "s", "source": "program_span",
+                "layer": "isotope patterns", "moves": "report_s"},
+            "patterns_computed_in_window": {
+                "unit": "count", "source": "program_counter",
+                "layer": "isotope patterns", "moves": "report_p95_s"},
+            "batch_host_ms": {
+                "unit": "ms", "source": "program_span", "layer": "scoring",
+                "moves": "ions_per_s"}}
+    for name in NEW:
+        entry = dict(by_name[name])
+        assert entry.pop("workloads")[0] == CELL    # later cells append
+        assert entry == {"name": name, "better": "lower", **want[name]}
     reported = {m["name"] for m in MANIFEST["end_to_end"]
                 if run.reports(m, CELL)}
     assert reported == {"report_s", "report_p95_s", "ions_per_s", "setup_s"}

@@ -2,8 +2,10 @@
 over a catalogue of datasets, read from a traffic file of parameters.
 
 Every mix is the same rule with other numbers: set-up submits each catalogue
-dataset once (``clients`` at a time), so every shape the window will use has
-compiled or loaded and the residency LRU is in its steady state; the window
+dataset once (``clients`` at a time; on a pool that places jobs over several
+chips once per placement, ``Driver.each_placement``), so every shape the
+window will use has compiled or loaded on every chip that will run it and the
+residency LRU is in its steady state; the window
 then goes on through the catalogue in cyclic order, each client waiting for
 its report before its next submit: under a fresh ``ds_id`` each (a new
 upload), or with ``"ds_id": "same"`` under the dataset's own (upstream's
@@ -19,6 +21,7 @@ import threading
 import time
 from pathlib import Path
 
+from oracle import ASSIGNMENT
 from serve import TERMINAL, Serve
 
 
@@ -83,12 +86,14 @@ class Driver:
                 for j in self.jobs:
                     j["done"].set()
 
-    def submit(self) -> dict:
-        """The next dataset of the cyclic order, under a fresh ds_id."""
+    def submit(self, k: int | None = None) -> dict:
+        """The next dataset of the cyclic order (or dataset ``k``, for
+        set-up), under a fresh ds_id."""
         with self._lock:
             n = self._next
             self._next += 1
-        k = n % len(self.catalogue)
+        if k is None:
+            k = n % len(self.catalogue)
         ds = self.catalogue[k]
         msg_id = f"{self.prefix}-{n:04d}"
         # a new upload gets a fresh ds_id; a reprocess keeps its dataset's
@@ -115,9 +120,51 @@ class Driver:
         if ok and job["row"]["state"] == "done":
             kept = self.answers / job["msg_id"]
             kept.mkdir(parents=True)
+            stored = self.serve.results / job["ds_id"]
             for name in ("all_metrics.parquet", "annotations.parquet"):
-                shutil.copy(self.serve.results / job["ds_id"] / name, kept)
+                shutil.copy(stored / name, kept)
+            # the decoy assignment, where the job stored one (README.md:
+            # required only with more than one target adduct)
+            if (stored / ASSIGNMENT).exists():
+                shutil.copy(stored / ASSIGNMENT, kept)
         return ok
+
+    def each_placement(self, placements: int, lease_of, go_on,
+                       tries: int = 3,
+                       job_timeout: float = 900.0) -> tuple[list, int]:
+        """Set-up on a pool that places jobs over several chips: every
+        catalogue dataset ``placements`` times at once and alone in the
+        pool, which leases the free chips of lowest index, so that one copy
+        lands on each placement.  The program compiles, caches and loads an
+        executable per chip, and a dataset's capacities pick its
+        executables: a dataset a chip has not scored yet costs that chip a
+        compile (~30 s under the lease) or a load inside the window.
+        ``lease_of(job)`` says which chips a finished job was leased; a
+        dataset that missed a placement goes round again, ``tries`` times in
+        all.  After the first, a round starts only while ``go_on()`` says so
+        (a run has a time limit; what is left then is counted, not hidden).
+        Returns the wall
+        seconds of each round and how many (dataset, placement) pairs no job
+        covered."""
+        walls, missing = [], 0
+        for k in range(len(self.catalogue)):
+            seen: set[tuple] = set()
+            for _ in range(tries):
+                if walls and not go_on():
+                    break
+                t0 = time.time()
+                jobs = [self.submit(k) for _ in range(placements)]
+                for job in jobs:
+                    if not self.wait(job, time.time() + job_timeout):
+                        raise RuntimeError(
+                            f"job {job['msg_id']} not terminal after "
+                            f"{job_timeout:.0f}s")
+                walls.append(round(time.time() - t0, 1))
+                seen |= {tuple(lease_of(job)) for job in jobs}
+                if len(seen) >= placements:
+                    break
+            missing += max(0, placements - len(seen))
+        return walls, missing
 
     def run(self, clients: int, until: float | None = None,
             count: int | None = None, job_timeout: float = 900.0) -> None:
