@@ -61,7 +61,11 @@ from sm_distributed_tpu.engine.daemon import (  # noqa: E402
     QueuePublisher,
     _STATES,
 )
-from sm_distributed_tpu.engine.storage import JobLedger  # noqa: E402
+from sm_distributed_tpu.engine.storage import (  # noqa: E402
+    RESULT_TABLES,
+    JobLedger,
+    read_result_tables,
+)
 from sm_distributed_tpu.io.fixtures import (  # noqa: E402
     FIXTURE_FORMULAS,
     generate_synthetic_dataset,
@@ -587,13 +591,7 @@ class Context:
 
 
 def _read_report(results: Path) -> tuple:
-    import pandas as pd
-
-    out = []
-    for name in ("annotations.parquet", "all_metrics.parquet"):
-        df = pd.read_parquet(results / DS_ID / name)
-        out.append(df.sort_values(["sf", "adduct"]).reset_index(drop=True))
-    return tuple(out)
+    return read_result_tables(results / DS_ID)
 
 
 def _assert_frames_equal(got, want, label: str, errs: list[str]) -> None:
@@ -662,8 +660,8 @@ def check_invariants(ctx: Context, golden) -> list[str]:
         except SegmentError as exc:
             errs.append(f"torn/unreadable read segment: {exc}")
     got = _read_report(ctx.results)
-    _assert_frames_equal(got[0], golden[0], "annotations", errs)
-    _assert_frames_equal(got[1], golden[1], "all_metrics", errs)
+    for name, g, w in zip(RESULT_TABLES, got, golden):
+        _assert_frames_equal(g, w, name, errs)
     return errs
 
 

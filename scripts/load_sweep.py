@@ -88,6 +88,10 @@ sys.path.insert(0, str(REPO_ROOT))
 from scripts.chaos_sweep import _debris  # noqa: E402 — shared invariant
 from sm_distributed_tpu.engine.daemon import annotate_callback  # noqa: E402
 from sm_distributed_tpu.engine.residency import DatasetResidency  # noqa: E402
+from sm_distributed_tpu.engine.storage import (  # noqa: E402
+    RESULT_TABLES,
+    read_result_tables,
+)
 from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset  # noqa: E402
 from sm_distributed_tpu.models import breaker as breaker_mod  # noqa: E402
 from sm_distributed_tpu.service import AnnotationService  # noqa: E402
@@ -1435,17 +1439,11 @@ def mix_stream(base: Path, fx: dict, n_batch: int = 6,
         # same spectra, down to the last bit (the ISSUE 19 tentpole) —
         # including the stream that crossed the replica boundary
         def _report(ds):
-            out = []
-            for name in ("annotations.parquet", "all_metrics.parquet"):
-                df = pd.read_parquet(h1.dir / "results" / ds / name)
-                out.append(df.sort_values(["sf", "adduct"])
-                           .reset_index(drop=True))
-            return out
+            return read_result_tables(h1.dir / "results" / ds)
         gold = _report("stream_gold")
         for ds in streams:
             got = _report(ds)
-            for label, g, w in zip(("annotations", "all_metrics"),
-                                   got, gold):
+            for label, g, w in zip(RESULT_TABLES, got, gold):
                 try:
                     pd.testing.assert_frame_equal(g, w, check_exact=True)
                 except AssertionError as e:

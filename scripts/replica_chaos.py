@@ -63,7 +63,11 @@ from sm_distributed_tpu.engine.daemon import (  # noqa: E402
     QueuePublisher,
     _STATES,
 )
-from sm_distributed_tpu.engine.storage import JobLedger  # noqa: E402
+from sm_distributed_tpu.engine.storage import (  # noqa: E402
+    RESULT_TABLES,
+    JobLedger,
+    read_result_tables,
+)
 from sm_distributed_tpu.io.fixtures import generate_synthetic_dataset  # noqa: E402
 from sm_distributed_tpu.service.leases import owned_shards, shard_of  # noqa: E402
 
@@ -257,13 +261,7 @@ def _messages(imzml_path: Path, formulas: list[str],
 
 
 def _read_report(results: Path, ds_id: str):
-    import pandas as pd
-
-    out = []
-    for name in ("annotations.parquet", "all_metrics.parquet"):
-        df = pd.read_parquet(results / ds_id / name)
-        out.append(df.sort_values(["sf", "adduct"]).reset_index(drop=True))
-    return tuple(out)
+    return read_result_tables(results / ds_id)
 
 
 def run_golden(base: Path, imzml_path: Path, formulas: list[str]):
@@ -374,8 +372,7 @@ def check_invariants(base: Path, golden, msgs: list[dict],
         except Exception as exc:
             errs.append(f"{m['ds_id']}: unreadable results: {exc}")
             continue
-        for label, g, w in (("annotations", got[0], golden[0]),
-                            ("all_metrics", got[1], golden[1])):
+        for label, g, w in zip(RESULT_TABLES, got, golden):
             try:
                 pd.testing.assert_frame_equal(g, w, rtol=1e-9, atol=1e-12)
             except AssertionError as e:

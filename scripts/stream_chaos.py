@@ -60,7 +60,10 @@ from sm_distributed_tpu.engine.daemon import (  # noqa: E402
     QueuePublisher,
     _STATES,
 )
-from sm_distributed_tpu.engine.storage import JobLedger  # noqa: E402
+from sm_distributed_tpu.engine.storage import (  # noqa: E402
+    RESULT_TABLES,
+    JobLedger,
+)
 from sm_distributed_tpu.io.fixtures import (  # noqa: E402
     FIXTURE_FORMULAS,
     generate_synthetic_dataset,
@@ -354,8 +357,7 @@ def check_invariants(base: Path, golden, msg_id: str,
     except Exception as exc:
         errs.append(f"{DS_ID}: unreadable results: {exc}")
         return
-    for label, g, w in (("annotations", got[0], golden[0]),
-                        ("all_metrics", got[1], golden[1])):
+    for label, g, w in zip(RESULT_TABLES, got, golden):
         try:
             pd.testing.assert_frame_equal(g, w, check_exact=True)
         except AssertionError as e:

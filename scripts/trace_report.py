@@ -28,7 +28,10 @@ running service) into the standard perf artifact for this repo:
   ``presize`` / ``score_plan {batches, executables, band_buckets,
   variants, slots, peaks}`` (the last two: the capacity slots the planned
   extractions are handed and the peaks really inside them),
-  ``fdr {ions, targets, decoys}``, ``store_tables {rows, bytes}``;
+  ``fdr {ions, targets, decoys, rankings}`` with its ``fdr_rank`` children
+  (one a target adduct: the adducts, the targets and the decoy entries
+  ranked, summed), ``store_tables {rows, bytes}`` with ``store_assignment
+  {rows, bytes}`` (the decoy assignment the job ranked by);
 - the **device split**, when a ``/debug/profile`` capture overlapped the
   job's lease hold: device seconds per ``jax.named_scope``, busy share of
   the hold per chip, and the longest idle gaps with the program span that
@@ -82,8 +85,10 @@ _CHILDREN = {
     "score": ("backend_build", "build_sort", "build_restrict",
               "build_pad_compact", "build_device_put", "presize",
               "score_plan"),
+    "fdr": ("fdr_rank",),
     "store_results": ("store_select", "store_extract_images",
-                      "store_write_images", "store_tables"),
+                      "store_write_images", "store_tables",
+                      "store_assignment"),
 }
 # children printed with their attrs: which road the layout took
 # (io/dataset.py: ``{hmax, occupancy: walk|search}``, ``{sort: packed}``),
@@ -92,7 +97,7 @@ _CHILDREN = {
 # the batch plans and the executables they mint, the tables' rows)
 _CHILD_ATTRS = _CHILDREN["prepare_resident"] + (
     "decoy_selection", "pattern_cache_load", "presize", "score_plan",
-    "store_tables")
+    "store_tables", "store_assignment")
 
 
 def load_records(args) -> list[dict]:
@@ -251,7 +256,7 @@ def summarize(records: list[dict]) -> dict:
     children = {}
     for name in (n for names in _CHILDREN.values() for n in names):
         found = list(_spans(records, name))
-        if found:
+        if found and name != "fdr_rank":
             children[name] = _agg(found)
             if name in _CHILD_ATTRS:
                 # the first's; of a job's score_plans (one a group) the one
@@ -260,6 +265,17 @@ def summarize(records: list[dict]) -> dict:
                     "batches", 0))
                 if said.get("attrs"):
                     children[name]["attrs"] = said["attrs"]
+    # the final rankings, one a target adduct, as ONE line (partial_fdr
+    # ranks a prefix the same way: left out)
+    final = {r["span_id"] for r in _spans(records, "fdr")}
+    ranked = [r for r in _spans(records, "fdr_rank")
+              if r.get("parent_id") in final]
+    if ranked:
+        children["fdr_rank"] = {**_agg(ranked), "attrs": {
+            "adducts": ",".join(r["attrs"]["adduct"] for r in ranked),
+            "targets": sum(r["attrs"]["targets"] for r in ranked),
+            "decoy_entries": sum(r["attrs"]["decoy_entries"]
+                                 for r in ranked)}}
     device = {"scopes": {}, "busy": [], "idle": []}
     for r in _spans(records, "device_scope"):
         a = r["attrs"]
@@ -442,7 +458,8 @@ def render(s: dict) -> str:
         for c in _CHILDREN.get(p, ()):
             if c in s.get("children", {}):
                 v = s["children"][c]
-                pad = "      " if c.startswith("build_") else "    "
+                pad = "      " if c.startswith("build_") \
+                    or c == "store_assignment" else "    "
                 lines.append(f"{pad}{c:<{26 - len(pad)}}{v['seconds']:8.3f}s "
                              f"{_pct(v['seconds'], total)}  x{v['count']:<3}"
                              f"{_cpu(v)}{_road(v)}")

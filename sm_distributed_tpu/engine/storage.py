@@ -6,8 +6,9 @@ TPU-native/offline replacements for the reference's service stack (SURVEY.md
 
 - ``JobLedger``     — sqlite tables ``dataset`` / ``job`` with status rows
   (STARTED/FINISHED/FAILED), the reference's job bookkeeping.
-- ``SearchResultsStore`` — per-job parquet files (annotations + all metrics)
-  plus sparse ion images as npz, the reference's ``iso_image_metrics`` /
+- ``SearchResultsStore`` — per-job parquet files (annotations, all metrics,
+  the decoy assignment they were ranked by) plus sparse ion images as npz,
+  the reference's ``iso_image_metrics`` / ``target_decoy_add`` /
   ``iso_image`` tables.
 - ``AnnotationIndex`` — a searchable sqlite table of flattened annotations
   (ds, sf, adduct, msm, fdr, mz), the reference's Elasticsearch index:
@@ -29,6 +30,7 @@ from pathlib import Path
 import numpy as np
 import pandas as pd
 
+from ..ops.fdr import ASSIGNMENT_COLUMNS
 from ..utils import tracing
 from ..utils.failpoints import failpoint, record_recovery, register_failpoint
 from ..utils.logger import logger
@@ -41,6 +43,25 @@ FP_INDEX_COMMIT = register_failpoint(
     "inside the annotation index delete+insert, before the commit")
 FP_LEDGER_FINISH = register_failpoint(
     "ledger.finish_job", "before the job row flips STARTED -> FINISHED")
+
+# the tables of a finished job, in the order ``SearchResultsStore.store``
+# writes them; what compares two jobs' results byte for byte reads this
+RESULT_TABLES = ("annotations.parquet", "all_metrics.parquet",
+                 "target_decoy_add.parquet")
+
+
+def read_result_tables(ds_dir: str | Path) -> tuple[pd.DataFrame, ...]:
+    """A finished job's ``RESULT_TABLES`` with their rows in one order
+    (each table's ion or triple columns are a key), for comparing two jobs'
+    results bit for bit."""
+    out = []
+    for name in RESULT_TABLES:
+        df = pd.read_parquet(Path(ds_dir) / name)
+        key = [c for c in ("sf", "adduct", *ASSIGNMENT_COLUMNS[1:])
+               if c in df.columns]
+        out.append(df.sort_values(key).reset_index(drop=True))
+    return tuple(out)
+
 
 # layout marker of ion_images.npz (store_ion_images); files without one are
 # the CSR triple written before PR 25
@@ -320,7 +341,8 @@ class SearchResultsStore:
 
     def store(self, ds_id: str, job_id: int, bundle,
               ion_mzs: dict[tuple[str, str], float] | None = None) -> Path:
-        """Write annotations + metrics parquet, index annotations. Returns the
+        """Write ``RESULT_TABLES`` (annotations, metrics, the decoy
+        assignment they were ranked by), index annotations. Returns the
         dataset results dir.
 
         Write order protects the previous successful job (ADVICE r1/r2):
@@ -332,6 +354,13 @@ class SearchResultsStore:
         index never references annotations that are not on disk.
         """
         d = self.ds_dir(ds_id)
+        # the draw the job ranked by (upstream's target_decoy_add [U]): its
+        # columns were made once, with the draw, so a resident
+        # re-annotation pays the write alone
+        assignment = (bundle.assignment.frame
+                      if bundle.assignment is not None
+                      else pd.DataFrame(columns=list(ASSIGNMENT_COLUMNS),
+                                        dtype=str))
         # disk-budget preflight (ISSUE 10, service/resources.py): deny the
         # store up front — before any tmp write — when the headroom floor
         # would be breached; rough estimate, refined by the GC rescan
@@ -339,7 +368,8 @@ class SearchResultsStore:
 
         _resources.preflight(
             "storage.results_store",
-            256 * (len(bundle.annotations) + len(bundle.all_metrics)) + 8192)
+            256 * (len(bundle.annotations) + len(bundle.all_metrics))
+            + 32 * len(assignment) + 8192)
         # sweep tmp debris a crashed previous store left behind: the rerun
         # overwrites the same names, but a FAILED-then-abandoned dataset
         # must not leak .tmp files forever
@@ -348,16 +378,16 @@ class SearchResultsStore:
             p.unlink(missing_ok=True)
         if stale:
             record_recovery("storage.stale_tmp")
-        tmps = []
-        for name, df in (("annotations.parquet", bundle.annotations),
-                         ("all_metrics.parquet", bundle.all_metrics)):
-            tmp = d / (name + ".tmp")
-            df.to_parquet(tmp, index=False)
-            tmps.append((tmp, d / name))
+        tmps = [(d / (name + ".tmp"), d / name) for name in RESULT_TABLES]
+        bundle.annotations.to_parquet(tmps[0][0], index=False)
+        bundle.all_metrics.to_parquet(tmps[1][0], index=False)
         # onto the caller's store_tables span
         tracing.annotate(
             rows=len(bundle.annotations) + len(bundle.all_metrics),
-            bytes=sum(tmp.stat().st_size for tmp, _dst in tmps))
+            bytes=sum(tmp.stat().st_size for tmp, _dst in tmps[:2]))
+        with tracing.span("store_assignment", rows=len(assignment)):
+            assignment.to_parquet(tmps[2][0], index=False)
+            tracing.annotate(bytes=tmps[2][0].stat().st_size)
         tmp_t = d / "timings.json.tmp"
         tmp_t.write_text(json.dumps(bundle.timings, indent=2))
         tmps.append((tmp_t, d / "timings.json"))

@@ -21,7 +21,7 @@ import pandas as pd
 from ..io.dataset import SpectralDataset
 from ..ops import buckets as shape_buckets
 from ..ops import metrics_np
-from ..ops.fdr import FDR, DecoyAssignment
+from ..ops.fdr import FDR, DecoyAssignment, count_ranked
 from ..ops.imager_np import SortedPeakView, extract_ion_images
 from ..ops.isocalc import (
     ISOCALC_PATTERN_VERSION,
@@ -400,6 +400,10 @@ class IsotopePrefetch:
             self.assignment = self.fdr.decoy_adduct_selection(self.formulas)
             self.pairs, self.flags = self.assignment.all_ion_tuples(
                 self.formulas, iso_cfg.adducts)
+            tracing.annotate(
+                target_adducts=len(iso_cfg.adducts),
+                triples=self.assignment.n_triples,
+                distinct_decoys=self.assignment.n_distinct_decoys)
         self.timings["decoy_selection"] = time.perf_counter() - t0
         # wrapper construction loads the cache shards (warm: seconds at
         # 1.68M ions; span pattern_cache_load) — deliberately inside this
@@ -530,6 +534,9 @@ class SearchResultsBundle:
     annotations: pd.DataFrame      # target ions with fdr/fdr_level
     all_metrics: pd.DataFrame      # every scored ion incl. decoys
     timings: dict[str, float] = field(default_factory=dict)
+    # the draw the annotations were ranked by, stored beside them as
+    # target_decoy_add.parquet (None: nothing was ranked)
+    assignment: DecoyAssignment | None = None
 
 
 class MSMBasicSearch:
@@ -1196,6 +1203,9 @@ class MSMBasicSearch:
                 }
             )
             annotations = fdr.estimate_fdr(all_df[["sf", "adduct", "msm"]], assignment)
+            ranked = pd.unique(annotations["adduct"]).tolist()
+            tracing.annotate(rankings=len(ranked))
+            count_ranked(assignment, ranked)
             annotations = annotations.merge(
                 all_df[["sf", "adduct", "chaos", "spatial", "spectral"]],
                 on=["sf", "adduct"],
@@ -1205,5 +1215,6 @@ class MSMBasicSearch:
             annotations = annotations[self._ANN_COLUMNS]
             all_df = all_df[self._ALL_COLUMNS]
         return SearchResultsBundle(
-            annotations=annotations, all_metrics=all_df, timings=timings
+            annotations=annotations, all_metrics=all_df, timings=timings,
+            assignment=assignment,
         )

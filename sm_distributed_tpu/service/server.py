@@ -201,6 +201,11 @@ class AnnotationService:
                        "Isotope patterns computed per second, over the "
                        "window since the previous scrape",
                        isocalc_mod.patterns_total)
+        # what the FDR ranked (ISSUE 47): sampled triples, distinct decoy
+        # ions and rankings per target adduct of the jobs that reached fdr
+        from ..ops import fdr as fdr_mod
+
+        fdr_mod.attach_metrics(self.metrics)
         # build identity + process health (ISSUE 5 satellite): dashboards
         # need a version/backend join key and leak-spotting gauges (RSS,
         # threads, FDs) the load sweep only catches in tests

@@ -108,7 +108,10 @@ def test_the_manifest_names_the_deployment_and_its_three_metrics():
         "chaos_device_s", "moments_device_s", "chaos_roofline_pct",
         "hold_stall_s", "hold_unnamed_s", "host_cpu_per_job_s",
         "interp_late_ms", "pattern_load_s", "patterns_computed_in_window",
-        "batch_host_ms", "extract_slot_fill_pct", "plan_executables"}
+        "batch_host_ms", "extract_slot_fill_pct", "plan_executables",
+        # one ranking and one stored assignment a job, against the three of
+        # hmdb-section64-3adducts-reannotate (ISSUE 47)
+        "fdr_rank_s", "assignment_store_s"}
 
 
 @pytest.fixture(scope="module")
@@ -186,7 +189,9 @@ def test_the_traces_say_what_the_table_cost(served):
             "table_bytes": N_IONS * (16 * n_peaks + 5)}
         if i == 0:
             decoys = _one(rec, "decoy_selection")
-            assert decoys["attrs"] == {"formulas": 400, "decoys": 20}
+            assert decoys["attrs"] == {
+                "formulas": 400, "decoys": 20, "target_adducts": 1,
+                "triples": 8000, "distinct_decoys": 8000}
             load = _one(rec, "pattern_cache_load")
             assert (decoys["parent_id"] == load["parent_id"]
                     == setup["span_id"])

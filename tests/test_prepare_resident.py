@@ -20,7 +20,7 @@ import pytest
 
 from sm_distributed_tpu.engine.residency import DatasetResidency
 from sm_distributed_tpu.engine.search_job import SearchJob
-from sm_distributed_tpu.engine.storage import JobLedger
+from sm_distributed_tpu.engine.storage import JobLedger, read_result_tables
 from sm_distributed_tpu.engine.stream import (
     ChunkLog,
     StreamSearchJob,
@@ -89,11 +89,11 @@ def _spans(records, name):
 
 
 def _stored_tables(sm, ds_id):
-    root = Path(sm.storage.results_dir) / ds_id
-    return tuple(
-        pd.read_parquet(root / f).sort_values(["sf", "adduct"])
-        .reset_index(drop=True)
-        for f in ("annotations.parquet", "all_metrics.parquet"))
+    """annotations, all metrics and the decoy assignment they were ranked
+    by (``RESULT_TABLES``)."""
+    tables = read_result_tables(Path(sm.storage.results_dir) / ds_id)
+    assert len(tables) == 3 and len(tables[2]) > 0
+    return tables
 
 
 # ---------------------------------------------- (a) the product, byte for byte

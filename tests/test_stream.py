@@ -18,6 +18,7 @@ import pandas as pd
 import pytest
 
 from sm_distributed_tpu.engine.daemon import annotate_callback
+from sm_distributed_tpu.engine.storage import read_result_tables
 from sm_distributed_tpu.engine.stream import (
     ChunkConflictError,
     ChunkLog,
@@ -305,11 +306,11 @@ def _post_chunk(base, ds_id, seq, coords, spectra):
 
 
 def _report(res_dir, ds_id):
-    out = []
-    for name in ("annotations.parquet", "all_metrics.parquet"):
-        df = pd.read_parquet(res_dir / ds_id / name)
-        out.append(df.sort_values(["sf", "adduct"]).reset_index(drop=True))
-    return tuple(out)
+    """annotations, all metrics and the decoy assignment they were ranked
+    by (``RESULT_TABLES``)."""
+    tables = read_result_tables(res_dir / ds_id)
+    assert len(tables) == 3 and len(tables[2]) > 0
+    return tables
 
 
 # ----------------------------------------------- streaming-vs-batch e2e
