@@ -257,10 +257,26 @@ def kernel_chaos(side: int, interpret: bool, strips: bool, n: int = 6,
             interpret=interpret, strip_rows=strip_rows)
         route = "strips"
     else:
-        got = cp.chaos_count_sums(imgs, nrows=side, ncols=side,
-                                  nlevels=nlevels, interpret=interpret)
+        # one more program of images holding a few isolated pixels each (a
+        # decoy's noise): the kernel's label-free path, and its flag
         rp, cpad, ib = cp._pack_geometry(side, side, 512)
+        sparse = np.zeros((-n % ib + ib, side, side), np.float32)
+        for img in sparse[-n % ib:]:
+            img[2 * rng.integers(0, side // 2, 9),
+                2 * rng.integers(0, side // 2, 9)] = rng.integers(1, 31, 9)
+        imgs = np.concatenate([imgs, sparse.reshape(-1, p)])
+        n = len(imgs)
+        got, flood = cp.chaos_count_sums(imgs, nrows=side, ncols=side,
+                                         nlevels=nlevels, interpret=interpret)
         route = "lean" if rp * cpad * ib > cp._MAX_CELLS else "packed"
+        m = imgs.reshape(n, side, side) > 0
+        pair = (m[:, :, 1:] & m[:, :, :-1]).any(axis=(1, 2)) \
+            | (m[:, 1:] & m[:, :-1]).any(axis=(1, 2))
+        want_flood = pair.reshape(-1, ib).any(axis=1).astype(int).tolist()
+        check(np.asarray(flood).tolist() == want_flood
+              and want_flood[-1] == 0,
+              f"chaos {route} {side}x{side}: flood flags "
+              f"{np.asarray(flood).tolist()} != {want_flood}")
     got = np.asarray(got)
     want = [_chaos_oracle(imgs[i].reshape(side, side), nlevels)
             for i in range(n)]

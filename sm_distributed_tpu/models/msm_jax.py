@@ -150,6 +150,15 @@ def _maybe_barrier(imgs: jnp.ndarray, k: int, n_pix: int) -> jnp.ndarray:
     return jax.lax.optimization_barrier(imgs)
 
 
+def _scored_block(metrics: jnp.ndarray, programs: jnp.ndarray) -> jnp.ndarray:
+    """What a scoring program hands back: the batch's (b, 4) metric rows
+    and, as row ``b``, the chaos kernel's (sparse, flood) program counts
+    (``ops/metrics_jax.measure_of_chaos_batch``) — one array, so the
+    counts reach the host with the scores' own fetch and every ``[:n]`` of
+    the rows drops them."""
+    return jnp.concatenate([metrics, jnp.pad(programs, (0, 2))[None, :]])
+
+
 def fused_score_fn_flat_banded(
     pixel_sorted: jnp.ndarray,  # (N,) int32
     int_sorted: jnp.ndarray,   # (N,) f32
@@ -200,10 +209,10 @@ def fused_score_fn_flat_banded(
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    return batch_metrics(
+    return _scored_block(*batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
-    )
+    ))
 
 
 def _extract_sliced(
@@ -264,10 +273,10 @@ def fused_score_fn_flat_banded_sliced(
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    return batch_metrics(
+    return _scored_block(*batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
-    )
+    ))
 
 
 def _extract_compact(
@@ -326,10 +335,10 @@ def fused_score_fn_flat_banded_compact(
             gc_width=gc_width, n_pixels=nrows * ncols)
         imgs = _maybe_barrier(imgs, k, nrows * ncols)
         imgs = imgs.reshape(b, k, -1)
-    return batch_metrics(
+    return _scored_block(*batch_metrics(
         imgs, theor_ints, n_valid, nrows, ncols, nlevels,
         do_preprocessing=do_preprocessing, q=q, n_real=n_real,
-    )
+    ))
 
 
 # One row per extraction variant so the dispatch/probe sites cannot drift:
@@ -480,7 +489,7 @@ def to_numpy_global(arr) -> np.ndarray:
     return out
 
 
-def fetch_scored_batches(pending) -> list[np.ndarray]:
+def fetch_scored_batches(pending, tally=None) -> list[np.ndarray]:
     """Fetch (device_out, n) pairs concurrently, preserving order.
 
     Each result fetch is a blocking device-to-host round-trip; done
@@ -488,8 +497,17 @@ def fetch_scored_batches(pending) -> list[np.ndarray]:
     overlaps them (the GIL is released during transfers), leaving device
     compute as the floor.  (A device-side jnp.stack + single fetch was
     tried first: it compiles one concat per distinct batch count.)
+    ``tally``, where given, sees each fetched block whole before its first
+    ``n`` rows are kept (``_count_chaos_programs`` reads the row under
+    them), from the fetching threads.
     """
     from concurrent.futures import ThreadPoolExecutor
+
+    def fetch(p):
+        block = to_numpy_global(p[0])
+        if tally is not None:
+            tally(block)
+        return block[:p[1]].astype(np.float64)
 
     if not pending:
         return []
@@ -498,11 +516,9 @@ def fetch_scored_batches(pending) -> list[np.ndarray]:
         # process_allgather COLLECTIVE, and threads could issue collectives
         # in different orders on different processes (SPMD deadlock) —
         # fetch sequentially, in pending order, on every process
-        return [to_numpy_global(p[0])[:p[1]].astype(np.float64)
-                for p in pending]
+        return [fetch(p) for p in pending]
     with ThreadPoolExecutor(max_workers=min(8, len(pending))) as pool:
-        return list(pool.map(
-            lambda p: to_numpy_global(p[0])[:p[1]].astype(np.float64), pending))
+        return list(pool.map(fetch, pending))
 
 
 # Warmup persistent-cache outcomes (ISSUE 6): "hit" = the warmup manifest
@@ -534,6 +550,26 @@ def _count_chaos_images(geometry, n_ions: int) -> None:
 def chaos_image_events() -> dict:
     with _CHAOS_IMAGES_LOCK:
         return dict(_CHAOS_IMAGES)
+
+
+# Programs of the packed chaos kernel by the path each took, as the kernel
+# itself said: {"sparse": label-free blocks, "flood": labelled ones}.  Read
+# off the last row of every scored block the host fetches
+# (``_scored_block``), pad programs of a short batch included; the service
+# pulls it at scrape as sm_chaos_programs_total{path=}.
+_CHAOS_PROGRAMS = {"sparse": 0, "flood": 0}
+
+
+def _count_chaos_programs(block: np.ndarray) -> None:
+    sparse, flood = int(block[-1, 0]), int(block[-1, 1])
+    with _CHAOS_IMAGES_LOCK:
+        _CHAOS_PROGRAMS["sparse"] += sparse
+        _CHAOS_PROGRAMS["flood"] += flood
+
+
+def chaos_program_events() -> dict:
+    with _CHAOS_IMAGES_LOCK:
+        return dict(_CHAOS_PROGRAMS)
 
 
 # What extraction was handed, per variant, counted beside the chaos images:
@@ -1052,7 +1088,9 @@ class JaxBackend:
     def score_batch(self, table: IsotopePatternTable) -> np.ndarray:
         out, n = self._dispatch(table)
         # smlint: host-sync-ok[single-batch API; the caller asked for the result — pipelined callers use score_batches]
-        return np.asarray(out)[:n].astype(np.float64)
+        block = np.asarray(out)
+        _count_chaos_programs(block)
+        return block[:n].astype(np.float64)
 
     def extract_ion_images(self, table: IsotopePatternTable) -> np.ndarray:
         """(n_ions, K, n_pix) de-quantized ion images from the DEVICE cube —
@@ -1199,7 +1237,8 @@ class JaxBackend:
                 "executable kinds", len(seen))
             return
         _WARMUP_CACHE_EVENTS["miss"] += 1
-        fetch_scored_batches([self._dispatch(t, plan) for t, plan in reps])
+        fetch_scored_batches([self._dispatch(t, plan) for t, plan in reps],
+                             tally=_count_chaos_programs)
         self._write_warmup_manifest(manifest_key)
 
     def _warmup_manifest_key(self, kinds) -> str | None:
@@ -1316,7 +1355,7 @@ class JaxBackend:
         pending = [self._enqueue_traced(t, plan)
                    for t, plan in zip(tables, plans)]
         with tracing.span("device_sync", batches=len(pending)):
-            return fetch_scored_batches(pending)
+            return fetch_scored_batches(pending, tally=_count_chaos_programs)
 
     def _enqueue_traced(self, table, plan):
         """One async device dispatch, wrapped in a per-batch scoring span.

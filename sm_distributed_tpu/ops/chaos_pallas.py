@@ -26,6 +26,13 @@ not per batch:
   means "window (i-d, i] is fully masked and crosses no image boundary".
 - HBM traffic: each image is read ONCE (f32) and one count row is written —
   everything else (labels, flags, masks) lives in registers/VMEM.
+- A program whose block has no two 4-adjacent pixels above 0 (a decoy's
+  few noise pixels: most ion images of a search) floods nothing: every
+  mask pixel is its own component at every level, so a level's count is
+  its pixels above threshold.  The kernel tests that once a program and
+  writes which path it took beside the counts
+  (tests/test_chaos_pallas.py::test_cell_blocks_both_paths holds both paths
+  to scipy on the blocks the benchmark's cells run).
 
 Reference semantics: ``pyImagingMSpec.measure_of_chaos`` per-level component
 counts [U] (SURVEY.md #11); oracle: ops/metrics_np.py::measure_of_chaos.
@@ -54,7 +61,7 @@ from ..analysis.surface import compile_surface
 NUMERICS = numerics_surface(__name__, {
     "chaos_count_sums":
         "contract=bit_exact; test=tests/test_chaos_pallas.py::"
-        "test_matches_full_chaos_oracle",
+        "test_cell_blocks_both_paths",
     "chaos_count_sums_strips":
         "contract=bit_exact; test=tests/test_chaos_pallas.py::"
         "test_strip_kernel_matches_scipy",
@@ -117,9 +124,21 @@ def _seg_min_scan(v: jnp.ndarray, o: jnp.ndarray, axis: int, reverse: bool,
     return v
 
 
-def _chaos_kernel(frac_ref, img_ref, vmax_ref, out_ref, *, ncols: int,
-                  nlevels: int, lean: bool = False, work_span: int = 0):
+def _chaos_kernel(frac_ref, img_ref, vmax_ref, out_ref, flood_ref, *,
+                  ncols: int, nlevels: int, lean: bool = False,
+                  work_span: int = 0):
     """One program: IB images of shape (R, ncols) packed as (R, IB*ncols).
+
+    Two paths, chosen by what the block holds (``flood_ref[program]`` says
+    which ran: 1 flood, 0 sparse).  A block with no two 4-adjacent pixels
+    above 0 is SPARSE: level 0's mask is ``img > 0`` and masks only shrink
+    going up, so at every level each mask pixel is its own component and
+    the level's count is the number of pixels above its threshold — no
+    labels, flags or sweeps.  A pair across two packed images is excluded
+    by the same boundary guard the row scans use.  Any other block floods
+    labels as below.  Per-lane sums differ between the paths (mask pixels
+    a lane against roots a lane); the per-image sum the caller takes is
+    the same integer.
 
     ``lean``: rematerialize the mask/open-flag arrays inside every sweep
     instead of hoisting them per level.  Hoisting is faster (flags computed
@@ -191,9 +210,26 @@ def _chaos_kernel(frac_ref, img_ref, vmax_ref, out_ref, *, ncols: int,
                       keepdims=True)                   # (1, IBC) per-lane
         return acc + cnt, lab
 
-    acc = jnp.zeros((1, shape[1]), jnp.int32)
-    big = jnp.full(shape, _BIG, jnp.int32)
-    out_ref[:] = lax.fori_loop(0, nlevels, level_body, (acc, big))[0]
+    def flood_counts():
+        acc = jnp.zeros((1, shape[1]), jnp.int32)
+        big = jnp.full(shape, _BIG, jnp.int32)
+        return lax.fori_loop(0, nlevels, level_body, (acc, big))[0]
+
+    def sparse_counts():
+        # the same thresholds bit for bit (host-divided fraction, one f32
+        # multiply), ONE sublane reduction after the loop
+        def level(li, acc):
+            return acc + (img > vmax * frac_ref[li]).astype(jnp.int32)
+
+        acc = lax.fori_loop(0, nlevels, level, jnp.zeros(shape, jnp.int32))
+        return jnp.sum(acc, axis=0, keepdims=True)
+
+    m0 = (img > 0).astype(jnp.int32)
+    left = _shift(m0, 1, 1, False, np.int32(0)) * (incol != 0)
+    above = _shift(m0, 1, 0, False, np.int32(0))
+    flood = jnp.any(m0 * (left + above) > 0)
+    flood_ref[pl.program_id(0)] = flood.astype(jnp.int32)
+    out_ref[:] = lax.cond(flood, flood_counts, sparse_counts)
 
 
 # Scoped-VMEM budget for one program's block, in CELLS (rows x lanes).  The
@@ -275,8 +311,11 @@ def chaos_count_sums(
     # ions vs full-span; spans are result-invariant — the span-2 certificate
     # carries exactness, work sweeps only accelerate)
     work_span: int = 32,
-) -> jnp.ndarray:
-    """(N,) f32: per-image SUM over levels of connected-component counts.
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(sums, flood)``: (N,) f32 per-image SUM over levels of
+    connected-component counts, and (programs,) i32, 1 where that program's
+    block flooded labels and 0 where it took the label-free sparse path
+    (``_chaos_kernel``; program ``j`` holds images ``[j*IB, (j+1)*IB)``).
 
     chaos = 1 - (sum/nlevels)/n_notnull is applied by the caller (exact: the
     sums are small integers, f32-representable).
@@ -301,21 +340,27 @@ def chaos_count_sums(
 
     grid = (n_pad // ib,)
     ibc = ib * cp
-    counts = pl.pallas_call(
+    counts, flood = pl.pallas_call(
         functools.partial(_chaos_kernel, ncols=cp, nlevels=nlevels, lean=lean,
                           work_span=work_span),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad * cp), jnp.int32),
+        out_shape=(jax.ShapeDtypeStruct((1, n_pad * cp), jnp.int32),
+                   jax.ShapeDtypeStruct(grid, jnp.int32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((rp, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
+        out_specs=(
+            pl.BlockSpec((1, ibc), lambda i: (0, i), memory_space=pltpu.VMEM),
+            # one scalar a program, the whole vector resident in SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ),
         interpret=interpret,
     )(_level_fracs(nlevels), img_l, vmax_l)
     # per-image count sum: reduce each image's cp lanes
-    return counts.reshape(n_pad, cp).sum(axis=1)[:n].astype(jnp.float32)
+    sums = counts.reshape(n_pad, cp).sum(axis=1)[:n].astype(jnp.float32)
+    return sums, flood
 
 
 # ---------------------------------------------------------------------------

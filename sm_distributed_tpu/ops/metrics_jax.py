@@ -146,10 +146,14 @@ def measure_of_chaos_batch(
     use_pallas: bool | None = None,
     vmax: jnp.ndarray | None = None,       # (N,) precomputed row max
     n_notnull: jnp.ndarray | None = None,  # (N,) precomputed positive count
-) -> jnp.ndarray:
-    """(N,) chaos scores; matches metrics_np.measure_of_chaos semantics:
-    thresholds vmax * i/nlevels for i in 0..nlevels-1, 4-connectivity,
-    chaos = max(0, 1 - mean(component counts)/n_nonzero), 0 for empty.
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(chaos, programs)``: (N,) chaos scores; matches
+    metrics_np.measure_of_chaos semantics: thresholds vmax * i/nlevels for i
+    in 0..nlevels-1, 4-connectivity, chaos = max(0, 1 - mean(component
+    counts)/n_nonzero), 0 for empty.  Beside them (2,) f32: how many
+    programs of the packed kernel took its label-free sparse path and how
+    many flooded labels (``chaos_pallas._chaos_kernel``); zeros on the
+    routes that have no such programs.
 
     Three routes, all exact (the dispatch cannot change results): on TPU,
     'packed' (whole image(s) VMEM-resident, ops/chaos_pallas.py) for
@@ -160,6 +164,7 @@ def measure_of_chaos_batch(
     no pallas route fits the shape; ``False`` forces the scan path.
     """
     route = chaos_dispatch(nrows, ncols, use_pallas).route
+    programs = jnp.zeros(2, jnp.float32)
     principal = jnp.maximum(principal, 0.0)
     if vmax is None:
         # smlint: masked-ok[lattice pad pixels are exact zeros, below every positive max — vmax is the real-pixel maximum]
@@ -171,8 +176,12 @@ def measure_of_chaos_batch(
     if route == "packed":
         from .chaos_pallas import chaos_count_sums
 
-        count_sums = chaos_count_sums(
+        count_sums, flood = chaos_count_sums(
             principal, nrows=nrows, ncols=ncols, nlevels=nlevels)
+        # smlint: masked-ok[a count of programs, not of pixels: zero pads never make a pair, so a pad can only leave a program sparse]
+        n_flood = flood.sum()
+        programs = jnp.stack(
+            [flood.size - n_flood, n_flood]).astype(jnp.float32)
     elif route == "strips":
         from .chaos_pallas import chaos_count_sums_strips
 
@@ -198,7 +207,7 @@ def measure_of_chaos_batch(
     denom = (nlevels * jnp.maximum(n_notnull, 1)).astype(jnp.float32)
     chaos = 1.0 - refine_quotient(count_sums / denom, count_sums, denom)
     chaos = jnp.clip(chaos, 0.0, 1.0)
-    return jnp.where((vmax > 0) & (n_notnull > 0), chaos, 0.0)
+    return jnp.where((vmax > 0) & (n_notnull > 0), chaos, 0.0), programs
 
 
 def correlation_from_moments(
@@ -280,8 +289,10 @@ def batch_metrics(
     do_preprocessing: bool = False,
     q: float = 99.0,
     n_real=None,              # traced i32 scalar: REAL pixels (lattice pad)
-) -> jnp.ndarray:
-    """(N, 4) of (chaos, spatial, spectral, msm) for a formula batch.
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(N, 4) of (chaos, spatial, spectral, msm) for a formula batch, and
+    the (2,) sparse / flood program counts of its chaos kernel
+    (``measure_of_chaos_batch``).
 
     ``n_real`` (ISSUE 13 shape-bucket lattice): when ``nrows`` is the
     ROW-BUCKETED grid (ops/buckets.row_bucket) the trailing rows are zero
@@ -314,7 +325,7 @@ def batch_metrics(
         sums, normsq, dots, vmax, n_notnull = batch_moments(images,
                                                             n_real=n_real)
     with jax.named_scope("sm_chaos"):
-        chaos = measure_of_chaos_batch(
+        chaos, programs = measure_of_chaos_batch(
             images[:, 0, :], nrows, ncols, nlevels,
             vmax=vmax, n_notnull=n_notnull)
     with jax.named_scope("sm_epilogue"):
@@ -326,4 +337,4 @@ def batch_metrics(
         spatial = jnp.where(alive, spatial, 0.0)
         spectral = jnp.where(alive, spectral, 0.0)
         msm = chaos * spatial * spectral
-        return jnp.stack([chaos, spatial, spectral, msm], axis=1)
+        return jnp.stack([chaos, spatial, spectral, msm], axis=1), programs

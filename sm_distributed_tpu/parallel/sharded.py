@@ -175,7 +175,9 @@ def build_sharded_score_factory(
         # ``nrows`` is the (possibly row-bucketed) metric grid; ``n_real``
         # carries the dataset's true pixel count as a traced scalar so the
         # masked centering stays bit-identical on lattice padding
-        out_mine = batch_metrics(
+        # the chaos kernel's program counts stay on the shards: only the
+        # single-device programs carry them to the host (models/msm_jax.py)
+        out_mine, _programs = batch_metrics(
             imgs_mine, ti[my], nv[my], nrows, ncols, nlevels,
             do_preprocessing=do_preprocessing, q=q, n_real=n_real[0],
         )                                                # (B_loc/n_pix, 4)

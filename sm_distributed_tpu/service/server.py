@@ -217,6 +217,7 @@ class AnnotationService:
         self.metrics.add_collector(self._collect_prepare)
         self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
+        self.metrics.add_collector(self._collect_chaos_programs)
         self.metrics.add_collector(self._collect_extract_load)
         self.metrics.add_collector(self._collect_scoring_jits)
         self.metrics.add_collector(self._collect_interp_probe)
@@ -321,6 +322,22 @@ class AnnotationService:
         mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
         for (route, ib), n in (mod.chaos_image_events() if mod else {}).items():
             c = images.labels(route=route, images_per_program=str(ib))
+            c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_chaos_programs(m: MetricsRegistry) -> None:
+        """Programs of the packed chaos kernel by the path each took
+        (``models/msm_jax.py::chaos_program_events``: the kernel's own
+        flags, summed on the device and read off the scored blocks the
+        host fetches anyway).  Pulled like the chaos images above."""
+        programs = m.counter(
+            "sm_chaos_programs_total",
+            "Programs of the packed measure-of-chaos kernel, by path: "
+            "sparse (no two adjacent pixels in the block, counted without "
+            "labels) or flood", ("path",))
+        mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
+        for path, n in (mod.chaos_program_events() if mod else {}).items():
+            c = programs.labels(path=path)
             c.inc(max(0.0, n - c.value))
 
     @staticmethod

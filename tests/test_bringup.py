@@ -174,7 +174,7 @@ _AOT = textwrap.dedent('''
                  ((8, k, p), f32))
         compile_(lambda x, n: mp.batch_moments_pallas_masked.__wrapped__(
             x, n), ((8, k, p), f32), ((), i32))
-    for side in (64, 256, 512):
+    for side in (64, 128, 256, 512):
         compile_(lambda x, s=side: cp.chaos_count_sums.__wrapped__(
             x, nrows=s, ncols=s), ((16, side * side), f32))
     compile_(lambda x: cp.chaos_count_sums_strips.__wrapped__(

@@ -17,6 +17,12 @@ from sm_distributed_tpu.ops.metrics_np import measure_of_chaos
 _S4 = [[0, 1, 0], [1, 1, 1], [0, 1, 0]]
 
 
+def _sums(*args, **kwargs):
+    """The kernel's per-image count sums alone; which path each program
+    took (its second output) is held by ``test_cell_blocks_both_paths``."""
+    return chaos_count_sums(*args, **kwargs)[0]
+
+
 def _oracle_count_sum(img2d: np.ndarray, nlevels: int) -> int:
     """Sum over levels of 4-connectivity component counts, with the kernel's
     exact threshold grid (f32 vmax * i/nlevels)."""
@@ -36,7 +42,7 @@ def test_random_masks_match_scipy(rng, shape):
     n = 6
     imgs = np.where(rng.random((n, r * c)) < 0.45,
                     rng.random((n, r * c)), 0).astype(np.float32)
-    got = np.asarray(chaos_count_sums(imgs, nrows=r, ncols=c, nlevels=6,
+    got = np.asarray(_sums(imgs, nrows=r, ncols=c, nlevels=6,
                                       interpret=True))
     for i in range(n):
         assert got[i] == _oracle_count_sum(imgs[i].reshape(r, c), 6)
@@ -49,7 +55,7 @@ def test_serpentine_single_component():
         img[row, :] = 1.0
         if row + 1 < r:
             img[row + 1, c - 1 if (row // 2) % 2 == 0 else 0] = 1.0
-    got = np.asarray(chaos_count_sums(img.reshape(1, -1), nrows=r, ncols=c,
+    got = np.asarray(_sums(img.reshape(1, -1), nrows=r, ncols=c,
                                       nlevels=1, interpret=True))
     assert got[0] == 1
 
@@ -58,11 +64,11 @@ def test_empty_and_full_images():
     r = c = 8
     empty = np.zeros((1, r * c), np.float32)
     full = np.ones((1, r * c), np.float32)
-    assert np.asarray(chaos_count_sums(empty, nrows=r, ncols=c, nlevels=4,
+    assert np.asarray(_sums(empty, nrows=r, ncols=c, nlevels=4,
                                        interpret=True))[0] == 0
     # full image: every level threshold vmax*i/4 keeps i=0..3 -> mask full
     # except the last level... thresholds < vmax keep all pixels: 1 comp each
-    assert np.asarray(chaos_count_sums(full, nrows=r, ncols=c, nlevels=4,
+    assert np.asarray(_sums(full, nrows=r, ncols=c, nlevels=4,
                                        interpret=True))[0] == 4
 
 
@@ -71,7 +77,7 @@ def test_matches_full_chaos_oracle(rng):
     r, c, n, nlevels = 10, 14, 5, 8
     imgs = np.where(rng.random((n, r * c)) < 0.3,
                     rng.random((n, r * c)), 0).astype(np.float32)
-    sums = np.asarray(chaos_count_sums(imgs, nrows=r, ncols=c,
+    sums = np.asarray(_sums(imgs, nrows=r, ncols=c,
                                        nlevels=nlevels, interpret=True))
     for i in range(n):
         n_notnull = (imgs[i] > 0).sum()
@@ -89,7 +95,7 @@ def test_image_isolation_across_lane_packing(rng):
     r = c = 8
     base = np.where(rng.random(r * c) < 0.5, rng.random(r * c), 0).astype(np.float32)
     batch = np.stack([base] * 7 + [np.zeros(r * c, np.float32)])
-    got = np.asarray(chaos_count_sums(batch, nrows=r, ncols=c, nlevels=3,
+    got = np.asarray(_sums(batch, nrows=r, ncols=c, nlevels=3,
                                       interpret=True))
     assert (got[:7] == got[0]).all()
     assert got[7] == 0
@@ -119,7 +125,7 @@ def test_wide_image_lean_kernel_matches_scipy(rng):
     assert rp2 * cp2 * ib2 > _MAX_CELLS
     img = np.where(rng.random((2, r * c)) < 0.4,
                    rng.random((2, r * c)), 0).astype(np.float32)
-    got = np.asarray(chaos_count_sums(img, nrows=r, ncols=c, nlevels=3,
+    got = np.asarray(_sums(img, nrows=r, ncols=c, nlevels=3,
                                       interpret=True))
     for i in range(2):
         assert got[i] == _oracle_count_sum(img[i].reshape(r, c), 3)
@@ -210,10 +216,109 @@ def test_slide256_block_is_one_padded_image_and_matches_scipy(rng):
     r = c = 256
     img = np.where(rng.random((2, r * c)) < 0.4,
                    rng.random((2, r * c)), 0).astype(np.float32)
-    got = np.asarray(chaos_count_sums(img, nrows=r, ncols=c, nlevels=3,
+    got = np.asarray(_sums(img, nrows=r, ncols=c, nlevels=3,
                                       interpret=True))
     for i in range(2):
         assert got[i] == _oracle_count_sum(img[i].reshape(r, c), 3)
+
+
+# The three blocks the benchmark's cells run: (side, images a program,
+# images in the case: two programs each, levels).  The dense case floods
+# labels in interpret mode, so the 256x256 block runs it at fewer levels.
+_CELL_BLOCKS = {64: (8, 16), 128: (4, 8), 256: (1, 2)}
+
+
+def _isolated(rng, side, n, hi=31):
+    """``n`` images of a few pixels each with no two 4-adjacent or
+    diagonal (every pixel on an even row and an even column), integer
+    intensities."""
+    imgs = np.zeros((n, side, side), np.float32)
+    for img in imgs:
+        k = int(rng.integers(1, 13))
+        rows = 2 * rng.integers(0, side // 2, k)
+        cols = 2 * rng.integers(0, side // 2, k)
+        img[rows, cols] = rng.integers(1, hi, k)
+    return imgs
+
+
+def _case(kind, rng, side, n):
+    """(images, flood flag wanted of each of the two programs)."""
+    ib = _CELL_BLOCKS[side][0]
+    imgs = _isolated(rng, side, n)
+    if kind == "isolated":
+        return imgs, [0, 0]
+    if kind == "diagonal":
+        # diagonal neighbours only: 4-connectivity keeps them apart, so the
+        # block is still sparse and every pixel is a component
+        for img in imgs:
+            img[:] = 0
+            i = int(rng.integers(0, side - 6))
+            img[i, i], img[i + 1, i + 1], img[i + 2, i] = 3, 7, 5
+        return imgs, [0, 0]
+    if kind == "image_boundary":
+        # last column of image i, first column of image i+1, same row: lane
+        # neighbours in the block where a program packs several images, and
+        # no pair whichever path the program takes
+        imgs[0, 5, side - 1] = 9
+        imgs[1, 5, 0] = 4
+        return imgs, [0, 0]
+    if kind == "one_dense":
+        # a blob image in the SECOND program: that program floods, the
+        # first stays sparse, every image comes out right
+        dense = np.where(rng.random((side, side)) < 0.45,
+                         rng.integers(1, 31, (side, side)), 0)
+        imgs[ib] = dense
+        return imgs, [0, 1]
+    if kind == "all_zero":
+        imgs[:] = 0
+        return imgs, [0, 0]
+    if kind == "on_thresholds":
+        # vmax 30 over 30 levels: every intensity 1..29 sits ON a threshold
+        # (``_level_fracs``' case), in the sparse program and, beside one
+        # adjacent pair, in the flood one
+        for img in imgs:
+            img[0, 0] = 30
+        imgs[ib, 10, 10], imgs[ib, 10, 11] = 15, 16
+        return imgs, [0, 1]
+    raise AssertionError(kind)
+
+
+@pytest.mark.parametrize("side", sorted(_CELL_BLOCKS))
+@pytest.mark.parametrize("kind", [
+    "isolated", "diagonal", "image_boundary", "one_dense", "all_zero",
+    "on_thresholds"])
+def test_cell_blocks_both_paths(rng, side, kind):
+    """The sparse and the flood path of ``_chaos_kernel`` on the blocks the
+    cells run ([64, 512] x 8 images, [128, 512] x 4, [256, 384] x 1): sums
+    bit-equal to ``scipy.ndimage.label`` and chaos to ``metrics_np.
+    measure_of_chaos``, and the kernel's flag says which path each program
+    took."""
+    from sm_distributed_tpu.ops.chaos_pallas import chaos_geometry
+
+    ib, n = _CELL_BLOCKS[side]
+    assert chaos_geometry(side, side).images_per_program == ib
+    nlevels = 4 if (side, kind) == (256, "one_dense") else 30
+    imgs, want_flood = _case(kind, rng, side, n)
+    sums, flood = chaos_count_sums(
+        imgs.reshape(n, -1), nrows=side, ncols=side, nlevels=nlevels,
+        interpret=True)
+    sums = np.asarray(sums)
+    assert np.asarray(flood).tolist() == want_flood
+    for i in range(n):
+        assert sums[i] == _oracle_count_sum(imgs[i], nlevels), (kind, i)
+        n_notnull = int((imgs[i] > 0).sum())
+        if n_notnull:
+            chaos = np.float32(1.0) - np.float32(sums[i]) / np.float32(
+                nlevels * n_notnull)
+            assert float(np.clip(chaos, 0, 1)) == measure_of_chaos(
+                imgs[i], nlevels), (kind, i)
+        else:
+            assert sums[i] == 0 and measure_of_chaos(imgs[i], nlevels) == 0.0
+    if kind in ("isolated", "diagonal", "image_boundary"):
+        # no labels needed: a level's count is its pixels above threshold
+        want = [sum(int((img > img.max() * (np.float32(li) / np.float32(
+            nlevels))).sum()) for li in range(nlevels)) for img in imgs]
+        assert sums.tolist() == want
 
 
 def _pallas_kernels(jaxpr):
@@ -304,10 +409,10 @@ def test_work_span_result_invariant(rng):
     r, c = 16, 33
     imgs = np.where(rng.random((4, r * c)) < 0.5,
                     rng.random((4, r * c)), 0).astype(np.float32)
-    base = np.asarray(chaos_count_sums(imgs, nrows=r, ncols=c, nlevels=5,
+    base = np.asarray(_sums(imgs, nrows=r, ncols=c, nlevels=5,
                                        interpret=True, work_span=0))
     for span in (2, 3, 8, 64):
-        got = np.asarray(chaos_count_sums(imgs, nrows=r, ncols=c, nlevels=5,
+        got = np.asarray(_sums(imgs, nrows=r, ncols=c, nlevels=5,
                                           interpret=True, work_span=span))
         np.testing.assert_array_equal(got, base, err_msg=f"span={span}")
     for i in range(4):

@@ -111,7 +111,9 @@ def test_the_manifest_names_the_deployment_and_its_three_metrics():
         "batch_host_ms", "extract_slot_fill_pct", "plan_executables",
         # one ranking and one stored assignment a job, against the three of
         # hmdb-section64-3adducts-reannotate (ISSUE 47)
-        "fdr_rank_s", "assignment_store_s"}
+        "fdr_rank_s", "assignment_store_s",
+        # which path the chaos kernel's programs took (ISSUE 48)
+        "chaos_sparse_pct"}
 
 
 @pytest.fixture(scope="module")

@@ -77,7 +77,7 @@ def test_chaos_batch_matches_numpy():
     imgs.append(np.zeros((14, 14)))
     imgs.append(np.ones((14, 14)))
     batch = np.stack([im.ravel().astype(np.float32) for im in imgs])
-    got = np.asarray(measure_of_chaos_batch(jnp.asarray(batch), 14, 14, nlevels=30))
+    got = np.asarray(measure_of_chaos_batch(jnp.asarray(batch), 14, 14, nlevels=30)[0])
     want = np.array([measure_of_chaos(im.astype(np.float32), 30) for im in imgs])
     np.testing.assert_allclose(got, want, atol=1e-6)
 

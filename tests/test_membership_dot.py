@@ -81,7 +81,7 @@ def _unchunked_scores(backend, table):
         backend._px_s, backend._in_f32(), jnp.asarray(pos),
         jnp.asarray(r_lo), jnp.asarray(r_hi),
         n_pixels=common["nrows"] * common["ncols"])
-    out = jax.jit(named_partial(batch_metrics, **common))(
+    out, _programs = jax.jit(named_partial(batch_metrics, **common))(
         imgs.reshape(b_eff, table.max_peaks, -1),
         jnp.asarray(ints_p), jnp.asarray(nv_p), n_real=backend._n_real)
     return np.asarray(out)[: table.n_ions].astype(np.float64)

@@ -105,7 +105,9 @@ def test_the_manifest_names_the_deployment_its_cell_and_two_metrics():
         "chaos_device_s", "moments_device_s", "chaos_roofline_pct",
         "hold_stall_s", "hold_unnamed_s", "host_cpu_per_job_s",
         "interp_late_ms", "pattern_load_s", "patterns_computed_in_window",
-        "batch_host_ms", "extract_slot_fill_pct", "plan_executables"}
+        "batch_host_ms", "extract_slot_fill_pct", "plan_executables",
+        # which path the chaos kernel's programs took (ISSUE 48)
+        "chaos_sparse_pct"}
     # the cell reports what the other resident cells report
     e2e = {m["name"] for m in MANIFEST["end_to_end"]
            if CELL in m.get("workloads", [CELL])}
