@@ -21,7 +21,9 @@ running service) into the standard perf artifact for this repo:
   half of the build, made before the lease) with its two ``prepare_*``
   children and the road each took (``hmax``, ``occupancy``, ``sort``),
   ``backend_build`` with its four ``build_*`` children under ``score``,
-  the four ``store_*`` children under ``store_results``; what a table of
+  the four ``store_*`` children under ``store_results`` (beside
+  ``store_write_images`` the ``chunks`` the export reached the writer
+  in); what a table of
   the job's size cost: ``isotope_prefetch_setup {formulas, ions, cache}``
   with ``decoy_selection`` and ``pattern_cache_load {shards, entries,
   bytes}``, ``isotope_patterns {ions, cached, computed, gen_s}``,
@@ -97,7 +99,10 @@ _CHILDREN = {
 # the batch plans and the executables they mint, the tables' rows)
 _CHILD_ATTRS = _CHILDREN["prepare_resident"] + (
     "decoy_selection", "pattern_cache_load", "presize", "score_plan",
-    "store_tables", "store_assignment")
+    "store_write_images", "store_tables", "store_assignment")
+# of the image writer's attrs, the one that says how the export reached it
+# (engine/storage.py: ``chunks``)
+_CHILD_ATTR_KEYS = {"store_write_images": ("chunks",)}
 
 
 def load_records(args) -> list[dict]:
@@ -263,8 +268,10 @@ def summarize(records: list[dict]) -> dict:
                 # that planned the most batches
                 said = max(found, key=lambda r: (r.get("attrs") or {}).get(
                     "batches", 0))
-                if said.get("attrs"):
-                    children[name]["attrs"] = said["attrs"]
+                keys = _CHILD_ATTR_KEYS.get(name, said.get("attrs") or ())
+                if attrs := {k: v for k, v in (said.get("attrs") or {}).items()
+                             if k in keys}:
+                    children[name]["attrs"] = attrs
     # the final rankings, one a target adduct, as ONE line (partial_fdr
     # ranks a prefix the same way: left out)
     final = {r["span_id"] for r in _spans(records, "fdr")}

@@ -406,21 +406,28 @@ class SearchJob:
                 n_valid=table.n_valid[idx],
                 targets=table.targets[idx],
             )
-        # ends when the images are on the host (both extractors return numpy)
+        # the two spans abut on this thread and add up to the export's wall
+        # time: the first ends when the FIRST chunk of the images is on the
+        # host (a whole array is its own only chunk), the second runs from
+        # there to the file's rename, the rest of the chunks landing under it
+        image_format = self.sm_config.storage.image_format
         with tracing.span("store_extract_images", ions=len(idx)):
             backend = search.last_backend
-            if backend is not None and hasattr(backend, "extract_ion_images"):
-                images = backend.extract_ion_images(sub)
+            if image_format == "npz" and hasattr(backend, "iter_ion_images"):
+                images = backend.iter_ion_images(sub)
+                images.wait_first()
+            elif backend is not None and hasattr(backend, "extract_ion_images"):
+                images = np.asarray(backend.extract_ion_images(sub))
             else:
                 from ..ops.imager_np import SortedPeakView, extract_ion_images
 
                 view = SortedPeakView.prepare(ds, self.ds_config.image_generation.ppm)
-                images = extract_ion_images(view, sub, self.ds_config.image_generation.ppm)
-            images = np.asarray(images)
-            tracing.annotate(bytes=int(images.nbytes))
-        with tracing.span("store_write_images",
-                          format=self.sm_config.storage.image_format,
-                          bytes=int(images.nbytes)):
+                images = np.asarray(extract_ion_images(
+                    view, sub, self.ds_config.image_generation.ppm))
+            nbytes = int(images.nbytes)
+            tracing.annotate(bytes=nbytes)
+        with tracing.span("store_write_images", format=image_format,
+                          bytes=nbytes):
             path = self.store.store_ion_images(
                 self.ds_id, images,
                 list(zip(sub.sfs, sub.adducts)), ds.nrows, ds.ncols,

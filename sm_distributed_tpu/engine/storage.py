@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import json
 import sqlite3
+import threading
 import time
 import zipfile
 from pathlib import Path
@@ -66,6 +67,42 @@ def read_result_tables(ds_dir: str | Path) -> tuple[pd.DataFrame, ...]:
 # layout marker of ion_images.npz (store_ion_images); files without one are
 # the CSR triple written before PR 25
 IMAGE_LAYOUT = "bitmask_v1"
+
+# image exports stored, by how the images reached the writer: "streamed" in
+# more than one chunk, "whole" in one.  One a job that stores images;
+# scheduler workers share it, hence the lock; the service pulls it at scrape
+# as sm_store_exports_total{path=}.
+_STORE_EXPORTS = {"streamed": 0, "whole": 0}
+_STORE_EXPORTS_LOCK = threading.Lock()
+
+
+def _count_export(path: str) -> None:
+    with _STORE_EXPORTS_LOCK:
+        _STORE_EXPORTS[path] += 1
+
+
+def store_export_events() -> dict:
+    with _STORE_EXPORTS_LOCK:
+        return dict(_STORE_EXPORTS)
+
+
+class ImageExportError(OSError):
+    """The image file could not be made from what the export handed over."""
+
+
+class _WholeImages:
+    """A dense ``(n_ions, K, n_pix)`` array as the one-chunk case of what
+    ``store_ion_images`` consumes (``models/image_export.IonImageChunks``)."""
+
+    n_chunks, nnz = 1, None
+
+    def __init__(self, images: np.ndarray):
+        self.shape = images.shape
+        self._flat = images.reshape(images.shape[0] * images.shape[1], -1)
+
+    def __iter__(self):
+        yield self._flat
+
 
 JOB_STARTED = "STARTED"
 JOB_FINISHED = "FINISHED"
@@ -407,7 +444,7 @@ class SearchResultsStore:
     def store_ion_images(
         self,
         ds_id: str,
-        images: np.ndarray,          # (n_ions, max_peaks, n_pix) dense
+        images,                      # (n_ions, max_peaks, n_pix) dense, or its chunks
         ions: list[tuple[str, str]],
         nrows: int,
         ncols: int,
@@ -434,7 +471,15 @@ class SearchResultsStore:
         - ``shape`` ``[n_ions, K, nrows, ncols]``, ``ions`` ``"sf|adduct"``,
           ``layout`` the marker ``load_ion_images`` branches on.
 
-        ``-0.0`` is a zero and reads back ``+0.0``; ``NaN`` is a value."""
+        ``-0.0`` is a zero and reads back ``+0.0``; ``NaN`` is a value.
+
+        ``images`` is the whole array or, for the npz format, the export as
+        it leaves the device (``models/image_export.IonImageChunks``: ``shape``,
+        ``n_chunks``, ``nnz`` and the flat row chunks in order).  The same
+        loop writes both on the caller's thread, a chunk at a time, while
+        the later chunks are still on the link: a whole array is the
+        one-chunk case.  Member order and the file's bytes may differ
+        between the two; what loads may not."""
         d = self.ds_dir(ds_id)
         if self.image_format == "png":
             from .png import PngGenerator
@@ -447,34 +492,98 @@ class SearchResultsStore:
                 for k in range(ion_imgs.shape[0]):
                     gen.save(ion_imgs[k].reshape(nrows, ncols),
                              img_dir / f"{name}_{k}.png")
+            _count_export("whole")
             return img_dir
-        flat = images.reshape(images.shape[0] * images.shape[1], -1)
-        nz = flat != 0
-        members = {
-            "data": flat[nz].astype(np.float32, copy=False),
-            "mask": np.packbits(nz),
-            "shape": np.array(
-                [images.shape[0], images.shape[1], nrows, ncols]),
-            "ions": np.array([f"{sf}|{adduct}" for sf, adduct in ions]),
-            "layout": np.array(IMAGE_LAYOUT),
-        }
+        if isinstance(images, np.ndarray):
+            images = _WholeImages(images)
+        n_ions, k, n_pix = images.shape
         # tmp + atomic rename: the tile service (ISSUE 16) reads this file
         # under concurrent re-annotation — readers must see the previous
         # complete npz or the new one, never a partial write
         tmp = d / "ion_images.npz.tmp"
-        with zipfile.ZipFile(tmp, "w", zipfile.ZIP_STORED) as zf:
-            for name, arr in members.items():
-                info = zipfile.ZipInfo(name + ".npy")
-                if name == "mask":
-                    info.compress_type = zipfile.ZIP_DEFLATED
-                with zf.open(info, "w", force_zip64=True) as fid:
-                    np.lib.format.write_array(fid, arr, allow_pickle=False)
-        # onto the caller's store_write_images span: which writer ran and
-        # what it put on disk
-        tracing.annotate(layout=IMAGE_LAYOUT, nnz=int(members["data"].size),
-                         file_bytes=tmp.stat().st_size)
-        tmp.replace(d / "ion_images.npz")
+        try:
+            with zipfile.ZipFile(tmp, "w", zipfile.ZIP_STORED) as zf:
+                nnz, mask = self._write_image_values(zf, images)
+                if mask.size != (n_ions * k * n_pix + 7) // 8:
+                    raise ImageExportError(
+                        f"{tmp}: the chunks do not add up to {n_ions * k} "
+                        f"rows of {n_pix} pixels")
+                members = {
+                    "mask": mask,
+                    "shape": np.array([n_ions, k, nrows, ncols]),
+                    "ions": np.array([f"{sf}|{adduct}" for sf, adduct in ions]),
+                    "layout": np.array(IMAGE_LAYOUT),
+                }
+                for name, arr in members.items():
+                    info = zipfile.ZipInfo(name + ".npy")
+                    if name == "mask":
+                        info.compress_type = zipfile.ZIP_DEFLATED
+                    with zf.open(info, "w", force_zip64=True) as fid:
+                        np.lib.format.write_array(fid, arr, allow_pickle=False)
+            # onto the caller's store_write_images span: what the writer was
+            # handed and what it put on disk
+            tracing.annotate(layout=IMAGE_LAYOUT, nnz=nnz,
+                             file_bytes=tmp.stat().st_size,
+                             chunks=images.n_chunks)
+            tmp.replace(d / "ion_images.npz")
+        except BaseException:
+            # whatever stage failed: no partial file, and the previous
+            # job's images stay in place
+            tmp.unlink(missing_ok=True)
+            raise
+        _count_export("streamed" if images.n_chunks > 1 else "whole")
         return d / "ion_images.npz"
+
+    @staticmethod
+    def _write_image_values(zf, images):
+        """Stream ``images``' chunks into ``data.npy``: (non-zeros written,
+        the packed bit mask).  Per chunk: the non-zero mask, the values
+        under it, the packed bits.  The values go to the zip as they come
+        where the producer knows their total count up front (``images.nnz``,
+        the device's own: the header needs it, and the chunks' counts must
+        add up to it); else they wait for the last chunk, whose end is when
+        the count is known.  A chunk that ends off a byte (a producer's
+        own cut: the device's are whole bytes) leaves its last bits to the
+        next."""
+        fid, bits, waiting, count = None, [], [], 0
+        carry = np.zeros(0, bool)
+
+        def member(nnz: int):
+            fid = zf.open(zipfile.ZipInfo("data.npy"), "w", force_zip64=True)
+            header = np.lib.format.header_data_from_array_1_0(
+                np.empty(0, np.float32))
+            np.lib.format.write_array_header_1_0(
+                fid, {**header, "shape": (int(nnz),)})
+            return fid
+
+        try:
+            for chunk in images:
+                nz = chunk != 0
+                vals = chunk[nz].astype(np.float32, copy=False)
+                count += vals.size
+                nz = nz.reshape(-1)
+                if carry.size or nz.size % 8:
+                    nz = np.concatenate([carry, nz])
+                    nz, carry = np.split(nz, [nz.size // 8 * 8])
+                bits.append(np.packbits(nz))
+                if fid is None and images.nnz is not None:
+                    fid = member(images.nnz)
+                if fid is None:
+                    waiting.append(vals)
+                else:
+                    fid.write(vals.data)
+            if fid is None:
+                fid = member(count)
+                for vals in waiting:
+                    fid.write(vals.data)
+        finally:
+            if fid is not None:
+                fid.close()
+        if images.nnz is not None and count != images.nnz:
+            raise ImageExportError(
+                f"the image chunks hold {count} non-zero pixels, the "
+                f"export's own count says {int(images.nnz)}")
+        return count, np.concatenate([*bits, np.packbits(carry)])
 
     @staticmethod
     def load_ion_images(path: str | Path) -> tuple[np.ndarray, list[tuple[str, str]]]:

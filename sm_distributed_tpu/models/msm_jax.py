@@ -32,7 +32,7 @@ from ..ops.imager_jax import (
     batch_peak_band,
     batch_peak_runs,
     compact_peaks,
-    extract_images_flat,
+    export_image_chunks,
     extract_images_flat_banded,
     flat_bound_ranks,
     window_chunks,
@@ -73,11 +73,11 @@ COMPILE_SURFACE = compile_surface(__name__, {
         "statics=none; buckets=probe-only — one f32 expansion of the "
         "compact resident cube per probed backend (production expands "
         "inside the scoring jits)",
-    "extract_images_flat":
-        "statics=closure(n_pixels); buckets=one executable per bucket of "
-        "the KEPT ion count — flat-path image export at (b_x, k), b_x = "
-        "ops/buckets.export_bucket(n_ions, batch), on the row-bucketed pixel "
-        "lattice",
+    "export_image_chunks":
+        "statics=closure(n_pixels,chunk_rows); buckets=one executable per "
+        "bucket of the KEPT ion count — flat-path image export at (b_x, k), "
+        "b_x = ops/buckets.export_bucket(n_ions, batch), on the row-bucketed "
+        "pixel lattice; chunk_rows = ops/buckets.export_chunk_rows(n_pixels)",
     "ext_base":
         "statics=closure(n_pixels,gc_width,n_keep,w_cap); buckets=probe-only "
         "re-jit of the production extraction variant (probe_phases inherits "
@@ -113,9 +113,9 @@ NUMERICS = numerics_surface(__name__, {
     "expand_cube_jnp":
         "contract=bit_exact; test=tests/test_cube_compaction.py::"
         "test_compact_expand_roundtrip",
-    "extract_images_flat":
-        "contract=bit_exact; test=tests/test_jax_backend.py::"
-        "test_extraction_parity",
+    "export_image_chunks":
+        "contract=bit_exact; test=tests/test_export_stream.py::"
+        "test_chunks_are_the_export_bit_for_bit",
     "ext_base":
         "contract=bit_exact; test=tests/test_jax_backend.py::"
         "test_extraction_parity",
@@ -438,11 +438,11 @@ def make_flat_jits(common: dict) -> dict:
 
 
 def make_extract_jit(n_pixels: int):
-    """The image export's extraction jit for one (bucketed) pixel count,
-    from the same registry and under the same promise of identity."""
-    closure = {"n_pixels": int(n_pixels)}
+    """The image export's jit for one (bucketed) pixel count, its chunk rows
+    with it, from the same registry and under the same promise of identity."""
+    closure = shape_buckets.export_statics(n_pixels)
     return _shared_jits(closure, lambda: jax.jit(
-        named_partial(extract_images_flat, **closure)))[0]
+        named_partial(export_image_chunks, **closure)))[0]
 
 
 def to_numpy_global(arr) -> np.ndarray:
@@ -1097,48 +1097,48 @@ class JaxBackend:
         the annotated-subset image export no longer re-extracts on CPU
         (VERDICT r1 item 9).  Bit-identical to the numpy path (shared
         integer grids: every sum is an exact f32 integer, so the padded
-        shape cannot move a bit).  The program's static shape follows the
-        number of ions KEPT, not the scoring batch: rows pad to the
-        lattice bucket of ``n_ions`` (``ops/buckets.export_bucket``), never
-        above ``self.batch`` — one extraction-only executable per bucket seen.
-        Annotates the open span (``store_extract_images``) with the padded
-        ``rows``, the ``fetched_bytes`` and the number of device ``calls``."""
-        from .msm_basic import _slice_table
+        shape cannot move a bit).  The concatenation of
+        ``iter_ion_images``'s pieces, which is what the store consumes."""
+        chunks = self.iter_ion_images(table)
+        return np.concatenate(list(chunks)).reshape(chunks.shape)
 
-        b, n = self.batch, table.n_ions
-        # batch internally: annotated subsets can exceed formula_batch
-        tables = [table] if n <= b else [
-            _slice_table(table, s, min(s + b, n)) for s in range(0, n, b)]
-        images, rows, fetched = zip(*(self._export_images(t) for t in tables))
-        tracing.annotate(rows=sum(rows), fetched_bytes=sum(fetched),
-                         calls=len(tables))
-        return images[0] if len(images) == 1 else np.concatenate(images)
+    def iter_ion_images(self, table: IsotopePatternTable):
+        """The export as a stream of row chunks, the first device call
+        already dispatched (``models/image_export.py``, where everything
+        of the export lives that is not this module's jit call sites)."""
+        from .image_export import iter_ion_images
+
+        return iter_ion_images(self, table)
 
     def _export_images(self, table: IsotopePatternTable):
-        """One device call of ``extract_ion_images`` (``n_ions <= batch``):
-        (images, padded rows, bytes fetched)."""
+        """One device call of the export (``n_ions <= batch``), every piece
+        that holds a kept row already on its way to the host: (those pieces
+        as device arrays, padded rows, the device's (W,) non-zero counts).
+        The program's static shape follows the number of ions KEPT, not the
+        scoring batch: rows pad to the lattice bucket of ``n_ions``
+        (``ops/buckets.export_bucket``), never above ``self.batch`` — one
+        export executable per bucket seen."""
         n, k = table.n_ions, table.max_peaks
         b_x = shape_buckets.export_bucket(n, self.batch)
         grid, r_lo, r_hi, _ints, _nv = self._padded_windows(table, b_x)
         if not hasattr(self, "_extract_fn"):
-            # bucketed extraction grid (lattice): the host-side slice
-            # below takes the exact-pixel prefix, so the export is
-            # bit-identical while the executable is shared per bucket
+            # bucketed extraction grid (lattice): the host-side slice takes
+            # the exact-pixel prefix, so the export is bit-identical while
+            # the executable is shared per bucket
             self._extract_fn = make_extract_jit(self._n_pix_b)
         pos = flat_bound_ranks(self._mz_host, grid)
-        imgs = self._extract_fn(
-            self._px_s, self._in_f32(), jax.device_put(pos),
-            jax.device_put(r_lo), jax.device_put(r_hi))
-        # the fetch is the bucket's rows, read in place (no second host
-        # copy); the divide writes the kept rows only
-        # smlint: host-sync-ok[image EXPORT; the annotated-subset fetch to host is the product of this method]
-        host = np.asarray(imgs).reshape(b_x, k, -1)
-        out = host[:n, :, : self.ds.n_pixels] / np.float32(self.int_scale)  # exact power-of-two division
-        # zero out padded isotope peaks (window [0,0) is empty anyway, but
-        # keep the contract explicit)
-        valid = np.arange(k)[None, :] < table.n_valid[:, None]
-        out[~valid] = 0.0
-        return out, b_x, int(host.nbytes)
+        # per flat row: the exact reciprocal of the power-of-two scale,
+        # 0 for padded isotope peaks and for the rows that pad the bucket
+        row_scale = np.zeros((b_x, k), np.float32)
+        row_scale[:n][np.arange(k)[None, :] < table.n_valid[:, None]] = (
+            np.float32(1.0) / np.float32(self.int_scale))
+        *chunks, nnz = self._extract_fn(
+            self._px_s, self._in_f32(), *map(jax.device_put, (
+                pos, r_lo, r_hi, row_scale.reshape(-1))))
+        chunks = chunks[: -(-n * k // chunks[0].shape[0])]
+        for arr in (*chunks, nnz):
+            arr.copy_to_host_async()
+        return chunks, b_x, nnz
 
     def presize(self, tables) -> None:
         """Grow the sticky static shapes to cover ``tables`` WITHOUT scoring.

@@ -56,6 +56,11 @@ PEAK_FLOOR = 4096       # resident-peak arrays (slots are 8 bytes)
 ROW_FLOOR = 8           # image rows
 PIXEL_FLOOR = 64        # flat pixel counts (oom shape keys)
 EXPORT_FLOOR = 64       # rows of the store's image export (kept ions)
+# bytes of f32 flat rows in one chunk of the store's image export: the device
+# hands the export over in pieces of this size, each on the link at once, so
+# the host compresses and writes one while the next lands (~10 chunks at
+# 256x256 px, 3 at 128x128, one for every 64x64 export of ~300 ions)
+EXPORT_CHUNK_BYTES = 32 << 20
 
 
 def pow2ish(n: int, floor: int = 1) -> int:
@@ -134,6 +139,21 @@ def export_bucket(n_ions: int, batch: int) -> int:
     footprint, OOM-shrunk or not) — the program's shape follows what is
     fetched, not ``formula_batch``."""
     return min(int(batch), pow2ish(n_ions, EXPORT_FLOOR))
+
+
+def export_chunk_rows(n_pixels: int) -> int:
+    """Flat image rows in one chunk of the store's export at ``n_pixels``
+    columns: ``EXPORT_CHUNK_BYTES`` of f32, in whole rows and a multiple of
+    8 of them, so that every chunk's bit mask ends on a byte whatever the
+    pixel count is.  A static of the export's program: it follows the
+    (bucketed) pixel count alone, never the number of rows exported."""
+    return max(8, EXPORT_CHUNK_BYTES // (4 * int(n_pixels)) // 8 * 8)
+
+
+def export_statics(n_pixels: int) -> dict:
+    """The statics of the export's program at ``n_pixels`` columns."""
+    return {"n_pixels": int(n_pixels),
+            "chunk_rows": export_chunk_rows(n_pixels)}
 
 
 def buckets_enabled(parallel_cfg) -> bool:

@@ -599,3 +599,38 @@ def fused_score_cost_model(
         total_bytes=int(total_bytes),
         matmul_flops=float(matmul_flops),
     )
+
+
+# -- the store's image export ---------------------------------------------------
+
+def export_image_chunks(
+    pixel_sorted: jnp.ndarray,  # (N,) int32, n_pixels = overflow row
+    int_sorted: jnp.ndarray,    # (N,) f32, 0 at padding
+    pos: jnp.ndarray,           # (G,) int32 host-computed bound ranks
+    r_lo: jnp.ndarray,          # (W,) int32 leftmost rank of each lo bound
+    r_hi: jnp.ndarray,          # (W,) int32 leftmost rank of each hi bound
+    row_scale: jnp.ndarray,     # (W,) f32: 1 / int_scale, 0 where a row is padding
+    *,
+    n_pixels: int,
+    chunk_rows: int,
+) -> tuple[jnp.ndarray, ...]:
+    """The store's export, as the writer takes it: ``extract_images_flat``
+    cut into pieces of ``chunk_rows`` flat rows, each an output of its own
+    (so each leaves for the host by itself), then the (W,) i32 count of
+    non-zero pixels a row.
+
+    ``row_scale`` does on the device what the host did over the whole
+    array: the de-quantization (a multiply by the exact reciprocal of the
+    power-of-two ``int_scale``: the same bits as the division) and the
+    zeroing of the isotope peaks past ``n_valid`` and of the rows that pad
+    the bucket (every sum is a non-negative integer, so ``x * 0`` is
+    ``+0.0``).  The scatter runs once, whatever the number of pieces."""
+    imgs = extract_images_flat(
+        pixel_sorted, int_sorted, pos, r_lo, r_hi, n_pixels=n_pixels)
+    with jax.named_scope("sm_store_extract"):
+        chunks = tuple(
+            imgs[s:s + chunk_rows] * row_scale[s:s + chunk_rows, None]
+            for s in range(0, imgs.shape[0], chunk_rows))
+        nnz = jnp.concatenate([
+            jnp.count_nonzero(c, axis=1).astype(jnp.int32) for c in chunks])
+        return (*chunks, nnz)

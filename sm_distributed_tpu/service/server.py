@@ -218,6 +218,7 @@ class AnnotationService:
         self.metrics.add_collector(self._collect_ingest)
         self.metrics.add_collector(self._collect_chaos_images)
         self.metrics.add_collector(self._collect_chaos_programs)
+        self.metrics.add_collector(self._collect_store_exports)
         self.metrics.add_collector(self._collect_extract_load)
         self.metrics.add_collector(self._collect_scoring_jits)
         self.metrics.add_collector(self._collect_interp_probe)
@@ -338,6 +339,21 @@ class AnnotationService:
         mod = sys.modules.get("sm_distributed_tpu.models.msm_jax")
         for path, n in (mod.chaos_program_events() if mod else {}).items():
             c = programs.labels(path=path)
+            c.inc(max(0.0, n - c.value))
+
+    @staticmethod
+    def _collect_store_exports(m: MetricsRegistry) -> None:
+        """Image exports stored, by how the images reached the writer
+        (``engine/storage.py::store_export_events``): in more than one
+        chunk, or whole.  Pulled like the chaos programs above."""
+        exports = m.counter(
+            "sm_store_exports_total",
+            "Jobs that stored ion images, by path: streamed (the export "
+            "reached the writer in more than one chunk) or whole",
+            ("path",))
+        mod = sys.modules.get("sm_distributed_tpu.engine.storage")
+        for path, n in (mod.store_export_events() if mod else {}).items():
+            c = exports.labels(path=path)
             c.inc(max(0.0, n - c.value))
 
     @staticmethod

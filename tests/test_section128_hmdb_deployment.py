@@ -107,7 +107,9 @@ def test_the_manifest_names_the_deployment_its_cell_and_two_metrics():
         "interp_late_ms", "pattern_load_s", "patterns_computed_in_window",
         "batch_host_ms", "extract_slot_fill_pct", "plan_executables",
         # which path the chaos kernel's programs took (ISSUE 48)
-        "chaos_sparse_pct"}
+        "chaos_sparse_pct",
+        # whether the image export reached the writer in chunks (ISSUE 49)
+        "export_streamed_pct"}
     # the cell reports what the other resident cells report
     e2e = {m["name"] for m in MANIFEST["end_to_end"]
            if CELL in m.get("workloads", [CELL])}

@@ -42,8 +42,11 @@ from sm_distributed_tpu.models.msm_jax import (  # noqa: E402
     make_flat_jits,
     named_partial,
 )
-from sm_distributed_tpu.ops.buckets import peak_bucket  # noqa: E402
-from sm_distributed_tpu.ops.imager_jax import extract_images_flat  # noqa: E402
+from sm_distributed_tpu.ops.buckets import (  # noqa: E402
+    export_chunk_rows,
+    peak_bucket,
+)
+from sm_distributed_tpu.ops.imager_jax import export_image_chunks  # noqa: E402
 from sm_distributed_tpu.ops.isocalc import IsocalcWrapper  # noqa: E402
 from sm_distributed_tpu.service import primer  # noqa: E402
 from sm_distributed_tpu.service.metrics import MetricsRegistry  # noqa: E402
@@ -124,9 +127,14 @@ def _with_private_jits(backend) -> JaxBackend:
     """``backend`` as the parent built it: jits of its own."""
     for variant, (attr, *_rest) in msm_jax._VARIANTS.items():
         setattr(backend, attr, _private(variant, backend._common))
-    backend._extract_fn = jax.jit(named_partial(
-        extract_images_flat, n_pixels=backend._n_pix_b))
+    backend._extract_fn = _private_export(backend._n_pix_b)
     return backend
+
+
+def _private_export(n_pixels: int):
+    return jax.jit(named_partial(
+        export_image_chunks, n_pixels=n_pixels,
+        chunk_rows=export_chunk_rows(n_pixels)))
 
 
 def _bits(arrays):
@@ -306,11 +314,12 @@ def test_the_programs_are_the_same_programs(sections, variant):
     # the export's program too
     grid, r_lo, r_hi, _i, _n = backend._padded_windows(batch, 32)
     pos = msm_jax.flat_bound_ranks(backend._mz_host, grid)
-    ext = (backend._px_s, backend._in_s, pos, r_lo, r_hi)
+    ext = (backend._px_s, backend._in_s, pos, r_lo, r_hi,
+           np.ones(r_lo.shape, np.float32))
     ext_text = make_extract_jit(backend._n_pix_b).lower(*ext).as_text()
-    assert ext_text == jax.jit(named_partial(
-        extract_images_flat, n_pixels=backend._n_pix_b)).lower(*ext).as_text()
-    assert "module @jit_extract_images_flat " in ext_text
+    assert ext_text == _private_export(
+        backend._n_pix_b).lower(*ext).as_text()
+    assert "module @jit_export_image_chunks " in ext_text
 
 
 def test_counter_and_span_say_which_backend_shared(tmp_path, sections):
