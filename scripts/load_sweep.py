@@ -160,9 +160,8 @@ class Harness:
         self.sm_config = SMConfig.from_dict(sm)
         # the residency cache handed to the service too, as ``cli serve``
         # does: /metrics then has sm_residency_{hits,misses}_total
-        n = self.sm_config.parallel.resident_datasets
-        residency = (DatasetResidency(max_datasets=n, max_backends=n)
-                     if n > 0 else None)
+        residency = DatasetResidency.from_config(
+            self.sm_config.parallel.resident_datasets)
         self.service = AnnotationService(
             self.queue_dir,
             annotate_callback(self.sm_config, residency=residency),

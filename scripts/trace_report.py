@@ -98,11 +98,16 @@ _CHILDREN = {
 # isocalc.py, models/msm_jax.py, engine/storage.py: the cache read back,
 # the batch plans and the executables they mint, the tables' rows)
 _CHILD_ATTRS = _CHILDREN["prepare_resident"] + (
-    "decoy_selection", "pattern_cache_load", "presize", "score_plan",
-    "store_write_images", "store_tables", "store_assignment")
+    "decoy_selection", "pattern_cache_load", "backend_build", "presize",
+    "score_plan", "store_write_images", "store_tables", "store_assignment")
 # of the image writer's attrs, the one that says how the export reached it
-# (engine/storage.py: ``chunks``)
-_CHILD_ATTR_KEYS = {"store_write_images": ("chunks",)}
+# (engine/storage.py: ``chunks``); of the build's, whether it was a lookup
+# and what the residency held after it (engine/residency.py; the datasets'
+# three are on the prepare_resident line)
+_CHILD_ATTR_KEYS = {
+    "store_write_images": ("chunks",),
+    "backend_build": ("cache_hit", "residency_entries", "residency_bytes",
+                      "residency_evicted")}
 
 
 def load_records(args) -> list[dict]:

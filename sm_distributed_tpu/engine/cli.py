@@ -149,12 +149,10 @@ def cmd_serve(args) -> int:
     from ..service import AnnotationService
     from .daemon import annotate_callback
 
-    residency = None
-    if sm_config.parallel.resident_datasets > 0:
-        from .residency import DatasetResidency
+    from .residency import DatasetResidency
 
-        n = sm_config.parallel.resident_datasets
-        residency = DatasetResidency(max_datasets=n, max_backends=n)
+    residency = DatasetResidency.from_config(
+        sm_config.parallel.resident_datasets)
     service = AnnotationService(
         args.queue_dir,
         annotate_callback(sm_config, residency=residency),

@@ -311,12 +311,13 @@ def annotate_callback(sm_config: SMConfig, residency=None):
     A shared ``DatasetResidency`` keeps parsed datasets + compiled backends
     warm across messages (the reference daemon's long-lived SparkContext
     analog): a repeat job on the same dataset/shapes skips prepare and
-    compile.  ``parallel.resident_datasets = 0`` disables."""
-    if residency is None and sm_config.parallel.resident_datasets > 0:
+    compile.  ``parallel.resident_datasets``: a count of each, 0 disables,
+    ``"auto"`` bounds them by bytes (``DatasetResidency.from_config``)."""
+    if residency is None:
         from .residency import DatasetResidency
 
-        n = sm_config.parallel.resident_datasets
-        residency = DatasetResidency(max_datasets=n, max_backends=n)
+        residency = DatasetResidency.from_config(
+            sm_config.parallel.resident_datasets)
 
     def cb(msg: dict, ctx=None) -> None:
         from ..utils import tracing

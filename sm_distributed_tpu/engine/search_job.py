@@ -60,9 +60,9 @@ class SearchJob:
         self.sm_config = sm_config or SMConfig.get_conf()
         self.formulas = formulas      # explicit list overrides the mol DB
         self.profile_dir = profile_dir
-        # service mode: engine/residency.DatasetResidency shared across jobs
-        # keeps parsed datasets + compiled backends warm (SURVEY #16 analog)
-        self.residency = residency
+        # service mode: this job's hold (what it is handed stays pinned until
+        # run() lets go) on the shared engine/residency.DatasetResidency
+        self.residency = residency.job() if residency is not None else None
         # service scheduler's device lease (service/device_pool.py — still
         # Lock-protocol compatible, so a plain threading.Lock works too):
         # when set, the device-bound compile+search+store phase runs under
@@ -288,6 +288,8 @@ class SearchJob:
                 logger.error("job %d FAILED: %s", job_id, exc)
             raise
         finally:
+            if self.residency is not None:
+                self.residency.release()
             # on failure the work dir survives even with clean=True: it holds
             # the checkpoint shards + staged input the rerun resumes from
             if clean and succeeded:
@@ -350,8 +352,12 @@ class SearchJob:
         if every_chip_refuses(None if pool is None else range(pool.size)):
             return
         ppm = self.ds_config.image_generation.ppm
+        # the dataset's lookup was read_dataset's; this span says what the
+        # residency held after it and what the lookup evicted
+        held = {} if self.residency is None else \
+            self.residency.span_attrs("dataset")
         with tracing.span("prepare_resident", peaks=int(ds.n_peaks),
-                          cached=ds.flat_sorted_cached(ppm)):
+                          cached=ds.flat_sorted_cached(ppm), **held):
             ds.flat_sorted(ppm, site="pre_lease")
         if self.cancel is not None:
             self.cancel.check("prepare_resident")

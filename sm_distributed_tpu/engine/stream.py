@@ -543,6 +543,11 @@ class StreamSearchJob(SearchJob):
                            "chunk(s) failed; preview stays stale",
                            self.ds_id, len(seqs), exc_info=True)
             return
+        finally:
+            # a prefix's backend is of no use to the next prefix: let the
+            # residency have it back before the acquisition goes on
+            if self.residency is not None:
+                self.residency.release()
         self.reranks += 1
         ann = bundle.annotations
         top = ann.sort_values("msm", ascending=False).head(5)

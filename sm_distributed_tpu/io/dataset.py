@@ -167,6 +167,18 @@ class SpectralDataset:
         cache[ppm] = FlatSortedPeaks(mz_s, px_s, in_s, scale)
         return cache[ppm]
 
+    def resident_bytes(self) -> int:
+        """Host bytes this dataset weighs in a residency once a job has
+        prepared it at one ppm: the CSR arrays, the intensity grid (4 B a
+        peak) and the flat sorted layout (12 B a slot).  Reckoned whether or
+        not the layout is made yet: a job makes it right after the lookup
+        that admits the dataset (``SearchJob._prepare_resident``)."""
+        csr = sum(int(a.nbytes) for a in (
+            self.pixel_inds, self.mask, self.mzs_flat, self.ints_flat,
+            self.row_ptr))
+        slots = -(-max(self.n_peaks, 1) // 1024) * 1024
+        return csr + 4 * self.n_peaks + 12 * slots
+
     @property
     def n_spectra(self) -> int:
         return int(self.pixel_inds.size)

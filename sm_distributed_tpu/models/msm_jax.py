@@ -1366,3 +1366,36 @@ class JaxBackend:
         with tracing.span("score_batch", backend="jax_tpu",
                           ions=int(table.n_ions), enqueue=True, **dev_attr):
             return self._dispatch(table, plan)
+
+    # -- what a byte-budgeted residency asks of a backend (engine/
+    # residency.py).  Down here, below the scoring call sites, on purpose:
+    # the compile cache keys the scoring programs by those sites' LINES
+    # (tests/test_export_stream.py pins them)
+
+    @property
+    def resident_host_bytes(self) -> int:
+        """The host-side m/z index every batch's bounds are ranked against."""
+        return int(self._mz_host.nbytes)
+
+    @property
+    def scoring_reserve_bytes(self) -> int:
+        """What a batch's scoring holds on the chip BESIDE the resident
+        arrays, reckoned from shapes: the histogram scratch
+        (``build_attrs.hist_scratch_bytes``) and the batch's image block
+        (batch x isotope peaks x bucketed pixels, f32) three times over -
+        the images, one working copy (the clip's sort, chaos), and the
+        store's export, whose bucket never passes one block.  A residency
+        under a budget keeps this much of the chip free of resident
+        arrays."""
+        k = self.ds_config.isotope_generation.n_peaks
+        return int(self.build_attrs["hist_scratch_bytes"]
+                   + 3 * 4 * self.batch * k * self._n_pix_b)
+
+    def release(self) -> None:
+        """Give the resident arrays' device memory back now.  Called by the
+        residency on a backend it evicted and no job holds: the object may
+        linger until the next collection, its bytes of the chip may not."""
+        for name in ("_px_s", "_in_s", "_in_f32_cache"):
+            arr = self.__dict__.pop(name, None)
+            if arr is not None and not arr.is_deleted():
+                arr.delete()

@@ -262,12 +262,33 @@ class AnnotationService:
                          "Residency cache hits", ("cache",))
         misses = m.counter("sm_residency_misses_total",
                            "Residency cache misses", ("cache",))
+        # what the store holds and may hold (engine/residency.py): a
+        # backend's bytes are its chips', its host-side m/z index is listed
+        # as cache "backend_index"; a tier without a budget has no sample
+        held = m.gauge("sm_residency_bytes",
+                       "Bytes the residency holds, by cache", ("cache",))
+        evictions = m.counter("sm_residency_evictions_total",
+                              "Entries the residency evicted, by cache and "
+                              "by the rule that chose them", ("cache", "cause"))
         for cache in ("dataset", "backend", "ion_table"):
-            h = hits.labels(cache=cache)
-            miss = misses.labels(cache=cache)
             # counters only move forward; set via delta from the live stats
-            h.inc(max(0.0, stats[f"{cache}_hits"] - h.value))
-            miss.inc(max(0.0, stats[f"{cache}_misses"] - miss.value))
+            moved = [(hits.labels(cache=cache), stats[f"{cache}_hits"]),
+                     (misses.labels(cache=cache), stats[f"{cache}_misses"])]
+            moved += [(evictions.labels(cache=cache, cause=cause),
+                       stats["evictions"].get((cache, cause), 0))
+                      for cause in ("count", "bytes")]
+            for counter, now in moved:
+                counter.inc(max(0.0, now - counter.value))
+            held.labels(cache=cache).set(stats[f"{cache}_bytes"])
+        held.labels(cache="backend_index").set(stats["backend_host_bytes"])
+        budget = m.gauge("sm_residency_budget_bytes",
+                         "Bytes a tier of the residency may hold (device: "
+                         "the fullest chip's bytes_limit less the scoring "
+                         "reserve; host: its share of the memory available "
+                         "at start-up)", ("tier",))
+        for tier, n in stats["budget_bytes"].items():
+            if n is not None:
+                budget.labels(tier=tier).set(n)
 
     @staticmethod
     def _collect_prepare(m: MetricsRegistry) -> None:

@@ -177,8 +177,10 @@ class ParallelConfig:
     overlap_isocalc: str = "auto"
     # daemon service mode: how many datasets' parsed layouts + compiled
     # backends + finished ion tables stay resident across queue messages
-    # (LRU; 0 disables) — engine/residency.py
-    resident_datasets: int = 2
+    # (engine/residency.py, LRU): an integer N keeps the last N of each, 0
+    # disables, "auto" keeps as many as their bytes fit the chip's memory
+    # and a share of the host's (budgets computed, docs/SERVICE.md)
+    resident_datasets: int | str = 2
     # shape-bucket lattice (ISSUE 13, ops/buckets.py): "auto"/"on" snap
     # dataset-dependent shapes (pixel rows, resident peak slots, pad-to
     # batch) to the canonical power-of-two-ish lattice so every dataset
@@ -770,6 +772,11 @@ class SMConfig:
                 raise ValueError(
                     f"parallel.{knob} must be one of {valid}, "
                     f"got {v!r}{removed}")
+        n = self.parallel.resident_datasets
+        if n != "auto" and not (type(n) is int and n >= 0):
+            raise ValueError(
+                "parallel.resident_datasets must be a count >= 0 or "
+                f"\"auto\", got {n!r}")
 
     # -- singleton access, mirroring SMConfig.set_path()/get_conf() [U] --
     _instance: ClassVar["SMConfig | None"] = None

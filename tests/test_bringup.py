@@ -248,3 +248,64 @@ def test_membership_product_meets_a_pred_operand_on_v5e():
                     + proc.stdout.strip()[-200:])
     assert proc.returncode == 0 and "AOT-OK" in proc.stdout, \
         (proc.stdout + proc.stderr)[-3000:]
+
+
+# ------------- the residency's scoring reserve covers the compiled program
+_RESERVE_AOT = textwrap.dedent('''
+    import os, sys, types
+    os.environ["TPU_ACCELERATOR_TYPE"] = "v5litepod-4"
+    os.environ["TPU_WORKER_HOSTNAMES"] = "localhost"
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        dev = topologies.get_topology_desc(
+            topology_name="v5e:1x1", platform="tpu",
+            chips_per_host_bounds=(1, 1, 1)).devices[0]
+    except Exception as exc:
+        print("NO-TOPOLOGY", exc); sys.exit(77)
+    jax.default_backend = lambda: "tpu"     # the chaos route asks it
+    from sm_distributed_tpu.models.msm_jax import JaxBackend
+    from sm_distributed_tpu.service.primer import _flat_lower_call
+    from sm_distributed_tpu.utils.config import DSConfig
+
+    side, b, k, n_res = 64, 2048, 4, 3670016    # section64's own shapes
+    spec = {"kind": "flat", "variant": "band", "nrows": side, "ncols": side,
+            "nlevels": 30, "do_preprocessing": True, "q": 99.0,
+            "n_resident": n_res, "b": b, "k": k, "gc_width": 1536,
+            "n_keep": 0, "r_pad": 0, "w_cap": 2097152, "g": 2 * b * k,
+            "c": 16, "wc": 512, "w": b * k, "devices": 1}
+    fn, args, statics = _flat_lower_call(spec)
+    sh = SingleDeviceSharding(dev)
+    avals = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+             for a in args]
+    mem = fn.trace(*avals, **statics).lower(
+        lowering_platforms=("tpu",)).compile().memory_analysis()
+    scratch = 4 * (side * side + 1) * (2 * b * k + 1)
+    backend = types.SimpleNamespace(
+        ds_config=DSConfig(), batch=b, _n_pix_b=side * side,
+        build_attrs={"hist_scratch_bytes": scratch})
+    reserve = JaxBackend.scoring_reserve_bytes.fget(backend)
+    print("TEMP", mem.temp_size_in_bytes, "SCRATCH", scratch,
+          "RESERVE", reserve)
+    assert scratch < mem.temp_size_in_bytes <= reserve
+    print("AOT-OK")
+''')
+
+
+def test_the_scoring_reserve_covers_what_the_compiled_program_holds():
+    """``JaxBackend.scoring_reserve_bytes`` (histogram scratch + three image
+    blocks, from shapes) is what a byte-budgeted residency keeps free of
+    resident arrays (``engine/residency.py``); XLA:TPU's own plan for the
+    band program at ``maldi-section-64``'s shapes, the clip on, must fit
+    inside it (0.54 GB of 0.67 here; 2.16 of 2.68 at 128x128 and 8.6 of
+    10.7 at 256x256, by hand, PR 51).  Compile only."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _RESERVE_AOT], cwd=str(REPO_ROOT),
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode == 77:
+        pytest.skip("libtpu offers no compile-only topology here: "
+                    + proc.stdout.strip()[-200:])
+    assert proc.returncode == 0 and "AOT-OK" in proc.stdout, \
+        (proc.stdout + proc.stderr)[-3000:]

@@ -30,15 +30,23 @@ globals().update({name: obj for name, obj in vars(cases).items()
                   if name.startswith("test_") or name == "served"})
 
 ADDED = "maldi-section-64-hmdb-3adducts"
+# PR 51: ``maldi-section-128``'s ``dataset`` block word for word (the
+# deployment differs in what stays resident, not in the section)
+WORKINGSET = "maldi-section-128-workingset"
 
 
 def test_every_configuration_is_pinned():  # noqa: F811
-    """The six configurations ISSUE 46 found keep the bytes it pinned (the
-    parametrised case beside this one); the one added since spreads its
-    signal over the three adducts."""
+    """The configurations ISSUE 46 found (and PR 50's, of the same block)
+    keep the bytes it pinned (the parametrised case beside this one); the
+    one added by ISSUE 47 spreads its signal over the three adducts.  By
+    NAME, so that a later configuration can stand anywhere in the manifest
+    (ROADMAP C20): the pinned names are among the manifest's, and one whose
+    ``dataset`` block is a pinned one's is that section."""
     names = {c["name"] for c in cases.MANIFEST["configs"]}
-    assert names == set(cases.PARENT) | {ADDED}
+    assert set(cases.PARENT) | {ADDED} <= names
     assert cases._block(ADDED)["adducts"] == cases.THREE
+    assert WORKINGSET in names
+    assert cases._block(WORKINGSET) == cases._block("maldi-section-128")
 
 
 def test_three_adducts_through_run_cell(monkeypatch, capsys):  # noqa: F811

@@ -218,8 +218,13 @@ def test_resident_dataset_is_a_lookup_and_the_trace_orders_the_spans(
     (build,) = _spans(first, "backend_build")
     (read,) = _spans(first, "read_dataset")
     (sort,) = _spans(first, "build_sort")
-    assert prep["attrs"] == {"peaks": prep["attrs"]["peaks"], "cached": False}
+    # beside its own two attrs, what the residency held after the lookup
+    assert prep["attrs"] == {
+        "peaks": prep["attrs"]["peaks"], "cached": False,
+        "residency_entries": 1, "residency_evicted": 0,
+        "residency_bytes": prep["attrs"]["residency_bytes"]}
     assert prep["attrs"]["peaks"] > 0
+    assert prep["attrs"]["residency_bytes"] > 12 * prep["attrs"]["peaks"]
     assert prep["parent_id"] == pre["span_id"] == read["parent_id"]
     assert read["ts"] + read["dur"] <= prep["ts"] + 1e-3
     assert prep["ts"] + prep["dur"] <= hold["ts"] + 1e-3
