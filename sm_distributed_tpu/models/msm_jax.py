@@ -1383,7 +1383,7 @@ class JaxBackend:
         arrays, reckoned from shapes: the histogram scratch
         (``build_attrs.hist_scratch_bytes``) and the batch's image block
         (batch x isotope peaks x bucketed pixels, f32) three times over -
-        the images, one working copy (the clip's sort, chaos), and the
+        the images, one working copy (the clip, chaos), and the
         store's export, whose bucket never passes one block.  A residency
         under a budget keeps this much of the chip free of resident
         arrays."""

@@ -253,26 +253,26 @@ def hotspot_clip_batch(images: jnp.ndarray, q: float) -> jnp.ndarray:
     each (ion, peak) image at the q-th linear-interpolated percentile of
     its positive pixels; images with no positive pixels pass through.
 
-    ``images``: (..., P).  Masked percentile without dynamic shapes: sort
-    the row ascending (zeros first), the positives occupy the top m slots,
-    and the percentile's interpolation base sits at integer index
-    (P - m) + floor((q/100)*(m-1)).  The float arithmetic is the oracle's
-    exact single-op sequence — the integer index offset stays in integer
-    space (folding it into the float position changes rounding), and an
-    optimization barrier keeps XLA from contracting the final mul+add into
-    an FMA, whose different rounding would flip clipped-pixel bits."""
-    p = images.shape[-1]
-    srt = jnp.sort(images, axis=-1)
-    # smlint: masked-ok[zero pads are never > 0 and sort to the low slots; m and the index arithmetic are pad-count invariant by construction]
+    ``images``: (..., P).  Masked percentile without dynamic shapes and
+    without a sort: of the row's order the cutoff reads two elements, the
+    interpolation base at index lo = floor((q/100)*(m-1)) among the m
+    positives ascending, which is the (m - lo)-th LARGEST of the row, and
+    its upper neighbour (clamped to the last), the max(m - lo - 1, 1)-th:
+    ``_kth_largest_bits`` / ``_next_above_bits`` (below ``batch_metrics``).
+    The float arithmetic is the oracle's single-op sequence, the ranks stay
+    integers, and an optimization barrier keeps XLA from contracting the
+    final mul+add into an FMA, which would flip clipped-pixel bits."""
+    # smlint: masked-ok[zero pads are never > 0, and lie below every candidate of the selection's compare-and-count; m and the rank arithmetic are pad-count invariant by construction]
     m = jnp.sum(images > 0, axis=-1).astype(jnp.int32)     # (...,)
     t = np.float32(q) / np.float32(100.0)                  # host f32 constant
     pos = t * jnp.maximum(m - 1, 0).astype(jnp.float32)    # one rounded mul
     lo = jnp.floor(pos)                                    # exact
     frac = (pos - lo)[..., None]                           # exact
-    i_lo = (p - m) + lo.astype(jnp.int32)                  # integer index math
-    i_hi = jnp.minimum(i_lo + 1, p - 1)
-    v_lo = jnp.take_along_axis(srt, jnp.clip(i_lo, 0, p - 1)[..., None], axis=-1)
-    v_hi = jnp.take_along_axis(srt, i_hi[..., None], axis=-1)
+    k_lo = jnp.maximum(m - lo.astype(jnp.int32), 1)        # integer rank math
+    b_lo = _kth_largest_bits(images, k_lo)                 # (m == 0: unused)
+    b_hi = _next_above_bits(images, b_lo, k_lo)
+    v_lo = lax.bitcast_convert_type(b_lo, jnp.float32)[..., None]
+    v_hi = lax.bitcast_convert_type(b_hi, jnp.float32)[..., None]
     prod = jax.lax.optimization_barrier((v_hi - v_lo) * frac)
     cutoff = v_lo + prod                                   # (..., 1)
     clipped = jnp.minimum(images, cutoff)
@@ -301,8 +301,8 @@ def batch_metrics(
     except the correlation's mean over pixels — which divides by
     ``n_real`` with the centered block masked back to zero past it
     (moments_pallas.batch_moments) — and the hotspot percentile, whose
-    sorted-index arithmetic is pad-count invariant by construction (the
-    positives occupy the top ``m`` slots wherever the zeros sit).  Chaos
+    rank arithmetic is pad-count invariant by construction (a zero lies
+    below every candidate its selection counts against).  Chaos
     runs on the padded grid unmasked: zero pixels are below every
     threshold, so component counts, ``vmax`` and ``n_notnull`` are exact
     integers either way.  Result: metrics are bit-identical to unpadded
@@ -338,3 +338,58 @@ def batch_metrics(
         spectral = jnp.where(alive, spectral, 0.0)
         msm = chaos * spatial * spectral
         return jnp.stack([chaos, spatial, spectral, msm], axis=1), programs
+
+
+def _kth_largest_bits(images: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
+    """The bit pattern (i32) of the ``k``-th LARGEST element ((...,) i32,
+    1-based) of every row of the f32 block ``images`` (..., P), by an exact
+    selection, for ``hotspot_clip_batch``.  (It stands below
+    ``batch_metrics`` so that the lines above it stay where the persistent
+    compile cache's keys know them.)
+
+    For positive f32 the bit pattern read as i32 is monotone in the value,
+    so the ``k``-th largest is the greatest ``v`` with ``count(bits >= v)
+    >= k``: ``v`` is built from the top, bit 30 alone and then bits (29,
+    28) ... (1, 0) a PAIR a step, a step being one pass over the block that
+    compares it against the three per-row candidates of the pair and sums
+    each along the row.  Two bits a step because a pass is bound by its
+    read of the block, not by its compares (v5e, PR 52: 0.73 ms a pass of
+    537 MB with three compares, 0.71 with one; 16 passes against 31).
+    Every candidate has a bit set below the sign, so it is > 0 as an
+    integer, and ``bits >= candidate`` is false for zeros (the lattice's
+    pads, the ``valid``-masked peaks), for ``-0.0`` and for anything
+    negative: no mask pass.  What comes back is an element of the row, the
+    one a sort would have put at that rank, so nothing after it can round
+    differently; a row with fewer than ``k`` positives reads 0.  Bit
+    identity of the clip with the numpy definition was shown on the v5e
+    for the sort this replaced (PR 50, 38 jobs) and for the selection
+    (PR 52, 30 jobs of 15 runs on 12 seeds: PERF.md section 6)."""
+    bits = lax.bitcast_convert_type(images, jnp.int32)
+
+    def reaches(cand):
+        # smlint: masked-ok[a zero pad is below every candidate: the count is of real positive pixels only]
+        n_ge = jnp.sum(bits >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return (n_ge >= k).astype(jnp.int32)
+
+    def step(i, v):                  # bits (29, 28) ... (1, 0), a pair a step
+        shift = jnp.int32(28) - 2 * i
+        passed = sum(reaches(v | jnp.left_shift(jnp.int32(j), shift))
+                     for j in (1, 2, 3))           # monotone: 0, 1, 2 or 3
+        return v | jnp.left_shift(passed, shift)
+
+    top = jnp.full(images.shape[:-1], 1 << 30, jnp.int32)
+    return lax.fori_loop(0, 15, step, top * reaches(top))
+
+
+def _next_above_bits(images: jnp.ndarray, v: jnp.ndarray,
+                     k: jnp.ndarray) -> jnp.ndarray:
+    """The (k-1)-th largest of each row (the k-th where k is 1), given its
+    k-th largest ``v``: one more pass.  ``n_above = count(bits > v)`` is at
+    most k - 1; where it is k - 1 the answer is the least element above
+    ``v``, where it is less ``v`` is tied and is the answer itself."""
+    bits = lax.bitcast_convert_type(images, jnp.int32)
+    above = bits > v[..., None]
+    # smlint: masked-ok[a zero pad is never above a value >= 0]
+    n_above = jnp.sum(above, axis=-1, dtype=jnp.int32)
+    nxt = jnp.min(jnp.where(above, bits, np.int32(2**31 - 1)), axis=-1)
+    return jnp.where((k > 1) & (n_above == k - 1), nxt, v)

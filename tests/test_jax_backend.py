@@ -82,18 +82,139 @@ def test_chaos_batch_matches_numpy():
     np.testing.assert_allclose(got, want, atol=1e-6)
 
 
-def test_hotspot_clip_batch_matches_numpy():
+_SCALE = np.float32(2.0 ** -7)      # images are counts times a power of two
+
+
+def _hotspot_rows(kind: str) -> tuple[np.ndarray, np.ndarray]:
+    """``(block, real)``: the f32 block handed to ``hotspot_clip_batch`` and
+    the rows the numpy definition is asked about (the same block, but for
+    ``bucket_pads``, whose real rows end where the zero pads begin)."""
+    rng = np.random.default_rng(52)
+    p = 200
+    if kind == "dense":
+        rows = rng.integers(1, 40000, (5, p))
+        rows[:, ::7] = 0
+    elif kind == "handful":
+        rows = np.zeros((6, p), np.int64)
+        for row in rows:
+            idx = rng.choice(p, rng.integers(3, 12), replace=False)
+            row[idx] = rng.integers(1, 90000, idx.size)
+    elif kind == "m0_m1_m2":
+        rows = np.zeros((3, p), np.int64)
+        rows[1, 17] = 77
+        rows[2, [3, 150]] = [900, 5]
+    elif kind == "all_equal":
+        rows = np.stack([np.full(p, 321), np.full(p, 1)])
+    elif kind == "ties_at_cutoff":
+        # the cutoff's two neighbours inside one run of equal pixels, at
+        # the run's first and last slot, and one slot past it
+        rows = np.stack([np.sort(rng.integers(0, 3, p)) * 1000,
+                         np.r_[np.full(p - 2, 50), 60, 60],
+                         np.r_[np.full(p - 3, 50), 60, 60, 70],
+                         np.r_[np.zeros(p - 100, int), np.full(98, 50), 60, 70],
+                         rng.integers(0, 4, p) * 500])
+    elif kind == "bucket_pads":
+        rows = rng.integers(0, 5000, (4, 144))          # a 12x12 section
+        rows[1, 5:] = 0
+    elif kind == "valid_masked_block":
+        rows = rng.integers(0, 9000, (3, 4, p))
+        rows[..., ::3] = 0
+        n_valid = np.array([4, 2, 0])
+        rows = np.where((np.arange(4)[None, :] < n_valid[:, None])[..., None],
+                        rows, 0)                        # as batch_metrics masks
+    else:
+        raise AssertionError(kind)
+    real = rows.astype(np.float32) * _SCALE
+    block = real
+    if kind == "bucket_pads":                           # 12 rows -> the 16-row bucket
+        block = np.concatenate([real, np.zeros((4, 192 - 144), np.float32)], 1)
+    return block, real
+
+
+@pytest.mark.parametrize("kind", [
+    "dense", "handful", "m0_m1_m2", "all_equal", "ties_at_cutoff",
+    "bucket_pads", "valid_masked_block"])
+@pytest.mark.parametrize("q", [99.0, 95.0, 50.0, 100.0, 1.0])
+def test_hotspot_clip_batch_matches_numpy(q, kind):
+    """``hotspot_clip_batch``'s ``bit_exact`` contract (``NUMERICS``): every
+    clipped pixel has the BITS of the numpy definition's, ``np.minimum(img,
+    hotspot_percentile_f32(np.sort(img[img > 0]), q))`` in f32 - over every
+    kind of row the selection has an edge in, and the zeros the lattice and
+    the ``valid`` mask hand it."""
     import jax.numpy as jnp
     from sm_distributed_tpu.ops.metrics_jax import hotspot_clip_batch
     from sm_distributed_tpu.ops.metrics_np import hotspot_clip
 
-    rng = np.random.default_rng(5)
-    imgs = rng.exponential(1.0, size=(6, 100)).astype(np.float32)
-    imgs[imgs < 0.3] = 0.0
-    imgs[3] = 0.0
-    got = np.asarray(hotspot_clip_batch(jnp.asarray(imgs), 99.0))
-    want = np.stack([hotspot_clip(im.astype(np.float64), 99.0) for im in imgs])
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    block, real = _hotspot_rows(kind)
+    got = np.asarray(hotspot_clip_batch(jnp.asarray(block), q))
+    assert got.dtype == np.float32 and got.shape == block.shape
+    want = np.zeros_like(block)                         # f32, pads stay zero
+    for img, out in zip(real.reshape(-1, real.shape[-1]),
+                        want.reshape(-1, want.shape[-1])):
+        out[:img.size] = hotspot_clip(img, q)           # that expression
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    if q < 100.0 and kind in ("dense", "handful"):
+        assert (got < block).any()                      # something was clipped
+
+
+def _metrics_args():
+    """``batch_metrics``' arguments for three ions of four 8x8 images."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(7)
+    images = rng.integers(0, 300, (3, 4, 64)).astype(np.float32)
+    theor = np.tile(np.array([1.0, 0.5, 0.2, 0.1], np.float32), (3, 1))
+    return (jnp.asarray(images), jnp.asarray(theor),
+            jnp.asarray(np.array([4, 3, 0], np.int32)), 8, 8)
+
+
+def test_the_clipping_program_holds_no_sort():
+    """``batch_metrics`` under ``do_preprocessing`` lowers to a program
+    without a ``sort`` op: the clip's two order statistics are selected."""
+    import jax
+    from sm_distributed_tpu.ops.metrics_jax import batch_metrics
+
+    images, theor, n_valid, nrows, ncols = _metrics_args()
+    fn = jax.jit(lambda a, b, c: batch_metrics(
+        a, b, c, nrows, ncols, do_preprocessing=True, q=99.0))
+    text = fn.lower(images, theor, n_valid).as_text()
+    assert "stablehlo.while" in text                    # the selection's loop
+    assert "stablehlo.sort" not in text
+
+
+def test_without_the_flag_no_program_reaches_the_clip(monkeypatch):
+    """Without ``do_preprocessing`` a trace of ``batch_metrics`` never calls
+    ``hotspot_clip_batch``: the programs of every configuration but the
+    hot-spot one cannot change with it."""
+    import jax
+    from sm_distributed_tpu.ops import metrics_jax
+
+    def reached(*_a, **_k):
+        raise AssertionError("hotspot_clip_batch traced without the flag")
+
+    monkeypatch.setattr(metrics_jax, "hotspot_clip_batch", reached)
+    images, theor, n_valid, nrows, ncols = _metrics_args()
+    out, _programs = jax.jit(lambda a, b, c: metrics_jax.batch_metrics(
+        a, b, c, nrows, ncols, do_preprocessing=False))(images, theor, n_valid)
+    assert out.shape == (3, 4)
+    with pytest.raises(AssertionError, match="without the flag"):
+        jax.jit(lambda a, b, c: metrics_jax.batch_metrics(
+            a, b, c, nrows, ncols, do_preprocessing=True))(
+            images, theor, n_valid)
+
+
+def test_batch_metrics_stays_where_the_compile_cache_knows_it():
+    """A tripwire beside ``tests/test_export_stream.py``'s: the moments and
+    chaos kernels are traced from ``batch_metrics``, whose file and LINE
+    their Mosaic payloads carry, so a line added above it re-keys every
+    scoring executable of every configuration in a persistent compile
+    cache.  The clip's selection stands below it for that reason.  Whoever
+    has to move it: move the pin, and say so in ``CHANGES.md``."""
+    from sm_distributed_tpu.ops import metrics_jax
+
+    assert metrics_jax.batch_metrics.__code__.co_firstlineno == 282
+    assert metrics_jax._kth_largest_bits.__code__.co_firstlineno > 282
+    assert metrics_jax._next_above_bits.__code__.co_firstlineno > 282
 
 
 @pytest.mark.parametrize("restricted", [False, True],
